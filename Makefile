@@ -26,6 +26,16 @@ manifest:
 check:
 	python -m pytest tests/test_bindings.py tests/test_attr.py tests/test_infer_shape.py -q
 
+# On the chip machine only (each refuses any other platform), one
+# command per chip-tool call. smoke: the quickest proof that the trainer
+# and the serving engine still start on the chip; smoke4: the dp x tp
+# trainer and the tp=4 engine on a four-chip host.
+smoke:
+	python chip_smoke.py
+
+smoke4:
+	python chip_smoke.py --chips 4
+
 bench:
 	python bench.py
 
@@ -33,7 +43,7 @@ bench:
 # exits nonzero when a judged key (tokens/s, *_ms, bytes_accessed, ...)
 # regressed past the threshold. See doc/performance.md "Comparing
 # bench rounds".
-#   make benchdiff OLD=BENCH_r05.json NEW=BENCH_extra.json
+#   make benchdiff OLD=old/BENCH_extra.json NEW=BENCH_extra.json
 #   make benchdiff OLD=a.json NEW=b.json THRESHOLD=10 KEYS=serving
 benchdiff:
 	@test -n "$(OLD)" -a -n "$(NEW)" || \
@@ -54,9 +64,11 @@ chaos:
 # Pallas kernel tests standalone, interpret mode on CPU (doc/serving.md
 # "Fused quantized kernels"): the paged-attention kernel suite plus the
 # quantized-matmul / fused-decode kernel suite, without the rest of
-# tier-1. Fast inner loop when hacking on ops/pallas_kernels.py.
+# tier-1, plus their compiles for a described v5e chip
+# (tests/test_chip_compile.py). Fast inner loop when hacking on
+# ops/pallas_kernels.py.
 kernels:
-	JAX_PLATFORMS=cpu python -m pytest tests/test_pallas.py tests/test_pallas_quant.py -q
+	JAX_PLATFORMS=cpu python -m pytest tests/test_pallas.py tests/test_pallas_quant.py tests/test_paged_attention.py tests/test_chip_compile.py -q
 
 lint:
 	python -m compileall -q mxnet_tpu tools example
@@ -70,4 +82,4 @@ lintobs:
 clean:
 	$(MAKE) -C cpp clean
 
-.PHONY: all native examples test manifest check bench benchdiff chaos kernels lint lintobs clean
+.PHONY: all native examples test manifest check smoke smoke4 bench benchdiff chaos kernels lint lintobs clean

@@ -71,9 +71,12 @@ class Context:
     def jax_device(self):
         """Resolve this context to a concrete jax.Device.
 
-        tpu/gpu → i-th device of the default (accelerator) backend; falls
-        back to host devices when no accelerator is present so code written
-        for ``mx.tpu()`` runs unchanged on CPU test meshes.
+        tpu/gpu → i-th device of the default backend, WHATEVER its
+        platform: with no accelerator present ``mx.tpu()`` names a host
+        device, which is what lets the tests run the same programs on
+        the CPU mesh. It also means a context never proves where a
+        program ran — the entry points that measure (``chip_smoke.py``,
+        ``bench.py``) check ``jax.devices()[0].platform`` themselves.
         cpu/cpu_pinned → i-th host-platform device.
         """
         import jax
@@ -94,10 +97,12 @@ class Context:
                 devs = jax.local_devices()
         if self.device_id < len(devs):
             return devs[self.device_id]
-        # Out-of-range ids resolve to device 0 rather than erroring: tests
-        # use fake multi-device contexts on a single-device host (reference
-        # behavior: allocation fails only when touched).
-        return devs[0]
+        # an id past the last device is an error, not device 0: a
+        # program written for four chips that finds one must not pile
+        # everything onto the first without a word
+        raise MXNetError(
+            "%s: this process has %d %s device(s)"
+            % (self, len(devs), devs[0].platform))
 
 
 Context.default_ctx = Context("cpu", 0)

@@ -48,34 +48,8 @@ def initialize(coordinator=None, num_processes=None, process_id=None,
     if local_device_count is not None:
         # must run before backend init
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices",
-                              int(local_device_count))
-        except AttributeError:
-            # older jax has no jax_num_cpu_devices option; the CPU
-            # device count is an XLA flag there, read lazily at first
-            # backend init — which has not happened yet on this path.
-            # An explicit local_device_count wins over a pre-existing
-            # flag value (a stale debugging leftover would otherwise
-            # silently size the mesh wrong), loudly.
-            import logging
-            import re
-            flags = os.environ.get("XLA_FLAGS", "")
-            want = ("--xla_force_host_platform_device_count=%d"
-                    % int(local_device_count))
-            if "xla_force_host_platform_device_count" in flags:
-                updated = re.sub(
-                    r"--xla_force_host_platform_device_count=\d+",
-                    want, flags)
-                if updated != flags:
-                    logging.warning(
-                        "distributed.initialize: replacing "
-                        "xla_force_host_platform_device_count in "
-                        "XLA_FLAGS with the explicitly requested %d",
-                        int(local_device_count))
-                os.environ["XLA_FLAGS"] = updated
-            else:
-                os.environ["XLA_FLAGS"] = (flags + " " + want).strip()
+        jax.config.update("jax_num_cpu_devices",
+                          int(local_device_count))
         try:
             jax.config.update("jax_cpu_collectives_implementation", "gloo")
         except Exception:

@@ -363,7 +363,10 @@ class ImageRecordIter(DataIter):
             py.close()
 
     def __del__(self):
-        self.close()
+        try:
+            self.close()
+        except Exception:
+            pass
 
 
 class _PyEngine:

@@ -405,7 +405,7 @@ def _train_fused(symbol, ctx, arg_params, aux_params, begin_epoch,
                  eval_data=None, eval_metric=None, epoch_end_callback=None,
                  batch_end_callback=None, logger=None, kvstore=None,
                  eval_batch_end_callback=None, checkpoint_prefix=None,
-                 resume_states=None):
+                 resume_states=None, compute_dtype=None):
     """The fused training loop: protocol-identical to
     ``_train_multi_device`` (metrics, callbacks, epoch_size semantics),
     but each step is ONE donated XLA program on a dp mesh
@@ -424,7 +424,7 @@ def _train_fused(symbol, ctx, arg_params, aux_params, begin_epoch,
     mesh = _mesh_for_ctx(ctx)
     input_shapes = dict(train_data.provide_data + train_data.provide_label)
     trainer = ParallelTrainer(symbol, input_shapes, optimizer=optimizer,
-                              mesh=mesh)
+                              mesh=mesh, compute_dtype=compute_dtype)
     trainer.init_params(arg_params, aux_params)
     if resume_states is not None and _resume_blob_fits(
             resume_states, "fused", type(optimizer).__name__, logger):
@@ -770,7 +770,7 @@ class FeedForward(BASE_ESTIMATOR):
     def __init__(self, symbol, ctx=None, num_epoch=None, epoch_size=None,
                  optimizer="sgd", initializer=Uniform(0.01), numpy_batch_size=128,
                  arg_params=None, aux_params=None, allow_extra_params=False,
-                 begin_epoch=0, **kwargs):
+                 begin_epoch=0, compute_dtype=None, **kwargs):
         if isinstance(symbol, sym.Symbol):
             self.symbol = symbol
             self.sym_gen = None
@@ -796,6 +796,10 @@ class FeedForward(BASE_ESTIMATOR):
         self.initializer = initializer
         self.numpy_batch_size = numpy_batch_size
         self.begin_epoch = begin_epoch
+        # forward/backward dtype of the FUSED fit path (ParallelTrainer
+        # compute_dtype: bf16 compute, f32 master params); the legacy
+        # executor loop has no mixed precision and refuses it in fit()
+        self.compute_dtype = compute_dtype
         self._pred_exec = None
 
     def _check_arguments(self):
@@ -1070,8 +1074,16 @@ class FeedForward(BASE_ESTIMATOR):
                     kvstore=kvstore, logger=logger,
                     eval_batch_end_callback=eval_batch_end_callback,
                     checkpoint_prefix=checkpoint_prefix,
-                    resume_states=resume_states)
+                    resume_states=resume_states,
+                    compute_dtype=self.compute_dtype)
             else:
+                if self.compute_dtype is not None:
+                    raise MXNetError(
+                        "FeedForward: compute_dtype=%r needs the fused "
+                        "fit path (an all-tpu ctx, or MXNET_FUSED_FIT="
+                        "1), and this fit() is not eligible for it — "
+                        "the legacy executor loop computes in the "
+                        "parameter dtype only" % (self.compute_dtype,))
                 _train_multi_device(
                     self.symbol, self.ctx, arg_names, param_names, aux_names,
                     self.arg_params, self.aux_params,

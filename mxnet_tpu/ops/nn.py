@@ -13,7 +13,7 @@ TPU-first notes
   all collapse into this single op; ``num_group`` maps to
   ``feature_group_count``.
 * Layout is NCHW at the API surface (reference layout). XLA:TPU internally
-  relayouts to its preferred packing, so no manual NHWC plumbing is needed.
+  re-lays out to its preferred packing, so no manual NHWC plumbing is needed.
 * BatchNorm carries its moving stats as *aux state* threaded functionally
   through the executor (the reference mutates aux NDArrays in place,
   batch_norm-inl.h:93-125).

@@ -312,6 +312,12 @@ class Decoder:
                 "Decoder: matmul_impl must be 'dense', 'pallas' or "
                 "'fused', got %r (MXNET_SERVING_MATMUL_IMPL sets the "
                 "default)" % (matmul_impl,))
+        if matmul_impl == "fused":
+            from ..ops.pallas_kernels import fused_decode_unsupported
+            why = fused_decode_unsupported()
+            if why:
+                raise MXNetError(
+                    "Decoder: matmul_impl='fused' is refused: " + why)
         self._matmul_impl = matmul_impl
         if weight_dtype in ("int8", "int4"):
             from ..serving.quant import (quantize_params,
@@ -445,11 +451,9 @@ class Decoder:
     def _write_cache(self, entry, k, v, pos):
         """Insert a [B, C, H, D] K/V chunk at ``pos`` into a cache entry.
 
-        Index tuples are uniformly int32: jax 0.4.37's dynamic-slice
-        BATCHING rule concatenates the index scalars without dtype
-        promotion, so a traced per-slot ``pos`` (int32, via
-        ``_run_slots``'s vmap) mixed with python-int literals trips
-        ``lax.concatenate`` otherwise.
+        Index tuples are uniformly int32: under the package's x64 a
+        python-int literal is an int64 index next to the traced int32
+        ``pos``, and dynamic-slice wants one index dtype.
 
         A VECTOR ``pos`` ([B] int32 — the paged ``_run_slots`` batched
         walk) scatters each batch row's chunk at its own positions
@@ -532,8 +536,9 @@ class Decoder:
         chunked host-level ``fori_loop`` (``scale_fused_matmul``);
         ``"pallas"``/``"fused"`` dispatch ``quant_matmul`` — the same
         output-channel partition at the SAME chunk size
-        (``resolve_chunk``), so the two impls are bitwise identical
-        on f32 activations (pinned by the serving gauntlet)."""
+        (``resolve_chunk``, lane-legal heights only), so the two impls
+        stage identically and agree to f32 rounding; what "pallas" is
+        held to is the serving gauntlet's token-level identity."""
         from ..serving.quant import resolve_chunk, scale_fused_matmul
         if impl in (None, "dense"):
             return scale_fused_matmul(x, qt)
@@ -1020,10 +1025,10 @@ class Decoder:
         running inside the serving engine's tensor-parallel shard_map
         and ``caches`` are this shard's kv-head slice — see ``_run``.
         Composes with both impls: under ``"paged"`` each shard runs
-        the Pallas kernel against its LOCAL cache shard — the kernel's
-        (slot, kv-head, kv-block) grid takes its kv-head extent from
-        the cache operand, so inside the shard_map it is a per-shard
-        kv-head grid automatically — and the per-attention-node
+        the Pallas kernel against its LOCAL cache shard — the kernel
+        takes its kv-head count from the cache operand, so inside the
+        shard_map it walks the shard's own kv heads automatically —
+        and the per-attention-node
         all-gather rebuilds the head output exactly as in the dense
         branch (doc/serving.md "Paged attention")."""
         if impl is None:
@@ -1119,7 +1124,7 @@ class Decoder:
         """Write a ``slot_prefix_rows`` result into rows ``[0, C)`` of
         ``slot`` (traced int32) — the write half of the slot-to-slot
         prefix copy. Index tuples are uniformly int32 (see
-        ``_write_cache`` on jax 0.4.37's strict index dtypes)."""
+        ``_write_cache``)."""
         def write(full, r):
             idx = (jnp.asarray(slot, jnp.int32),) \
                 + (jnp.int32(0),) * (full.ndim - 1)

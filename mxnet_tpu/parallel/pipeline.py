@@ -66,7 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding
-from .compat import shard_map
+from jax import shard_map
 
 from ..base import MXNetError
 from .. import ndarray as nd

@@ -43,7 +43,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .compat import shard_map
+from jax import shard_map
 
 from .shard import P
 

@@ -27,7 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
-from .compat import shard_map
+from jax import shard_map
 
 from ..base import MXNetError
 from .. import ndarray as nd

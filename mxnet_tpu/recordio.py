@@ -136,13 +136,20 @@ class MXRecordIO:
     def close(self):
         if not self.is_open:
             return
+        # closed FIRST: a close that raises after the native free (at
+        # interpreter shutdown the module globals, check_call among
+        # them, are already None) must not be retried by __del__ — a
+        # second free of the same handle corrupts the heap
+        self.is_open = False
+        handle, self.handle = self.handle, None
         if self._lib is not None:
             fn = (self._lib.MXTRecordIOWriterFree if self.writable
                   else self._lib.MXTRecordIOReaderFree)
-            check_call(fn(self.handle))
+            rc = fn(handle)
+            if rc != 0:
+                check_call(rc)
         else:
-            self.handle.close()
-        self.is_open = False
+            handle.close()
 
     def __del__(self):
         try:

@@ -60,10 +60,10 @@ class PallasOp:
 
     def apply(self, *xs):
         """Traceable application on jax arrays (usable inside jit)."""
-        from jax.experimental import pallas as pl
+        from .ops.pallas_kernels import _pallas_call, _use_interpret
         interpret = self.interpret
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = _use_interpret()
         out_shapes = self._shapes_for([x.shape for x in xs])
         dtypes = self.out_dtypes or [xs[0].dtype] * len(out_shapes)
         out_shape = [jax.ShapeDtypeStruct(s, d)
@@ -77,8 +77,10 @@ class PallasOp:
             kwargs["in_specs"] = self.in_specs
         if self.out_specs is not None:
             kwargs["out_specs"] = self.out_specs
-        return pl.pallas_call(self.kernel, out_shape=out_shape,
-                              interpret=interpret, **kwargs)(*xs)
+        # traced with x64 off like the library's own kernels: a user
+        # kernel's python ints would otherwise reach Mosaic as int64
+        return _pallas_call(self.kernel, *xs, out_shape=out_shape,
+                            interpret=interpret, **kwargs)
 
     def push(self, ins, out=None):
         """Eager launch on NDArrays (reference ``Rtc.push(ins, outs, ...)``:

@@ -471,7 +471,7 @@ class InferenceEngine:
     steps_per_round : int
         Tokens decoded per dispatched round: the decode program is a
         ``lax.scan`` of this many fused all-slots steps, amortizing
-        the per-dispatch host/relay overhead k-fold (one jit call,
+        the per-dispatch host overhead k-fold (one jit call,
         one [k, S] output drain per k tokens). Admission/retirement
         granularity coarsens to k tokens — a slot freed mid-round sits
         frozen until the round ends, so k should stay well under the
@@ -1017,12 +1017,13 @@ class InferenceEngine:
         # fused quantized kernels (doc/serving.md "Fused quantized
         # kernels"): which impl the quantized matmuls trace — threaded
         # into every Decoder._run_slots/_run dispatch like attn_impl.
-        # "pallas" is bitwise-identical to "dense" (same output-
-        # channel partition at the same resolve_chunk size); "fused"
-        # additionally collapses each decode step's QKV→attention→
-        # out-proj chain into one dispatch where eligible (paged,
-        # c==1, tp=1, float KV) and falls back to the pallas product
-        # elsewhere — token-stable, so it is its OWN knob value
+        # "pallas" runs the same output-channel partition as "dense"
+        # through the Pallas kernel and agrees with it to f32 rounding
+        # (token-level identity is what the gauntlet pins, not bits —
+        # tests/test_pallas_quant.py); "fused" additionally collapses
+        # each decode step's QKV→attention→out-proj chain into one
+        # dispatch where eligible (paged, c==1, tp=1, float KV) —
+        # token-stable, so it is its OWN knob value
         if matmul_impl is None:
             matmul_impl = decoder._matmul_impl
         if matmul_impl not in ("dense", "pallas", "fused"):
@@ -1030,6 +1031,15 @@ class InferenceEngine:
                 "InferenceEngine: matmul_impl must be 'dense', "
                 "'pallas' or 'fused', got %r (MXNET_SERVING_MATMUL_"
                 "IMPL sets the default)" % (matmul_impl,))
+        if matmul_impl == "fused":
+            from ..ops.pallas_kernels import fused_decode_unsupported
+            why = fused_decode_unsupported()
+            if why:
+                # refused HERE, by name — never a quiet switch to the
+                # unfused product inside a program
+                raise MXNetError(
+                    "InferenceEngine: matmul_impl='fused' is refused: "
+                    + why)
         self.matmul_impl = matmul_impl
         # disaggregated prefill/decode (doc/serving.md "Disaggregated
         # prefill/decode"): role gates which program families ever
@@ -1401,13 +1411,13 @@ class InferenceEngine:
         collectives are the one-per-attention-node all-gathers
         ``Decoder._cached_mha`` inserts, so the program count and the
         trace-time compile log are exactly the tp=1 ones.
-        ``check_rep=False``: replication of the replicated outputs is
+        ``check_vma=False``: replication of the replicated outputs is
         by construction (identical inputs, identical per-device
         programs), not something the rep-checker can see through the
         collectives."""
         if self._mesh is None:
             return fn
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
 
         rep = PartitionSpec()
@@ -1422,7 +1432,7 @@ class InferenceEngine:
                 and not isinstance(out_specs, PartitionSpec):
             out_specs = tuple(rep if is_r(s) else s for s in out_specs)
         return shard_map(fn, mesh=self._mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+                         out_specs=out_specs, check_vma=False)
 
     def _make_step(self):
         dec = self._dec
@@ -1853,6 +1863,19 @@ class InferenceEngine:
             # drops it when no round is)
             self._phase_add("h2d", time.perf_counter() - th0)
 
+    def _put_tokens(self, padded):
+        """Host prompt tokens -> the device array every prefill
+        dispatch takes. Under tp it lands REPLICATED on the mesh (a
+        bare device_put commits to device 0, which the sharded
+        programs would reject). Staged whole prompts and host-built
+        chunks both go through here: an array on the mesh and a host
+        array are different argument TYPES to jit (the mesh is part
+        of the aval), so mixing them traced — and compiled — a
+        prefill bucket twice."""
+        if self._mesh is not None:
+            return jax.device_put(padded, self._rep_shard)
+        return jax.device_put(padded)
+
     def _place_prompt_inner(self, req):
         try:
             p = len(req.seq)
@@ -1863,11 +1886,7 @@ class InferenceEngine:
             bucket = self._bucket_for(p)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :p] = req.seq
-            # under tp the staged array must land REPLICATED on the
-            # mesh (a bare device_put commits to device 0, which the
-            # sharded programs would reject)
-            dev = jax.device_put(padded, self._rep_shard) \
-                if self._mesh is not None else jax.device_put(padded)
+            dev = self._put_tokens(padded)
             self.flight.event(req.id, "staged", bucket=bucket)
             return req, dev
         except Exception as e:               # noqa: BLE001 — isolated
@@ -2665,7 +2684,7 @@ class InferenceEngine:
             bucket = self._bucket_for(piece)
             chunk = np.zeros((1, bucket), np.int32)
             chunk[0, :piece] = req.seq[start:start + piece]
-            dev = chunk
+            dev = self._put_tokens(chunk)
         fn = self._prefill_fn(bucket)
         tp0 = time.perf_counter()
         with tele.span("serving.prefill", cat="serving", bucket=bucket,
@@ -2686,7 +2705,7 @@ class InferenceEngine:
             profiler.register_program(
                 "serving_prefill_b%d" % bucket, fn,
                 (params, aux, self._caches, self._state, np.int32(0),
-                 np.zeros((1, bucket), np.int32), np.int32(0),
+                 dev, np.int32(0),
                  np.int32(1), np.bool_(True), np.float32(0),
                  _raw_key(0), np.int32(-1), np.int32(1)))
         self.flight.event(req.id, "prefill_chunk", start=start,

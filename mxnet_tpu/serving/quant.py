@@ -271,16 +271,24 @@ def quantize_params(params, names, bits=8, group=None, row_quant=()):
     return {k: one(k, v) for k, v in params.items()}
 
 
+# Chunk heights are lane-legal: the Pallas kernel's output block carries
+# the chunk on its lane axis, where the TPU lowering takes a multiple of
+# 128 or the whole axis and nothing else. The fori walk keeps to the
+# same heights, so both impls run ONE partition on both backends (what
+# the interpreter tests is what the chip runs).
+_LANE = 128
+
+
 def _block_rows(f):
     """Default output-channel chunk height for the fused-dequant loop:
-    the largest of (256 .. 8) dividing ``f`` into at least 8 chunks —
+    the larger of (256, 128) dividing ``f`` into at least 8 chunks —
     the float staging (convert + dot read of ONE chunk) must be a
     small fraction of the int8 stream for the loop to pay, in the
     cost model and in scratch bytes alike — falling back to >= 2
-    chunks for small weights, else None (tiny weights dequantize
-    whole: same math, the loop would buy nothing)."""
+    chunks for small weights, else None (weights with no such divisor
+    dequantize whole: same math)."""
     for least in (8, 2):
-        for r in (256, 128, 64, 32, 16, 8):
+        for r in (2 * _LANE, _LANE):
             if f % r == 0 and f // r >= least:
                 return r
     return None
@@ -289,12 +297,13 @@ def _block_rows(f):
 def resolve_chunk(f):
     """Output-channel chunk for a weight with ``f`` output rows.
     ``MXNET_QUANT_CHUNK`` overrides the :func:`_block_rows` divisor
-    table explicitly; a non-divisor value is refused with a loud
+    table explicitly; a value that does not divide ``f`` or is not a
+    multiple of 128 (see ``_LANE``) is refused with a loud
     ``MXNetError`` instead of silently falling back (the silent pick
     made the staging footprint — and the cost model's read of it —
     depend on a hidden table). ``0``/unset = the auto pick. A chunk
     >= ``f`` means "dequantize whole" (returned as None, like the
-    auto path's tiny-weight fallback)."""
+    auto path's small-weight fallback)."""
     env = os.environ.get("MXNET_QUANT_CHUNK", "").strip()
     if not env or env == "0":
         return _block_rows(f)
@@ -303,12 +312,14 @@ def resolve_chunk(f):
     except ValueError:
         raise MXNetError(
             "MXNET_QUANT_CHUNK=%r is not an integer chunk size" % env)
-    if r < 0 or (r < f and f % r):
+    if r < 0 or (r < f and (f % r or r % _LANE)):
         raise MXNetError(
-            "MXNET_QUANT_CHUNK=%d must divide the weight's output-"
-            "channel count (%d here): the chunk walk partitions "
-            "output rows exactly; pick a divisor or 0 for the auto "
-            "choice" % (r, f))
+            "MXNET_QUANT_CHUNK=%d must be a multiple of %d that divides "
+            "the weight's output-channel count (%d here): the chunk "
+            "walk partitions output rows exactly, in blocks the TPU's "
+            "lane axis can hold; pick such a divisor, a value >= the "
+            "count (dequantize whole) or 0 for the auto choice"
+            % (r, _LANE, f))
     return None if r >= f else r
 
 

@@ -1,0 +1,294 @@
+"""The main path's Pallas kernels, compiled for a DESCRIBED v5e chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (``v5e:2x2``): what it refuses costs no chip
+time. Every kernel runs at the widths of the 124M model — 12 heads,
+D=64, E=768, 32 slots at L=1024 — since interpret mode (the rest of the
+kernel tests) cannot see tiling, VMEM or 64-bit-type refusals. A
+compile that passes is not a chip run: ``chip_smoke.py`` is that.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture, which skips when
+it cannot be; shardings and shapes are built in fixtures and tests,
+never at import, in a ``skipif`` or in ``parametrize`` arguments; all
+such tests live in this ONE file (only one process may load the TPU
+library, and under xdist a file goes to one worker); the persistent
+compile cache is off around them.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import pallas_kernels as pk
+
+S, L, H, D, E, V = 32, 1024, 12, 64, 768, 32000
+BF16, F32, I8, U8, I32 = (jnp.bfloat16, jnp.float32, jnp.int8,
+                          jnp.uint8, jnp.int32)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                  # no TPU compiler here
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip; keep it off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def compile_for_chip(one_chip):
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+        return text
+    return run
+
+
+def _flash(window=0):
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, window=window,
+                                  interpret=False)
+    return fwd
+
+
+def _flash_grad(window=0):
+    fwd = _flash(window)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+    return bwd
+
+
+@pytest.mark.parametrize("b,t,window", [(8, 1024, 0), (2, 4096, 0),
+                                        (8, 1024, 256)])
+def test_flash_attention_fwd(compile_for_chip, b, t, window):
+    compile_for_chip(_flash(window), *[((b, t, H, D), BF16)] * 3)
+
+
+@pytest.mark.parametrize("b,t,window", [(8, 1024, 0), (2, 4096, 0),
+                                        (8, 1024, 256)])
+def test_flash_attention_bwd(compile_for_chip, b, t, window):
+    text = compile_for_chip(_flash_grad(window),
+                            *[((b, t, H, D), BF16)] * 3)
+    assert text.count("tpu_custom_call") >= 3       # fwd, dQ, dK/dV
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from mxnet_tpu.parallel import build_mesh
+    return build_mesh({"dp": 2, "tp": 2}, topo.devices)
+
+
+@pytest.mark.parametrize("fn", ["fwd", "bwd"])
+def test_flash_attention_over_dp_x_tp(mesh_2x2, fn):
+    """The four-chip trainer's attention: operands sharded batch over
+    dp and heads over tp on the described 2x2. Under ``kernel_mesh``
+    (as ``ParallelTrainer`` traces its step) the kernel partitions
+    itself and compiles; without it the compiler refuses the Mosaic
+    call, which the CPU interpreter — it inlines kernels — never
+    shows."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sh = NamedSharding(mesh_2x2, P("dp", None, "tp", None))
+    args = [jax.ShapeDtypeStruct((8, 1024, H, D), BF16, sharding=sh)] * 3
+    f = jax.jit(_flash() if fn == "fwd" else _flash_grad())
+    with pk.kernel_mesh(mesh_2x2):
+        text = f.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= (1 if fn == "fwd" else 3)
+    with pytest.raises(Exception, match="cannot be automatically "
+                                        "partitioned"):
+        jax.jit(_flash() if fn == "fwd" else _flash_grad()) \
+            .lower(*args).compile()
+
+
+@pytest.mark.parametrize("chunk,kv,qdt", [
+    (1, H, "bfloat16"),     # plain decode
+    (5, H, "bfloat16"),     # speculative verify chunk
+    (1, 4, "bfloat16"),     # grouped-query: 3 query heads per kv head
+    (1, 3, "bfloat16"),     # a tp=4 shard: 3 local kv heads, 192 lanes
+    (1, H, "float32"),
+])
+def test_paged_attention_float_kv(compile_for_chip, chunk, kv, qdt):
+    h = H if kv in (H, 4) else kv
+    qdt = jnp.dtype(qdt)
+    compile_for_chip(
+        lambda q, k, v, p: pk.paged_attention(q, k, v, p,
+                                              interpret=False),
+        ((S, chunk, h, D), qdt), ((S, L, kv, D), qdt),
+        ((S, L, kv, D), qdt), ((S,), I32))
+
+
+@pytest.mark.parametrize("chunk", [1, 5])
+def test_paged_attention_int8_kv(compile_for_chip, chunk):
+    compile_for_chip(
+        lambda q, k, v, p, ks, vs: pk.paged_attention(
+            q, k, v, p, k_scale=ks, v_scale=vs, interpret=False),
+        ((S, chunk, H, D), BF16), ((S, L, H, D), I8), ((S, L, H, D), I8),
+        ((S,), I32), ((S, L, H), F32), ((S, L, H), F32))
+
+
+# (rows, contraction, output channels): the QKV, out, FFN and
+# unembedding projections of a decode round (m=32), of one sequence and
+# of an odd-length prompt (rows ride whole, unpadded)
+_QMM = [(32, E, 3 * E), (32, E, E), (32, E, 4 * E), (32, 4 * E, E),
+        (32, E, V), (1, E, 3 * E), (3, E, 3 * E)]
+
+
+@pytest.mark.parametrize("m,e,f", _QMM)
+@pytest.mark.parametrize("xdt", ["bfloat16", "float32"])
+def test_quant_matmul_int8(compile_for_chip, m, e, f, xdt):
+    compile_for_chip(
+        lambda x, q, s: pk.quant_matmul(x, q, s, bits=8,
+                                        interpret=False),
+        ((m, e), jnp.dtype(xdt)), ((f, e), I8), ((f,), F32))
+
+
+@pytest.mark.parametrize("m,e,f", _QMM)
+@pytest.mark.parametrize("xdt", ["bfloat16", "float32"])
+def test_quant_matmul_int4(compile_for_chip, m, e, f, xdt):
+    group = 64
+    compile_for_chip(
+        lambda x, q, s: pk.quant_matmul(x, q, s, bits=4, group=group,
+                                        interpret=False),
+        ((m, e), jnp.dtype(xdt)), ((f, e // 2), U8),
+        ((f, e // group), F32))
+
+
+def test_quant_matmul_engine_chunks_are_lane_legal(compile_for_chip):
+    """The kernel compiles at the chunk the engine passes it
+    (``resolve_chunk``, shared with the fori walk) for every
+    projection of the model; a chunk the lane axis cannot hold is
+    refused before the compiler sees it, never replaced."""
+    from mxnet_tpu.serving.quant import resolve_chunk
+    for f in (3 * E, E, 4 * E, V):
+        chunk = resolve_chunk(f)
+        assert chunk in (128, 256) and f % chunk == 0
+        compile_for_chip(
+            lambda x, q, s: pk.quant_matmul(x, q, s, bits=8,
+                                            block_f=chunk,
+                                            interpret=False),
+            ((32, E), BF16), ((f, E), I8), ((f,), F32))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        pk.quant_matmul(jnp.zeros((32, E), BF16), jnp.zeros((E, E), I8),
+                        jnp.ones((E,), F32), bits=8, block_f=64,
+                        interpret=False)
+
+
+@pytest.mark.parametrize("m", [8192, 32])
+def test_fused_linear(compile_for_chip, m):
+    compile_for_chip(
+        lambda x, w, b: pk.fused_linear(x, w, b, "relu",
+                                        interpret=False),
+        ((m, E), BF16), ((E, 4 * E), BF16), ((4 * E,), BF16))
+
+
+def test_matmul_stats(compile_for_chip):
+    compile_for_chip(
+        lambda x, w: pk.matmul_stats(x, w, interpret=False),
+        ((256 * 56 * 56, 64), BF16), ((64, 256), BF16))
+
+
+def test_fused_conv_bn_act(compile_for_chip):
+    compile_for_chip(
+        lambda x, w, s, b: pk.fused_conv_bn_act(
+            x, w, s, b, stride=(1, 1), pad=(1, 1), interpret=False),
+        ((32, 64, 56, 56), BF16), ((64, 64, 3, 3), BF16), ((64,), F32),
+        ((64,), F32))
+
+
+def test_striped_pair_attention(compile_for_chip):
+    def fwd(q, k, v, a, b):
+        return pk.striped_pair_attention(q, k, v, a, b, n_stride=4,
+                                         interpret=False)
+
+    def bwd(q, k, v, a, b):
+        return jax.grad(
+            lambda q, k, v: sum(x.astype(F32).sum()
+                                for x in fwd(q, k, v, a, b)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    qkv = [((8 * H, 1024, D), BF16)] * 3 + [((), I32)] * 2
+    compile_for_chip(fwd, *qkv)
+    compile_for_chip(bwd, *qkv)
+
+
+def test_rtc_user_kernel(compile_for_chip):
+    """A user kernel with plain python ints: traced x64-off like the
+    library's own, or Mosaic would see int64."""
+    from mxnet_tpu.rtc import Rtc
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2 + 1
+
+    rtc = Rtc("double_plus_one", kernel, [(256, 512)], interpret=False)
+    compile_for_chip(rtc.apply, ((256, 512), F32))
+
+
+def test_fused_decode_refused_loudly(monkeypatch, one_chip):
+    """``fused_decode_attention`` does not compile for the chip, and
+    where kernels are compiled the engine says so at construction — by
+    the kernel's name and the compiler's reason — instead of giving
+    way to the unfused product."""
+    def lower():
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in (((S, E), BF16), ((S,), I32),
+                             ((S, L, H, D), BF16), ((S, L, H, D), BF16),
+                             ((3 * E, E), I8), ((3 * E,), F32),
+                             ((3 * E,), F32), ((E, E), I8), ((E,), F32),
+                             ((E,), F32))]
+        return jax.jit(lambda *a: pk.fused_decode_attention(
+            *a, heads=H, kv_heads=H, bits=8, interpret=False)
+        ).lower(*args).compile()
+
+    with pytest.raises(Exception, match="last two dimensions"):
+        lower()
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_transformer_lm
+    sym = get_transformer_lm(17, num_layers=1, embed_dim=16, num_heads=2,
+                             impl="dense")
+    shapes = {"data": (2, 8), "softmax_label": (2, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    rng = np.random.RandomState(0)
+    params = {n: jnp.asarray(rng.uniform(-0.3, 0.3, s).astype(np.float32))
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    dec = mx.parallel.Decoder(sym, params, max_len=8)
+    # as on the chip: kernels compiled, not interpreted
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    with pytest.raises(MXNetError,
+                       match="fused_decode_attention.*last two "
+                             "dimensions"):
+        mx.serving.InferenceEngine(dec, slots=2, weight_dtype="int8",
+                                   matmul_impl="fused")
+    with pytest.raises(MXNetError, match="fused_decode_attention"):
+        mx.parallel.Decoder(sym, params, max_len=8, weight_dtype="int8",
+                            matmul_impl="fused")

@@ -14,7 +14,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import parallel as par
 from mxnet_tpu.parallel import P
-from mxnet_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _mlp_symbol():

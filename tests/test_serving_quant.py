@@ -134,8 +134,8 @@ def test_quantize_roundtrip_rms_and_scheme():
     # peak row values quantize to exactly +/-127 * scale
     q = np.asarray(qt.q)
     assert (np.abs(q).max(axis=1)[live] == 127).all()
-    # chunked == plain, bitwise (512 rows -> the r=64, 8-chunk loop:
-    # _block_rows wants >= 8 chunks before it accepts a row height)
+    # chunked == plain, bitwise (512 rows -> the r=256, 2-chunk loop:
+    # _block_rows hands out lane-legal heights only)
     x = jnp.asarray(rng.randn(3, 24).astype(np.float32))
     plain = jnp.einsum("...e,fe->...f", x, qt.q.astype(x.dtype)) \
         * qt.scale.astype(x.dtype)

@@ -278,18 +278,14 @@ def test_tp2_paged_byte_identical_to_tp1_paged(lm):
     """Paged attention under tensor parallelism (ISSUE 15, closing
     the PR 14 follow-up): a tp=2 engine with ``attn_impl="paged"``
     serves the Pallas kernel against its LOCAL cache shard — the
-    kernel's (slot, kv-head, kv-block) grid takes its kv-head extent
-    from the cache operand, so inside the shard_map it is a per-shard
-    kv-head grid — with NO dense-fallback warning, byte-identical to
+    kernel takes its kv-head count from the cache operand, so inside
+    the shard_map it walks the shard's own kv heads — with NO
+    dense-fallback warning, byte-identical to
     the tp=1 paged engine AND to the dense offline oracle (fp paged
     == dense is the PR 11 contract). Cache sharding asserted; compile
     contract unchanged at both degrees."""
     import warnings
 
-    from test_paged_attention import _probe_paged
-    reason = _probe_paged()
-    if reason:
-        pytest.skip(reason)
     sym, params, dec = lm
     e1 = _engine(sym, params, attn_impl="paged")
     with warnings.catch_warnings():

@@ -64,7 +64,7 @@ def main():
         if d > 0.02 * t1:
             diffs.append(d / steps)
     if not diffs:
-        print("RESULT ms_per_step=NaN (relay glitch)")
+        print("RESULT ms_per_step=NaN (no positive chain difference)")
         return
     ms = 1e3 * sorted(diffs)[len(diffs) // 2]
     spread = (max(diffs) - min(diffs)) / min(diffs) * 100
