@@ -19,6 +19,12 @@ import jax as _jax
 # python-float scalars) everywhere — never numpy float64 scalars.
 _jax.config.update("jax_enable_x64", True)
 
+# A persistent compilation cache, where one is on, is keyed by the
+# programs' scope names too (compile_cache.py says why, and why here and
+# not only in compile_cache.enable()).
+from . import compile_cache as _compile_cache  # noqa: E402
+_compile_cache.names_in_key()
+
 from .base import MXNetError  # noqa: E402
 from .context import Context, current_context, cpu, gpu, tpu, cpu_pinned  # noqa: E402
 from . import ndarray  # noqa: E402

@@ -268,25 +268,23 @@ def _train_multi_device(symbol, ctx, arg_names, param_names, aux_names,
             executor_manager.load_data_batch(data_batch)
             if monitor is not None:
                 monitor.tic()
-            step_t0 = time.perf_counter()
-            executor_manager.forward(is_train=True)
-            executor_manager.backward()
-            if update_on_kvstore:
-                _update_params_on_kvstore(
-                    executor_manager.param_arrays,
-                    executor_manager.grad_arrays, kvstore)
-            else:
-                _update_params(executor_manager.param_arrays,
-                               executor_manager.grad_arrays,
-                               updater=updater, num_device=len(ctx),
-                               kvstore=kvstore)
             # forward+backward+update = one training step: the legacy
             # loop's dispatch is host-blocking per phase, so this wall
             # time is the honest per-batch cost (the fused loop's
             # step/input/device split needs its staged stream)
+            with tele.span("train.step", hist=_TM_TRAIN_STEP_MS):
+                executor_manager.forward(is_train=True)
+                executor_manager.backward()
+                if update_on_kvstore:
+                    _update_params_on_kvstore(
+                        executor_manager.param_arrays,
+                        executor_manager.grad_arrays, kvstore)
+                else:
+                    _update_params(executor_manager.param_arrays,
+                                   executor_manager.grad_arrays,
+                                   updater=updater, num_device=len(ctx),
+                                   kvstore=kvstore)
             _TM_TRAIN_STEPS.inc()
-            _TM_TRAIN_STEP_MS.observe(
-                (time.perf_counter() - step_t0) * 1e3)
             if monitor is not None:
                 monitor.toc_print()
             executor_manager.update_metric(eval_metric, data_batch.label)
@@ -455,38 +453,34 @@ def _train_fused(symbol, ctx, arg_params, aux_params, begin_epoch,
     staged.reset()
     for epoch in range(begin_epoch, end_epoch):
         tic = time.time()
-        ep_t0 = time.perf_counter()
         eval_metric.reset()
         nbatch = 0
-        while True:
-            do_reset = True
-            for data_batch, dev_batch in staged:
-                outs = trainer.step(dev_batch)
-                # blocked-on-device: the host stalls HERE, fetching the
-                # step's outputs for the metric (step() itself only
-                # dispatched)
-                fw_t0 = time.perf_counter()
-                out_nds = [nd.array(np.asarray(o)) for o in outs]
-                _TM_DEVICE_MS.observe((time.perf_counter() - fw_t0) * 1e3)
-                eval_metric.update(data_batch.label, out_nds)
-                nbatch += 1
-                if batch_end_callback is not None:
-                    batch_end_params = BatchEndParam(epoch=epoch,
-                                                     nbatch=nbatch,
-                                                     eval_metric=eval_metric,
-                                                     locals=locals())
-                    _run_callbacks(batch_end_callback, batch_end_params)
-                if epoch_size is not None and nbatch >= epoch_size:
-                    do_reset = False
+        with tele.span("train.epoch", epoch=epoch):
+            while True:
+                do_reset = True
+                for data_batch, dev_batch in staged:
+                    outs = trainer.step(dev_batch)
+                    # blocked-on-device: the host stalls HERE, fetching the
+                    # step's outputs for the metric (step() itself only
+                    # dispatched)
+                    with tele.span("train.device_wait",
+                                   hist=_TM_DEVICE_MS):
+                        out_nds = [nd.array(np.asarray(o)) for o in outs]
+                    eval_metric.update(data_batch.label, out_nds)
+                    nbatch += 1
+                    if batch_end_callback is not None:
+                        batch_end_params = BatchEndParam(
+                            epoch=epoch, nbatch=nbatch,
+                            eval_metric=eval_metric, locals=locals())
+                        _run_callbacks(batch_end_callback, batch_end_params)
+                    if epoch_size is not None and nbatch >= epoch_size:
+                        do_reset = False
+                        break
+                if do_reset:
+                    logger.info("Epoch[%d] Resetting Data Iterator", epoch)
+                    staged.reset()
+                if epoch_size is None or nbatch >= epoch_size:
                     break
-            if do_reset:
-                logger.info("Epoch[%d] Resetting Data Iterator", epoch)
-                staged.reset()
-            if epoch_size is None or nbatch >= epoch_size:
-                break
-        tele.trace_complete("train.epoch", ep_t0,
-                            time.perf_counter() - ep_t0,
-                            args={"epoch": epoch})
         toc = time.time()
         logger.info("Epoch[%d] Time cost=%.3f", epoch, toc - tic)
 
@@ -661,8 +655,8 @@ def save_checkpoint(prefix, epoch, symbol, arg_params, aux_params,
     # state
     states_name = local[:-len(".params")] + ".states" \
         if local.endswith(".params") else local + ".states"
-    ckpt_t0 = time.perf_counter()
-    with _save_lock_for(prefix):
+    with tele.span("checkpoint.save", hist=_TM_CKPT_MS, epoch=epoch), \
+            _save_lock_for(prefix):
         # symbol.json is atomic like .params/.states: a crash mid-write
         # must not leave a truncated symbol file that breaks every
         # future resume while latest_checkpoint still reports good epochs
@@ -699,10 +693,6 @@ def save_checkpoint(prefix, epoch, symbol, arg_params, aux_params,
                 with _SAVE_LOCKS_GUARD:
                     _STATES_PUBLISHED.add(os.path.abspath(states_name))
         _publish(param_name, lambda p: nd.save(p, save_dict))
-    ckpt_dt = time.perf_counter() - ckpt_t0
-    _TM_CKPT_MS.observe(ckpt_dt * 1e3)
-    tele.trace_complete("checkpoint.save", ckpt_t0, ckpt_dt,
-                        args={"epoch": epoch})
     logging.info("Saved checkpoint to \"%s\"", param_name)
 
 

@@ -35,7 +35,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-__all__ = ["FusionPlan", "eval_graph"]
+__all__ = ["FusionPlan", "eval_graph", "node_scope"]
 
 
 def fusion_enabled():
@@ -157,11 +157,14 @@ class FusionPlan:
         entry = self.chains.get(id(n))
         if entry is None or not self._active(entry[0], entry[1], is_train):
             return False
-        kind = entry[0]
-        if is_train and kind in ("conv_bn", "conv_bn_relu"):
-            return self._execute_conv_bn_train(entry, env, aux_vals,
-                                               new_aux)
-        return self._execute_eval(entry, env, aux_vals)
+        kind, nodes = entry
+        # the chain is named after its first node (the GEMM or the
+        # convolution, where the time goes) and its fused kind
+        with jax.named_scope("%s/%s" % (node_scope(nodes[0]), kind)):
+            if is_train and kind in ("conv_bn", "conv_bn_relu"):
+                return self._execute_conv_bn_train(entry, env, aux_vals,
+                                                   new_aux)
+            return self._execute_eval(entry, env, aux_vals)
 
     def _execute_conv_bn_train(self, entry, env, aux_vals, new_aux):
         """1x1 conv as a Pallas GEMM whose epilogue emits sum/sumsq of
@@ -250,9 +253,19 @@ class FusionPlan:
         return True
 
 
+def node_scope(n):
+    """``<OpType>/<node name>``: the name every operation a node lowers
+    to carries in the compiled program's metadata (and so in the
+    profiler's op view; JAX adds ``jvp(...)`` / ``transpose(jvp(...))``
+    around it for forward and backward). doc/observability.md."""
+    return "%s/%s" % (n.spec.name, n.name)
+
+
 def eval_graph(topo, heads, arg_vals, aux_vals, is_train, rng, plan=None):
     """The shared topological walk (reference: per-node RunOps,
     ``graph_executor.cc:776-819``; here ONE trace → one XLA program).
+    Every node's operations are traced under ``jax.named_scope(
+    "<OpType>/<node name>")``: metadata only, no run-time cost.
     Returns (head_outs, new_aux, env)."""
     env = {}
     var_iter = iter(arg_vals)
@@ -276,8 +289,9 @@ def eval_graph(topo, heads, arg_vals, aux_vals, is_train, rng, plan=None):
         ins = [env[(id(inp), idx)] for inp, idx in n.inputs]
         aux_in = list(aux_vals[aux_cursor:aux_cursor + n_aux])
         node_rng = jax.random.fold_in(rng, i)
-        outs, aux_out = n.spec.forward(n.params, ins, aux_in, is_train,
-                                       node_rng)
+        with jax.named_scope(node_scope(n)):
+            outs, aux_out = n.spec.forward(n.params, ins, aux_in,
+                                           is_train, node_rng)
         for j, o in enumerate(outs):
             env[(id(n), j)] = o
         if n_aux:

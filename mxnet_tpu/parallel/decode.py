@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
+from ..ops.fusion import node_scope
 
 __all__ = ["Decoder"]
 
@@ -448,6 +449,12 @@ class Decoder:
         return q, s
 
     # -- the derived incremental walk -----------------------------------
+    # Inside an attention node's scope (``MultiHeadAttention/<name>``,
+    # entered by _run) two sub-scopes name what ROADMAP S1 is about:
+    # ``cache`` (the write of the new rows, the read / dequantization
+    # of the stored ones) and ``attend`` (scores, softmax, values).
+    # The projections stay directly under the node's scope.
+    @jax.named_scope("cache")
     def _write_cache(self, entry, k, v, pos):
         """Insert a [B, C, H, D] K/V chunk at ``pos`` into a cache entry.
 
@@ -490,6 +497,7 @@ class Decoder:
                 lax.dynamic_update_slice(cv, v.astype(cv.dtype),
                                          (z, p, z, z)))
 
+    @jax.named_scope("cache")
     def _read_cache(self, entry, dtype, limit=None):
         """Whole-cache K/V for the attention read: dequantized to
         ``dtype`` if int8, else returned at the stored dtype (jnp
@@ -569,12 +577,13 @@ class Decoder:
         kv = _MHA.kv_heads(node.params)
         posv = jnp.asarray(pos, jnp.int32) if jnp.ndim(pos) == 1 \
             else jnp.full((b,), pos, jnp.int32)
-        out, kn, vn = fused_decode_attention(
-            x.reshape(b, e), posv, entry[0], entry[1],
-            wqkv.q, wqkv.scale, bqkv, wo.q, wo.scale, bo,
-            heads=h, kv_heads=kv, bits=wqkv.bits, group=wqkv.group,
-            rope=bool(node.params.get("rope")),
-            rope_base=float(node.params.get("rope_base") or 10000.0))
+        with jax.named_scope("attend"):
+            out, kn, vn = fused_decode_attention(
+                x.reshape(b, e), posv, entry[0], entry[1],
+                wqkv.q, wqkv.scale, bqkv, wo.q, wo.scale, bo,
+                heads=h, kv_heads=kv, bits=wqkv.bits, group=wqkv.group,
+                rope=bool(node.params.get("rope")),
+                rope_base=float(node.params.get("rope_base") or 10000.0))
         entry = self._write_cache(entry, kn[:, None], vn[:, None],
                                   posv)
         return out.reshape(b, 1, e), entry
@@ -668,13 +677,14 @@ class Decoder:
             from ..ops.pallas_kernels import paged_attention
             posv = jnp.asarray(pos, jnp.int32) if jnp.ndim(pos) == 1 \
                 else jnp.full((b,), pos, jnp.int32)
-            if self._cache_int8:
-                ck, ks, cv, vs = entry
-                o = paged_attention(q, ck, cv, posv, k_scale=ks,
-                                    v_scale=vs)
-            else:
-                ck, cv = entry
-                o = paged_attention(q, ck, cv, posv)
+            with jax.named_scope("attend"):
+                if self._cache_int8:
+                    ck, ks, cv, vs = entry
+                    o = paged_attention(q, ck, cv, posv, k_scale=ks,
+                                        v_scale=vs)
+                else:
+                    ck, cv = entry
+                    o = paged_attention(q, ck, cv, posv)
         elif self._cache_block is not None and c == 1:
             o = self._blocked_attn(q, entry, pos)
         else:
@@ -688,28 +698,29 @@ class Decoder:
             if isinstance(pos, (int, np.integer)):
                 limit = min(self.max_len, int(pos) + c)
             ck, cv = self._read_cache(entry, q.dtype, limit=limit)
-            if kv == h:
-                s = jnp.einsum("bqhd,bkhd->bhqk", q,
-                               ck) / float(np.sqrt(d))
-                kpos = jnp.arange(limit)[None, None, None, :]
-                qpos = pos + jnp.arange(c)[None, None, :, None]
-                s = jnp.where(kpos <= qpos, s,
-                              jnp.float32(-1e30).astype(s.dtype))
-                o = jnp.einsum("bhqk,bkhd->bqhd",
-                               jax.nn.softmax(s, axis=-1), cv)
-            else:
-                # GQA: grouped einsums read the kv-head cache directly —
-                # query heads fold to [B, C, Hkv, G, D] and contract
-                # against their shared K/V head, no repeated cache copy
-                qg = q.reshape(b, c, kv, h // kv, d)
-                s = jnp.einsum("bqKgd,bkKd->bKgqk", qg,
-                               ck) / float(np.sqrt(d))
-                kpos = jnp.arange(limit)[None, None, None, None, :]
-                qpos = pos + jnp.arange(c)[None, None, None, :, None]
-                s = jnp.where(kpos <= qpos, s,
-                              jnp.float32(-1e30).astype(s.dtype))
-                o = jnp.einsum("bKgqk,bkKd->bqKgd",
-                               jax.nn.softmax(s, axis=-1), cv)
+            with jax.named_scope("attend"):
+                if kv == h:
+                    s = jnp.einsum("bqhd,bkhd->bhqk", q,
+                                   ck) / float(np.sqrt(d))
+                    kpos = jnp.arange(limit)[None, None, None, :]
+                    qpos = pos + jnp.arange(c)[None, None, :, None]
+                    s = jnp.where(kpos <= qpos, s,
+                                  jnp.float32(-1e30).astype(s.dtype))
+                    o = jnp.einsum("bhqk,bkhd->bqhd",
+                                   jax.nn.softmax(s, axis=-1), cv)
+                else:
+                    # GQA: grouped einsums read the kv-head cache directly —
+                    # query heads fold to [B, C, Hkv, G, D] and contract
+                    # against their shared K/V head, no repeated cache copy
+                    qg = q.reshape(b, c, kv, h // kv, d)
+                    s = jnp.einsum("bqKgd,bkKd->bKgqk", qg,
+                                   ck) / float(np.sqrt(d))
+                    kpos = jnp.arange(limit)[None, None, None, None, :]
+                    qpos = pos + jnp.arange(c)[None, None, None, :, None]
+                    s = jnp.where(kpos <= qpos, s,
+                                  jnp.float32(-1e30).astype(s.dtype))
+                    o = jnp.einsum("bKgqk,bkKd->bqKgd",
+                                   jax.nn.softmax(s, axis=-1), cv)
         if tp is not None:
             # ONE collective per attention node: gather the per-shard
             # head outputs (axis 2 is kv-major in every o layout —
@@ -745,44 +756,45 @@ class Decoder:
         b, c, h, d = q.shape
         kvh = k.shape[2]
         g = h // kvh
-        if self._cache_int8:
-            ck, ks, cv, vs, cpos = entry
-            ckf = ck * ks[..., None]
-            cvf = cv * vs[..., None]
-        else:
-            ck, cv, cpos = entry
-            ckf, cvf = ck, cv
-
         def to_h(z):  # GQA: broadcast the (small) ring/chunk K/V rows
             return jnp.repeat(z, g, axis=2) if g > 1 else z
 
+        with jax.named_scope("cache"):
+            if self._cache_int8:
+                ck, ks, cv, vs, cpos = entry
+                ckf = ck * ks[..., None]
+                cvf = cv * vs[..., None]
+            else:
+                ck, cv, cpos = entry
+                ckf, cvf = ck, cv
+            ckf = to_h(ckf.astype(jnp.float32))
+            cvf = to_h(cvf.astype(jnp.float32))
         qf = q.astype(jnp.float32)
-        ckf = to_h(ckf.astype(jnp.float32))
-        cvf = to_h(cvf.astype(jnp.float32))
         kf = to_h(k.astype(jnp.float32))
         vf = to_h(v.astype(jnp.float32))
-        qpos = pos + jnp.arange(c)
-        scale = 1.0 / float(np.sqrt(d))
+        with jax.named_scope("attend"):
+            qpos = pos + jnp.arange(c)
+            scale = 1.0 / float(np.sqrt(d))
 
-        s_ring = jnp.einsum("bqhd,bkhd->bhqk", qf, ckf) * scale
-        cp = cpos[:, None, None, :]
-        ring_ok = (cp >= 0) & (cp < pos) \
-            & (cp > qpos[None, None, :, None] - win)
-        s_ring = jnp.where(ring_ok, s_ring, -jnp.inf)
+            s_ring = jnp.einsum("bqhd,bkhd->bhqk", qf, ckf) * scale
+            cp = cpos[:, None, None, :]
+            ring_ok = (cp >= 0) & (cp < pos) \
+                & (cp > qpos[None, None, :, None] - win)
+            s_ring = jnp.where(ring_ok, s_ring, -jnp.inf)
 
-        s_chunk = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * scale
-        chunk_ok = (qpos[:, None] >= qpos[None, :]) \
-            & (qpos[:, None] - qpos[None, :] < win)
-        s_chunk = jnp.where(chunk_ok[None, None], s_chunk, -jnp.inf)
+            s_chunk = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * scale
+            chunk_ok = (qpos[:, None] >= qpos[None, :]) \
+                & (qpos[:, None] - qpos[None, :] < win)
+            s_chunk = jnp.where(chunk_ok[None, None], s_chunk, -jnp.inf)
 
-        # one softmax over ring + chunk keys (self is always valid, so
-        # no empty rows)
-        p = jax.nn.softmax(
-            jnp.concatenate([s_ring, s_chunk], axis=-1), axis=-1)
-        nring = ckf.shape[1]
-        o = jnp.einsum("bhqk,bkhd->bqhd", p[..., :nring], cvf) \
-            + jnp.einsum("bhqk,bkhd->bqhd", p[..., nring:], vf)
-        o = o.astype(q.dtype)
+            # one softmax over ring + chunk keys (self is always valid, so
+            # no empty rows)
+            p = jax.nn.softmax(
+                jnp.concatenate([s_ring, s_chunk], axis=-1), axis=-1)
+            nring = ckf.shape[1]
+            o = jnp.einsum("bhqk,bkhd->bqhd", p[..., :nring], cvf) \
+                + jnp.einsum("bhqk,bkhd->bqhd", p[..., nring:], vf)
+            o = o.astype(q.dtype)
 
         # write the last min(win, #valid) VALID rows of the chunk —
         # earlier valid rows would be overwritten within this same
@@ -795,35 +807,37 @@ class Decoder:
         # write static-shaped ([win] rows); rows before the chunk
         # scatter out of bounds under mode="drop". valid_len=None
         # degenerates to the old last-min(c, win)-rows behavior.
-        p32 = jnp.asarray(pos, jnp.int32)
-        if valid_len is None:
-            vc = jnp.int32(c)
-        else:
-            vc = jnp.clip(jnp.asarray(valid_len, jnp.int32) - p32, 0, c)
-        idx = vc - win + jnp.arange(win)       # chunk rows to write
-        valid = idx >= 0
-        gidx = jnp.clip(idx, 0, c - 1)
-        newpos = p32 + gidx
-        slots = jnp.where(valid, newpos % win, win)  # win: dropped
-        kt = jnp.take(k, gidx, axis=1)
-        vt = jnp.take(v, gidx, axis=1)
-        posb = jnp.broadcast_to(newpos[None], (b, win)).astype(jnp.int32)
-        if self._cache_int8:
-            k8, ksc = self._quantize_rows(kt)
-            v8, vsc = self._quantize_rows(vt)
-            entry = (ck.at[:, slots].set(k8, mode="drop"),
-                     ks.at[:, slots].set(ksc, mode="drop"),
-                     cv.at[:, slots].set(v8, mode="drop"),
-                     vs.at[:, slots].set(vsc, mode="drop"),
-                     cpos.at[:, slots].set(posb, mode="drop"))
-        else:
-            entry = (ck.at[:, slots].set(kt.astype(ck.dtype),
-                                         mode="drop"),
-                     cv.at[:, slots].set(vt.astype(cv.dtype),
-                                         mode="drop"),
-                     cpos.at[:, slots].set(posb, mode="drop"))
+        with jax.named_scope("cache"):
+            p32 = jnp.asarray(pos, jnp.int32)
+            if valid_len is None:
+                vc = jnp.int32(c)
+            else:
+                vc = jnp.clip(jnp.asarray(valid_len, jnp.int32) - p32, 0, c)
+            idx = vc - win + jnp.arange(win)       # chunk rows to write
+            valid = idx >= 0
+            gidx = jnp.clip(idx, 0, c - 1)
+            newpos = p32 + gidx
+            slots = jnp.where(valid, newpos % win, win)  # win: dropped
+            kt = jnp.take(k, gidx, axis=1)
+            vt = jnp.take(v, gidx, axis=1)
+            posb = jnp.broadcast_to(newpos[None], (b, win)).astype(jnp.int32)
+            if self._cache_int8:
+                k8, ksc = self._quantize_rows(kt)
+                v8, vsc = self._quantize_rows(vt)
+                entry = (ck.at[:, slots].set(k8, mode="drop"),
+                         ks.at[:, slots].set(ksc, mode="drop"),
+                         cv.at[:, slots].set(v8, mode="drop"),
+                         vs.at[:, slots].set(vsc, mode="drop"),
+                         cpos.at[:, slots].set(posb, mode="drop"))
+            else:
+                entry = (ck.at[:, slots].set(kt.astype(ck.dtype),
+                                             mode="drop"),
+                         cv.at[:, slots].set(vt.astype(cv.dtype),
+                                             mode="drop"),
+                         cpos.at[:, slots].set(posb, mode="drop"))
         return o, entry
 
+    @jax.named_scope("attend")
     def _blocked_attn(self, q, entry, pos):
         """Single-token attention reading only the filled cache prefix.
 
@@ -846,6 +860,7 @@ class Decoder:
         g = h // kvh
         qg = qf.reshape(b, c, kvh, g, d)
 
+        @jax.named_scope("cache")
         def _block(buf, scale, i):
             z = lax.dynamic_slice(buf, (0, i * bl, 0, 0),
                                   (b, bl, kvh, d))
@@ -931,67 +946,69 @@ class Decoder:
                 continue
             ins = [env[(id(inp), idx)] for inp, idx in n.inputs]
             name = n.spec.name
-            if name == "MultiHeadAttention":
-                out, new_caches[mha_i] = self._cached_mha(
-                    n, ins, new_caches[mha_i], pos, valid_len, tp,
-                    mm_impl=mm_impl)
-                mha_i += 1
-                env[(id(n), 0)] = out
-                continue
-            if name == "PositionalEmbedding":
-                x, posp = ins
-                if jnp.ndim(pos) == 1:
-                    # per-slot clocks (paged batched walk): gather each
-                    # batch row's positions from the table
-                    idx = jnp.asarray(pos, jnp.int32)[:, None] \
-                        + jnp.arange(x.shape[1], dtype=jnp.int32)
-                    env[(id(n), 0)] = x + jnp.take(posp, idx, axis=0)
+            with jax.named_scope(node_scope(n)):
+                if name == "MultiHeadAttention":
+                    out, new_caches[mha_i] = self._cached_mha(
+                        n, ins, new_caches[mha_i], pos, valid_len, tp,
+                        mm_impl=mm_impl)
+                    mha_i += 1
+                    env[(id(n), 0)] = out
                     continue
-                # all-int32 indices: see _write_cache on the vmapped
-                # batching rule's strict index dtypes
-                rows = lax.dynamic_slice(
-                    posp, (jnp.asarray(pos, jnp.int32), jnp.int32(0)),
-                    (x.shape[1], posp.shape[1]))
-                env[(id(n), 0)] = x + rows[None]
-                continue
-            if name == "FullyConnected" \
-                    and isinstance(ins[1], QuantizedTensor):
-                xin = ins[0]
-                if n.params["flatten"]:
-                    xin = xin.reshape(xin.shape[0], -1)
-                out = self._qmm(xin, ins[1], mm_impl)
-                if not n.params["no_bias"]:
-                    out = out + ins[2]
-                env[(id(n), 0)] = out
-                continue
-            if name == "Embedding" \
-                    and isinstance(ins[1], QuantizedTensor):
-                idx = lax.stop_gradient(ins[0]).astype(jnp.int32)
-                env[(id(n), 0)] = embedding_rows(ins[1], idx)
-                continue
-            if name == "MoEFFN" and (ep is not None or any(
-                    isinstance(z, QuantizedTensor) for z in ins[1:])):
-                env[(id(n), 0)] = moe_ffn_forward(n.params, ins,
-                                                  mm=qmm, ep=ep)
-                continue
-            if name == "BatchNorm" and ins[0].ndim >= 3:
-                # BatchNorm normalizes axis 1, which for rank>=3 LM data
-                # [B, T, E] is the TIME axis: a [B, 1, E] decode chunk
-                # would silently broadcast against length-T moving stats
-                # instead of behaving position-wise. Refuse loudly.
-                raise MXNetError(
-                    "Decoder: BatchNorm node %r normalizes axis 1 of its "
-                    "rank-%d input — the time axis under decoding, so it "
-                    "is not position-wise; use LayerNorm for sequence "
-                    "models (or BatchNorm on rank-2 [B, E] data only)"
-                    % (n.name, ins[0].ndim))
-            n_aux = len(n.spec.aux_states(n.params))
-            aux_in = aux[aux_cursor:aux_cursor + n_aux]
-            aux_cursor += n_aux
-            outs, _ = n.spec.forward(n.params, ins, aux_in, False,
-                                     jax.random.fold_in(rng, i))
-            for j, o in enumerate(outs):
-                env[(id(n), j)] = o
+                if name == "PositionalEmbedding":
+                    x, posp = ins
+                    if jnp.ndim(pos) == 1:
+                        # per-slot clocks (paged batched walk): gather each
+                        # batch row's positions from the table
+                        idx = jnp.asarray(pos, jnp.int32)[:, None] \
+                            + jnp.arange(x.shape[1], dtype=jnp.int32)
+                        env[(id(n), 0)] = x + jnp.take(posp, idx, axis=0)
+                        continue
+                    # all-int32 indices: see _write_cache on the vmapped
+                    # batching rule's strict index dtypes
+                    rows = lax.dynamic_slice(
+                        posp, (jnp.asarray(pos, jnp.int32), jnp.int32(0)),
+                        (x.shape[1], posp.shape[1]))
+                    env[(id(n), 0)] = x + rows[None]
+                    continue
+                if name == "FullyConnected" \
+                        and isinstance(ins[1], QuantizedTensor):
+                    xin = ins[0]
+                    if n.params["flatten"]:
+                        xin = xin.reshape(xin.shape[0], -1)
+                    out = self._qmm(xin, ins[1], mm_impl)
+                    if not n.params["no_bias"]:
+                        out = out + ins[2]
+                    env[(id(n), 0)] = out
+                    continue
+                if name == "Embedding" \
+                        and isinstance(ins[1], QuantizedTensor):
+                    idx = lax.stop_gradient(ins[0]).astype(jnp.int32)
+                    env[(id(n), 0)] = embedding_rows(ins[1], idx)
+                    continue
+                if name == "MoEFFN" and (ep is not None or any(
+                        isinstance(z, QuantizedTensor) for z in ins[1:])):
+                    env[(id(n), 0)] = moe_ffn_forward(n.params, ins,
+                                                      mm=qmm, ep=ep)
+                    continue
+                if name == "BatchNorm" and ins[0].ndim >= 3:
+                    # BatchNorm normalizes axis 1, which for rank>=3 LM data
+                    # [B, T, E] is the TIME axis: a [B, 1, E] decode chunk
+                    # would silently broadcast against length-T moving stats
+                    # instead of behaving position-wise. Refuse loudly.
+                    raise MXNetError(
+                        "Decoder: BatchNorm node %r normalizes axis 1 of "
+                        "its rank-%d input — the time axis under decoding, "
+                        "so it is not position-wise; use LayerNorm for "
+                        "sequence models (or BatchNorm on rank-2 [B, E] "
+                        "data only)"
+                        % (n.name, ins[0].ndim))
+                n_aux = len(n.spec.aux_states(n.params))
+                aux_in = aux[aux_cursor:aux_cursor + n_aux]
+                aux_cursor += n_aux
+                outs, _ = n.spec.forward(n.params, ins, aux_in, False,
+                                         jax.random.fold_in(rng, i))
+                for j, o in enumerate(outs):
+                    env[(id(n), j)] = o
         head, idx = self._heads[0]
         return env[(id(head), idx)], new_caches
 

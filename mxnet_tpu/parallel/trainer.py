@@ -101,12 +101,12 @@ class _StagedStream:
         # blocked-on-input: everything the consumer thread waits on for
         # the next staged batch (decode pool, host collate, h2d
         # dispatch). Epoch ends (StopIteration) are not a wait sample.
-        t0 = time.perf_counter()
-        out = self._stream.next()
-        dt = time.perf_counter() - t0
-        _TM_INPUT_MS.observe(dt * 1e3)
-        tele.trace_complete("io.input_wait", t0, dt, cat="io")
-        return out
+        with tele.span("io.input_wait", cat="io", hist=_TM_INPUT_MS) as sp:
+            try:
+                return self._stream.next()
+            except StopIteration:
+                sp.drop()
+                raise
 
 
 class ParallelTrainer:
@@ -406,8 +406,11 @@ class ParallelTrainer:
         if self.remat:
             fwd = jax.checkpoint(fwd)
         # Pallas kernels in the graph partition themselves over the
-        # trainer's mesh (GSPMD cannot partition a Mosaic kernel)
-        with kernel_mesh(self.mesh):
+        # trainer's mesh (GSPMD cannot partition a Mosaic kernel).
+        # mx.grads / mx.clip / mx.optimizer name the three parts of the
+        # step in the compiled program's metadata (doc/observability.md
+        # "Scopes inside the compiled programs").
+        with jax.named_scope("mx.grads"), kernel_mesh(self.mesh):
             outs, vjp_fn, new_aux = jax.vjp(fwd, params, has_aux=True)
             if self.compute_dtype is not None:
                 # moving stats stay f32 across steps (stable jit
@@ -456,20 +459,22 @@ class ParallelTrainer:
             # measured on the RESCALED gradient (rescale_grad = 1/batch
             # on the string path), so the threshold means "norm of the
             # mean gradient" as in standard transformer recipes.
-            sq = sum(jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
-                     for n in self.param_names)
-            gnorm = jnp.sqrt(sq) * self.optimizer.rescale_grad
-            scale = jnp.minimum(1.0, self.clip_grad_norm
-                                / jnp.maximum(gnorm, 1e-12))
-            grads = {n: (grads[n].astype(jnp.float32)
-                         * scale).astype(grads[n].dtype)
-                     for n in self.param_names}
+            with jax.named_scope("mx.clip"):
+                sq = sum(jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
+                         for n in self.param_names)
+                gnorm = jnp.sqrt(sq) * self.optimizer.rescale_grad
+                scale = jnp.minimum(1.0, self.clip_grad_norm
+                                    / jnp.maximum(gnorm, 1e-12))
+                grads = {n: (grads[n].astype(jnp.float32)
+                             * scale).astype(grads[n].dtype)
+                         for n in self.param_names}
         new_params, new_state = {}, {}
-        for name in self.param_names:
-            w, s = self._opt_update(params[name], grads[name],
-                                    opt_state[name], lr, t, rng)
-            new_params[name] = w
-            new_state[name] = s
+        with jax.named_scope("mx.optimizer"):
+            for name in self.param_names:
+                w, s = self._opt_update(params[name], grads[name],
+                                        opt_state[name], lr, t, rng)
+                new_params[name] = w
+                new_state[name] = s
         return new_params, new_state, list(new_aux), list(outs)
 
     def _shape_key(self):
@@ -640,18 +645,15 @@ class ParallelTrainer:
             lr = self.optimizer.lr
         # numpy scalars (not jnp) keep this dispatch-only — no eager
         # device ops on the host critical path; the telemetry probe is
-        # two perf_counter reads + one histogram add (host-side, no
-        # sync), pinned < 2% by bench.py's overhead arm
-        t0 = time.perf_counter()
-        with self.mesh:
+        # one span (two perf_counter reads, one histogram add, one
+        # profiler annotation: host-side, no sync), pinned < 2% by
+        # bench.py's overhead arm
+        with tele.span("train.step", hist=_TM_STEP_MS), self.mesh:
             self.params, self.opt_state, self.aux, outs = \
                 self._jit_step(self.params, self.opt_state, self.aux,
                                batch, np.float32(lr),
                                np.int32(self._t), self._rng)
-        dt = time.perf_counter() - t0
         _TM_STEPS.inc()
-        _TM_STEP_MS.observe(dt * 1e3)
-        tele.trace_complete("train.step", t0, dt)
         if not self._prog_registered:
             # one-time: register the step program for program.* cost/
             # memory introspection (doc/observability.md). Post-call
@@ -839,62 +841,59 @@ class ParallelTrainer:
             eval_metric.reset()
             acc_state = _zero_state() if device_metric else None
             tic = time.time()
-            ep_t0 = time.perf_counter()
-            for nbatch, (dbatch, dev_batch) in enumerate(staged):
-                outs = self.step(dev_batch)
-                if device_metric:
-                    if dm_kind == "loss":
-                        # label unused by the accumulator — works for
-                        # label-free loss heads (MakeLoss-style) too
-                        lab = np.float32(0)
+            with tele.span("train.epoch", epoch=epoch):
+                for nbatch, (dbatch, dev_batch) in enumerate(staged):
+                    outs = self.step(dev_batch)
+                    if device_metric:
+                        if dm_kind == "loss":
+                            # label unused by the accumulator — works for
+                            # label-free loss heads (MakeLoss-style) too
+                            lab = np.float32(0)
+                        else:
+                            # single-process: uncommitted host numpy, jit
+                            # places it with the other operands. Multi-
+                            # process: each process holds only its local
+                            # label slice, so build the GLOBAL sharded array
+                            # the same way step() does for data
+                            lab = dbatch.label[0]
+                            if isinstance(lab, NDArray):
+                                lab = lab._val
+                            lab = np.asarray(lab)
+                            if jax.process_count() > 1:
+                                lab = jax.make_array_from_process_local_data(
+                                    self._data_sh[label_names[0]], lab)
+                        with self.mesh:
+                            acc_state = _acc_update(acc_state, outs[0], lab)
+                        if dm_kind == "ce" and epoch == 0 and nbatch == 0 \
+                                and jax.process_count() == 1:
+                            # the CE accumulator assumes the monitored output
+                            # is a probability distribution (the reference
+                            # CrossEntropy metric's contract); a logits-
+                            # output symbol silently yields garbage. One
+                            # cheap first-batch host check catches that.
+                            row = np.asarray(
+                                outs[0][(0,) * (outs[0].ndim - 1)],
+                                dtype=np.float64)
+                            if not 0.9 <= float(row.sum()) <= 1.1:
+                                logger.warning(
+                                    "device_metric cross-entropy expects "
+                                    "probability outputs (rows summing to "
+                                    "1); the first output row sums to %.4g "
+                                    "- the reported CE will be meaningless "
+                                    "if the symbol emits raw logits.",
+                                    float(row.sum()))
                     else:
-                        # single-process: uncommitted host numpy, jit
-                        # places it with the other operands. Multi-
-                        # process: each process holds only its local
-                        # label slice, so build the GLOBAL sharded array
-                        # the same way step() does for data
-                        lab = dbatch.label[0]
-                        if isinstance(lab, NDArray):
-                            lab = lab._val
-                        lab = np.asarray(lab)
-                        if jax.process_count() > 1:
-                            lab = jax.make_array_from_process_local_data(
-                                self._data_sh[label_names[0]], lab)
-                    with self.mesh:
-                        acc_state = _acc_update(acc_state, outs[0], lab)
-                    if dm_kind == "ce" and epoch == 0 and nbatch == 0 \
-                            and jax.process_count() == 1:
-                        # the CE accumulator assumes the monitored output
-                        # is a probability distribution (the reference
-                        # CrossEntropy metric's contract); a logits-
-                        # output symbol silently yields garbage. One
-                        # cheap first-batch host check catches that.
-                        row = np.asarray(
-                            outs[0][(0,) * (outs[0].ndim - 1)],
-                            dtype=np.float64)
-                        if not 0.9 <= float(row.sum()) <= 1.1:
-                            logger.warning(
-                                "device_metric cross-entropy expects "
-                                "probability outputs (rows summing to "
-                                "1); the first output row sums to %.4g "
-                                "- the reported CE will be meaningless "
-                                "if the symbol emits raw logits.",
-                                float(row.sum()))
-                else:
-                    # this fetch is where the host actually BLOCKS on
-                    # the device finishing step nbatch
-                    fw_t0 = time.perf_counter()
-                    out_nds = [nd.array(np.asarray(o)) for o in outs]
-                    _TM_DEVICE_MS.observe(
-                        (time.perf_counter() - fw_t0) * 1e3)
-                    eval_metric.update(dbatch.label, out_nds)
-                if batch_end_callback is not None:
-                    _run_callbacks(batch_end_callback, BatchEndParam(
-                        epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
-                        locals=locals()))
-            tele.trace_complete("train.epoch", ep_t0,
-                                time.perf_counter() - ep_t0,
-                                args={"epoch": epoch})
+                        # this fetch is where the host actually BLOCKS on
+                        # the device finishing step nbatch
+                        with tele.span("train.device_wait",
+                                       hist=_TM_DEVICE_MS):
+                            out_nds = [nd.array(np.asarray(o))
+                                       for o in outs]
+                        eval_metric.update(dbatch.label, out_nds)
+                    if batch_end_callback is not None:
+                        _run_callbacks(batch_end_callback, BatchEndParam(
+                            epoch=epoch, nbatch=nbatch,
+                            eval_metric=eval_metric, locals=locals()))
             if device_metric:
                 msum, total = (float(acc_state[0]),
                                float(acc_state[1]))  # ONE host sync

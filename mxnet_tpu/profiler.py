@@ -17,7 +17,6 @@ Usage::
 """
 from __future__ import annotations
 
-import contextlib
 import weakref as _weakref
 
 import jax
@@ -50,114 +49,14 @@ def stop():
         _state["running"] = False
 
 
-@contextlib.contextmanager
-def scope(name: str):
-    """Annotate a named region: an XLA ``TraceAnnotation`` (shows up in
-    the ``mx.profiler.start``/TensorBoard device trace) AND an
-    ``mx.telemetry`` span (shows up in the ``MXNET_TRACE_DIR``
-    host-side Chrome trace) — one ``with`` statement marks the region
-    in both captures, so device and host timelines can be lined up in
-    Perfetto by name. See doc/observability.md."""
-    with jax.profiler.TraceAnnotation(name):
-        with telemetry.span(name, cat="profiler.scope"):
-            yield
+# a named region in BOTH captures (the profiler's host plane and the
+# ``MXNET_TRACE_DIR`` Chrome trace): it IS the telemetry span
+scope = telemetry.span
 
 
 def device_memory_profile() -> bytes:
     """Snapshot of current device memory (pprof format)."""
     return jax.profiler.device_memory_profile()
-
-
-# ---------------------------------------------------------------------------
-# step statistics (Speedometer-adjacent, but library-level: the reference
-# logs samples/sec from a callback; this accumulates step wall-times so
-# perf regressions are visible without TensorBoard)
-
-import time as _time
-
-_steps = {"times": []}
-
-
-@contextlib.contextmanager
-def record_step():
-    """Time one training step:  ``with mx.profiler.record_step(): step()``.
-    Includes device wait only if the caller blocks (as FeedForward's
-    metric update does); pair with get_step_stats()."""
-    tic = _time.perf_counter()
-    try:
-        yield
-    finally:
-        _steps["times"].append(_time.perf_counter() - tic)
-
-
-def reset_step_stats():
-    _steps["times"] = []
-
-
-def get_step_stats():
-    """dict(count, mean_ms, p50_ms, p99_ms, total_s) over recorded steps."""
-    ts = sorted(_steps["times"])
-    if not ts:
-        return {"count": 0, "mean_ms": 0.0, "p50_ms": 0.0, "p99_ms": 0.0,
-                "total_s": 0.0}
-    n = len(ts)
-    return {
-        "count": n,
-        "mean_ms": 1e3 * sum(ts) / n,
-        "p50_ms": 1e3 * ts[n // 2],
-        "p99_ms": 1e3 * ts[min(n - 1, (99 * n) // 100)],
-        "total_s": sum(ts),
-    }
-
-
-# ---------------------------------------------------------------------------
-# throughput measurement by chain differencing: two DEPENDENT chain
-# lengths that each end in a real value fetch, differenced, which
-# cancels the constant dispatch/flush overhead. On the v5e chip
-# `block_until_ready` and a value fetch agree (chip_smoke.py's clock
-# phase, PERF.md); ROADMAP S1 keeps one method.
-
-def benchmark_chain(step_fn, state, *, steps=15, reps=3,
-                    fetch=None):
-    """Seconds per call of ``state = step_fn(state)``.
-
-    ``step_fn`` MUST thread its output back as its input (a donated
-    train step, ``y = f(y)``, ...) — that data dependence is what makes
-    the timing honest. ``fetch(state)`` forces completion (default:
-    ``np.asarray`` of the first leaf's first element). Returns
-    ``(seconds_per_step, spread)`` where spread is the relative
-    max-min range across ``reps`` measurements — distrust results
-    with spread > 0.1.
-    """
-    import numpy as _np
-
-    if fetch is None:
-        def fetch(s):
-            leaf = jax.tree_util.tree_leaves(s)[0]
-            _np.asarray(leaf).ravel()[:1]
-
-    def chain(n, s):
-        tic = _time.perf_counter()
-        for _ in range(n):
-            s = step_fn(s)
-        fetch(s)
-        return _time.perf_counter() - tic, s
-
-    _, state = chain(3, state)  # warmup/compile
-    diffs = []
-    for _ in range(reps):
-        t1, state = chain(steps, state)
-        t2, state = chain(2 * steps, state)
-        if t2 - t1 > 0:
-            diffs.append((t2 - t1) / steps)
-    if not diffs:
-        raise RuntimeError(
-            "benchmark_chain: no positive chain difference in any rep; "
-            "raise `steps` so compute dominates the flush-cost "
-            "variance")
-    dt = float(sorted(diffs)[len(diffs) // 2])
-    spread = (max(diffs) - min(diffs)) / dt if len(diffs) > 1 else 0.0
-    return dt, spread
 
 
 # ---------------------------------------------------------------------------
@@ -206,10 +105,12 @@ def compiled_stats(compiled):
 # * collection reads `Lowered.cost_analysis()` through jax's lowering
 #   cache — the avals match the dispatch that already traced, so this
 #   re-traces nothing, compiles nothing, and never touches the device.
-#   `compile=True` additionally AOT-compiles for the exact post-
-#   optimization `memory_analysis()` (one extra backend compile per
-#   program, cached by jax thereafter) — bench/tool territory, never
-#   the scrape path.
+#   The TPU reports no cost for a lowering: there the gauges exist only
+#   after `compile=True`, which AOT-compiles and reads `cost_analysis()`
+#   AND `memory_analysis()` (temporaries included) from the compiled
+#   executable (one extra backend compile per program, a cache read
+#   where the persistent cache is on) — bench/tool territory, on
+#   demand only, never the scrape path or set-up.
 #
 # Everything is best-effort: an analysis a backend doesn't report
 # degrades to an absent gauge, never an error.
@@ -290,23 +191,30 @@ def register_program(name, fn, args, eager=True):
 
 def _collect_one(name, fn, avals, compile):
     """Lower + analyze one program into its gauges; returns the stats
-    dict (empty when the backend reports nothing)."""
+    dict (empty when the backend reports nothing). Without ``compile``
+    the cost is the lowering's (the CPU backend reports one, the TPU
+    none); with it, cost and memory both come from the COMPILED
+    executable, which every backend fills."""
     stats = {}
     _collecting.active = True
     try:
-        low = fn.lower(*avals)
+        prog = fn.lower(*avals)
     finally:
         _collecting.active = False
     try:
-        cost = low.cost_analysis()
+        if compile:
+            prog = prog.compile()
+        cost = prog.cost_analysis()
+        if isinstance(cost, (list, tuple)):   # one dict per partition
+            cost = cost[0] if cost else {}
         for k in ("flops", "bytes accessed", "transcendentals"):
-            if k in cost:
+            if k in (cost or {}):
                 stats[k.replace(" ", "_")] = float(cost[k])
     except Exception:
         pass
     if compile:
         try:
-            ma = low.compile().memory_analysis()
+            ma = prog.memory_analysis()
             for k in ("argument_size_in_bytes",
                       "output_size_in_bytes", "temp_size_in_bytes",
                       "generated_code_size_in_bytes"):

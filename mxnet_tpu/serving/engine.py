@@ -276,8 +276,9 @@ _SLO_CADENCE_WINDOWS = (
     (3600.0, tele.gauge("serving.slo_cadence_burn_1h")))
 # round-phase attribution (doc/observability.md "Round-phase
 # attribution"): where one step()'s wall time went. Every phase is a
-# same-thread perf_counter interval the step already brackets; "sched"
-# is the unattributed remainder (host scheduling — sweeps, queue
+# same-thread ``engine._phase(...)`` span (``serving.<name>``, in the
+# Chrome capture and on the profiler's host plane alike); "sched" is
+# the unattributed remainder (host scheduling — sweeps, queue
 # bookkeeping, chunk math), so the phases SUM to the round wall time
 # by construction. Sub-ms buckets: decode rounds are ms-scale.
 _PHASE_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
@@ -304,6 +305,40 @@ _TM_ROUND_WALL = tele.histogram("serving.round_wall_ms",
 # bounded per-engine ledger of recent rounds (GET /rounds); the
 # histograms above are the fleet view, the ledger is the incident view
 _ROUND_LEDGER = 256
+
+
+# the spans whose seconds land under another name in the phase ledger
+# (every other span is the phase of its own name)
+_PHASE_OF = {"decode_round": "dispatch", "verify_round": "dispatch",
+             "draft_round": "dispatch", "prefix_copy": "copy",
+             "handoff_export": "copy", "handoff_import": "copy"}
+
+
+class _Phase(tele.span):
+    """One phase of a round: the telemetry span ``serving.<name>`` whose
+    seconds are also added to the in-flight round's phase ledger (a
+    no-op outside ``step()`` — e.g. a submit-path h2d), under
+    ``_PHASE_OF[name]`` where that differs from the span's name. The
+    ledger is charged from the phase's creation to the end of its exit,
+    the span's own bookkeeping included, so that the round's remainder
+    (``sched``) holds the scheduler's work and not the instrumentation's."""
+
+    __slots__ = ("_engine", "_key", "_made")
+
+    def __init__(self, engine, name, hist, args):
+        self._made = time.perf_counter()
+        super().__init__("serving." + name, cat="serving", hist=hist,
+                         **args)
+        self._engine = engine
+        self._key = _PHASE_OF.get(name, name)
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        acc = self._engine._phase_acc
+        if acc is not None:
+            acc[self._key] = acc.get(self._key, 0.0) \
+                + time.perf_counter() - self._made
+        return False
 
 
 class Request:
@@ -1317,11 +1352,10 @@ class InferenceEngine:
         self._last_ok_t = time.perf_counter()
         self._watchdog_stuck_t = None
         self._prog_seen = set()
-        # round-phase attribution: _phase is the accumulator dict
-        # while a step() is in flight (instrumented sites add their
-        # same-thread perf_counter intervals), _rounds the bounded
-        # ledger GET /rounds reads
-        self._phase = None
+        # round-phase attribution: _phase_acc is the accumulator dict
+        # while a step() is in flight (every _phase span adds its
+        # seconds), _rounds the bounded ledger GET /rounds reads
+        self._phase_acc = None
         self._rounds = collections.deque(maxlen=_ROUND_LEDGER)
         self._round_no = 0
         # traffic capture: opened LAST so the header carries the final
@@ -1702,13 +1736,11 @@ class InferenceEngine:
         """Bucket ``length`` and dispatch the copy program (prefix-hit
         admission or retention insert)."""
         bucket = self._bucket_for(length)
-        tc0 = time.perf_counter()
-        with tele.span("serving.prefix_copy", cat="serving",
-                       bucket=bucket, to_pool=bool(dst_pool)):
+        with self._phase("prefix_copy", bucket=bucket,
+                         to_pool=bool(dst_pool)):
             self._caches, self._pool = self._copy_fn(bucket)(
                 self._caches, self._pool, np.int32(src), np.int32(dst),
                 np.bool_(src_pool), np.bool_(dst_pool))
-        self._phase_add("copy", time.perf_counter() - tc0)
         if ("copy", bucket) not in self._prog_seen:
             self._prog_seen.add(("copy", bucket))
             profiler.register_program(
@@ -1761,13 +1793,10 @@ class InferenceEngine:
         (rounded up to the covering bucket — the decode side clips by
         position, so the pad rows are junk it never reads)."""
         bucket = self._bucket_for(length)
-        tc0 = time.perf_counter()
-        with tele.span("serving.handoff_export", cat="serving",
-                       bucket=bucket):
+        with self._phase("handoff_export", bucket=bucket):
             rows = self._handoff_fn(bucket)(self._caches,
                                             np.int32(slot))
             rows = jax.tree_util.tree_map(np.asarray, rows)
-        self._phase_add("copy", time.perf_counter() - tc0)
         if ("handoff", bucket, "export") not in self._prog_seen:
             self._prog_seen.add(("handoff", bucket, "export"))
             profiler.register_program(
@@ -1782,12 +1811,9 @@ class InferenceEngine:
         the transfer was int8)."""
         bucket = self._bucket_for(length)
         rows = unpack_rows(rows, self._caches)
-        tc0 = time.perf_counter()
-        with tele.span("serving.handoff_import", cat="serving",
-                       bucket=bucket):
+        with self._phase("handoff_import", bucket=bucket):
             self._caches = self._handoff_fn(bucket, write=True)(
                 self._caches, np.int32(slot), rows)
-        self._phase_add("copy", time.perf_counter() - tc0)
         if ("handoff", bucket, "import") not in self._prog_seen:
             self._prog_seen.add(("handoff", bucket, "import"))
             profiler.register_program(
@@ -1854,14 +1880,10 @@ class InferenceEngine:
         the error rides the staged tuple to admission, where the
         request retires with reason ``"error"`` instead of unwinding
         ``step()`` from inside the stager fill."""
-        th0 = time.perf_counter()
-        try:
+        # the stager is inline, so fills run inside _admit and the
+        # time lands on the round in flight (dropped when none is)
+        with self._phase("h2d"):
             return self._place_prompt_inner(req)
-        finally:
-            # the stager is inline, so fills run inside _admit and the
-            # time lands on the round in flight (the _phase guard
-            # drops it when no round is)
-            self._phase_add("h2d", time.perf_counter() - th0)
 
     def _put_tokens(self, padded):
         """Host prompt tokens -> the device array every prefill
@@ -2515,13 +2537,9 @@ class InferenceEngine:
             try:
                 hit, entry, depth = 0, None, 0
                 if self._prefix is not None:
-                    tl0 = time.perf_counter()
-                    with tele.span("serving.prefix_lookup",
-                                   cat="serving",
-                                   hist=_TM_PREFIX_LOOKUP_MS):
+                    with self._phase("prefix_lookup",
+                                     hist=_TM_PREFIX_LOOKUP_MS):
                         depth, entry = self._prefix.lookup(req.seq)
-                    self._phase_add("prefix_lookup",
-                                    time.perf_counter() - tl0)
                     # a FULL hit still re-prefills the last prompt
                     # token: the cache retains K/V only, and the first
                     # generated token needs the last position's logits
@@ -2624,18 +2642,17 @@ class InferenceEngine:
         p = len(req.seq)
         start = 0
         top = self.prefill_buckets[-1]
-        td0 = time.perf_counter()
-        while start < p:
-            piece = min(p - start, top)
-            bucket = self._bucket_for(piece)
-            chunk = np.zeros((1, bucket), np.int32)
-            chunk[0, :piece] = req.seq[start:start + piece]
-            self._draft_caches = self._draft_prefill_fn(bucket)(
-                self._draft_params, self._draft_aux,
-                self._draft_caches, np.int32(slot), chunk,
-                np.int32(start), np.int32(piece))
-            start += piece
-        self._phase_add("prefill", time.perf_counter() - td0)
+        with self._phase("prefill", slot=slot, draft=True):
+            while start < p:
+                piece = min(p - start, top)
+                bucket = self._bucket_for(piece)
+                chunk = np.zeros((1, bucket), np.int32)
+                chunk[0, :piece] = req.seq[start:start + piece]
+                self._draft_caches = self._draft_prefill_fn(bucket)(
+                    self._draft_params, self._draft_aux,
+                    self._draft_caches, np.int32(slot), chunk,
+                    np.int32(start), np.int32(piece))
+                start += piece
         self._draft_pos[slot] = p
         self._draft_pending[slot] = []
 
@@ -2686,9 +2703,8 @@ class InferenceEngine:
             chunk[0, :piece] = req.seq[start:start + piece]
             dev = self._put_tokens(chunk)
         fn = self._prefill_fn(bucket)
-        tp0 = time.perf_counter()
-        with tele.span("serving.prefill", cat="serving", bucket=bucket,
-                       slot=slot, start=start):
+        with self._phase("prefill", bucket=bucket, slot=slot,
+                         start=start):
             self._caches, self._state, t0 = fn(
                 params, aux, self._caches, self._state,
                 np.int32(slot), dev, np.int32(start), np.int32(piece),
@@ -2696,7 +2712,6 @@ class InferenceEngine:
                 _raw_key(req.seed),
                 np.int32(-1 if req.eos_id is None else req.eos_id),
                 np.int32(req.limit - req.resumed))
-        self._phase_add("prefill", time.perf_counter() - tp0)
         if ("prefill", bucket) not in self._prog_seen:
             self._prog_seen.add(("prefill", bucket))
             # post-dispatch arrays carry the same avals the dispatch
@@ -2846,19 +2861,15 @@ class InferenceEngine:
                     % self.round_timeout_ms)
             time.sleep(0.001)
 
-    def _phase_add(self, key, dt):
-        """Attribute ``dt`` seconds of the in-flight round to a phase
-        (no-op outside step() — e.g. a submit-path capture write)."""
-        acc = self._phase
-        if acc is not None:
-            acc[key] = acc.get(key, 0.0) + dt
+    def _phase(self, name, hist=None, **args):
+        """``with self._phase("prefill", bucket=...):`` — the span
+        ``serving.<name>`` (:class:`_Phase`), its seconds attributed to
+        the in-flight round's ledger phase of that name."""
+        return _Phase(self, name, hist, args)
 
     def _drain_one(self):
-        t0 = time.perf_counter()
-        try:
+        with self._phase("drain"):
             self._drain_one_inner()
-        finally:
-            self._phase_add("drain", time.perf_counter() - t0)
 
     def _drain_one_inner(self):
         entry = self._drain[0]       # peek: a watchdog trip must not
@@ -2972,13 +2983,11 @@ class InferenceEngine:
         ndraft = int(dlen.sum())
         self.stats["spec_drafted"] += ndraft
         _TM_SPEC_DRAFTED.inc(ndraft)
-        tv0 = time.perf_counter()
-        with tele.span("serving.verify_round", cat="serving",
-                       slots_busy=busy, drafted=ndraft):
+        with self._phase("verify_round", slots_busy=busy,
+                         drafted=ndraft):
             self._caches, self._state, out = self._verify_fn(
                 self._params, self._aux, self._caches,
                 self._state, drafts, dlen)
-        self._phase_add("dispatch", time.perf_counter() - tv0)
         if "verify" not in self._prog_seen:
             self._prog_seen.add("verify")
             profiler.register_program(
@@ -3036,11 +3045,10 @@ class InferenceEngine:
                         again = True
                     else:
                         newly_done.append(s)
-            tdf0 = time.perf_counter()
-            self._draft_caches, props = self._draft_fn(
-                self._draft_params, self._draft_aux,
-                self._draft_caches, pos, catchup, clen)
-            self._phase_add("dispatch", time.perf_counter() - tdf0)
+            with self._phase("draft_round"):
+                self._draft_caches, props = self._draft_fn(
+                    self._draft_params, self._draft_aux,
+                    self._draft_caches, pos, catchup, clen)
             if "draft" not in self._prog_seen:
                 self._prog_seen.add("draft")
                 profiler.register_program(
@@ -3080,114 +3088,116 @@ class InferenceEngine:
         by construction (doc/observability.md "Round-phase
         attribution")."""
         self._check_open()
-        rt0 = time.perf_counter()
-        self._phase = {}
-        dispatched = None
+        self._phase_acc = {}
         try:
-            if self._spec and self._drain:
-                # speculation drains EAGERLY: drafting needs the
-                # current context (the n-gram drafter and the
-                # draft-model catch-up read drained tokens) and exact
-                # per-slot positions; the tokens-per-dispatch the
-                # verify step buys replaces the drain-lag pipelining
-                # drain_depth bought (doc/serving.md)
-                while self._drain:
-                    self._drain_one()
-            self._sweep()
-            # chunked prefill, Sarathi-style per-round budget: at most
-            # ~prefill_chunk tokens of prefill work run between decode
-            # rounds — ONE piece of the oldest parked request, then
-            # admissions' first pieces until the budget is spent
-            # (_admit holds the overflow request for next round).
-            # Resident decoders therefore stall at most one budget's
-            # worth of prefill per round, however many long prompts
-            # are in flight.
-            self._round_budget = self.prefill_chunk or float("inf")
-            if self._chunking:
-                st = self._chunking.popleft()
-                try:
-                    if not self._advance_chunk(st):
-                        self._chunking.append(st)
-                except Exception as e:   # noqa: BLE001 — poisoned
-                    self._poison(st, e)
-            admitted = self._admit()
-            busy = self.slots - len(self._free)
-            _TM_OCCUPANCY.set(busy)
-            if admitted or busy:
-                # zero-admission rounds COUNT while work is resident
-                # (they are what admission starvation looks like — the
-                # histogram's 0 bucket exists for them); only
-                # fully-idle polls are not a scheduling round
-                _TM_ADMITTED.observe(admitted)
-            # slots still mid-prefill have nothing to decode: a round
-            # with ONLY those resident would be pure wasted dispatch.
-            # Handoff-pinned slots likewise (their requests left), and
-            # a prefill-role engine NEVER dispatches the decode family
-            # — that is the role's compile contract
-            if busy - len(self._chunking) - len(self._handoff_slots) > 0 \
-                    and self.role != "prefill":
-                if self._spec and self._spec_round(busy):
-                    dispatched = "verify"
-                else:
-                    if self._spec:
-                        # speculation armed but no slot had a usable
-                        # draft (cold context, budget exhausted, or a
-                        # slot too near the cache end for the chunk
-                        # write): plain decode serves the round
-                        _TM_SPEC_FALLBACK.inc()
-                        self.stats["spec_fallback_rounds"] += 1
-                    td0 = time.perf_counter()
-                    with tele.span("serving.decode_round",
-                                   cat="serving", slots_busy=busy):
-                        self._caches, self._state, out = self._step_fn(
-                            self._params, self._aux,
-                            self._caches, self._state)
-                    self._phase_add("dispatch",
-                                    time.perf_counter() - td0)
-                    dispatched = "decode"
-                    if "decode" not in self._prog_seen:
-                        self._prog_seen.add("decode")
-                        profiler.register_program(
-                            "serving_decode", self._step_fn,
-                            (self._params, self._aux,
-                             self._caches, self._state))
-                    self._drain.append(("step", out))
-                    self.stats["steps"] += 1
-                    _TM_ROUNDS.inc()
-                    _TM_SLOTS_BUSY.observe(busy)
-                    flt = _SERVING_FAULTS
-                    if flt is not None:
-                        flt.serving_crash()   # injected process death
-            # a prefill-role engine drains eagerly: no decode rounds
-            # follow to push results out of the drain-lag window, and
-            # every drained prefill is a handoff package the router is
-            # waiting on
-            while len(self._drain) > (
-                    self._drain_depth
-                    if self._busy() and self.role != "prefill" else 0):
-                self._drain_one()
-            self._last_ok_t = time.perf_counter()
-            self._slo_tick(self._last_ok_t)
-            self._record_round(rt0, busy, admitted, dispatched)
+            with tele.span("serving.round", cat="serving") as rnd:
+                busy, admitted, dispatched = self._round()
+            self._record_round(rnd, busy, admitted, dispatched)
         finally:
-            self._phase = None
+            self._phase_acc = None
         done_now, self._done_buf = self._done_buf, []
         return done_now
 
-    def _record_round(self, rt0, busy, admitted, dispatched):
-        """Land the finished round in the phase ledger + histograms.
-        Pure-idle polls (nothing resident, admitted, or drained) are
-        not scheduling rounds and are skipped; an aborted round (a
-        watchdog trip unwinding step()) records nothing — its drain
-        retries next round."""
-        acc = self._phase
-        wall = time.perf_counter() - rt0
+    def _round(self):
+        """The body of :meth:`step`; returns (busy slots, admissions,
+        which program the round dispatched or None)."""
+        dispatched = None
+        if self._spec and self._drain:
+            # speculation drains EAGERLY: drafting needs the
+            # current context (the n-gram drafter and the
+            # draft-model catch-up read drained tokens) and exact
+            # per-slot positions; the tokens-per-dispatch the
+            # verify step buys replaces the drain-lag pipelining
+            # drain_depth bought (doc/serving.md)
+            while self._drain:
+                self._drain_one()
+        self._sweep()
+        # chunked prefill, Sarathi-style per-round budget: at most
+        # ~prefill_chunk tokens of prefill work run between decode
+        # rounds — ONE piece of the oldest parked request, then
+        # admissions' first pieces until the budget is spent
+        # (_admit holds the overflow request for next round).
+        # Resident decoders therefore stall at most one budget's
+        # worth of prefill per round, however many long prompts
+        # are in flight.
+        self._round_budget = self.prefill_chunk or float("inf")
+        if self._chunking:
+            st = self._chunking.popleft()
+            try:
+                if not self._advance_chunk(st):
+                    self._chunking.append(st)
+            except Exception as e:   # noqa: BLE001 — poisoned
+                self._poison(st, e)
+        admitted = self._admit()
+        busy = self.slots - len(self._free)
+        _TM_OCCUPANCY.set(busy)
+        if admitted or busy:
+            # zero-admission rounds COUNT while work is resident
+            # (they are what admission starvation looks like — the
+            # histogram's 0 bucket exists for them); only
+            # fully-idle polls are not a scheduling round
+            _TM_ADMITTED.observe(admitted)
+        # slots still mid-prefill have nothing to decode: a round
+        # with ONLY those resident would be pure wasted dispatch.
+        # Handoff-pinned slots likewise (their requests left), and
+        # a prefill-role engine NEVER dispatches the decode family
+        # — that is the role's compile contract
+        if busy - len(self._chunking) - len(self._handoff_slots) > 0 \
+                and self.role != "prefill":
+            if self._spec and self._spec_round(busy):
+                dispatched = "verify"
+            else:
+                if self._spec:
+                    # speculation armed but no slot had a usable
+                    # draft (cold context, budget exhausted, or a
+                    # slot too near the cache end for the chunk
+                    # write): plain decode serves the round
+                    _TM_SPEC_FALLBACK.inc()
+                    self.stats["spec_fallback_rounds"] += 1
+                with self._phase("decode_round", slots_busy=busy):
+                    self._caches, self._state, out = self._step_fn(
+                        self._params, self._aux,
+                        self._caches, self._state)
+                dispatched = "decode"
+                if "decode" not in self._prog_seen:
+                    self._prog_seen.add("decode")
+                    profiler.register_program(
+                        "serving_decode", self._step_fn,
+                        (self._params, self._aux,
+                         self._caches, self._state))
+                self._drain.append(("step", out))
+                self.stats["steps"] += 1
+                _TM_ROUNDS.inc()
+                _TM_SLOTS_BUSY.observe(busy)
+                flt = _SERVING_FAULTS
+                if flt is not None:
+                    flt.serving_crash()   # injected process death
+        # a prefill-role engine drains eagerly: no decode rounds
+        # follow to push results out of the drain-lag window, and
+        # every drained prefill is a handoff package the router is
+        # waiting on
+        while len(self._drain) > (
+                self._drain_depth
+                if self._busy() and self.role != "prefill" else 0):
+            self._drain_one()
+        self._last_ok_t = time.perf_counter()
+        self._slo_tick(self._last_ok_t)
+        return busy, admitted, dispatched
+
+    def _record_round(self, rnd, busy, admitted, dispatched):
+        """Land the finished round (``rnd``: its ``serving.round`` span)
+        in the phase ledger + histograms. Pure-idle polls (nothing
+        resident, admitted, or drained) are not scheduling rounds and
+        are skipped; an aborted round (a watchdog trip unwinding
+        step()) records nothing — its drain retries next round."""
+        acc = self._phase_acc
+        rt0, wall = rnd.t0, rnd.dt
         if not (admitted or busy or acc):
             return
         # host scheduling = the unattributed remainder (sweep, queue
         # bookkeeping, chunk math, drafter proposals). The attributed
-        # phases are disjoint same-thread intervals inside
-        # [rt0, now], so the remainder is >= 0 up to float error —
+        # phases are disjoint same-thread spans inside the round's
+        # own, so the remainder is >= 0 up to float error —
         # clamped, and the phases sum to wall_ms exactly.
         acc["sched"] = max(0.0, wall - sum(acc.values()))
         phases_ms = {k: round(v * 1e3, 4) for k, v in acc.items()}

@@ -15,7 +15,9 @@ Design constraints (and why the hot paths can afford this):
 * **host-side only** — ``time.perf_counter`` and python ints; nothing
   here is ever traced into a compiled program and nothing forces a
   device sync. ``bench.py``'s overhead arm pins the fused-step cost
-  of leaving telemetry on at < 2%.
+  of leaving telemetry on at < 2%. A :class:`span` also enters a
+  ``jax.profiler.TraceAnnotation``, so the same host regions show on
+  the profiler's trace beside the device's operations.
 * **pre-resolved handles** — instrumentation sites call
   ``counter(name)`` once at import and keep the object; the per-event
   cost is one enabled-flag check + one small-lock add.
@@ -46,6 +48,8 @@ import os
 import re
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from .base import MXNetError
 
@@ -524,23 +528,62 @@ def tracing_paused():
             _state.trace_active = was and _state.trace_path is not None
 
 
-@contextlib.contextmanager
-def span(name, cat="mx", hist=None, **args):
-    """Time a region: always feeds ``hist`` (a :class:`Histogram`, in
-    milliseconds) when given, and records a trace span while a capture
-    is armed. Near-free when disabled (one flag check)."""
-    if not _state.enabled:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if hist is not None:
-            hist.observe(dt * 1e3)
+class span:
+    """Time a host region: the one primitive, two sinks, one clock.
+
+    ``with span(name, hist=h, **args):`` observes ``hist`` (a
+    :class:`Histogram`, milliseconds) when given, records a Chrome
+    trace event while a capture is armed (:func:`start_trace`), and
+    enters ``jax.profiler.TraceAnnotation(name, **args)``: under
+    ``mx.profiler.start`` / ``jax.profiler`` the same span lands on the
+    host plane of the profiler's trace, on the device trace's clock,
+    with ``args`` as its event stats. Without a profiler session the
+    annotation costs well under a microsecond.
+
+    ``.t0`` (``perf_counter`` at entry) and, on exit, ``.dt`` (the
+    seconds the region took) are left for callers that keep a ledger
+    of their own (the serving engine's round phases). :meth:`drop`,
+    called inside the block, says the region turned out not to be a
+    sample (an iterator's ``StopIteration``): nothing is observed and
+    no Chrome event written. With ``MXNET_TELEMETRY=0`` only the two
+    clock reads remain: no annotation, no observation, no event."""
+
+    __slots__ = ("name", "cat", "hist", "args", "t0", "dt", "_ann",
+                 "_dropped")
+
+    def __init__(self, name, cat="mx", hist=None, **args):
+        self.name = name
+        self.cat = cat
+        self.hist = hist
+        self.args = args
+        self.dt = 0.0
+        self._ann = None
+        self._dropped = False
+
+    def drop(self):
+        self._dropped = True
+
+    def __enter__(self):
+        if _state.enabled:
+            self._ann = _Annotation(self.name, **self.args)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dt = dt = time.perf_counter() - self.t0
+        ann, self._ann = self._ann, None
+        if ann is None:
+            return False
+        ann.__exit__(*exc)
+        if self._dropped:
+            return False
+        if self.hist is not None:
+            self.hist.observe(dt * 1e3)
         if _state.trace_active:
-            trace_complete(name, t0, dt, cat=cat, args=args or None)
+            trace_complete(self.name, self.t0, dt, cat=self.cat,
+                           args=self.args or None)
+        return False
 
 
 # ---------------------------------------------------------------------------

@@ -211,18 +211,6 @@ def test_reference_api_shims():
     assert issubclass(mx.rtc.Rtc, mx.rtc.PallasOp)
 
 
-def test_profiler_step_stats():
-    """Step-time accumulation: count/mean/percentiles."""
-    mx.profiler.reset_step_stats()
-    for _ in range(5):
-        with mx.profiler.record_step():
-            pass
-    st = mx.profiler.get_step_stats()
-    assert st["count"] == 5 and st["total_s"] >= 0
-    mx.profiler.reset_step_stats()
-    assert mx.profiler.get_step_stats()["count"] == 0
-
-
 def test_profiler_compiled_stats_executor():
     """compiled_stats reports XLA memory/cost analysis for an Executor
     (the example/memcost capability: the reference dumps its memory
@@ -294,29 +282,6 @@ def test_loss_metric():
     assert m.get() == ("loss", 3.0)
     m.update([None], [mx.nd.array(np.array([7.0], np.float32))])
     assert m.get()[1] == 4.0
-
-
-def test_profiler_benchmark_chain():
-    """The honest-timing utility (doc/performance.md methodology as a
-    library API): measures a dependent jitted chain, returns sane
-    positive per-step time and spread."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    @jax.jit
-    def step(x):
-        # heavy enough (a 256^3 matmul) that the N-vs-2N difference
-        # stands clear of scheduling jitter on a loaded test box
-        return jnp.tanh(x @ x * 1e-3)
-
-    x0 = jnp.ones((256, 256), jnp.float32)
-    dt, spread = mx.profiler.benchmark_chain(step, x0, steps=32, reps=3)
-    assert dt > 0
-    assert spread >= 0
-
-    with pytest.raises(TypeError):
-        mx.profiler.benchmark_chain(step, x0, 8)  # steps is kw-only
 
 
 def test_reference_module_aliases():
