@@ -1044,10 +1044,11 @@ def matmul_stats(x, w, *, block_m=256, block_n=256, block_k=512,
 # ---------------------------------------------------------------------------
 # paged attention — the serving engine's decode/verify read (ISSUE 11).
 #
-# The slot-paged KV cache is [S, max_len, Hkv, D] with every slot at its
-# own position; the dense read gathers (and, for int8, dequantizes) ALL
-# max_len rows per emitted token even when a slot is 40 tokens into a
-# 1024-row cache. This kernel walks only each slot's LIVE blocks: grid
+# The slot-paged KV cache is stored [S, max_len, Hkv*D] (kv-major
+# lanes; parallel/decode.py states the layout) with every slot at its
+# own position; the dense read streams (and masks) ALL max_len rows
+# per emitted token even when a slot is 40 tokens into a 1024-row
+# cache. This kernel walks only each slot's LIVE blocks: grid
 # over (slot, kv-block) under a PrefetchScalarGridSpec — the
 # per-slot position vector is scalar-prefetched so the cache index
 # maps clamp every grid step past ceil((pos + C) / block_k) back to
@@ -1057,8 +1058,7 @@ def matmul_stats(x, w, *, block_m=256, block_n=256, block_k=512,
 # accumulation merges blocks exactly (a reassociation, not an
 # approximation — the same argument as Decoder._blocked_attn), and
 # int8 caches dequantize per block IN the kernel from the side-scale
-# operands, so the cache is read once at 1 byte/elem instead of being
-# materialized as a full float copy first. C > 1 serves the
+# operands, so the cache is read once at 1 byte/elem. C > 1 serves the
 # chunked-query flavors: the speculative verify step's [S, K+1] chunk
 # and the draft model's catch-up window (doc/serving.md "Paged
 # attention").
@@ -1176,15 +1176,22 @@ def _paged_attn_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k,
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def paged_attention(q, k, v, pos, *, k_scale=None, v_scale=None,
-                    scale=None, block_k=None, interpret=None):
+def paged_attention(q, k, v, pos, *, kv_heads, k_scale=None,
+                    v_scale=None, scale=None, block_k=None,
+                    interpret=None):
     """Slot-paged decode attention reading only the live KV rows.
 
     q: [S, C, H, D] — each slot's C-token query chunk (C=1 plain
     decode; C=K+1 the speculative verify chunk; C=W the draft
-    catch-up). k, v: [S, L, Hkv, D] cache buffers (float, or int8 with
+    catch-up). k, v: the cache buffers AS STORED, [S, L, Hkv*D] with
+    the ``kv_heads`` heads' D values side by side, kv-major
+    (``parallel/decode.py`` states the layout; float, or int8 with
     ``k_scale``/``v_scale`` [S, L, Hkv] f32 row scales — dequantized
-    inside the kernel). pos: [S] int32, the chunk's start position per
+    inside the kernel). The kernel takes them as they are: a
+    (block_k, Hkv*D) block is whole on the lane axis. Inside a
+    tensor-parallel shard the buffers are the shard's own lanes and
+    ``kv_heads`` its local head count. pos: [S] int32, the chunk's
+    start position per
     slot: the chunk rows at [pos, pos+C) must already be WRITTEN (the
     decoder writes before reading, same as the dense path), and each
     query row attends keys [0, pos + its chunk offset]. Returns
@@ -1210,7 +1217,12 @@ def paged_attention(q, k, v, pos, *, k_scale=None, v_scale=None,
     _count_dispatch()
     s_, c, h, d = q.shape
     l_ = k.shape[1]
-    kv = k.shape[2]
+    kv = int(kv_heads)
+    if k.ndim != 3 or k.shape[2] != kv * d or v.shape != k.shape:
+        raise ValueError(
+            "paged_attention: k and v must be the stored cache buffers "
+            "[S, L, kv_heads*D] = [%d, L, %d], got %s and %s"
+            % (s_, kv * d, k.shape, v.shape))
     g = h // kv
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -1248,7 +1260,7 @@ def paged_attention(q, k, v, pos, *, k_scale=None, v_scale=None,
     def kmap(si, j, pref):
         return (si, live_j(si, j, pref), 0)
 
-    # the cache rides as its free [S, L, Hkv*D] view: a (block_k,
+    # the cache rides as it is stored, [S, L, Hkv*D]: a (block_k,
     # Hkv*D) block is whole on the lane axis, which the TPU lowering
     # accepts at any head count (see the kernel docstring)
     in_specs = [
@@ -1256,7 +1268,7 @@ def paged_attention(q, k, v, pos, *, k_scale=None, v_scale=None,
         pl.BlockSpec((1, block_k, kv * d), kmap),
         pl.BlockSpec((1, block_k, kv * d), kmap),
     ]
-    operands = [qg, k.reshape(s_, l_, kv * d), v.reshape(s_, l_, kv * d)]
+    operands = [qg, k, v]
     if quant:
         # [S, L, KV] row scales: a (block_k, KV) block, one lane per
         # kv head; the kernel broadcasts column h over head h's D
@@ -1534,7 +1546,7 @@ def fused_decode_attention(x, pos, k_cache, v_cache, wqkv, sqkv, bqkv,
     tiles resident across slots instead of re-fetching per grid step.
 
     x: [S, E] current-token activations; pos: [S] int32;
-    k_cache/v_cache: [S, L, KV, D] float caches (int8 KV composes
+    k_cache/v_cache: the stored [S, L, KV*D] float caches (int8 KV composes
     with ``matmul_impl="pallas"`` instead — the fused path wants the
     unquantized read). ``wqkv``/``wo`` + scales/biases as in
     :func:`quant_matmul` (one ``bits`` for both). Returns
@@ -1547,7 +1559,8 @@ def fused_decode_attention(x, pos, k_cache, v_cache, wqkv, sqkv, bqkv,
         interpret = _use_interpret()
     _count_dispatch()
     s_, e = x.shape
-    l_, kv, d = k_cache.shape[1], k_cache.shape[2], k_cache.shape[3]
+    l_, kv = k_cache.shape[1], int(kv_heads)
+    d = k_cache.shape[2] // kv
     fq = wqkv.shape[0]
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -1576,9 +1589,6 @@ def fused_decode_attention(x, pos, k_cache, v_cache, wqkv, sqkv, bqkv,
     def slot2(i, pref):
         return (i, 0)
 
-    def slot4(i, pref):
-        return (i, 0, 0, 0)
-
     def slot3(i, pref):
         return (i, 0, 0)
 
@@ -1587,8 +1597,8 @@ def fused_decode_attention(x, pos, k_cache, v_cache, wqkv, sqkv, bqkv,
         grid=(s_,),
         in_specs=[
             pl.BlockSpec((1, e), slot2),                   # x
-            pl.BlockSpec((1, l_, kv, d), slot4),           # k cache
-            pl.BlockSpec((1, l_, kv, d), slot4),           # v cache
+            pl.BlockSpec((1, l_, kv * d), slot3),          # k cache
+            pl.BlockSpec((1, l_, kv * d), slot3),          # v cache
             pl.BlockSpec((fq, wqkv.shape[1]), full),       # wqkv
             pl.BlockSpec((fq, sq2.shape[1]), full),        # sqkv
             pl.BlockSpec((1, fq), full),                   # bqkv

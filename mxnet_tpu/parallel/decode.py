@@ -6,8 +6,8 @@ users to write a second, incremental model (and keep it in sync with the
 training symbol), ``Decoder`` DERIVES the incremental program from the
 same Symbol graph the trainer compiled: the topological walk of
 ``parallel.graph.make_graph_fn`` re-runs with every ``MultiHeadAttention``
-node swapped for a cached variant (new tokens' K/V written into a
-[B, max_len, H, D] ring of buffers with ``lax.dynamic_update_slice``;
+node swapped for a cached variant (new tokens' K/V written into
+[B, max_len, Hkv*D] buffers with ``lax.dynamic_update_slice``;
 queries attend to the cache under the mask ``key_pos <= query_pos``) and
 ``PositionalEmbedding`` sliced at the current position. Every other LM op
 (Embedding, LayerNorm, FullyConnected, activations, elementwise
@@ -57,6 +57,44 @@ _POSITIONWISE = {
 _TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding"}
 
 _LOSS_HEADS = {"SoftmaxOutput", "SoftmaxCELoss"}
+
+# -- the stored layout of the KV cache, stated once ------------------------
+# A cache buffer is [B, rows, Hkv*D]: axis 0 the batch row / serving slot,
+# axis 1 the cache row (a position, or a ring slot of a windowed node),
+# axis 2 every kv head's D values side by side, kv-major (head h owns
+# lanes [h*D, (h+1)*D), so a tensor-parallel shard of axis 2 holds whole
+# heads). It is the layout the decode read consumes: the minor dimension
+# fills the chip's 128 lanes where D=64 alone would be padded to twice
+# its bytes, and the compiled decode program holds no relayout of it.
+# int8 caches keep [B, rows, Hkv] f32 row scales beside it. Everything
+# that needs the head axis goes through these two functions.
+
+# a chunk of at most this many tokens (decode, the speculative verify
+# and draft chunks) is written row by row and read straight off the
+# stored rows (Decoder._lane_attn); a longer one (prefill) is written
+# as a block and read per head off an unfolded copy
+_SHORT_CHUNK = 8
+
+
+def fold_heads(x):
+    """A [B, C, Hkv, D] chunk as stored cache rows [B, C, Hkv*D]."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+def unfold_heads(rows, kv):
+    """Stored rows [B, C, Hkv*D] as [B, C, Hkv, D]. On the chip this is
+    a relayout copy of what it is given, not a view (D=64 fills half a
+    lane tile): hand it a SMALL slice — a ring, a block, one slot's rows
+    beside a prefill — never the whole cache inside a decode step."""
+    return rows.reshape(rows.shape[:-1] + (kv, rows.shape[-1] // kv))
+
+
+def head_segments(kv, d, dtype):
+    """The constant 0/1 [Hkv*D, Hkv] matrix that maps a stored lane to
+    its kv head: contracting with it sums each head's D lanes, its
+    transpose spreads a per-head number over them."""
+    lane = jnp.arange(kv * d, dtype=jnp.int32) // d
+    return (lane[:, None] == jnp.arange(kv, dtype=jnp.int32)).astype(dtype)
 
 
 def _logits_symbol(symbol):
@@ -115,7 +153,8 @@ class Decoder:
     cache_dtype : str, optional
         ``"int8"`` stores K/V quantized — symmetric per-(position, head)
         row scales (``amax/127``, f32, D-fold smaller than the rows they
-        scale) kept in side buffers, dequantized at the attention read.
+        scale) kept in side buffers; the decode read applies them to
+        the scores and the weights, prefill dequantizes the rows.
         Halves cache RESIDENCY vs bf16 (2x the max_len x batch budget
         in the same HBM) at ~0.4% row RMS error (per-row scales, so one
         outlier position cannot poison its neighbours). NOT a speed
@@ -134,9 +173,10 @@ class Decoder:
         (``ops.pallas_kernels.paged_attention``): walk only each
         sequence's LIVE cache rows — bounded by the (per-slot)
         position — with online-softmax accumulation and in-kernel int8
-        dequantization, so the K/V buffers are read once at their
-        stored width instead of gathered (and, for int8, dequantized
-        to a full float copy) whole every step. Exact: online softmax
+        dequantization, so only the live rows of the K/V buffers are
+        read where the dense read streams (and masks) all ``max_len``
+        rows every step. Both take the buffers as they are stored
+        ([S, max_len, Hkv*D]: no reshape, no copy). Exact: online softmax
         reassociates, it does not approximate — greedy outputs match
         the dense path (float flavors byte-identical through the
         serving gauntlet; int8 under the usual quantized-cache
@@ -377,24 +417,28 @@ class Decoder:
 
     # -- cache ----------------------------------------------------------
     def init_cache(self, batch_size, kv_sharding=None):
-        """Zeroed K/V buffers, [B, max_len, Hkv, D] per attention node
-        (plus [B, max_len, Hkv] f32 row scales when
-        ``cache_dtype="int8"``). ``Hkv < num_heads`` under grouped-query
-        attention — the cache shrinks by the group factor. Sliding-
-        window nodes get a RING of only ``window`` slots plus a
-        [B, window] int32 buffer of each slot's absolute position
-        (-1 = never written) — decode memory O(window) regardless of
-        generation length.
+        """Zeroed K/V buffers, [B, max_len, Hkv*D] per attention node:
+        the stored layout stated at the top of this module (axis 0 the
+        batch row or serving slot, axis 1 the cache row, axis 2 every
+        kv head's D values side by side, kv-major), plus
+        [B, max_len, Hkv] f32 row scales when ``cache_dtype="int8"``.
+        ``Hkv < num_heads`` under grouped-query attention — the cache
+        shrinks by the group factor. Sliding-window nodes get a RING
+        of only ``window`` rows, [B, window, Hkv*D], plus a [B, window]
+        int32 buffer of each ring row's absolute position (-1 = never
+        written) — decode memory O(window) regardless of generation
+        length.
 
         ``kv_sharding`` (optional ``jax.sharding.NamedSharding`` whose
-        spec names the kv-head dimension, e.g.
+        spec names dimension 2, e.g.
         ``NamedSharding(mesh, P(None, None, "model"))``): every K/V
         and row-scale buffer is laid out sharded over the mesh's model
-        axis on its kv-head dim — each shard holds ``Hkv/tp`` heads of
-        every row — and ring-position buffers (rank 2, headless)
-        replicate. This is the tensor-parallel serving cache layout
-        (doc/serving.md "Tensor-parallel serving"); the matching
-        compute runs through ``_run_slots``'s ``tp=`` axis."""
+        axis on dimension 2 — kv-major lanes, so each shard holds
+        ``Hkv/tp`` whole heads of every row — and ring-position
+        buffers (rank 2, headless) replicate. This is the
+        tensor-parallel serving cache layout (doc/serving.md
+        "Tensor-parallel serving"); the matching compute runs through
+        ``_run_slots``'s ``tp=`` axis."""
         from ..ops.attention import MultiHeadAttention as _MHA
 
         caches = []
@@ -403,12 +447,14 @@ class Decoder:
             h = n.params["num_heads"]
             win = self._node_window(n)
             slots = win or self.max_len
-            shape = (batch_size, slots, _MHA.kv_heads(n.params), e // h)
+            kv = _MHA.kv_heads(n.params)
+            shape = (batch_size, slots, kv * (e // h))
             if self._cache_int8:
+                scales = (batch_size, slots, kv)
                 entry = (jnp.zeros(shape, jnp.int8),
-                         jnp.ones(shape[:3], jnp.float32),
+                         jnp.ones(scales, jnp.float32),
                          jnp.zeros(shape, jnp.int8),
-                         jnp.ones(shape[:3], jnp.float32))
+                         jnp.ones(scales, jnp.float32))
             else:
                 entry = (jnp.zeros(shape, self._cache_dtype),
                          jnp.zeros(shape, self._cache_dtype))
@@ -427,8 +473,9 @@ class Decoder:
     @staticmethod
     def cache_specs(caches, axis="model"):
         """Per-leaf ``PartitionSpec`` tree for a cache pytree: K/V and
-        scale buffers (rank >= 3) shard their kv-head dim (dim 2) over
-        ``axis``; ring-position buffers (rank 2, no head dim)
+        scale buffers (rank 3) shard dimension 2 — the kv-major lanes
+        [Hkv*D], or the [Hkv] scales — over ``axis``, so a shard holds
+        whole kv heads; ring-position buffers (rank 2, no head dim)
         replicate. Shared by ``init_cache(kv_sharding=...)`` and the
         serving engine's shard_map program specs, so the two can never
         drift."""
@@ -456,52 +503,50 @@ class Decoder:
     # The projections stay directly under the node's scope.
     @jax.named_scope("cache")
     def _write_cache(self, entry, k, v, pos):
-        """Insert a [B, C, H, D] K/V chunk at ``pos`` into a cache entry.
+        """Insert a [B, C, Hkv, D] K/V chunk at ``pos`` into a cache
+        entry, as stored rows [B, C, Hkv*D] (int8: quantized per
+        (position, head) first, the scales beside them).
+
+        A SHORT chunk (decode, the verify and draft chunks) is written
+        row by row, a scatter over (batch row, position): the slot
+        walk's ``vmap`` batches that into ONE fused scatter over the
+        slots, where a batched dynamic-update-slice becomes a loop of
+        one small update a slot (on the chip 0.4 ms an array a round
+        of 16 slots x 8 steps against 0.03). A VECTOR ``pos`` ([B]
+        int32 — the paged ``_run_slots`` batched walk) is the same
+        scatter with each batch row's own positions. A LONG chunk
+        (prefill) goes in as one dynamic-update-slice block.
 
         Index tuples are uniformly int32: under the package's x64 a
         python-int literal is an int64 index next to the traced int32
-        ``pos``, and dynamic-slice wants one index dtype.
-
-        A VECTOR ``pos`` ([B] int32 — the paged ``_run_slots`` batched
-        walk) scatters each batch row's chunk at its own positions
-        (value-identical to the vmapped per-lane update)."""
-        if jnp.ndim(pos) == 1:
-            p = jnp.asarray(pos, jnp.int32)
-            b, c = k.shape[0], k.shape[1]
-            rows = p[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-            sidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            if self._cache_int8:
-                ck, ks, cv, vs = entry
-                k8, ksc = self._quantize_rows(k)
-                v8, vsc = self._quantize_rows(v)
-                return (ck.at[sidx, rows].set(k8),
-                        ks.at[sidx, rows].set(ksc),
-                        cv.at[sidx, rows].set(v8),
-                        vs.at[sidx, rows].set(vsc))
-            ck, cv = entry
-            return (ck.at[sidx, rows].set(k.astype(ck.dtype)),
-                    cv.at[sidx, rows].set(v.astype(cv.dtype)))
-        z = jnp.int32(0)
-        p = jnp.asarray(pos, jnp.int32)
+        ``pos``, and dynamic-slice wants one index dtype."""
         if self._cache_int8:
-            ck, ks, cv, vs = entry
-            k8, ksc = self._quantize_rows(k)
-            v8, vsc = self._quantize_rows(v)
-            return (lax.dynamic_update_slice(ck, k8, (z, p, z, z)),
-                    lax.dynamic_update_slice(ks, ksc, (z, p, z)),
-                    lax.dynamic_update_slice(cv, v8, (z, p, z, z)),
-                    lax.dynamic_update_slice(vs, vsc, (z, p, z)))
-        ck, cv = entry
-        return (lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                         (z, p, z, z)),
-                lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                         (z, p, z, z)))
+            k, ks = self._quantize_rows(k)
+            v, vs = self._quantize_rows(v)
+            new = (fold_heads(k), ks, fold_heads(v), vs)
+        else:
+            new = (fold_heads(k).astype(entry[0].dtype),
+                   fold_heads(v).astype(entry[1].dtype))
+        b, c = k.shape[:2]
+        p = jnp.asarray(pos, jnp.int32)
+        if p.ndim == 1 or c <= _SHORT_CHUNK:
+            rows = p.reshape(-1, 1) + jnp.arange(c, dtype=jnp.int32)
+            sidx = jnp.arange(b, dtype=jnp.int32)[:, None]
+            return tuple(buf.at[sidx, rows].set(x)
+                         for buf, x in zip(entry, new))
+        z = jnp.int32(0)
+        return tuple(lax.dynamic_update_slice(buf, x, (z, p, z))
+                     for buf, x in zip(entry, new))
 
     @jax.named_scope("cache")
-    def _read_cache(self, entry, dtype, limit=None):
-        """Whole-cache K/V for the attention read: dequantized to
-        ``dtype`` if int8, else returned at the stored dtype (jnp
-        promotion governs mixed cache/compute float dtypes).
+    def _read_cache(self, entry, dtype, kv, limit=None):
+        """K/V with the head axis unfolded, [B, rows, Hkv, D], for the
+        per-head read of a LONG query chunk (prefill): dequantized to
+        ``dtype`` if int8, else at the stored dtype (jnp promotion
+        governs mixed cache/compute float dtypes). The unfold is a
+        copy of what it is given (``unfold_heads``): small beside a
+        prefill, and never on the decode step's path — a short chunk
+        reads the stored rows as they are (``_lane_attn``).
 
         ``limit`` (STATIC int, optional): read only rows [0, limit) —
         the max live position of the dispatch, when the caller knows
@@ -512,20 +557,82 @@ class Decoder:
         so the full read stays and the dead rows are MASKED at the
         score stage instead — value-identical, pinned by
         tests/test_paged_attention.py."""
+        entry = self._live_rows(entry, limit)
         if self._cache_int8:
             ck, ks, cv, vs = entry
-            if limit is not None and limit < ck.shape[1]:
-                ck = lax.slice_in_dim(ck, 0, limit, axis=1)
-                ks = lax.slice_in_dim(ks, 0, limit, axis=1)
-                cv = lax.slice_in_dim(cv, 0, limit, axis=1)
-                vs = lax.slice_in_dim(vs, 0, limit, axis=1)
-            return ((ck * ks[..., None]).astype(dtype),
-                    (cv * vs[..., None]).astype(dtype))
+            return ((unfold_heads(ck, kv) * ks[..., None]).astype(dtype),
+                    (unfold_heads(cv, kv) * vs[..., None]).astype(dtype))
         ck, cv = entry
-        if limit is not None and limit < ck.shape[1]:
-            ck = lax.slice_in_dim(ck, 0, limit, axis=1)
-            cv = lax.slice_in_dim(cv, 0, limit, axis=1)
-        return ck, cv
+        return unfold_heads(ck, kv), unfold_heads(cv, kv)
+
+    @staticmethod
+    def _live_rows(entry, limit):
+        """Rows [0, limit) of every buffer of a linear cache entry
+        (``limit`` static; None or past the end: the entry as it is)."""
+        if limit is None or limit >= entry[0].shape[1]:
+            return entry
+        return tuple(lax.slice_in_dim(buf, 0, limit, axis=1)
+                     for buf in entry)
+
+    @jax.named_scope("attend")
+    def _lane_attn(self, q, entry, pos, kv):
+        """Attention of a SHORT query chunk (decode ``c=1``, the
+        speculative verify and draft chunks) read straight off the
+        stored rows: no reshape of the cache, so nothing cache-sized is
+        copied inside the step and every stored byte streams once.
+
+        ``q`` [B, C, H, D]; ``entry`` the (possibly row-limited) cache
+        entry, K/V [B, L, Hkv*D]; scalar ``pos`` (query row i sits at
+        ``pos + i`` and sees keys ``<= pos + i``). Returns
+        [B, C, H, D] in ``q``'s dtype.
+
+        Scores: each query row is spread into a block-diagonal
+        [Hkv*D, Hkv] matrix (its head-h values in column h, zeros
+        elsewhere — exact, a multiplication by 0/1) and the stored
+        rows are contracted with it: ``K [L, Hkv*D] @ Qbd`` gives every
+        head's score in ONE lane-dense product, operands in the compute
+        dtype, products and sums in float32 — where the per-head
+        einsum rounded its scores to the compute dtype. Softmax in
+        float32 over the masked rows. Values: ``p^T [Hkv, L] @ V
+        [L, Hkv*D]`` and the diagonal blocks of the result (head h's
+        weights against head h's lanes), picked by the same 0/1
+        matrix. Grouped-query attention folds the G query heads of a
+        kv head into the row axis (row = (c, g)), the group fold of
+        the per-head path. int8 caches apply the row scales to the
+        [L, Hkv] scores and to ``p`` — exact, and cheaper than
+        dequantizing the rows."""
+        b, c, h, d = q.shape
+        g = h // kv
+        if self._cache_int8:
+            ck, ks, cv, vs = entry
+            # int8 values are exact in any float dtype
+            ck, cv = ck.astype(q.dtype), cv.astype(q.dtype)
+        else:
+            ck, cv = entry
+            ks = vs = None
+        rows = ck.shape[1]
+        f32 = jnp.float32
+        seg = head_segments(kv, d, q.dtype)                  # [kv*d, kv]
+        # query rows r = (c, g); lanes (kv, d) as the cache stores them
+        qr = q.reshape(b, c, kv, g, d).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, c * g, kv * d)
+        qbd = qr[..., None] * seg                            # [b,r,kv*d,kv]
+        s = jnp.einsum("blk,brkh->brlh", ck, qbd,
+                       preferred_element_type=f32)
+        if ks is not None:
+            s = s * ks[:, None]
+        s = s * f32(1.0 / float(np.sqrt(d)))
+        kpos = jnp.arange(rows)[None, None, :, None]
+        qpos = pos + (jnp.arange(c * g) // g)[None, :, None, None]
+        s = jnp.where(kpos <= qpos, s, f32(-1e30))
+        p = jax.nn.softmax(s, axis=2)                        # [b,r,l,kv]
+        if vs is not None:
+            p = p * vs[:, None]
+        m = jnp.einsum("brlh,blk->brhk", p.astype(cv.dtype), cv,
+                       preferred_element_type=f32)           # [b,r,kv,kv*d]
+        o = jnp.sum(m * seg.T.astype(f32), axis=2)           # [b,r,kv*d]
+        return o.reshape(b, c, g, kv, d).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, c, h, d).astype(q.dtype)
 
     def _embedding_weight_names(self):
         """Parameter names consumed as Embedding tables — always
@@ -671,56 +778,41 @@ class Decoder:
         entry = self._write_cache(entry, k, v, pos)
         if self._attn_impl == "paged" or jnp.ndim(pos) == 1:
             # Pallas paged attention (ops/pallas_kernels.py): walk only
-            # rows [0, pos+C) per slot, int8 dequantized IN the kernel
-            # from the side scales — the cache is read once at its
-            # stored width instead of being dequantized/gathered whole
+            # rows [0, pos+C) per slot of the stored buffer, int8
+            # dequantized IN the kernel from the side scales
             from ..ops.pallas_kernels import paged_attention
             posv = jnp.asarray(pos, jnp.int32) if jnp.ndim(pos) == 1 \
                 else jnp.full((b,), pos, jnp.int32)
             with jax.named_scope("attend"):
                 if self._cache_int8:
                     ck, ks, cv, vs = entry
-                    o = paged_attention(q, ck, cv, posv, k_scale=ks,
-                                        v_scale=vs)
+                    o = paged_attention(q, ck, cv, posv, kv_heads=kv,
+                                        k_scale=ks, v_scale=vs)
                 else:
                     ck, cv = entry
-                    o = paged_attention(q, ck, cv, posv)
+                    o = paged_attention(q, ck, cv, posv, kv_heads=kv)
         elif self._cache_block is not None and c == 1:
-            o = self._blocked_attn(q, entry, pos)
+            o = self._blocked_attn(q, entry, pos, kv)
         else:
             # dense read. A STATIC dispatch position (offline
             # generate/beam prefill call _run with a python-int pos)
-            # bounds the live rows statically: the gather/dequant is
-            # clamped to [0, pos+c) instead of masking all max_len
-            # rows (the masked full read remains for traced positions,
-            # where shapes cannot shrink — see _read_cache)
+            # bounds the live rows statically: the read is clamped to
+            # [0, pos+c) instead of masking all max_len rows (the
+            # masked full read remains for traced positions, where
+            # shapes cannot shrink — see _read_cache)
             limit = self.max_len
             if isinstance(pos, (int, np.integer)):
                 limit = min(self.max_len, int(pos) + c)
-            ck, cv = self._read_cache(entry, q.dtype, limit=limit)
-            with jax.named_scope("attend"):
-                if kv == h:
-                    s = jnp.einsum("bqhd,bkhd->bhqk", q,
-                                   ck) / float(np.sqrt(d))
-                    kpos = jnp.arange(limit)[None, None, None, :]
-                    qpos = pos + jnp.arange(c)[None, None, :, None]
-                    s = jnp.where(kpos <= qpos, s,
-                                  jnp.float32(-1e30).astype(s.dtype))
-                    o = jnp.einsum("bhqk,bkhd->bqhd",
-                                   jax.nn.softmax(s, axis=-1), cv)
-                else:
-                    # GQA: grouped einsums read the kv-head cache directly —
-                    # query heads fold to [B, C, Hkv, G, D] and contract
-                    # against their shared K/V head, no repeated cache copy
-                    qg = q.reshape(b, c, kv, h // kv, d)
-                    s = jnp.einsum("bqKgd,bkKd->bKgqk", qg,
-                                   ck) / float(np.sqrt(d))
-                    kpos = jnp.arange(limit)[None, None, None, None, :]
-                    qpos = pos + jnp.arange(c)[None, None, None, :, None]
-                    s = jnp.where(kpos <= qpos, s,
-                                  jnp.float32(-1e30).astype(s.dtype))
-                    o = jnp.einsum("bKgqk,bkKd->bqKgd",
-                                   jax.nn.softmax(s, axis=-1), cv)
+            if c <= _SHORT_CHUNK:
+                # a matrix-vector read wants the lanes: straight off
+                # the stored rows
+                o = self._lane_attn(q, self._live_rows(entry, limit),
+                                    pos, kv)
+            else:
+                # a matrix-matrix read (prefill) wants the heads
+                o = self._head_attn(
+                    q, *self._read_cache(entry, q.dtype, kv, limit),
+                    pos)
         if tp is not None:
             # ONE collective per attention node: gather the per-shard
             # head outputs (axis 2 is kv-major in every o layout —
@@ -730,6 +822,33 @@ class Decoder:
             # position-wise op run with tp=1's shapes on every shard
             o = lax.all_gather(o, tp[0], axis=2, tiled=True)
         return out_proj(o), entry
+
+    @staticmethod
+    @jax.named_scope("attend")
+    def _head_attn(q, ck, cv, pos):
+        """Per-head matrix products of a LONG query chunk (the prefill
+        buckets, offline ``generate``'s prompt) against K/V unfolded
+        to [B, rows, Hkv, D] (``_read_cache``), masked by position."""
+        b, c, h, d = q.shape
+        rows, kv = ck.shape[1], ck.shape[2]
+        if kv == h:
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, ck) / float(np.sqrt(d))
+            kpos = jnp.arange(rows)[None, None, None, :]
+            qpos = pos + jnp.arange(c)[None, None, :, None]
+            s = jnp.where(kpos <= qpos, s,
+                          jnp.float32(-1e30).astype(s.dtype))
+            return jnp.einsum("bhqk,bkhd->bqhd",
+                              jax.nn.softmax(s, axis=-1), cv)
+        # GQA: grouped einsums read the kv-head rows directly — query
+        # heads fold to [B, C, Hkv, G, D] and contract against their
+        # shared K/V head, no repeated copy of the rows
+        qg = q.reshape(b, c, kv, h // kv, d)
+        s = jnp.einsum("bqKgd,bkKd->bKgqk", qg, ck) / float(np.sqrt(d))
+        kpos = jnp.arange(rows)[None, None, None, None, :]
+        qpos = pos + jnp.arange(c)[None, None, None, :, None]
+        s = jnp.where(kpos <= qpos, s, jnp.float32(-1e30).astype(s.dtype))
+        return jnp.einsum("bKgqk,bkKd->bqKgd",
+                          jax.nn.softmax(s, axis=-1), cv)
 
     def _window_attn(self, q, k, v, entry, pos, win, valid_len=None):
         """Sliding-window attention against a ring-buffer cache.
@@ -760,13 +879,14 @@ class Decoder:
             return jnp.repeat(z, g, axis=2) if g > 1 else z
 
         with jax.named_scope("cache"):
+            # the ring is small (``win`` rows): unfold it per head
             if self._cache_int8:
                 ck, ks, cv, vs, cpos = entry
-                ckf = ck * ks[..., None]
-                cvf = cv * vs[..., None]
+                ckf = unfold_heads(ck, kvh) * ks[..., None]
+                cvf = unfold_heads(cv, kvh) * vs[..., None]
             else:
                 ck, cv, cpos = entry
-                ckf, cvf = ck, cv
+                ckf, cvf = unfold_heads(ck, kvh), unfold_heads(cv, kvh)
             ckf = to_h(ckf.astype(jnp.float32))
             cvf = to_h(cvf.astype(jnp.float32))
         qf = q.astype(jnp.float32)
@@ -824,25 +944,26 @@ class Decoder:
             if self._cache_int8:
                 k8, ksc = self._quantize_rows(kt)
                 v8, vsc = self._quantize_rows(vt)
-                entry = (ck.at[:, slots].set(k8, mode="drop"),
+                entry = (ck.at[:, slots].set(fold_heads(k8), mode="drop"),
                          ks.at[:, slots].set(ksc, mode="drop"),
-                         cv.at[:, slots].set(v8, mode="drop"),
+                         cv.at[:, slots].set(fold_heads(v8), mode="drop"),
                          vs.at[:, slots].set(vsc, mode="drop"),
                          cpos.at[:, slots].set(posb, mode="drop"))
             else:
-                entry = (ck.at[:, slots].set(kt.astype(ck.dtype),
-                                             mode="drop"),
-                         cv.at[:, slots].set(vt.astype(cv.dtype),
-                                             mode="drop"),
+                entry = (ck.at[:, slots].set(
+                             fold_heads(kt).astype(ck.dtype), mode="drop"),
+                         cv.at[:, slots].set(
+                             fold_heads(vt).astype(cv.dtype), mode="drop"),
                          cpos.at[:, slots].set(posb, mode="drop"))
         return o, entry
 
     @jax.named_scope("attend")
-    def _blocked_attn(self, q, entry, pos):
+    def _blocked_attn(self, q, entry, pos, kvh):
         """Single-token attention reading only the filled cache prefix.
 
         Online-softmax (flash-decoding) accumulation over the
-        ``ceil((pos+1)/cache_block)`` leading blocks of the K/V cache —
+        ``ceil((pos+1)/cache_block)`` leading blocks of the K/V cache
+        (``kvh`` kv heads in its stored rows) —
         a ``lax.fori_loop`` whose trip count is the TRACED ``pos``, so
         the compiled program's HBM reads grow with the decoded prefix
         instead of always touching all ``max_len`` rows. Exact: the
@@ -856,14 +977,14 @@ class Decoder:
             ck, ks, cv, vs = entry
         else:
             ck, cv = entry
-        kvh = ck.shape[2]  # < h under grouped-query attention
-        g = h // kvh
+        g = h // kvh  # kvh < h under grouped-query attention
         qg = qf.reshape(b, c, kvh, g, d)
 
         @jax.named_scope("cache")
         def _block(buf, scale, i):
-            z = lax.dynamic_slice(buf, (0, i * bl, 0, 0),
-                                  (b, bl, kvh, d))
+            # one block of stored rows, unfolded per head (small)
+            z = unfold_heads(lax.dynamic_slice(
+                buf, (0, i * bl, 0), (b, bl, kvh * d)), kvh)
             z = z.astype(jnp.float32)
             if scale is not None:
                 sb = lax.dynamic_slice(scale, (0, i * bl, 0),
@@ -1030,8 +1151,8 @@ class Decoder:
         read strategy. ``"dense"`` vmaps over the slot axis — each lane
         is a b=1 ``_run`` at its own traced position, so cache writes
         become per-slot scatters and masks follow each slot's own
-        clock, and every lane gathers (and, for int8, dequantizes) all
-        ``max_len`` cache rows. ``"paged"`` runs ONE batched walk with
+        clock, and every lane reads (and masks) all ``max_len`` cache
+        rows as they are stored. ``"paged"`` runs ONE batched walk with
         the position VECTOR: position-wise ops see [S, C, E] directly,
         cache writes scatter per slot, and the attention read is the
         Pallas paged kernel (ops/pallas_kernels.py) that touches only
@@ -1042,10 +1163,9 @@ class Decoder:
         running inside the serving engine's tensor-parallel shard_map
         and ``caches`` are this shard's kv-head slice — see ``_run``.
         Composes with both impls: under ``"paged"`` each shard runs
-        the Pallas kernel against its LOCAL cache shard — the kernel
-        takes its kv-head count from the cache operand, so inside the
-        shard_map it walks the shard's own kv heads automatically —
-        and the per-attention-node
+        the Pallas kernel against its LOCAL cache shard — it is
+        handed the shard's own lanes and local kv-head count — and
+        the per-attention-node
         all-gather rebuilds the head output exactly as in the dense
         branch (doc/serving.md "Paged attention")."""
         if impl is None:

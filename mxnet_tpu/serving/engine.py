@@ -629,9 +629,9 @@ class InferenceEngine:
         (``ops.pallas_kernels.paged_attention``): each slot's read
         walks only its LIVE cache rows — bounded by the per-slot
         position vector — with in-kernel int8 dequantization, cutting
-        the per-token HBM traffic that dominates decode (the cache is
-        read once at its stored width instead of gathered, and for
-        int8 dequantized to a full float copy, whole every step).
+        the per-token HBM traffic that dominates decode (the dense
+        read streams and masks all ``max_len`` rows of every slot each
+        step; both read the [S, max_len, Hkv*D] buffers as stored).
         Greedy outputs stay byte-identical to ``"dense"`` in float
         flavors (online softmax is a reassociation); int8 carries the
         usual quantized-cache tolerance. The compile-count contract is

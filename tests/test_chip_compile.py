@@ -16,6 +16,7 @@ library, and under xdist a file goes to one worker); the persistent
 compile cache is off around them.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -139,19 +140,21 @@ def test_flash_attention_over_dp_x_tp(mesh_2x2, fn):
 def test_paged_attention_float_kv(compile_for_chip, chunk, kv, qdt):
     h = H if kv in (H, 4) else kv
     qdt = jnp.dtype(qdt)
+    # the cache as the decoder stores it: [S, L, Hkv*D]
     compile_for_chip(
-        lambda q, k, v, p: pk.paged_attention(q, k, v, p,
+        lambda q, k, v, p: pk.paged_attention(q, k, v, p, kv_heads=kv,
                                               interpret=False),
-        ((S, chunk, h, D), qdt), ((S, L, kv, D), qdt),
-        ((S, L, kv, D), qdt), ((S,), I32))
+        ((S, chunk, h, D), qdt), ((S, L, kv * D), qdt),
+        ((S, L, kv * D), qdt), ((S,), I32))
 
 
 @pytest.mark.parametrize("chunk", [1, 5])
 def test_paged_attention_int8_kv(compile_for_chip, chunk):
     compile_for_chip(
         lambda q, k, v, p, ks, vs: pk.paged_attention(
-            q, k, v, p, k_scale=ks, v_scale=vs, interpret=False),
-        ((S, chunk, H, D), BF16), ((S, L, H, D), I8), ((S, L, H, D), I8),
+            q, k, v, p, kv_heads=H, k_scale=ks, v_scale=vs,
+            interpret=False),
+        ((S, chunk, H, D), BF16), ((S, L, H * D), I8), ((S, L, H * D), I8),
         ((S,), I32), ((S, L, H), F32), ((S, L, H), F32))
 
 
@@ -260,7 +263,7 @@ def test_fused_decode_refused_loudly(monkeypatch, one_chip):
     def lower():
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in (((S, E), BF16), ((S,), I32),
-                             ((S, L, H, D), BF16), ((S, L, H, D), BF16),
+                             ((S, L, H * D), BF16), ((S, L, H * D), BF16),
                              ((3 * E, E), I8), ((3 * E,), F32),
                              ((3 * E,), F32), ((E, E), I8), ((E,), F32),
                              ((E,), F32))]
@@ -292,3 +295,68 @@ def test_fused_decode_refused_loudly(monkeypatch, one_chip):
     with pytest.raises(MXNetError, match="fused_decode_attention"):
         mx.parallel.Decoder(sym, params, max_len=8, weight_dtype="int8",
                             matmul_impl="fused")
+
+
+@pytest.fixture(scope="module")
+def serve_chat_engine():
+    """The benchmark's serve-chat engine at OPT-1.3B widths (hidden
+    2048, 32 heads of 64, ffn 8192, the whole vocabulary, bf16) cut to
+    2 layers: 16 slots x 1024 rows, 8 steps a round, and the
+    speculative verify program beside the decode program. Weights are
+    zeros: only shapes reach the compiler."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_transformer_lm
+    e, h, f, v, layers = 2048, 32, 8192, 50272, 2
+    sym = get_transformer_lm(v, num_layers=layers, embed_dim=e,
+                             num_heads=h, ffn_hidden=f, impl="flash",
+                             pos_encoding="learned")
+    shapes = {"data": (1, 8), "softmax_label": (1, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    params = {n: jnp.zeros(s, BF16)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    params["pos_embed"] = jnp.zeros((2048, e), BF16)
+    dec = mx.parallel.Decoder(sym, params, max_len=1024,
+                              compute_dtype="bfloat16", cache_block=None,
+                              weight_dtype="float")
+    return mx.serving.InferenceEngine(
+        dec, slots=16, prefill_buckets=(512, 768), steps_per_round=8,
+        prefix_cache_mb=0, prefill_chunk=0, spec_k=4, draft="ngram")
+
+
+@pytest.mark.parametrize("program", ["decode", "verify"])
+def test_decode_program_holds_no_copy_of_the_cache(serve_chat_engine,
+                                                   one_chip, program):
+    """The stored KV layout is the layout the decode read consumes:
+    compiled for the chip, the engine's decode program (and its verify
+    program, a 5-token chunk through the same read) keeps temporaries
+    under a tenth of the cache's bytes and no ``copy`` as large as one
+    cache buffer. With the cache stored [S, L, Hkv, D] this read 515 MB
+    of temporaries for 256 MB of cache and 8 such copies (4 to a
+    lane-padded layout on the way in, 4 back): the round's 32 ms of
+    ``copy.N`` and the reason 32 slots did not fit (PERF.md PR 27)."""
+    eng = serve_chat_engine
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    args = [eng._params, eng._aux, eng._caches, eng._state]
+    fn = eng._step_fn
+    if program == "verify":
+        s_ = eng.slots
+        fn = eng._verify_fn
+        args += [jnp.zeros((s_, eng.spec_k), I32), jnp.zeros((s_,), I32)]
+    # donated as on the chip (the engine donates nothing on the CPU)
+    compiled = jax.jit(fn, donate_argnums=(2, 3)) \
+        .lower(*[abstract(a) for a in args]).compile()
+    leaves = jax.tree_util.tree_leaves(eng._caches)
+    cache_bytes = sum(x.nbytes for x in leaves)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.1 * cache_bytes, (temp, cache_bytes)
+    # `%copy.N = bf16[16,1024,2048]{...} copy(...)`: the result's dims
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
+    big = [dims for dims in copies
+           if np.prod([int(d) for d in dims.split(",")]) == leaves[0].size]
+    assert not big, big

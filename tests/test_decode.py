@@ -310,7 +310,7 @@ def _gqa_kv_cache_case(h, kv, extra, rng):
                              num_kv_heads=kv, **extra)
     params = _init_params(sym, T, 2, rng)
     dec = Decoder(sym, params, max_len=T)
-    assert dec.init_cache(2)[0][0].shape == (2, T, kv, EMBED // h)
+    assert dec.init_cache(2)[0][0].shape == (2, T, kv * (EMBED // h))
 
     toks = rng.randint(0, VOCAB, (2, T))
     want = _full_logits(sym, params, toks)
@@ -387,7 +387,7 @@ def test_decode_sliding_window_ring_cache():
         dec = Decoder(sym, params, max_len=T)
         caches = dec.init_cache(2)
         kv = extra.get("num_kv_heads", 0) or HEADS
-        assert caches[0][0].shape == (2, W, kv, EMBED // HEADS)
+        assert caches[0][0].shape == (2, W, kv * (EMBED // HEADS))
         assert caches[0][-1].shape == (2, W)  # slot-position buffer
 
         toks = rng.randint(0, VOCAB, (2, T))

@@ -126,6 +126,7 @@ def test_paged_kernel_matches_dense_reference(shape):
     quantized values (the dequant arithmetic is identical — the kernel
     just never materializes the float copy)."""
     from mxnet_tpu.ops.pallas_kernels import paged_attention
+    from mxnet_tpu.parallel.decode import fold_heads
 
     s_, c, h, kv, d, l_ = shape
     rng = np.random.RandomState(7)
@@ -133,8 +134,9 @@ def test_paged_kernel_matches_dense_reference(shape):
     k = rng.randn(s_, l_, kv, d).astype(np.float32)
     v = rng.randn(s_, l_, kv, d).astype(np.float32)
     pos = rng.randint(0, l_ - c, (s_,)).astype(np.int32)
-    got = np.asarray(paged_attention(jnp.asarray(q), jnp.asarray(k),
-                                     jnp.asarray(v), pos))
+    # the kernel takes the cache as the decoder stores it
+    got = np.asarray(paged_attention(jnp.asarray(q), fold_heads(k),
+                                     fold_heads(v), pos, kv_heads=kv))
     np.testing.assert_allclose(got, _ref_attention(q, k, v, pos),
                                rtol=2e-5, atol=2e-5)
 
@@ -148,8 +150,8 @@ def test_paged_kernel_matches_dense_reference(shape):
     k8, ks = quant(k)
     v8, vs = quant(v)
     got8 = np.asarray(paged_attention(
-        jnp.asarray(q), jnp.asarray(k8), jnp.asarray(v8), pos,
-        k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs)))
+        jnp.asarray(q), fold_heads(k8), fold_heads(v8), pos,
+        kv_heads=kv, k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs)))
     want8 = _ref_attention(q, k8.astype(np.float32) * ks[..., None],
                            v8.astype(np.float32) * vs[..., None], pos)
     np.testing.assert_allclose(got8, want8, rtol=2e-5, atol=2e-5)

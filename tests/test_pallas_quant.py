@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.parallel.decode import fold_heads
 from mxnet_tpu.serving.quant import (dequantize, pack_int4,
                                      quantize_tensor, resolve_chunk,
                                      scale_fused_matmul, unpack_int4)
@@ -263,8 +264,9 @@ def test_fused_decode_attention_vs_composed(bits, rope):
     vc = rng.randn(s_, l_, kv, d).astype(np.float32)
     pos = np.array([3, 7], np.int32)
     out, kn, vn = pk.fused_decode_attention(
-        jnp.asarray(x), jnp.asarray(pos), jnp.asarray(kc),
-        jnp.asarray(vc), wq.q, wq.scale, jnp.asarray(bq), wo.q,
+        jnp.asarray(x), jnp.asarray(pos),
+        fold_heads(jnp.asarray(kc)), fold_heads(jnp.asarray(vc)),
+        wq.q, wq.scale, jnp.asarray(bq), wo.q,
         wo.scale, jnp.asarray(bo), heads=heads, kv_heads=kv,
         bits=bits, group=group, rope=rope)
     ro, rk, rv = _fused_ref(x, pos, kc, vc,
