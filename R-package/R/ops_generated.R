@@ -2,7 +2,7 @@
 # doc/api_manifest.json — do not edit. Regenerate with:
 #     python ../tools/gen_api_manifest.py && python generate_ops_r.py
 
-#' relu/sigmoid/tanh/softrelu (``activation-inl.h`` + mshadow_op.h).
+#' relu/sigmoid/tanh/softrelu (``activation-inl.h`` + mshadow_op.h),
 #'
 #' Parameters: act_type (str)
 #' Symbol inputs: data
@@ -27,6 +27,15 @@
 #' @export
 `mx.symbol.BlockGrad` <- function(..., name = "") {
   mx.symbol.internal.create("BlockGrad", name, list(...))
+}
+
+#' Compressed convolutional attention (CCA; Zyphra,
+#'
+#' Parameters: head_dim (int); impl (str, default flash); num_heads (int); num_kv_heads (int); rope_base (float, default 10000.0); rotary_dim (int, default 0)
+#' Symbol inputs: data, qk_weight, v_weight, conv0_weight, conv0_bias, conv1_weight, conv1_bias, temp, out_weight
+#' @export
+`mx.symbol.CCAttention` <- function(..., name = "") {
+  mx.symbol.internal.create("CCAttention", name, list(...))
 }
 
 #' dtype conversion (``cast-inl.h``).
@@ -112,7 +121,7 @@
 
 #' out = data · weightᵀ + bias (``fully_connected-inl.h:53-81``).
 #'
-#' Parameters: flatten (bool, default True); no_bias (bool, default False); num_hidden (int)
+#' Parameters: flatten (bool, default True); no_bias (bool, default False); num_hidden (int); precision (str, default )
 #' Symbol inputs: data, weight, bias
 #' @export
 `mx.symbol.FullyConnected` <- function(..., name = "") {
@@ -184,7 +193,7 @@
 
 #' Mixture-of-experts position-wise FFN (soft or top-k routing).
 #'
-#' Parameters: hidden (int); num_experts (int); top_k (int, default 0)
+#' Parameters: gated (bool, default False); hidden (int); num_experts (int); router (str, default linear); top_k (int, default 0)
 #' Symbol inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2
 #' @export
 `mx.symbol.MoEFFN` <- function(..., name = "") {
@@ -218,6 +227,15 @@
   mx.symbol.internal.create("PositionalEmbedding", name, list(...))
 }
 
+#' Root-mean-square normalization over the trailing axis:
+#'
+#' Parameters: eps (float, default 1e-05)
+#' Symbol inputs: data, gamma
+#' @export
+`mx.symbol.RMSNorm` <- function(..., name = "") {
+  mx.symbol.internal.create("RMSNorm", name, list(...))
+}
+
 #' View change (``reshape-inl.h``). ``target_shape`` excludes batch
 #'
 #' Parameters: shape (shape, default []); target_shape (shape, default [])
@@ -225,6 +243,15 @@
 #' @export
 `mx.symbol.Reshape` <- function(..., name = "") {
   mx.symbol.internal.create("Reshape", name, list(...))
+}
+
+#' A residual connection with learned per-channel scales:
+#'
+#' Parameters: affine (str, default full)
+#' Symbol inputs: data, branch, data_bias, data_scale, branch_bias, branch_scale
+#' @export
+`mx.symbol.ResidualMerge` <- function(..., name = "") {
+  mx.symbol.internal.create("ResidualMerge", name, list(...))
 }
 
 #' Split along an axis into num_outputs (``slice_channel-inl.h``);

@@ -12,6 +12,8 @@ Parity map (reference ``example/``):
   ``symbol_googlenet.py``                               -> :mod:`.inception`
 * ``example/rnn/lstm.py`` (unroll + bucketing)          -> :mod:`.lstm`
 * ``example/fcn-xs/symbol_fcnxs.py``                    -> :mod:`.fcn`
+* no reference counterpart (decoder-only LMs):
+  ``get_transformer_lm``, ``get_zaya_lm``                 -> :mod:`.transformer`
 
 Every constructor returns a :class:`mxnet_tpu.symbol.Symbol` whose single
 head is a ``SoftmaxOutput`` (classification) so it drops straight into
@@ -31,7 +33,7 @@ from .lstm import lstm_unroll, LSTMState, LSTMParam
 from .fcn import get_fcn_symbol
 from . import transformer
 from .transformer import (get_transformer_lm, transformer_block,
-                          moe_transformer_block)
+                          moe_transformer_block, get_zaya_lm, zaya_block)
 
 _REGISTRY = {
     "mlp": get_mlp,

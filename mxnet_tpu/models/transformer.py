@@ -28,7 +28,8 @@ def _ln(data, name):
                          name=name)
 
 __all__ = ["transformer_block", "moe_transformer_block",
-           "get_transformer_lm", "tp_rules", "ep_rules"]
+           "get_transformer_lm", "zaya_block", "get_zaya_lm", "tp_rules",
+           "ep_rules"]
 
 
 def _attn_sublayer(data, num_heads, name, causal, impl, dropout,
@@ -180,22 +181,137 @@ def get_transformer_lm(vocab_size, num_layers=2, embed_dim=128, num_heads=4,
         ln_f = _ln(net, "lnf")
         logits = sym.FullyConnected(data=ln_f, num_hidden=vocab_size,
                                     name="lm_head", flatten=False)
-        if loss_layout in ("flat", "ce"):
-            flat = sym.Reshape(data=logits, shape=(-1, vocab_size),
-                               name="logits_flat")
-            flat_label = sym.Reshape(
-                data=sym.Variable("softmax_label"), shape=(-1,),
-                name="label_flat")
-            if loss_layout == "ce":
-                return sym.SoftmaxCELoss(data=flat, label=flat_label,
-                                         name="softmax")
-            return sym.SoftmaxOutput(data=flat, label=flat_label,
+        return _lm_loss(logits, vocab_size, loss_layout)
+
+
+def _lm_loss(logits, vocab_size, loss_layout):
+    """The loss head over [B, T, V] logits in one of the three layouts
+    ``get_transformer_lm`` documents."""
+    if loss_layout not in ("reference", "flat", "ce"):
+        raise ValueError("loss_layout must be 'reference', 'flat' or "
+                         "'ce', got %r" % (loss_layout,))
+    if loss_layout in ("flat", "ce"):
+        flat = sym.Reshape(data=logits, shape=(-1, vocab_size),
+                           name="logits_flat")
+        flat_label = sym.Reshape(
+            data=sym.Variable("softmax_label"), shape=(-1,),
+            name="label_flat")
+        if loss_layout == "ce":
+            return sym.SoftmaxCELoss(data=flat, label=flat_label,
                                      name="softmax")
-        # per-position softmax: label [B, T]
-        logits_t = sym.SwapAxis(data=logits, dim1=1, dim2=2,
-                                name="logits_t")
-        return sym.SoftmaxOutput(data=logits_t, name="softmax",
-                                 multi_output=True)
+        return sym.SoftmaxOutput(data=flat, label=flat_label,
+                                 name="softmax")
+    # per-position softmax: label [B, T]
+    logits_t = sym.SwapAxis(data=logits, dim1=1, dim2=2,
+                            name="logits_t")
+    return sym.SoftmaxOutput(data=logits_t, name="softmax",
+                             multi_output=True)
+
+
+def _rms(data, name, eps):
+    return sym.RMSNorm(data=data, gamma=sym.Variable(name + "_gamma"),
+                       eps=eps, name=name)
+
+
+def _merge(data, branch, name):
+    """``(x + b_r) * s_r + (y + b_y) * s_y``: the scaled residual
+    merge of both ZAYA sublayers."""
+    return sym.ResidualMerge(
+        data=data, branch=branch,
+        data_bias=sym.Variable(name + "_res_bias"),
+        data_scale=sym.Variable(name + "_res_scale"),
+        branch_bias=sym.Variable(name + "_out_bias"),
+        branch_scale=sym.Variable(name + "_out_scale"),
+        affine="full", name=name + "_merge")
+
+
+def zaya_block(data, router_prev, name, num_heads, num_kv_heads, head_dim,
+               num_experts, expert_hidden, router_hidden, top_k=1,
+               rotary_dim=0, rope_base=10000.0, eps=1e-5, impl="flash"):
+    """One ZAYA layer (Zyphra): a CCA sublayer, then a routed-experts
+    sublayer whose router is an MLP over a narrow projection of the
+    stream, mixed with the previous layer's (``router_prev``, None for
+    the first layer). Both sublayers end in the scaled residual merge.
+    Returns (the stream, this layer's router state for the next).
+
+    The router is ordinary symbols and runs in float32 at full
+    product precision whatever the model computes in: what it decides
+    is discrete."""
+    attn = sym.CCAttention(
+        data=_rms(data, name + "_attn_norm", eps),
+        qk_weight=sym.Variable(name + "_cca_qk_weight"),
+        v_weight=sym.Variable(name + "_cca_v_weight"),
+        conv0_weight=sym.Variable(name + "_cca_conv0_weight"),
+        conv0_bias=sym.Variable(name + "_cca_conv0_bias"),
+        conv1_weight=sym.Variable(name + "_cca_conv1_weight"),
+        conv1_bias=sym.Variable(name + "_cca_conv1_bias"),
+        temp=sym.Variable(name + "_cca_temp"),
+        out_weight=sym.Variable(name + "_cca_out_weight"),
+        num_heads=num_heads, num_kv_heads=num_kv_heads,
+        head_dim=head_dim, rotary_dim=rotary_dim, rope_base=rope_base,
+        impl=impl, name=name + "_cca")
+    x = _merge(data, attn, name + "_attn")
+    h = _rms(x, name + "_moe_norm", eps)
+
+    def fc(z, width, tag):
+        return sym.FullyConnected(
+            data=z, num_hidden=width, no_bias=True, flatten=False,
+            precision="highest", name="%s_router_%s" % (name, tag))
+
+    def gelu(z, tag):
+        return sym.Activation(data=z, act_type="gelu",
+                              name="%s_router_%s" % (name, tag))
+
+    r = fc(sym.Cast(data=h, dtype="float32", name=name + "_router_in"),
+           router_hidden, "down")
+    if router_prev is not None:
+        r = sym.ResidualMerge(
+            data=r, branch=router_prev,
+            branch_scale=sym.Variable(name + "_router_mix"),
+            affine="scale", name=name + "_router_eda")
+    z = fc(gelu(fc(gelu(fc(_rms(r, name + "_router_norm", eps),
+                            router_hidden, "fc1"), "act1"),
+                   router_hidden, "fc2"), "act2"),
+           num_experts, "fc3")
+    probs = sym.SoftmaxActivation(data=z, name=name + "_router_probs")
+    moe = sym.MoEFFN(
+        data=h, probs=probs,
+        select_bias=sym.Variable(name + "_router_balance"),
+        expert_w1=sym.Variable(name + "_expert_w1"),
+        expert_w2=sym.Variable(name + "_expert_w2"),
+        num_experts=num_experts, hidden=expert_hidden, top_k=top_k,
+        router="given", gated=True, name=name + "_moe")
+    return _merge(x, moe, name + "_moe"), r
+
+
+def get_zaya_lm(vocab_size, num_layers, embed_dim, num_heads, num_kv_heads,
+                head_dim, num_experts, expert_hidden, router_hidden,
+                top_k=1, rotary_dim=0, rope_base=10000.0, eps=1e-5,
+                impl="flash", loss_layout="reference"):
+    """ZAYA1-shaped decoder-only LM (Zyphra): ``num_layers`` of
+    ``zaya_block`` between an embedding and the SAME matrix as the
+    output head (tied), RMSNorm throughout, no biases, no positional
+    table (rotary inside the attention). Built from registered Symbol
+    ops like every zoo model: it binds, trains through ``FeedForward``
+    / ``ParallelTrainer`` and is served by ``Decoder`` /
+    ``InferenceEngine``. ``loss_layout`` as in
+    ``get_transformer_lm``."""
+    embed = sym.Variable("embed_weight")
+    net = sym.Embedding(data=sym.Variable("data"), weight=embed,
+                        input_dim=vocab_size, output_dim=embed_dim,
+                        name="embed")
+    router = None
+    for i in range(num_layers):
+        net, router = zaya_block(
+            net, router, "layer%d" % i, num_heads, num_kv_heads, head_dim,
+            num_experts, expert_hidden, router_hidden, top_k=top_k,
+            rotary_dim=rotary_dim, rope_base=rope_base, eps=eps,
+            impl=impl)
+    logits = sym.FullyConnected(
+        data=_rms(net, "final_norm", eps), weight=embed,
+        num_hidden=vocab_size, no_bias=True, flatten=False,
+        name="lm_head")
+    return _lm_loss(logits, vocab_size, loss_layout)
 
 
 def tp_rules():

@@ -46,6 +46,74 @@ class LayerNorm(OpSpec):
 
 
 @register
+class RMSNorm(OpSpec):
+    """Root-mean-square normalization over the trailing axis:
+    ``x / sqrt(mean(x^2) + eps) * gamma`` (Zhang & Sennrich 2019): no
+    mean subtraction, no shift. The statistics are taken in float32
+    whatever the input's dtype; the output keeps the input's dtype."""
+
+    name = "RMSNorm"
+    params = {"eps": Param("float", 1e-5)}
+
+    def arguments(self, p):
+        return ["data", "gamma"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        return [d, shape_assign(in_shapes[1], (d[-1],),
+                                "RMSNorm gamma")], [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x, gamma = ins
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        y = xf * jax.lax.rsqrt(ms + p["eps"]) * gamma.astype(jnp.float32)
+        return [y.astype(x.dtype)], []
+
+
+@register
+class ResidualMerge(OpSpec):
+    """A residual connection with learned per-channel scales:
+    ``affine="scale"``: ``data + branch * branch_scale`` (2 + 1
+    inputs); ``affine="full"``:
+    ``(data + data_bias) * data_scale + (branch + branch_bias) *
+    branch_scale`` (2 + 4 inputs). Every vector is [C], the trailing
+    axis of both tensors. Position-wise."""
+
+    name = "ResidualMerge"
+    params = {"affine": Param("str", "full")}
+
+    def arguments(self, p):
+        if p["affine"] == "scale":
+            return ["data", "branch", "branch_scale"]
+        if p["affine"] != "full":
+            raise MXNetError("ResidualMerge: affine must be 'scale' or "
+                             "'full', got %r" % (p["affine"],))
+        return ["data", "branch", "data_bias", "data_scale",
+                "branch_bias", "branch_scale"]
+
+    def infer_shape(self, p, in_shapes):
+        d = shape_assign(in_shapes[0], in_shapes[1], "ResidualMerge")
+        if d is None:
+            return list(in_shapes), [None], []
+        vec = [shape_assign(s, (d[-1],), "ResidualMerge vector")
+               for s in in_shapes[2:]]
+        return [d, d] + vec, [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        # float32 inside whatever the stream's dtype: four roundings a
+        # sublayer would otherwise add to the residual stream
+        dt = jnp.result_type(ins[0], ins[1])
+        x, y, *vec = (z.astype(jnp.float32) for z in ins)
+        if p["affine"] == "scale":
+            return [(x + y * vec[0]).astype(dt)], []
+        xb, xs, yb, ys = vec
+        return [((x + xb) * xs + (y + yb) * ys).astype(dt)], []
+
+
+@register
 class PositionalEmbedding(OpSpec):
     """out = data + pos[None, :, :] — learned additive positional
     embedding. data: [B, T, E]; pos: [T, E] (a parameter). Under
@@ -78,37 +146,63 @@ class PositionalEmbedding(OpSpec):
 class MoEFFN(OpSpec):
     """Mixture-of-experts position-wise FFN (soft or top-k routing).
 
-    data: [B, T, E]. gate_weight: [X, E] (X = num_experts);
-    expert_w1: [X, H, E], expert_b1: [X, H]; expert_w2: [X, E, H],
-    expert_b2: [X, E]. out[b,t] = Σ_x gate[b,t,x] · FFN_x(data[b,t]).
+    data: [B, T, E]; out[b,t] = sum_x gate[b,t,x] * FFN_x(data[b,t]).
+    Two switches choose the block:
+
+    ``router``: ``"linear"`` (default) owns its gate, ``gate_weight``
+    [X, E]: logits = data . gate^T, softmax over the kept logits.
+    ``"given"`` takes the routing from the graph: ``probs`` [B, T, X]
+    (a router built from ordinary symbols) and ``select_bias`` [X]
+    (balancing biases): the experts are chosen by ``probs +
+    select_bias`` and weighted by ``probs`` itself, not renormalized.
+
+    ``gated``: False (default) is the biased ReLU pair, ``expert_w1``
+    [X, H, E], ``expert_b1`` [X, H], ``expert_w2`` [X, E, H],
+    ``expert_b2`` [X, E]. True is the SiLU-gated pair without biases:
+    ``expert_w1`` [X, 2H, E] (the gate's rows, then the up
+    projection's), ``expert_w2`` [X, E, H]:
+    ``w2 (silu(w1[:H] x) * (w1[H:] x))``.
+
+    Routing: ``top_k=0`` (default) is soft routing: every expert
+    weighs in and computes every token, fully differentiable.
+    ``top_k=k`` is hard routing: the k best experts per token carry
+    weight (ties broken by index, like ``lax.top_k``), and ONLY THEY
+    COMPUTE: the token-expert pairs are sorted by expert and run as a
+    grouped matrix product over the experts that have tokens
+    (``moe_ffn_math``, ``pallas_kernels.grouped_matmul``), so the
+    operations follow k, not X, and a decode step reads the weights
+    of the experts it touched and no others. Gradients flow through
+    the kept gates' values and through the chosen experts.
 
     Expert parallelism: shard the leading X dim of the expert params
-    over an ``ep`` mesh axis (``models.transformer.ep_rules()``) — each
-    device computes its experts for all tokens and XLA inserts the psum
-    over ``ep`` for the gate-weighted combine.
-
-    Routing: ``top_k=0`` (default) is soft/dense routing — every expert
-    weighs in, fully differentiable, the XLA-friendly baseline.
-    ``top_k=k`` is the standard MoE hard routing in its STATIC-SHAPED
-    form: keep the k largest gates per token, renormalize them, zero
-    the rest. All experts still COMPUTE every token (no dynamic
-    dispatch — XLA needs static shapes, and under ``ep`` sharding the
-    per-device compute is already experts/n_ep of the total); what
-    top-k changes is the LEARNING dynamics (sparse credit assignment,
-    expert specialization) and it reproduces exactly the reference-free
-    standard gating math. The straight-through trick is unnecessary:
-    the mask is a function of the gate ORDER, and gradients flow
-    through the kept gates' renormalized values like in Shazeer-style
-    noisy-top-k without the noise. No reference counterpart (2015).
+    over an ``ep`` mesh axis (``models.transformer.ep_rules()``). A
+    shard holds its experts only, so under ``ep`` (and under the
+    serving engine's quantized weights, whose products are scale-fused
+    per expert) the experts run in the dense form: every local expert
+    computes every token and the gates, zero outside the top k, pick
+    the result; the combine ends in one ``psum`` over ``ep``.
     """
 
     name = "MoEFFN"
     params = {"num_experts": Param("int"), "hidden": Param("int"),
-              "top_k": Param("int", 0)}
+              "top_k": Param("int", 0),
+              "router": Param("str", "linear"),
+              "gated": Param("bool", False)}
 
     def arguments(self, p):
-        return ["data", "gate_weight", "expert_w1", "expert_b1",
-                "expert_w2", "expert_b2"]
+        route = ["probs", "select_bias"] if self.given(p) \
+            else ["gate_weight"]
+        experts = ["expert_w1", "expert_w2"] if p["gated"] else \
+            ["expert_w1", "expert_b1", "expert_w2", "expert_b2"]
+        return ["data"] + route + experts
+
+    @staticmethod
+    def given(p):
+        r = p.get("router", "linear")
+        if r not in ("linear", "given"):
+            raise MXNetError("MoEFFN: router must be 'linear' or "
+                             "'given', got %r" % (r,))
+        return r == "given"
 
     def infer_shape(self, p, in_shapes):
         d = in_shapes[0]
@@ -118,11 +212,14 @@ class MoEFFN(OpSpec):
                 raise MXNetError("MoEFFN: data must be [B, T, E]")
             e = d[2]
             x, h = p["num_experts"], p["hidden"]
-            ins[1] = shape_assign(ins[1], (x, e), "MoEFFN gate_weight")
-            ins[2] = shape_assign(ins[2], (x, h, e), "MoEFFN expert_w1")
-            ins[3] = shape_assign(ins[3], (x, h), "MoEFFN expert_b1")
-            ins[4] = shape_assign(ins[4], (x, e, h), "MoEFFN expert_w2")
-            ins[5] = shape_assign(ins[5], (x, e), "MoEFFN expert_b2")
+            names = self.arguments(p)
+            want = {"gate_weight": (x, e), "probs": (d[0], d[1], x),
+                    "select_bias": (x,),
+                    "expert_w1": (x, 2 * h if p["gated"] else h, e),
+                    "expert_b1": (x, h), "expert_w2": (x, e, h),
+                    "expert_b2": (x, e)}
+            for i, n in enumerate(names[1:], 1):
+                ins[i] = shape_assign(ins[i], want[n], "MoEFFN " + n)
         return ins, [d], []
 
     def forward(self, p, ins, aux, is_train, rng):
@@ -130,13 +227,20 @@ class MoEFFN(OpSpec):
 
 
 def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
-                 ep=None):
+                 ep=None, stats=None):
     """The ONE MoE routing + combine implementation, parameterized
-    over its three matmuls (``None`` = the plain einsums). The
+    over its three matmuls (``None`` = the plain products). The
     serving engine's weight-quantized path (``serving/quant.py``)
     passes scale-fused forms for whichever weights are quantized —
     sharing this function is what keeps quantized MoE routing from
     silently diverging from the fp op it is tested against.
+
+    Two forms of the experts' products, one routing. With ``top_k >
+    0`` and plain products on one shard the experts are ROUTED
+    (``_routed_experts``): only the chosen experts' rows are computed.
+    Soft routing, ``ep`` and the quantized products run them DENSE:
+    every (local) expert computes every token and the gates, zero
+    outside the top k, pick the result.
 
     ``ep=(axis_name, degree)`` runs the SAME math expert-parallel
     inside a ``shard_map``: every expert-stacked input (gate rows,
@@ -148,62 +252,184 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     a sum over experts, partitioned across shards. The psum
     reassociates the float sum, so ep>1 is token-stable rather than
     bitwise vs ep=1 (the PR 14 all-gather precedent is the same
-    contract family)."""
-    x, gate_w, w1, b1, w2, b2 = ins
-    logits = gate_mm(x, gate_w) if gate_mm is not None \
-        else jnp.einsum("bte,xe->btx", x, gate_w)
+    contract family).
+
+    ``stats`` (a dict, optional): the routed form leaves
+    ``stats["experts_touched"]`` there, the number of experts that
+    were given a token (a traced int32 scalar)."""
+    given, gated = MoEFFN.given(p), bool(p.get("gated", False))
+    it = iter(ins)
+    x = next(it)
+    if given:
+        probs, select_bias = next(it), next(it)
+        if gate_mm is not None or (ep is not None and ep[1] > 1):
+            raise MXNetError(
+                "MoEFFN: router='given' does not run expert-parallel "
+                "or with quantized weights yet (ROADMAP R-M2)")
+    else:
+        gate_w = next(it)
+    w1 = next(it)
+    b1 = None if gated else next(it)
+    w2 = next(it)
+    b2 = None if gated else next(it)
     k = int(p["top_k"])
     nx = int(p["num_experts"])
-    nloc = gate_w.shape[0] if hasattr(gate_w, "shape") else nx
-    if ep is not None:
-        ax, nep = ep
-        if nep > 1:
-            # full gate row for routing; this shard's slice of the
-            # renormalized gates comes back out below
-            logits = jax.lax.all_gather(logits, ax, axis=-1,
-                                        tiled=True)
-    if k > 0:
-        if k >= nx:
-            raise MXNetError(
-                "MoEFFN: top_k=%d must be < num_experts=%d (use "
-                "top_k=0 for dense routing)" % (k, nx))
-        # static-shaped hard routing: mask logits outside the top-k
-        # BEFORE the softmax, so kept gates renormalize among
-        # themselves and dropped gates get exactly zero weight.
-        # Build the mask from top_k's INDICES (not a >= threshold,
-        # which would keep every expert tied with the k-th — e.g.
-        # all of them at zero-init): exactly k experts, ties broken
-        # by index like lax.top_k itself
-        _, idx = jax.lax.top_k(logits, k)
-        mask = jnp.sum(jax.nn.one_hot(idx, nx, dtype=logits.dtype),
-                       axis=-2) > 0
-        logits = jnp.where(mask, logits,
-                           jnp.float32(-1e30).astype(logits.dtype))
-    gates = jax.nn.softmax(logits, axis=-1)
-    if ep is not None and ep[1] > 1:
-        # this shard's slice of the (globally renormalized) gates
-        i = jax.lax.axis_index(ep[0])
-        gates = jax.lax.dynamic_slice_in_dim(
-            gates, i * nloc, nloc, axis=-1)
-    up = up_mm(x, w1) if up_mm is not None \
-        else jnp.einsum("bte,xhe->btxh", x, w1)
-    h = jax.nn.relu(up + b1[None, None])
-    y = (down_mm(h, w2) if down_mm is not None
-         else jnp.einsum("btxh,xeh->btxe", h, w2)) + b2[None, None]
-    out = jnp.einsum("btxe,btx->bte", y, gates)
-    if ep is not None and ep[1] > 1:
+    if k >= nx:
+        raise MXNetError(
+            "MoEFFN: top_k=%d must be < num_experts=%d (use "
+            "top_k=0 for dense routing)" % (k, nx))
+    sharded = ep is not None and ep[1] > 1
+    with jax.named_scope("route"):
+        if given:
+            score = probs + select_bias
+        else:
+            score = gate_mm(x, gate_w) if gate_mm is not None \
+                else jnp.einsum("bte,xe->btx", x, gate_w)
+            nloc = gate_w.shape[0] if hasattr(gate_w, "shape") else nx
+            if sharded:
+                # full gate row for routing; this shard's slice of
+                # the renormalized gates comes back out below
+                score = jax.lax.all_gather(score, ep[0], axis=-1,
+                                           tiled=True)
+        idx = None
+        if k > 0:
+            # exactly k experts from top_k's INDICES (not a >=
+            # threshold, which would keep every expert tied with the
+            # k-th — e.g. all of them at zero-init), ties broken by
+            # index like lax.top_k itself
+            _, idx = jax.lax.top_k(score, k)
+            mask = jnp.sum(jax.nn.one_hot(idx, nx, dtype=score.dtype),
+                           axis=-2) > 0
+        if given:
+            gates = probs if k == 0 else jnp.where(mask, probs, 0)
+        else:
+            if k > 0:
+                # mask BEFORE the softmax, so kept gates renormalize
+                # among themselves and dropped gates get exactly zero
+                score = jnp.where(
+                    mask, score,
+                    jnp.float32(-1e30).astype(score.dtype))
+            gates = jax.nn.softmax(score, axis=-1)
+            if sharded:
+                # this shard's slice of the (globally renormalized)
+                # gates
+                i = jax.lax.axis_index(ep[0])
+                gates = jax.lax.dynamic_slice_in_dim(
+                    gates, i * nloc, nloc, axis=-1)
+    plain = gate_mm is None and up_mm is None and down_mm is None
+    if k > 0 and plain and not sharded:
+        b, t, e = x.shape
+        picked = jnp.take_along_axis(gates, idx, axis=-1)     # [b,t,k]
+        y = _routed_experts(x.reshape(b * t, e), idx.reshape(b * t, k),
+                            nx, w1, b1, w2, b2, gated, stats)
+        out = jnp.sum(y.astype(jnp.float32)
+                      * picked.reshape(b * t, k, 1).astype(jnp.float32),
+                      axis=1)
+        return out.reshape(b, t, e).astype(x.dtype)
+    with jax.named_scope("experts"):
+        up = up_mm(x, w1) if up_mm is not None \
+            else jnp.einsum("bte,xhe->btxh", x, w1)
+        if gated:
+            hid = up.shape[-1] // 2
+            h = jax.nn.silu(up[..., :hid]) * up[..., hid:]
+        else:
+            h = jax.nn.relu(up + b1[None, None])
+        y = down_mm(h, w2) if down_mm is not None \
+            else jnp.einsum("btxh,xeh->btxe", h, w2)
+        if not gated:
+            y = y + b2[None, None]
+        out = jnp.einsum("btxe,btx->bte", y, gates.astype(y.dtype))
+    if sharded:
         out = jax.lax.psum(out, ep[0])
     return out
 
 
-def rope_rotate(x, positions, base=10000.0):
+def routed_block_rows(pairs, num_experts):
+    """Rows of one block of the routed experts' grouped product: half
+    an average group (``pairs / num_experts`` token-expert pairs), as a
+    power of two between the bf16 tile's 16 rows and the MXU's 128.
+    Every expert with a token pads its group to whole blocks, so the
+    rows computed stay under ``pairs + num_experts * block``: one and
+    a half times the pairs once groups outgrow a tile, where the dense
+    form computes ``num_experts`` times the tokens. (The kernel copies
+    an expert's matrix once however many blocks it has, so small
+    blocks cost no traffic.)"""
+    per = int(pairs) // (2 * int(num_experts))
+    rows = 16
+    while rows * 2 <= per and rows < 128:
+        rows *= 2
+    return rows
+
+
+def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
+    """``x`` [N, E] through each token's own experts ``idx`` [N, k]:
+    returns [N, k, E]. The N*k token-expert pairs are sorted by expert
+    and laid out in blocks of ``routed_block_rows`` rows, each block
+    one expert's (a group is padded to whole blocks); the two products
+    then run block by block against that block's expert
+    (``pallas_kernels.grouped_matmul``), and the rows go back to their
+    pairs. The number of blocks is static: ``pairs // rows`` full ones
+    at most, and one partly filled one per expert."""
+    from .pallas_kernels import grouped_matmul
+    n, k = idx.shape
+    pairs = n * k
+    rows = routed_block_rows(pairs, nx)
+    nb = pairs // rows + min(nx, pairs)
+    i32 = jnp.int32
+    with jax.named_scope("route"):
+        expert = idx.reshape(pairs).astype(i32)
+        sizes = jnp.zeros((nx,), i32).at[expert].add(1)
+        if stats is not None:
+            stats["experts_touched"] = jnp.sum(sizes > 0).astype(i32)
+        padded = (sizes + rows - 1) // rows * rows
+        ends = jnp.cumsum(padded)                 # padded group ends
+        order = jnp.argsort(expert, stable=True).astype(i32)
+        sorted_e = expert[order]
+        first = jnp.cumsum(sizes) - sizes         # group starts, packed
+        rank = jnp.arange(pairs, dtype=i32) - first[sorted_e]
+        dest = jnp.zeros((pairs,), i32).at[order].set(
+            (ends - padded)[sorted_e] + rank)     # pair -> padded row
+        used = (ends[-1] // rows).astype(i32)     # blocks that hold rows
+        # block -> its expert; the blocks past the used ones repeat the
+        # last used block's expert, so the kernel fetches nothing new
+        at = jnp.minimum(jnp.arange(nb, dtype=i32),
+                         jnp.maximum(used - 1, 0)) * rows
+        block_e = jnp.minimum(
+            jnp.searchsorted(ends, at, side="right",
+                             method="compare_all").astype(i32), nx - 1)
+        xp = jnp.zeros((nb * rows, x.shape[1]), x.dtype).at[dest].set(
+            jnp.repeat(x, k, axis=0) if k > 1 else x)
+    with jax.named_scope("experts"):
+        def per_row(v):             # an expert's vector for its blocks
+            return jnp.repeat(v[block_e], rows, axis=0)
+        up = grouped_matmul(xp, w1, block_e, used, rows)
+        if gated:
+            hid = up.shape[-1] // 2
+            h = jax.nn.silu(up[:, :hid]) * up[:, hid:]
+        else:
+            h = jax.nn.relu(up + per_row(b1))
+        y = grouped_matmul(h.astype(x.dtype), w2, block_e, used, rows)
+        if not gated:
+            y = y + per_row(b2)
+    with jax.named_scope("route"):
+        return y[dest].reshape(n, k, -1)
+
+
+def rope_rotate(x, positions, base=10000.0, rotary_dim=None):
     """Rotary position embedding (RoFormer / GPT-NeoX half-split form):
     rotate the two halves of each head dim by position-dependent angles,
     so q·k depends only on RELATIVE distance. x: [B, T, H, D] (D even);
     positions: [T] absolute positions of these tokens, or [B, T] when
     each batch row sits at its own clock (the decoder's slot-paged
-    batched walk — every row gets its own angles)."""
+    batched walk — every row gets its own angles). ``rotary_dim``
+    (even, default D): only the first ``rotary_dim`` dims of each head
+    turn, as two halves of their own; the rest pass through (partial
+    rotary)."""
     d = x.shape[-1]
+    r = d if rotary_dim is None else int(rotary_dim)
+    if r < d:
+        return jnp.concatenate(
+            [rope_rotate(x[..., :r], positions, base), x[..., r:]], -1)
     half = d // 2
     freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freq
@@ -215,6 +441,180 @@ def rope_rotate(x, positions, base=10000.0):
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+
+
+@register
+class CCAttention(OpSpec):
+    """Compressed convolutional attention (CCA; Zyphra,
+    arXiv:2510.04476): causal grouped-query attention held entirely in
+    a compressed space, whose queries and keys are mixed over the last
+    three positions by two small causal convolutions and whose values
+    are half the previous token's.
+
+    data [B, T, E] (already normalized). With ``Q = num_heads * D``,
+    ``K = num_kv_heads * D``, ``W = Q + K``:
+
+    - ``qk_weight`` [W, E]: ``u_t = [q~_t ; k~_t]``;
+      ``v_weight`` [K, E]: the first half of its rows gives this
+      token's half of the values, the second half the half that the
+      NEXT token uses (``v_t = [v1 h_t ; v2 h_{t-1}]``, ``h_{-1} = 0``:
+      the first ``num_kv_heads / 2`` kv heads hold this token's values,
+      the others the previous token's);
+    - ``conv0_weight`` [W, 2], ``conv0_bias`` [W]: depthwise,
+      ``c1_t = w[:, 0] * u_t + w[:, 1] * u_{t-1} + b``;
+    - ``conv1_weight`` [G, 2, D, D] (``G = num_heads + num_kv_heads``),
+      ``conv1_bias`` [W]: per head,
+      ``c2_t[g] = w[g, 0] c1_t[g] + w[g, 1] c1_{t-1}[g] + b[g]``, the
+      sequence of ``c1`` padded with zeros on the left;
+    - q-k mean: ``m_t[i] = (q~_t[i] + k~_t[kv(i)]) / 2``,
+      ``q_t = c2_t[:Q] + m_t``, ``k_t[j] = c2_t[Q:][j] + mean of m_t
+      over j's query heads``;
+    - per head ``q <- sqrt(D) q / |q|``,
+      ``k <- sqrt(D) exp(temp_j) k / |k|`` (``temp`` [num_kv_heads]);
+      rotary on the first ``rotary_dim`` dims at ``rope_base``;
+    - causal softmax(q . k / sqrt(D)) over v, grouped;
+      ``out_weight`` [E, Q].
+
+    The full-sequence forward (training, the executor) attends with
+    the flash kernel (``impl``); the decoder's cached form
+    (``parallel/decode.py``) keeps K and V rows and a short ring of
+    the last positions' ``u`` and ``v2`` per sequence, and computes the
+    same mixing through ``cca_qkv``."""
+
+    name = "CCAttention"
+    params = {"num_heads": Param("int"), "num_kv_heads": Param("int"),
+              "head_dim": Param("int"),
+              "rotary_dim": Param("int", 0),
+              "rope_base": Param("float", 10000.0),
+              "impl": Param("str", "flash")}
+
+    def arguments(self, p):
+        return ["data", "qk_weight", "v_weight", "conv0_weight",
+                "conv0_bias", "conv1_weight", "conv1_bias", "temp",
+                "out_weight"]
+
+    @staticmethod
+    def widths(p):
+        """(Q, K, D): the query and key/value widths and the head's."""
+        h, kv, d = p["num_heads"], p["num_kv_heads"], p["head_dim"]
+        if kv < 2 or kv % 2 or h % kv:
+            raise MXNetError(
+                "CCAttention: num_kv_heads=%d must be even (half hold "
+                "the previous token's values) and divide num_heads=%d"
+                % (kv, h))
+        return h * d, kv * d, d
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        if len(d) != 3:
+            raise MXNetError("CCAttention: data must be [B, T, E]")
+        q, k, hd = self.widths(p)
+        e, g = d[2], p["num_heads"] + p["num_kv_heads"]
+        want = [d, (q + k, e), (k, e), (q + k, 2), (q + k,),
+                (g, 2, hd, hd), (q + k,), (p["num_kv_heads"],), (e, q)]
+        names = self.arguments(p)
+        return [shape_assign(s, w, "CCAttention " + n)
+                for s, w, n in zip(in_shapes, want, names)], [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x, wqk, wv, c0w, c0b, c1w, c1b, temp, wo = ins
+        b, t, _ = x.shape
+        qw, kw, d = self.widths(p)
+        h, kv = p["num_heads"], p["num_kv_heads"]
+        with jax.named_scope("proj"):
+            u = jnp.einsum("bte,fe->btf", x, wqk)
+            vv = jnp.einsum("bte,fe->btf", x, wv)
+        with jax.named_scope("conv"):
+            q, k, v = cca_qkv(p, u, vv, (c0w, c0b, c1w, c1b, temp),
+                              jnp.arange(t, dtype=jnp.int32)[None])
+        with jax.named_scope("attend"):
+            k = jnp.repeat(k, h // kv, axis=2)
+            v = jnp.repeat(v, h // kv, axis=2)
+            if p["impl"] == "flash":
+                from .pallas_kernels import flash_attention
+                o = flash_attention(q, k, v, causal=True)
+            elif p["impl"] == "dense":
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / float(np.sqrt(d))
+                mask = jnp.tril(jnp.ones((t, t), bool))
+                s = jnp.where(mask[None, None], s, -jnp.inf)
+                o = jnp.einsum("bhqk,bkhd->bqhd",
+                               jax.nn.softmax(s, axis=-1), v)
+            else:
+                raise MXNetError("CCAttention: unknown impl %r (flash "
+                                 "or dense)" % (p["impl"],))
+        with jax.named_scope("proj"):
+            return [jnp.einsum("btq,eq->bte", o.reshape(b, t, qw), wo)], []
+
+
+def cca_qkv(p, u, vv, mix, positions, prev=None):
+    """CCA's queries, keys and values of a chunk from its raw
+    projections: the two convolutions, the q-k mean, the value shift,
+    the norms and the rotary turn. One function for the full forward
+    (``prev=None``: the chunk starts the sequence) and the decoder's
+    cached walk.
+
+    ``u`` [B, C, W], ``vv`` [B, C, K]: the chunk's ``qk`` and ``v``
+    projections. ``mix``: (conv0_weight, conv0_bias, conv1_weight,
+    conv1_bias, temp). ``positions`` [1 or B, C] int32, absolute and
+    consecutive. ``prev``: (u of the position before the chunk, u of
+    the one before that, the ``v2`` half of the position before), each
+    [B, 1, .]; whatever lies before position 0 is taken as zero here,
+    by position, so a caller hands over what its ring holds and never
+    has to clear it. Returns q [B, C, H, D], k and v [B, C, Hkv, D] in
+    ``u``'s dtype; the mixing itself runs in float32."""
+    c0w, c0b, c1w, c1b, temp = mix
+    qw, kw, d = CCAttention.widths(p)
+    h, kv = p["num_heads"], p["num_kv_heads"]
+    b, c, w = u.shape
+    f32 = jnp.float32
+    positions = jnp.asarray(positions, jnp.int32)
+    first = positions[:, :1]                               # [1|B, 1]
+    uf, vf = u.astype(f32), vv.astype(f32)
+    if prev is None:
+        u1 = u2 = jnp.zeros((b, 1, w), f32)
+        v2p = jnp.zeros((b, 1, kw // 2), f32)
+    else:
+        u1, u2, v2p = (z.astype(f32) for z in prev)
+        u1 = jnp.where((first >= 1)[..., None], u1, 0)
+        u2 = jnp.where((first >= 2)[..., None], u2, 0)
+        v2p = jnp.where((first >= 1)[..., None], v2p, 0)
+    # positions first-2 .. first+C-1, then the first conv over
+    # first-1 .. first+C-1 (what lies before position 0 is padding)
+    ue = jnp.concatenate([u2, u1, uf], axis=1)
+    c0w, c0b = c0w.astype(f32), c0b.astype(f32)
+    c1 = c0w[:, 0] * ue[:, 1:] + c0w[:, 1] * ue[:, :-1] + c0b
+    pe = jnp.concatenate([first - 1, positions], axis=1)
+    c1 = jnp.where((pe >= 0)[..., None], c1, 0)
+    g = h + kv
+    c1h = c1.reshape(b, c + 1, g, d)
+    c1w = c1w.astype(f32)
+    c2 = (jnp.einsum("bcgi,goi->bcgo", c1h[:, 1:], c1w[:, 0])
+          + jnp.einsum("bcgi,goi->bcgo", c1h[:, :-1], c1w[:, 1])
+          + c1b.astype(f32).reshape(g, d))
+    qt = uf[..., :qw].reshape(b, c, kv, h // kv, d)
+    kt = uf[..., qw:].reshape(b, c, kv, 1, d)
+    m = (qt + kt) * 0.5                                    # [b,c,kv,G,d]
+    q = c2[:, :, :h] + m.reshape(b, c, h, d)
+    k = c2[:, :, h:] + jnp.mean(m, axis=3)
+
+    def unit(z):
+        n = jnp.sqrt(jnp.sum(jnp.square(z), axis=-1, keepdims=True))
+        return z * (float(np.sqrt(d)) / jnp.maximum(n, 1e-12))
+
+    q = unit(q)
+    k = unit(k) * jnp.exp(temp.astype(f32))[:, None]
+    rot = p.get("rotary_dim") or d
+    q = rope_rotate(q, positions, p["rope_base"], rot)
+    k = rope_rotate(k, positions, p["rope_base"], rot)
+    half = kw // 2
+    v = jnp.concatenate(
+        [vf[..., :half],
+         jnp.concatenate([v2p, vf[:, :-1, half:]], axis=1)], axis=-1)
+    dt = u.dtype
+    return (q.astype(dt), k.astype(dt),
+            v.reshape(b, c, kv, d).astype(dt))
 
 
 @register

@@ -83,11 +83,17 @@ class FullyConnected(OpSpec):
     ``flatten=False`` the dot applies position-wise over the trailing
     axis ([..., K] -> [..., num_hidden]), the layout transformer FFNs
     need. The dot is the canonical MXU op; bias-add fuses into it.
+
+    ``precision="highest"`` asks the backend for full-precision
+    products (on the TPU a float32 product otherwise rounds its
+    operands to bfloat16): for the few small layers whose output
+    decides something discrete, such as a router's.
     """
 
     name = "FullyConnected"
     params = {"num_hidden": Param("int"), "no_bias": Param("bool", False),
-              "flatten": Param("bool", True)}
+              "flatten": Param("bool", True),
+              "precision": Param("str", "")}
 
     def arguments(self, p):
         return ["data", "weight"] if p["no_bias"] else ["data", "weight", "bias"]
@@ -113,11 +119,13 @@ class FullyConnected(OpSpec):
         return ins, [out], []
 
     def forward(self, p, ins, aux, is_train, rng):
+        prec = p.get("precision") or None
         if p["flatten"]:
             x = ins[0].reshape(ins[0].shape[0], -1)
-            out = jnp.dot(x, ins[1].T)
+            out = jnp.dot(x, ins[1].T, precision=prec)
         else:
-            out = jnp.einsum("...k,nk->...n", ins[0], ins[1])
+            out = jnp.einsum("...k,nk->...n", ins[0], ins[1],
+                             precision=prec)
         if not p["no_bias"]:
             out = out + ins[2]
         return [out], []
@@ -275,7 +283,8 @@ class Deconvolution(OpSpec):
 
 @register
 class Activation(OpSpec):
-    """relu/sigmoid/tanh/softrelu (``activation-inl.h`` + mshadow_op.h)."""
+    """relu/sigmoid/tanh/softrelu (``activation-inl.h`` + mshadow_op.h),
+    and the transformer era's silu and (exact, erf) gelu."""
 
     name = "Activation"
     params = {"act_type": Param("str")}
@@ -284,6 +293,8 @@ class Activation(OpSpec):
         "sigmoid": jax.nn.sigmoid,
         "tanh": jnp.tanh,
         "softrelu": jax.nn.softplus,
+        "silu": jax.nn.silu,
+        "gelu": lambda x: jax.nn.gelu(x, approximate=False),
     }
 
     def infer_shape(self, p, in_shapes):

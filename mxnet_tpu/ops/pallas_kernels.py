@@ -34,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "fused_linear", "striped_pair_attention",
            "matmul_stats", "paged_attention", "default_paged_block_k",
-           "quant_matmul", "fused_decode_attention",
+           "quant_matmul", "grouped_matmul", "fused_decode_attention",
            "fused_decode_unsupported", "dispatch_count",
            "reset_dispatch_count"]
 
@@ -1440,6 +1440,131 @@ def quant_matmul(x, q, scale, *, bits=8, group=None, block_f=None,
         ],
         out_specs=pl.BlockSpec((m, bf), lambda i: (0, i)),
         interpret=interpret)
+
+
+# -- grouped matmul: the routed experts' product ------------------------
+
+def _grouped_mm_kernel(block_e_ref, used_ref, x_ref, w_ref, o_ref):
+    """One (row block, output tile) of ``grouped_matmul``: the block's
+    rows against its own expert's tile, float32 sums. A block past the
+    used ones holds no row: it writes zeros and multiplies nothing."""
+    i = pl.program_id(1)
+
+    @pl.when(i < used_ref[0])
+    def _():
+        o_ref[...] = lax.dot_general(
+            x_ref[...], w_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(i >= used_ref[0])
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _grouped_mm_xla(x, w, block_e, used, rows):
+    """``grouped_matmul`` in plain XLA: each block against a gathered
+    copy of its expert's matrix. The operations are the kernel's; the
+    gather is ``blocks`` copies of a matrix, which is why the chip runs
+    the kernel."""
+    nb = x.shape[0] // rows
+    xb = x.reshape(nb, rows, x.shape[1])
+    y = jnp.einsum("bmk,bnk->bmn", xb, w[block_e],
+                   preferred_element_type=jnp.float32)
+    live = (jnp.arange(nb, dtype=jnp.int32) < used)[:, None, None]
+    return jnp.where(live, y, 0).astype(x.dtype).reshape(
+        nb * rows, w.shape[1])
+
+
+def _grouped_mm_pallas(x, w, block_e, used, rows, block_n, interpret):
+    m, kdim = x.shape
+    nx, n, _ = w.shape
+    nb = m // rows
+    if block_n is None:
+        # a [block_n, K] tile of the expert's matrix, twice (the
+        # pipeline's two buffers), inside a quarter of the 16 MB of
+        # fast memory a kernel may use
+        block_n = next((c for c in (1024, 512, 256, 128)
+                        if n % c == 0
+                        and 2 * c * kdim * w.dtype.itemsize <= 4 << 20),
+                       n)
+    if n % block_n:
+        raise ValueError("grouped_matmul: block_n=%d must divide the "
+                         "output width %d" % (block_n, n))
+    # output tiles outermost, row blocks innermost: the blocks of one
+    # expert follow each other, so its tile stays where it is and an
+    # expert's matrix is copied from HBM ONCE however many blocks it
+    # has; a block past the used ones re-reads the last used block and
+    # its expert's tile, which is no new copy either
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n // block_n, nb),
+        in_specs=[
+            pl.BlockSpec((rows, kdim), lambda j, i, be, u: (
+                jnp.minimum(i, jnp.maximum(u[0] - 1, 0)), 0)),
+            pl.BlockSpec((1, block_n, kdim),
+                         lambda j, i, be, u: (be[i], j, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, block_n), lambda j, i, be, u: (i, j)),
+    )
+    return _pallas_call(
+        _grouped_mm_kernel, block_e.astype(jnp.int32),
+        jnp.reshape(used, (1,)).astype(jnp.int32), x, w,
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        grid_spec=grid_spec, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _grouped_mm_chip(x, w, block_e, used, rows, block_n):
+    return _grouped_mm_pallas(x, w, block_e, used, rows, block_n, False)
+
+
+def _grouped_mm_chip_fwd(x, w, block_e, used, rows, block_n):
+    return (_grouped_mm_pallas(x, w, block_e, used, rows, block_n, False),
+            (x, w, block_e, used))
+
+
+def _grouped_mm_chip_bwd(rows, block_n, res, g):
+    # the kernel has no backward of its own: the plain form's is exact
+    x, w, block_e, used = res
+    _, vjp = jax.vjp(lambda a, b: _grouped_mm_xla(a, b, block_e, used,
+                                                  rows), x, w)
+    return vjp(g) + (None, None)
+
+
+_grouped_mm_chip.defvjp(_grouped_mm_chip_fwd, _grouped_mm_chip_bwd)
+
+
+def grouped_matmul(x, w, block_e, used, rows, *, block_n=None,
+                   interpret=None):
+    """Rows in blocks, each block against ITS expert's matrix: the
+    product of the routed experts (``ops.attention._routed_experts``).
+
+    ``x`` [blocks * rows, K]: the token rows sorted by expert, every
+    expert's group padded to whole blocks of ``rows``. ``w`` [X, N, K]:
+    the experts' matrices, ``out x in`` like every weight here.
+    ``block_e`` [blocks] int32: each block's expert. ``used`` (int32
+    scalar): how many leading blocks hold rows; the others come back
+    zero. Returns [blocks * rows, N] in ``x``'s dtype, float32 sums.
+
+    On the TPU this is a Pallas kernel over (block, output tile) with
+    ``block_e`` prefetched as scalars: the expert's tile is addressed
+    through it, so only matrices of experts that have a block are ever
+    copied from HBM, each once, and the operations are ``blocks * rows
+    * N * K`` whatever X is. Elsewhere (the CPU tests) the same
+    blocks run as one plain batched product against gathered matrices,
+    so XLA's cost analysis counts what the kernel computes;
+    ``interpret=True`` runs the kernel itself under the interpreter.
+    Differentiable: the kernel's backward is the plain form's."""
+    if x.shape[0] % rows:
+        raise ValueError("grouped_matmul: %d rows are not whole blocks "
+                         "of %d" % (x.shape[0], rows))
+    if interpret is None and _use_interpret():
+        return _grouped_mm_xla(x, w, block_e, used, rows)
+    _count_dispatch()
+    if interpret:
+        return _grouped_mm_pallas(x, w, block_e, used, rows, block_n,
+                                  True)
+    return _grouped_mm_chip(x, w, block_e, used, rows, block_n)
 
 
 def fused_decode_unsupported():

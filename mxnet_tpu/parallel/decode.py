@@ -8,10 +8,14 @@ same Symbol graph the trainer compiled: the topological walk of
 ``parallel.graph.make_graph_fn`` re-runs with every ``MultiHeadAttention``
 node swapped for a cached variant (new tokens' K/V written into
 [B, max_len, Hkv*D] buffers with ``lax.dynamic_update_slice``;
-queries attend to the cache under the mask ``key_pos <= query_pos``) and
+queries attend to the cache under the mask ``key_pos <= query_pos``),
+every ``CCAttention`` node likewise (its K/V rows the same, and beside
+them a short ring of the last positions' raw projections: the second
+kind of cache leaf, ``STATE_ROWS`` below) and
 ``PositionalEmbedding`` sliced at the current position. Every other LM op
-(Embedding, LayerNorm, FullyConnected, activations, elementwise
-arithmetic, MoEFFN, BatchNorm-on-rank-2-data) is position-wise and runs
+(Embedding, LayerNorm, RMSNorm, FullyConnected, activations, elementwise
+arithmetic, ResidualMerge, MoEFFN, BatchNorm-on-rank-2-data) is
+position-wise and runs
 its ordinary ``OpSpec.forward`` unchanged, so there is no duplicated
 model math to drift. BatchNorm normalizes axis 1 — the TIME axis of
 rank-3 [B, T, E] sequence data — so it is position-wise only on rank-2
@@ -49,12 +53,25 @@ __all__ = ["Decoder"]
 _POSITIONWISE = {
     "Embedding", "LayerNorm", "FullyConnected", "Activation", "LeakyReLU",
     "MoEFFN", "Dropout", "BlockGrad", "Cast", "ElementWiseSum",
-    "BatchNorm",
+    "BatchNorm", "RMSNorm", "ResidualMerge", "SoftmaxActivation",
     "_Plus", "_Minus", "_Mul", "_Div", "_PlusScalar", "_MinusScalar",
     "_MulScalar", "_DivScalar", "_RMinusScalar", "_RDivScalar",
 }
 # handled specially
-_TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding"}
+_TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention"}
+
+# CCAttention's rolling state, the second kind of cache leaf: per
+# sequence the last STATE_ROWS positions' [u ; v2] (the raw q/k
+# projections and the half of the values the NEXT token takes), the
+# row of position p at p % STATE_ROWS, stored flat [B, STATE_ROWS * W]
+# (rank 2: a leaf without a head axis, replicated under tp like the
+# rings' position buffers). A position reads the two before it and
+# writes its own, so THREE rows make a step idempotent: the engine
+# re-runs a frozen slot's last step every round, and a ring of two
+# would have overwritten u_{t-2} the first time. Rows of positions
+# before 0 are never read (``ops.attention.cca_qkv`` masks by
+# position), so a reused slot needs no clearing.
+STATE_ROWS = 3
 
 _LOSS_HEADS = {"SoftmaxOutput", "SoftmaxCELoss"}
 
@@ -225,6 +242,7 @@ class Decoder:
                 "(MXNET_SERVING_ATTN_IMPL sets the default)"
                 % (attn_impl,))
         self._attn_impl = attn_impl
+        auto_block = cache_block == "auto"
         if attn_impl == "paged":
             if cache_block == "auto":
                 # paged reads are already prefix-bounded; the blocked
@@ -247,7 +265,9 @@ class Decoder:
                 "Decoder: cache_block=%r must be a positive divisor of "
                 "max_len=%d" % (cache_block, self.max_len))
 
-        self._mha = []
+        self._mha = []      # MultiHeadAttention nodes
+        self._cca = []      # CCAttention nodes (K/V rows + rolling state)
+        self._cached = []   # both, in graph order: one cache entry each
         for n in self._topo:
             if n.is_var:
                 continue
@@ -259,6 +279,17 @@ class Decoder:
                         "autoregressive decoding is defined only for "
                         "causal attention" % n.name)
                 self._mha.append(n)
+                self._cached.append(n)
+            elif name == "CCAttention":
+                self._cca.append(n)
+                self._cached.append(n)
+            elif name == "SoftmaxActivation" \
+                    and n.params["mode"] != "instance":
+                raise MXNetError(
+                    "Decoder: SoftmaxActivation node %r normalizes "
+                    "axis 1, the time axis of sequence data; only "
+                    "mode='instance' (the trailing axis) is "
+                    "position-wise" % n.name)
             elif name in _TEMPORAL or name in _POSITIONWISE:
                 pass
             else:
@@ -268,6 +299,15 @@ class Decoder:
                     "standard LM ops (%s)"
                     % (name, n.name, ", ".join(sorted(_POSITIONWISE))))
 
+        if self._cca:
+            # what the rolling state cannot ride yet refuses here, by
+            # the op's name (ROADMAP D4: no silent fallback)
+            if self._attn_impl == "paged":
+                self.refuse_rolling_state("attn_impl='paged'")
+            if self._cache_block is not None and not auto_block:
+                self.refuse_rolling_state(
+                    "cache_block=%r (blocked reads)" % (cache_block,))
+            self._cache_block = None
         if self._attn_impl == "paged" \
                 and any(self._node_window(n) for n in self._mha):
             # refuse LOUDLY, then serve exactly: ring rows live at
@@ -320,6 +360,9 @@ class Decoder:
                     "dtype, got %r" % (cache_dtype,))
             self._cache_dtype = cdt
 
+        if self._cca and self._cache_int8:
+            self.refuse_rolling_state("cache_dtype='int8'")
+
         # pos_embed bounds the decodable length
         for n in self._topo:
             if not n.is_var and n.spec.name == "PositionalEmbedding":
@@ -343,6 +386,11 @@ class Decoder:
                 "Decoder: weight_dtype must be 'float', 'int8' or "
                 "'int4', got %r (MXNET_SERVING_WEIGHT_DTYPE sets the "
                 "default)" % (weight_dtype,))
+        if weight_dtype != "float":
+            if self._cca:
+                self.refuse_rolling_state("weight_dtype=%r"
+                                          % (weight_dtype,))
+            self.refuse_given_router("weight_dtype=%r" % (weight_dtype,))
         self.weight_dtype = weight_dtype
         self.weight_group = weight_group
         if matmul_impl is None:
@@ -409,6 +457,46 @@ class Decoder:
                                for k, v in aux_params.items()},
                    **kwargs)
 
+    def refuse_rolling_state(self, feature):
+        """Raise for a feature that cannot carry CCAttention's rolling
+        state (the per-sequence ring beside its K/V rows), naming the
+        node: never a silent wrong answer (the decoder's own options,
+        and the serving engine's)."""
+        raise MXNetError(
+            "%s does not compose with CCAttention (node %r): its "
+            "rolling state, the last %d positions' [u ; v2] per "
+            "sequence, is a cache leaf that %s cannot carry yet "
+            "(ROADMAP R-M5 / D3)"
+            % (feature, self._cca[0].name, STATE_ROWS, feature))
+
+    def refuse_given_router(self, feature):
+        """Raise if a MoEFFN node takes its routing from the graph
+        (``router='given'``) or has gated experts: the quantized and
+        expert-parallel forms know the linear-gate ReLU pair only."""
+        for n in self._topo:
+            if not n.is_var and n.spec.name == "MoEFFN" and (
+                    n.params.get("router", "linear") != "linear"
+                    or n.params.get("gated")):
+                raise MXNetError(
+                    "%s does not compose with MoEFFN(router=%r, "
+                    "gated=%r) (node %r): only the linear-gate, "
+                    "biased ReLU experts have a quantized / "
+                    "expert-parallel form (ROADMAP R-M2)"
+                    % (feature, n.params.get("router"),
+                       bool(n.params.get("gated")), n.name))
+
+    @property
+    def slots_walk_batched(self):
+        """Whether the slot-addressed walk (``_run_slots``) runs ONE
+        batched walk over all slots, each at its own position, rather
+        than a ``vmap`` of one-slot walks. CCAttention's cached form
+        takes a vector of positions as it stands, and the routed
+        experts behind it have to see every slot's token at once to
+        sort them by expert; MultiHeadAttention's dense read is
+        written for one position, so a graph that holds one keeps the
+        ``vmap``."""
+        return bool(self._cca) and not self._mha
+
     def _node_window(self, node):
         """Ring-buffer slot count for a windowed attention node (0 for
         ordinary full-history nodes)."""
@@ -427,7 +515,9 @@ class Decoder:
         of only ``window`` rows, [B, window, Hkv*D], plus a [B, window]
         int32 buffer of each ring row's absolute position (-1 = never
         written) — decode memory O(window) regardless of generation
-        length.
+        length. A CCAttention node gets K and V rows of the same
+        layout and its rolling state, [B, STATE_ROWS * W] (rank 2: no
+        head axis; see ``STATE_ROWS``).
 
         ``kv_sharding`` (optional ``jax.sharding.NamedSharding`` whose
         spec names dimension 2, e.g.
@@ -439,10 +529,23 @@ class Decoder:
         tensor-parallel serving cache layout (doc/serving.md
         "Tensor-parallel serving"); the matching compute runs through
         ``_run_slots``'s ``tp=`` axis."""
+        from ..ops.attention import CCAttention as _CCA
         from ..ops.attention import MultiHeadAttention as _MHA
 
         caches = []
-        for n in self._mha:
+        for n in self._cached:
+            if n.spec.name == "CCAttention":
+                # K and V rows in the stored layout, and the rolling
+                # state (STATE_ROWS above): [B, STATE_ROWS * (W + K/2)]
+                qw, kw, _ = _CCA.widths(n.params)
+                rows = (batch_size, self.max_len, kw)
+                caches.append((
+                    jnp.zeros(rows, self._cache_dtype),
+                    jnp.zeros(rows, self._cache_dtype),
+                    jnp.zeros((batch_size,
+                               STATE_ROWS * (qw + kw + kw // 2)),
+                              self._cache_dtype)))
+                continue
             e = self._params[n.inputs[1][0].name].shape[1]  # qkv [F, E]
             h = n.params["num_heads"]
             win = self._node_window(n)
@@ -475,8 +578,9 @@ class Decoder:
         """Per-leaf ``PartitionSpec`` tree for a cache pytree: K/V and
         scale buffers (rank 3) shard dimension 2 — the kv-major lanes
         [Hkv*D], or the [Hkv] scales — over ``axis``, so a shard holds
-        whole kv heads; ring-position buffers (rank 2, no head dim)
-        replicate. Shared by ``init_cache(kv_sharding=...)`` and the
+        whole kv heads; leaves without a head axis (rank 2: the rings'
+        position buffers, CCAttention's rolling state) replicate.
+        Shared by ``init_cache(kv_sharding=...)`` and the
         serving engine's shard_map program specs, so the two can never
         drift."""
         from jax.sharding import PartitionSpec as P
@@ -582,8 +686,9 @@ class Decoder:
         copied inside the step and every stored byte streams once.
 
         ``q`` [B, C, H, D]; ``entry`` the (possibly row-limited) cache
-        entry, K/V [B, L, Hkv*D]; scalar ``pos`` (query row i sits at
-        ``pos + i`` and sees keys ``<= pos + i``). Returns
+        entry, K/V [B, L, Hkv*D]; ``pos`` a scalar (query row i sits at
+        ``pos + i`` and sees keys ``<= pos + i``) or a [B] vector, each
+        batch row at its own position. Returns
         [B, C, H, D] in ``q``'s dtype.
 
         Scores: each query row is spread into a block-diagonal
@@ -623,6 +728,8 @@ class Decoder:
             s = s * ks[:, None]
         s = s * f32(1.0 / float(np.sqrt(d)))
         kpos = jnp.arange(rows)[None, None, :, None]
+        if jnp.ndim(pos) == 1:
+            pos = jnp.asarray(pos, jnp.int32)[:, None, None, None]
         qpos = pos + (jnp.arange(c * g) // g)[None, :, None, None]
         s = jnp.where(kpos <= qpos, s, f32(-1e30))
         p = jax.nn.softmax(s, axis=2)                        # [b,r,l,kv]
@@ -822,6 +929,73 @@ class Decoder:
             # position-wise op run with tp=1's shapes on every shard
             o = lax.all_gather(o, tp[0], axis=2, tiled=True)
         return out_proj(o), entry
+
+    def _cached_cca(self, node, ins, entry, pos, valid_len=None):
+        """CCAttention on a chunk at ``pos`` (a scalar, or a [B] vector:
+        every batch row at its own position) against its cache entry
+        ``(K rows, V rows, rolling state)``. The mixing is the op's own
+        (``ops.attention.cca_qkv``), fed the two positions before the
+        chunk from the state; K and V go into the stored rows like
+        MultiHeadAttention's and are read the same two ways; the
+        state then takes the chunk's last STATE_ROWS REAL positions
+        (``valid_len``, absolute: a right-padded prefill bucket must
+        leave the state of its last real token, not of its padding)."""
+        from ..ops.attention import CCAttention as _CCA, cca_qkv
+        x, wqk, wv, c0w, c0b, c1w, c1b, temp, wo = ins
+        p = node.params
+        b, c, _ = x.shape
+        qw, kw, d = _CCA.widths(p)
+        kv = p["num_kv_heads"]
+        i32 = jnp.int32
+        with jax.named_scope("proj"):
+            u = jnp.einsum("bte,fe->btf", x, wqk)
+            vv = jnp.einsum("bte,fe->btf", x, wv)
+        first = jnp.broadcast_to(
+            jnp.asarray(pos, i32).reshape(-1, 1), (b, 1))     # [B, 1]
+        with jax.named_scope("cache"):
+            ck, cv, flat = entry
+            state = flat.reshape(b, STATE_ROWS, -1)   # small: a view
+            def before(k):           # the state's row of position -k
+                row = jnp.take_along_axis(
+                    state, ((first - k) % STATE_ROWS)[..., None], axis=1)
+                return row[..., :qw + kw], row[..., qw + kw:]
+            (u1, v2p), (u2, _) = before(1), before(2)
+        with jax.named_scope("conv"):
+            q, k, v = cca_qkv(p, u, vv, (c0w, c0b, c1w, c1b, temp),
+                              first + jnp.arange(c, dtype=i32),
+                              prev=(u1, u2, v2p))
+        kvrows = self._write_cache((ck, cv), k, v, pos)
+        limit = self.max_len
+        if isinstance(pos, (int, np.integer)):
+            limit = min(self.max_len, int(pos) + c)
+        if c <= _SHORT_CHUNK:
+            o = self._lane_attn(q, self._live_rows(kvrows, limit), pos, kv)
+        else:
+            if jnp.ndim(pos) == 1:
+                raise MXNetError(
+                    "Decoder: a chunk of %d tokens at per-slot "
+                    "positions has no dense read (CCAttention node %r)"
+                    % (c, node.name))
+            o = self._head_attn(
+                q, *self._read_cache(kvrows, q.dtype, kv, limit), pos)
+        with jax.named_scope("cache"):
+            # the chunk's last STATE_ROWS real positions, each to its
+            # own row; rows of padding (and of a chunk shorter than
+            # the ring) scatter out of bounds and are dropped
+            real = jnp.full((b, 1), c, i32) if valid_len is None else \
+                jnp.clip(jnp.asarray(valid_len, i32) - first, 0, c)
+            idx = real - STATE_ROWS + jnp.arange(STATE_ROWS, dtype=i32)
+            at = jnp.clip(idx, 0, c - 1)
+            new = jnp.take_along_axis(
+                jnp.concatenate([u, vv[..., kw // 2:]], axis=-1)
+                .astype(state.dtype), at[..., None], axis=1)
+            rows = jnp.where(idx >= 0, (first + at) % STATE_ROWS,
+                             STATE_ROWS)
+            state = state.at[jnp.arange(b, dtype=i32)[:, None], rows] \
+                .set(new, mode="drop")
+        with jax.named_scope("proj"):
+            out = jnp.einsum("btq,eq->bte", o.reshape(b, c, qw), wo)
+        return out, kvrows + (state.reshape(flat.shape),)
 
     @staticmethod
     @jax.named_scope("attend")
@@ -1027,7 +1201,7 @@ class Decoder:
         return o.transpose(0, 2, 1, 3)             # [b,c,h,d]
 
     def _run(self, params, aux, caches, pos, tokens, valid_len=None,
-             tp=None, mm_impl=None, ep=None):
+             tp=None, mm_impl=None, ep=None, stats=None):
         """One chunk: tokens [B, C] at positions [pos, pos+C) →
         (logits [B, C, V], updated caches). ``valid_len`` marks a
         right-padded chunk's true length — only windowed ring WRITES
@@ -1047,7 +1221,13 @@ class Decoder:
         one (attention projections, FullyConnected, Embedding, MoEFFN
         — ``quant.quantized_weight_names`` guarantees no other op
         does) dequantize on the fly via the scale-fused forms
-        below."""
+        below.
+
+        ``stats`` (a dict, optional): what the walk counts on the
+        device is summed into it — ``experts_touched`` (experts given
+        a token, summed over the routed MoEFFN nodes): the serving
+        engine's ``serving.moe_experts_touched``."""
+        from ..ops.attention import moe_ffn_math
         from ..serving.quant import (QuantizedTensor, embedding_rows,
                                      moe_ffn_forward)
 
@@ -1072,6 +1252,12 @@ class Decoder:
                     out, new_caches[mha_i] = self._cached_mha(
                         n, ins, new_caches[mha_i], pos, valid_len, tp,
                         mm_impl=mm_impl)
+                    mha_i += 1
+                    env[(id(n), 0)] = out
+                    continue
+                if name == "CCAttention":
+                    out, new_caches[mha_i] = self._cached_cca(
+                        n, ins, new_caches[mha_i], pos, valid_len)
                     mha_i += 1
                     env[(id(n), 0)] = out
                     continue
@@ -1111,6 +1297,14 @@ class Decoder:
                     env[(id(n), 0)] = moe_ffn_forward(n.params, ins,
                                                       mm=qmm, ep=ep)
                     continue
+                if name == "MoEFFN" and stats is not None \
+                        and n.params["top_k"] > 0:
+                    seen = {}
+                    env[(id(n), 0)] = moe_ffn_math(n.params, ins,
+                                                   stats=seen)
+                    stats["experts_touched"] = seen["experts_touched"] \
+                        + stats.get("experts_touched", 0)
+                    continue
                 if name == "BatchNorm" and ins[0].ndim >= 3:
                     # BatchNorm normalizes axis 1, which for rank>=3 LM data
                     # [B, T, E] is the TIME axis: a [B, 1, E] decode chunk
@@ -1142,7 +1336,7 @@ class Decoder:
     # included) with zero duplication.
 
     def _run_slots(self, params, aux, caches, pos, tokens, impl=None,
-                   tp=None, mm_impl=None, ep=None):
+                   tp=None, mm_impl=None, ep=None, stats=None):
         """Per-slot-position ``_run``: ``pos`` [S] int32 positions (one
         per cache slot), ``tokens`` [S, C] → (logits [S, C, V], updated
         caches).
@@ -1167,7 +1361,12 @@ class Decoder:
         handed the shard's own lanes and local kv-head count — and
         the per-attention-node
         all-gather rebuilds the head output exactly as in the dense
-        branch (doc/serving.md "Paged attention")."""
+        branch (doc/serving.md "Paged attention").
+
+        A graph whose temporal nodes all take a position vector as
+        they stand (``slots_walk_batched``: CCAttention) runs the ONE
+        batched walk under ``"dense"`` too, with its own dense read;
+        ``stats`` is filled there (see ``_run``)."""
         if impl is None:
             impl = self._attn_impl
         elif impl == "dense" and self._attn_impl == "paged":
@@ -1179,10 +1378,12 @@ class Decoder:
                 "Decoder: impl='dense' requested on a decoder built "
                 "with attn_impl='paged' — build the decoder dense "
                 "(the engine threads its own attn_impl per dispatch)")
-        if impl == "paged":
+        if impl == "paged" or self.slots_walk_batched:
+            # ``stats``: only this one walk sees every slot's token,
+            # so only here can experts be counted once per step
             return self._run(params, aux, caches,
                              jnp.asarray(pos, jnp.int32), tokens,
-                             tp=tp, mm_impl=mm_impl, ep=ep)
+                             tp=tp, mm_impl=mm_impl, ep=ep, stats=stats)
 
         def one(slot_caches, p, t):
             # vmap hands each lane the slot's cache WITHOUT its leading
@@ -1226,7 +1427,7 @@ class Decoder:
         recycled slot (traced ``start == 0``) may wipe the ring; later
         chunks must keep the positions their predecessors wrote."""
         out = []
-        for n, entry in zip(self._mha, caches):
+        for n, entry in zip(self._cached, caches):
             if self._node_window(n):
                 wiped = jnp.full_like(entry[-1], -1)
                 if only_if is not None:

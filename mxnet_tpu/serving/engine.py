@@ -166,6 +166,13 @@ _TM_COMPLETED = tele.counter("serving.completed")
 _TM_RETIRED_EOS = tele.counter("serving.retired_eos")
 _TM_RETIRED_LENGTH = tele.counter("serving.retired_length")
 _TM_ROUNDS = tele.counter("serving.rounds")
+# routed experts (MoEFFN top_k > 0 in the batched slot walk): experts
+# that were given a token, summed over layers and decode steps, beside
+# the layer-steps it was summed over. Counted on the device inside the
+# decode program and brought back as one more column of the round's
+# tokens: no new sync, no new transfer.
+_TM_MOE_TOUCHED = tele.counter("serving.moe_experts_touched")
+_TM_MOE_LAYER_STEPS = tele.counter("serving.moe_layer_steps")
 _TM_PREFILLS = tele.counter("serving.prefills")
 _TM_ADMITTED = tele.histogram(
     "serving.admitted_per_round", buckets=(0, 1, 2, 4, 8, 16, 32, 64))
@@ -860,6 +867,14 @@ class InferenceEngine:
                              "(1 = no expert sharding; "
                              "MXNET_SERVING_EP sets the default), "
                              "got %d" % ep)
+        # a CCAttention decoder keeps a rolling state beside its rows;
+        # every feature that cannot carry it refuses by the op's name,
+        # here and below (ROADMAP D4: no silent fallback)
+        rolling = bool(decoder._cca)
+        if rolling and tp > 1:
+            decoder.refuse_rolling_state("tp=%d" % tp)
+        if ep > 1:
+            decoder.refuse_given_router("ep=%d" % ep)
         moe_nodes = [n for n in decoder._topo
                      if not n.is_var and n.spec.name == "MoEFFN"]
         if ep > 1:
@@ -925,6 +940,12 @@ class InferenceEngine:
                 "weights would re-round; build the decoder float (the "
                 "engine quantizes its own copy)"
                 % (weight_dtype, decoder.weight_dtype))
+        if weight_dtype != "float":
+            if rolling:
+                decoder.refuse_rolling_state("weight_dtype=%r"
+                                             % (weight_dtype,))
+            decoder.refuse_given_router("weight_dtype=%r"
+                                        % (weight_dtype,))
         self.weight_dtype = weight_dtype
         if weight_group is None:
             weight_group = decoder.weight_group
@@ -1003,9 +1024,18 @@ class InferenceEngine:
         # keeps vmapping over exactly S lanes — pool size must never
         # tax the per-token path.
         if prefix_cache_mb is None:
-            prefix_cache_mb = float(os.environ.get(
-                "MXNET_SERVING_PREFIX_CACHE_MB") or "64")
+            prefix_cache_mb = os.environ.get(
+                "MXNET_SERVING_PREFIX_CACHE_MB")
+            if prefix_cache_mb is None or prefix_cache_mb == "":
+                # the default pool; none where a rolling state would
+                # have to be snapshotted with the rows (asked for by
+                # value, it refuses below)
+                prefix_cache_mb = 0 if rolling else 64
         self.prefix_cache_mb = float(prefix_cache_mb)
+        if rolling and self.prefix_cache_mb > 0:
+            decoder.refuse_rolling_state(
+                "prefix_cache_mb=%g (the prefix pool copies rows)"
+                % self.prefix_cache_mb)
         if self.prefix_cache_mb < 0:
             raise MXNetError("InferenceEngine: prefix_cache_mb must "
                              "be >= 0 (0 disables the prefix cache)")
@@ -1029,6 +1059,8 @@ class InferenceEngine:
                 "built with attn_impl='paged' — build the decoder "
                 "dense; the engine threads its own attn_impl into the "
                 "slot programs")
+        if attn_impl == "paged" and rolling:
+            decoder.refuse_rolling_state("attn_impl='paged'")
         if attn_impl == "paged" and self._windowed:
             # refuse LOUDLY, then serve exactly (prefix-cache /
             # speculation precedent): ring rows live at wrapped
@@ -1090,6 +1122,9 @@ class InferenceEngine:
                 "InferenceEngine: role must be 'unified', 'prefill' "
                 "or 'decode', got %r (MXNET_SERVING_ROLE sets the "
                 "default)" % (role,))
+        if role != "unified" and rolling:
+            decoder.refuse_rolling_state(
+                "role=%r (the KV handoff ships rows)" % (role,))
         if role != "unified" and self._windowed:
             raise MXNetError(
                 "InferenceEngine: windowed-ring decoders do not "
@@ -1145,6 +1180,10 @@ class InferenceEngine:
             spec_k = int(os.environ.get("MXNET_SERVING_SPEC_K", "")
                          or 4)
         self.spec_k = int(spec_k)
+        if draft != "off" and rolling:
+            decoder.refuse_rolling_state(
+                "draft=%r (a rejected draft would have advanced the "
+                "state)" % (draft,))
         if draft != "off":
             if self.spec_k < 1:
                 raise MXNetError(
@@ -1320,6 +1359,11 @@ class InferenceEngine:
         self._donate = (2, 3) if on_chip else ()
         self._copy_donate = (0, 1) if on_chip else ()
         cs = self._cache_spec(self._caches)
+        # routed MoEFFN layers whose touched experts the decode program
+        # counts (0: none, or a walk that never sees all slots at once)
+        self._moe_counted = sum(
+            1 for n in moe_nodes if n.params["top_k"] > 0) \
+            if decoder.slots_walk_batched else 0
         self._step_fn = jax.jit(
             self._wrap_tp(self._make_step(),
                           (ps, "r", cs, "r"), (cs, "r", "r")),
@@ -1476,15 +1520,18 @@ class InferenceEngine:
         tp_ax = self._tp_ax
         ep_ax = self._ep_ax
 
+        counted = self._moe_counted
+
         def one_step(caches, state, params, aux):
             pos, tok, live, temp, keys, eos, last = state
             # write each slot's pending token at ITS position, read
             # logits for the next one (frozen slots rewrite their last
             # token in place — idempotent)
+            stats = {} if counted else None
             logits, caches = dec._run_slots(params, aux, caches, pos,
                                             tok[:, None], impl=impl,
                                             tp=tp_ax, mm_impl=mm,
-                                            ep=ep_ax)
+                                            ep=ep_ax, stats=stats)
             logits = logits[:, 0]
             nxt_pos = pos + 1
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1509,6 +1556,12 @@ class InferenceEngine:
                            lambda _: greedy, None)
             done_now = (nxt == eos) | (nxt_pos >= last)
             out = jnp.where(live, nxt, -1)     # -1: slot had no token
+            if counted:
+                # one more column beside the S tokens: the experts
+                # this step touched, over the routed layers
+                out = jnp.concatenate(
+                    [out, stats["experts_touched"].astype(out.dtype)
+                     .reshape(1)])
             live2 = live & ~done_now
             pos2 = jnp.where(live, nxt_pos, pos)
             tok2 = jnp.where(live, nxt, tok)
@@ -1530,7 +1583,7 @@ class InferenceEngine:
 
             (caches, state), outs = lax.scan(body, (caches, state),
                                              None, length=k_rounds)
-            return caches, state, outs          # outs [k, S]
+            return caches, state, outs          # outs [k, S (+1)]
 
         return step
 
@@ -2916,6 +2969,12 @@ class InferenceEngine:
                 _TM_SPEC_ACCEPTED.inc(acc)
         else:
             rounds = np.asarray(entry[1])        # [steps_per_round, S]
+            if self._moe_counted:
+                # [steps, S + 1]: the last column is the device's
+                # count of experts touched, step by step
+                _TM_MOE_TOUCHED.inc(int(rounds[:, self.slots].sum()))
+                _TM_MOE_LAYER_STEPS.inc(
+                    self._moe_counted * rounds.shape[0])
             for row in rounds:
                 for s in range(self.slots):
                     req = self._mirror[s]

@@ -12,7 +12,7 @@ import ml.dmlc.mxnet_tpu.{NDArray, Symbol}
   * dmlc-style stringified params). */
 object GeneratedOps {
 
-  /** relu/sigmoid/tanh/softrelu (``activation-inl.h`` + mshadow_op.h).
+  /** relu/sigmoid/tanh/softrelu (``activation-inl.h`` + mshadow_op.h),
     * params: act_type: str
     * inputs: data */
   def Activation(name: String, inputs: Map[String, Symbol],
@@ -32,6 +32,13 @@ object GeneratedOps {
   def BlockGrad(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("BlockGrad", name, attrs, inputs)
+
+  /** Compressed convolutional attention (CCA; Zyphra,
+    * params: head_dim: int; impl: str = flash; num_heads: int; num_kv_heads: int; rope_base: float = 10000.0; rotary_dim: int = 0
+    * inputs: data, qk_weight, v_weight, conv0_weight, conv0_bias, conv1_weight, conv1_bias, temp, out_weight */
+  def CCAttention(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("CCAttention", name, attrs, inputs)
 
   /** dtype conversion (``cast-inl.h``).
     * params: dtype: str
@@ -97,7 +104,7 @@ object GeneratedOps {
     Symbol.createFromNamedArgs("Flatten", name, attrs, inputs)
 
   /** out = data · weightᵀ + bias (``fully_connected-inl.h:53-81``).
-    * params: flatten: bool = True; no_bias: bool = False; num_hidden: int
+    * params: flatten: bool = True; no_bias: bool = False; num_hidden: int; precision: str = 
     * inputs: data, weight, bias */
   def FullyConnected(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
@@ -153,7 +160,7 @@ object GeneratedOps {
     Symbol.createFromNamedArgs("MAERegressionOutput", name, attrs, inputs)
 
   /** Mixture-of-experts position-wise FFN (soft or top-k routing).
-    * params: hidden: int; num_experts: int; top_k: int = 0
+    * params: gated: bool = False; hidden: int; num_experts: int; router: str = linear; top_k: int = 0
     * inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2 */
   def MoEFFN(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
@@ -180,12 +187,26 @@ object GeneratedOps {
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("PositionalEmbedding", name, attrs, inputs)
 
+  /** Root-mean-square normalization over the trailing axis:
+    * params: eps: float = 1e-05
+    * inputs: data, gamma */
+  def RMSNorm(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("RMSNorm", name, attrs, inputs)
+
   /** View change (``reshape-inl.h``). ``target_shape`` excludes batch
     * params: shape: shape = []; target_shape: shape = []
     * inputs: data */
   def Reshape(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("Reshape", name, attrs, inputs)
+
+  /** A residual connection with learned per-channel scales:
+    * params: affine: str = full
+    * inputs: data, branch, data_bias, data_scale, branch_bias, branch_scale */
+  def ResidualMerge(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("ResidualMerge", name, attrs, inputs)
 
   /** Split along an axis into num_outputs (``slice_channel-inl.h``);
     * params: axis: int = 1; num_outputs: int; squeeze_axis: bool = False

@@ -360,3 +360,61 @@ def test_decode_program_holds_no_copy_of_the_cache(serve_chat_engine,
     big = [dims for dims in copies
            if np.prod([int(d) for d in dims.split(",")]) == leaves[0].size]
     assert not big, big
+
+
+# -- ZAYA1-8B: the routed experts' kernel and the decode program ---------
+
+@pytest.mark.parametrize("rows,blocks", [(16, 18), (128, 48)])
+def test_grouped_matmul(compile_for_chip, rows, blocks):
+    """The routed experts' product at ZAYA1-8B's widths (16 experts,
+    gate and up of 2048 x 2048 in one matrix): a decode step's 32
+    tokens in blocks of 16 rows, and 4096 prefill tokens in blocks of
+    128."""
+    def fn(x, w, block_e, used):
+        return pk.grouped_matmul(x, w, block_e, used, rows,
+                                 interpret=False)
+    compile_for_chip(fn, ((blocks * rows, 2048), BF16),
+                     ((16, 4096, 2048), BF16), ((blocks,), I32), ((), I32))
+
+
+def test_zaya_decode_program(one_chip, monkeypatch):
+    """The engine's decode program for ZAYA1-8B's block at its
+    published widths (2 layers, a cut vocabulary, 32 slots x 2048
+    rows), compiled for the chip: the routed experts run as the Pallas
+    kernel, two calls a layer; nothing as large as a layer's expert
+    stack is copied (XLA's own grouped product lays out a 134 MB
+    temporary and multiplies every expert); the temporaries stay under
+    a tenth of the cache."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_zaya_lm
+    layers = 2
+    sym = get_zaya_lm(8192, layers, 2048, 8, 2, 128, 16, 2048, 256,
+                      rotary_dim=64, rope_base=5e6)
+    shapes = {"data": (1, 8), "softmax_label": (1, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    params = {n: jnp.zeros(s, BF16)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    dec = mx.parallel.Decoder(sym, params, max_len=2048,
+                              compute_dtype="bfloat16", cache_block=None)
+    eng = mx.serving.InferenceEngine(
+        dec, slots=32, prefill_buckets=(128, 512), steps_per_round=8,
+        prefix_cache_mb=0, prefill_chunk=0)
+    # the process sees the CPU; the program is traced as the chip would
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        [eng._params, eng._aux, eng._caches, eng._state])
+    compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * layers
+    stack = 16 * 4096 * 2048
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert not [d for d in copies
+                if np.prod([int(n) for n in d.split(",")]) >= stack]
+    cache_bytes = sum(x.nbytes for x in
+                      jax.tree_util.tree_leaves(eng._caches))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.1 * cache_bytes
+    eng.close()
