@@ -517,11 +517,11 @@ def phase_serve(mx, seed):
     engine.close()
     said = judge(dec, reqs, got, "serve",
                  want=[offline(dec, p, n) for p, n in reqs])
-    print("[serve] dense bf16 against Decoder.generate: %d requests, "
-          "every token within the allowance of the reference argmax, "
-          "%s; compiles=%s peak_gb=%s"
-          % (len(reqs), said, json.dumps(cc, default=str), peak_gb()),
-          flush=True)
+    print("[serve] bf16, attn_impl=%s, against Decoder.generate: %d "
+          "requests, every token within the allowance of the reference "
+          "argmax, %s; compiles=%s peak_gb=%s"
+          % (engine.attn_impl, len(reqs), said,
+             json.dumps(cc, default=str), peak_gb()), flush=True)
 
     # -- float32, full matmul precision: byte-identity -----------------
     with jax.default_matmul_precision("highest"):
@@ -533,7 +533,7 @@ def phase_serve(mx, seed):
         identical(got, [offline(dec32, p, n) for p, n in reqs],
                   "serve_float32")
     del dec32, e32
-    print("[serve] dense float32 against Decoder.generate: %d of %d "
+    print("[serve] float32 against Decoder.generate: %d of %d "
           "requests byte-identical (%d tokens), compiles=%s"
           % (len(reqs), len(reqs), sum(n for _, n in reqs),
              json.dumps(cc, default=str)), flush=True)

@@ -1122,10 +1122,11 @@ class FeedForward(BASE_ESTIMATOR):
         knobs (load shedding policy, round watchdog — doc/serving.md
         "Serving under hostile traffic"); ``spec_k``/``draft``/
         ``draft_decoder`` arm speculative decoding (doc/serving.md
-        "Speculative decoding"); ``attn_impl="paged"`` serves
-        decode/verify through the Pallas paged-attention kernel that
-        reads only each slot's live KV rows (doc/serving.md "Paged
-        attention"); ``tp=N`` shards the KV cache and every compiled
+        "Speculative decoding"); ``attn_impl`` names the decode /
+        verify cache read — left out, a linear cache is read through
+        the Pallas paged-attention kernel, which fetches only the rows
+        live requests hold and nothing of a slot that holds none
+        (doc/serving.md "Paged attention"); ``tp=N`` shards the KV cache and every compiled
         serving program over an N-device mesh's model axis
         (doc/serving.md "Tensor-parallel serving");
         ``weight_dtype="int8"`` quantizes the engine's copy of the

@@ -34,6 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "fused_linear", "striped_pair_attention",
            "matmul_stats", "paged_attention", "default_paged_block_k",
+           "paged_rows_fetched",
            "quant_matmul", "grouped_matmul", "fused_decode_attention",
            "fused_decode_unsupported", "dispatch_count",
            "reset_dispatch_count"]
@@ -1042,39 +1043,51 @@ def matmul_stats(x, w, *, block_m=256, block_n=256, block_k=512,
 
 
 # ---------------------------------------------------------------------------
-# paged attention — the serving engine's decode/verify read (ISSUE 11).
+# paged attention — the serving engine's decode/verify/draft read.
 #
-# The slot-paged KV cache is stored [S, max_len, Hkv*D] (kv-major
-# lanes; parallel/decode.py states the layout) with every slot at its
-# own position; the dense read streams (and masks) ALL max_len rows
-# per emitted token even when a slot is 40 tokens into a 1024-row
-# cache. This kernel walks only each slot's LIVE blocks: grid
-# over (slot, kv-block) under a PrefetchScalarGridSpec — the
-# per-slot position vector is scalar-prefetched so the cache index
-# maps clamp every grid step past ceil((pos + C) / block_k) back to
-# the slot's last live block (a revisited block index, whose HBM->VMEM
-# copy Mosaic elides; the body is pl.when-gated off), i.e. the bound
-# cuts the DMA itself, not just the compute. Online-softmax scratch
-# accumulation merges blocks exactly (a reassociation, not an
-# approximation — the same argument as Decoder._blocked_attn), and
-# int8 caches dequantize per block IN the kernel from the side-scale
-# operands, so the cache is read once at 1 byte/elem. C > 1 serves the
-# chunked-query flavors: the speculative verify step's [S, K+1] chunk
-# and the draft model's catch-up window (doc/serving.md "Paged
-# attention").
+# The slot cache is stored [S, max_len, Hkv*D] (kv-major lanes;
+# parallel/decode.py states the layout) with every slot at its own
+# position, and most of it holds nothing a request needs: rows past a
+# slot's position, and every row of a slot whose request has finished.
+# This kernel fetches only the blocks of rows that live requests hold.
+# Grid (slot visit, kv-block) under a PrefetchScalarGridSpec: the
+# per-slot LENGTHS (rows the read may fetch; 0 for a slot that holds no
+# request) are scalar-prefetched, slots are visited live ones first,
+# and the cache index maps send every grid step past a slot's last live
+# block — and every step of a dead slot — to the block just visited (a
+# revisited block index, whose HBM->VMEM copy Mosaic elides; the body
+# is pl.when-gated off): the bound cuts the DMA itself, and a dead slot
+# costs no copy at all. Inside a block the arithmetic is the dense
+# read's (Decoder._lane_attn): all kv heads at once off the lane-dense
+# rows, against the query spread block-diagonally over the lanes.
+# Online-softmax scratch merges blocks exactly (a reassociation, not an
+# approximation), int8 caches apply their side scales to the scores and
+# the weights in the kernel, and C > 1 serves the chunked-query
+# flavors: the speculative verify step's [S, K+1] chunk and the draft
+# model's catch-up window (doc/serving.md "Paged attention").
 #
 # NOT ring-safe: a windowed ring stores rows at wrapped positions, so
-# "rows [0, pos+C)" is not the live set — the engine refuses loudly and
-# serves those models with the exact dense ring walk (UserWarning
-# precedent: speculation, prefix cache).
+# "rows [0, pos+C)" is not the live set — those models keep the exact
+# dense ring walk.
+
+# a K or V block in flight holds at most this many bytes (two of each
+# are in flight: 4 MB of the chip's VMEM at the cap). On the chip, at
+# bf16 rows of 2048 lanes with 5 of 16 slots live, blocks of 128 / 256 /
+# 512 rows read 0.069 / 0.064 / 0.066 ms a layer and step (PERF.md
+# section 6, PR 29): smaller blocks round a slot's length up by less,
+# larger ones take fewer grid steps
+_PAGED_BLOCK_BYTES = 1 << 20
 
 
-def default_paged_block_k(max_len):
+def default_paged_block_k(max_len, row_bytes=None):
     """KV rows per block for ``paged_attention``: the largest of
-    (128, 64, 32, 16, 8) dividing ``max_len`` (whole blocks keep the
-    in-kernel slices static), else ``max_len`` itself — a cache too
-    short/odd to block degenerates to one block, still bounded by the
-    position mask. ``MXNET_PAGED_BLOCK_K`` overrides."""
+    (512, 256, 128, 64, 32, 16, 8) that divides ``max_len`` (whole
+    blocks keep the in-kernel slices static) and, where the stored
+    row's ``row_bytes`` are given, keeps a block within 1 MB — a slot
+    of a few hundred live rows is then two to four grid steps; else
+    ``max_len`` itself: a cache too short/odd to block degenerates to
+    one block, still bounded by the position mask.
+    ``MXNET_PAGED_BLOCK_K`` overrides."""
     import os
     override = os.environ.get("MXNET_PAGED_BLOCK_K")
     if override:
@@ -1088,125 +1101,167 @@ def default_paged_block_k(max_len):
                 "MXNET_PAGED_BLOCK_K=%s must be a positive divisor of "
                 "the cache length %d" % (override, max_len))
         return b
-    for b in (128, 64, 32, 16, 8):
-        if max_len % b == 0:
+    fits = [b for b in (512, 256, 128, 64, 32, 16, 8)
+            if max_len % b == 0]
+    for b in fits:
+        if row_bytes is None or b * row_bytes <= _PAGED_BLOCK_BYTES:
             return b
-    return max_len
+    return fits[-1] if fits else max_len
 
 
-def _paged_attn_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k,
-                       chunk, n_blocks, scale, quant, kv_heads,
-                       head_dim):
-    """One (slot, kv-block) grid cell of the paged read.
+def paged_rows_fetched(lens, max_len, block_k):
+    """Rows of ONE [S, max_len, ...] buffer that ``paged_attention``
+    fetches for the lengths ``lens`` [S]: each slot's length rounded
+    up to whole blocks (the serving engine's
+    ``serving.attn_rows_read``). With no live slot at all the kernel
+    still stages one block, which this leaves out."""
+    lens = jnp.clip(jnp.asarray(lens, jnp.int32), 0, max_len)
+    return jnp.sum((lens + (block_k - 1)) // block_k * block_k)
 
-    The kv-block axis is a GRID dimension, not an in-kernel loop, so
-    the per-slot bound cuts the DMA itself: the cache BlockSpecs'
-    index maps (see ``paged_attention``) send every dead step back to
-    the slot's last live block — an unchanged block index, whose copy
-    Mosaic elides — and this body is ``pl.when``-gated off for them.
-    The cache block is the ``[block_k, Hkv*D]`` lane-dense view of
-    ALL kv heads' rows (the TPU lowering wants the last two block
-    dims (8k, 128k) or whole, which a one-head ``(block_k, 1, D)``
-    block is not); the kv heads are a static in-kernel loop over
-    ``D``-wide lane slices of it. Online-softmax state (acc/l/m, one
-    plane per kv head) lives in VMEM scratch carried across the
-    innermost grid sweep; the output block is written once, on the
-    final step. q block [Hkv, G*C, D] (each kv head's G query heads x
-    C chunk rows, row r = g*C + c — the decoder's GQA fold order);
-    int8 caches dequantize per block from the row-scale operands.
-    int32 arithmetic throughout (see ``_pallas_call``)."""
+
+def _paged_attn_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, pos_ref,
+                       q_ref, k_ref, v_ref, hm_ref, *rest, block_k,
+                       chunk, group, n_blocks, scale, quant, kv_heads,
+                       kv_pad):
+    """One (slot visit, kv-block) grid cell of the paged read.
+
+    Step ``i`` visits slot ``order[i]`` (live slots first), whose
+    ``nkb[i]`` leading blocks hold rows a request needs; the cache
+    BlockSpecs' index maps (see ``paged_attention``) keep every other
+    step on the block fetched last, and this body is ``pl.when``-gated
+    off for them. The cache block is the ``[block_k, Hkv*D]``
+    lane-dense view of ALL kv heads' rows, consumed whole: the query
+    rows (r = (c, g): chunk position, member of the GQA group) are
+    spread into ``Qbd [R * Hkv', Hkv*D]`` — row (r, h) holds query row
+    r's head-h values on head h's lanes, zeros elsewhere (``hm``, the
+    0/1 lane-to-head matrix; ``Hkv'`` is Hkv padded to whole sublane
+    tiles, the padding rows all zero) — so ``Qbd @ K_block^T`` gives
+    every head's scores in one product with the operands in the
+    compute dtype and float32 sums, the softmax runs in float32 with
+    ``block_k`` on the lanes, and ``p @ V_block`` gives every head's
+    weights against every lane, of which the diagonal blocks (head h's
+    weights against head h's lanes) are picked once, on the last step.
+    Online-softmax state (acc/l/m) lives in VMEM scratch carried
+    across the kv-block sweep. int8 caches: the row scales
+    ``[block_k, Hkv]`` are turned and spread over the rows (r, h) by a
+    product with a 0/1 matrix at full precision (exact) and applied to
+    the scores and to ``p``, as ``Decoder._lane_attn`` does. int32
+    arithmetic throughout (see ``_pallas_call``)."""
     if quant:
-        ks_ref, vs_ref, o_ref, acc_ref, l_ref, m_ref = rest
+        ks_ref, vs_ref, sel_ref, o_ref, qbd_ref, acc_ref, l_ref, m_ref \
+            = rest
     else:
-        o_ref, acc_ref, l_ref, m_ref = rest
-    s = pl.program_id(0)
+        o_ref, qbd_ref, acc_ref, l_ref, m_ref = rest
+    i = pl.program_id(0)
     j = pl.program_id(1)
-    p = pos_ref[s]
-    nkb = jnp.minimum(
-        lax.div(p + jnp.int32(chunk + block_k - 1), jnp.int32(block_k)),
-        jnp.int32(n_blocks))
+    rows = chunk * group
+    n = rows * kv_pad
     neg_big = jnp.float32(-1e30)
-    d = head_dim
+    f32 = jnp.float32
+    cdt = qbd_ref.dtype
+    # f32 operands (the byte-identity contract) multiply exactly
+    prec = lax.Precision.HIGHEST if cdt == jnp.float32 else None
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        m_ref[...] = jnp.full(m_ref.shape, neg_big, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        m_ref[...] = jnp.full(m_ref.shape, neg_big, f32)
+        for r in range(rows):
+            qbd_ref[r * kv_pad:(r + 1) * kv_pad, :] = \
+                q_ref[0, r] * hm_ref[...]
 
-    @pl.when(j < nkb)
+    @pl.when(j < nkb_ref[i])
     def _block():
-        rows = q_ref.shape[2]
-        # query absolute positions: row r sits at chunk offset r % C
-        qpos = p + lax.rem(
-            lax.broadcasted_iota(jnp.int32, (rows, block_k), 0),
-            jnp.int32(chunk))
+        p0 = pos_ref[order_ref[i]]
+        # query absolute positions: row (r, h) sits at chunk offset
+        # r // group
+        row = lax.broadcasted_iota(jnp.int32, (n, block_k), 0)
+        qpos = jnp.full((n, block_k), p0, jnp.int32)
+        for c in range(1, chunk):
+            qpos = jnp.where(row >= c * group * kv_pad, p0 + c, qpos)
         kpos = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (rows, block_k), 1)
+            jnp.int32, (n, block_k), 1)
         mask = kpos <= qpos              # causal; also masks the tail
-        for h in range(kv_heads):
-            q = q_ref[0, h].astype(jnp.float32)      # [G*C, D]
-            kb = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
-            vb = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
-            if quant:
-                # in-kernel dequant: int8 rows x [bk, 1] f32 row
-                # scales — the same arithmetic as Decoder._read_cache,
-                # minus the full-cache float materialization
-                kb = kb * ks_ref[0, :, h:h + 1]
-                vb = vb * vs_ref[0, :, h:h + 1]
-            sc = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) \
-                * scale
-            sc = jnp.where(mask, sc, neg_big)
-            m = m_ref[h]
-            new_m = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
-            pexp = jnp.where(mask, jnp.exp(sc - new_m), 0.0)
-            corr = jnp.exp(m - new_m)
-            l_ref[h] = l_ref[h] * corr \
-                + jnp.sum(pexp, axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * corr \
-                + jnp.dot(pexp, vb, preferred_element_type=jnp.float32)
-            m_ref[h] = new_m
+        kb = k_ref[0].astype(cdt)        # int8 values are exact
+        vb = v_ref[0].astype(cdt)
+        sc = lax.dot_general(qbd_ref[...], kb, (((1,), (1,)), ((), ())),
+                             precision=prec,
+                             preferred_element_type=f32)     # [n, bk]
+        if quant:
+            hi = lax.Precision.HIGHEST
+            sc = sc * lax.dot_general(
+                sel_ref[...], ks_ref[0], (((1,), (1,)), ((), ())),
+                precision=hi, preferred_element_type=f32)
+        sc = jnp.where(mask, sc * scale, neg_big)
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+        pexp = jnp.where(mask, jnp.exp(sc - new_m), 0.0)
+        corr = jnp.exp(m - new_m)
+        l_ref[...] = l_ref[...] * corr \
+            + jnp.sum(pexp, axis=1, keepdims=True)
+        if quant:
+            pexp = pexp * lax.dot_general(
+                sel_ref[...], vs_ref[0], (((1,), (1,)), ((), ())),
+                precision=hi, preferred_element_type=f32)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            pexp.astype(cdt), vb, precision=prec,
+            preferred_element_type=f32)                      # [n, W]
+        m_ref[...] = new_m
 
-    # row `pos` was written before the read, so block 0 always holds a
-    # valid key: the denominator is never the clamp
+    # a live slot's row `pos` was written before the read, so its
+    # denominator is never the clamp; a dead slot (no block at all)
+    # emits zeros
     @pl.when(j == jnp.int32(n_blocks - 1))
     def _emit():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        x = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        hm = hm_ref[...].astype(f32)
+        out_row = lax.broadcasted_iota(jnp.int32, o_ref.shape[1:], 0)
+        o = jnp.zeros(o_ref.shape[1:], f32)
+        for r in range(rows):
+            o_r = jnp.sum(x[r * kv_pad:(r + 1) * kv_pad] * hm, axis=0,
+                          keepdims=True)                     # [1, W]
+            o = jnp.where(out_row == r, o_r, o)
+        o_ref[0] = o.astype(o_ref.dtype)
 
 
-def paged_attention(q, k, v, pos, *, kv_heads, k_scale=None,
+def paged_attention(q, k, v, pos, *, kv_heads, lens=None, k_scale=None,
                     v_scale=None, scale=None, block_k=None,
                     interpret=None):
-    """Slot-paged decode attention reading only the live KV rows.
+    """Slot-paged decode attention reading only the rows live requests
+    hold.
 
     q: [S, C, H, D] — each slot's C-token query chunk (C=1 plain
     decode; C=K+1 the speculative verify chunk; C=W the draft
     catch-up). k, v: the cache buffers AS STORED, [S, L, Hkv*D] with
     the ``kv_heads`` heads' D values side by side, kv-major
     (``parallel/decode.py`` states the layout; float, or int8 with
-    ``k_scale``/``v_scale`` [S, L, Hkv] f32 row scales — dequantized
+    ``k_scale``/``v_scale`` [S, L, Hkv] f32 row scales, applied
     inside the kernel). The kernel takes them as they are: a
     (block_k, Hkv*D) block is whole on the lane axis. Inside a
     tensor-parallel shard the buffers are the shard's own lanes and
     ``kv_heads`` its local head count. pos: [S] int32, the chunk's
-    start position per
-    slot: the chunk rows at [pos, pos+C) must already be WRITTEN (the
-    decoder writes before reading, same as the dense path), and each
-    query row attends keys [0, pos + its chunk offset]. Returns
+    start position per slot: the chunk rows at [pos, pos+C) must
+    already be WRITTEN (the decoder writes before reading, same as the
+    dense path), and each query row attends keys [0, pos + its chunk
+    offset]. lens: [S] int32 (default ``pos + C``), the rows of each
+    slot the read may FETCH, rounded up to whole blocks: the serving
+    engine hands ``pos + C`` for a slot that holds a request and 0 for
+    one that does not, whose output is then zeros (finite; its
+    position is stale and its rows belong to nobody). Returns
     [S, C, H, D] in q's dtype, f32 accumulation.
 
     The kv-block walk is a grid dimension under a
-    ``PrefetchScalarGridSpec``: ``pos`` is scalar-prefetched, so the
-    cache index maps can clamp every step past a slot's live prefix
-    back to its last live block — a REVISITED block index whose
-    HBM->VMEM copy Mosaic elides — and the kernel body is
-    ``pl.when``-gated off there. Dead rows are therefore never
-    FETCHED, not merely never computed on (the distinction the dense
-    read and a naive full-plane BlockSpec both miss). Grouped-query
-    attention is native: each (slot, kv-block) step streams one block
-    of every kv head's K/V rows past that head's whole query group.
+    ``PrefetchScalarGridSpec``. What is scalar-prefetched is made here
+    from ``lens``: the order of the visit (slots with rows first),
+    each visit's count of live blocks, and for a visit with none the
+    (slot, block) fetched last before it — so the cache index maps
+    keep every step past a slot's live prefix, and every step of a
+    dead slot, on a REVISITED block index whose HBM->VMEM copy Mosaic
+    elides; the kernel body is ``pl.when``-gated off there. Dead rows
+    are therefore never FETCHED, not merely never computed on.
+    Grouped-query attention is native: a block of every kv head's K/V
+    rows streams once past the whole query group.
     On TPU the kernel runs compiled; on CPU (tests) it runs under the
     Pallas interpreter — same testing discipline as the flash kernel
     above. NOTE the interpreter executes all ``n_blocks`` grid steps
@@ -1218,16 +1273,19 @@ def paged_attention(q, k, v, pos, *, kv_heads, k_scale=None,
     s_, c, h, d = q.shape
     l_ = k.shape[1]
     kv = int(kv_heads)
-    if k.ndim != 3 or k.shape[2] != kv * d or v.shape != k.shape:
+    w = kv * d
+    if k.ndim != 3 or k.shape[2] != w or v.shape != k.shape:
         raise ValueError(
             "paged_attention: k and v must be the stored cache buffers "
             "[S, L, kv_heads*D] = [%d, L, %d], got %s and %s"
-            % (s_, kv * d, k.shape, v.shape))
+            % (s_, w, k.shape, v.shape))
     g = h // kv
+    rows = c * g
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     if block_k is None:
-        block_k = default_paged_block_k(l_)
+        block_k = default_paged_block_k(
+            l_, w * jnp.dtype(k.dtype).itemsize)
     if l_ % block_k:
         raise ValueError(
             "paged_attention: block_k=%d must divide the cache length "
@@ -1237,66 +1295,93 @@ def paged_attention(q, k, v, pos, *, kv_heads, k_scale=None,
         raise ValueError("paged_attention: k_scale and v_scale must be "
                          "passed together")
     nb = l_ // block_k
-    pos = jnp.asarray(pos, jnp.int32)
-    # [S, C, H, D] -> [S, KV, G*C, D]: the head axis splits (kv, g),
-    # matching the decoder's GQA fold q.reshape(b, c, kv, g, d)
-    qg = q.transpose(0, 2, 1, 3).reshape(s_, kv, g, c, d) \
-        .reshape(s_, kv, g * c, d)
+    i32 = jnp.int32
+    pos = jnp.asarray(pos, i32)
+    lens = pos + c if lens is None else jnp.asarray(lens, i32)
+    # the visit: slots with rows first (a stable sort keeps their
+    # order), every step of the others parked on the block the last of
+    # them ended on
+    nkb_slot = (jnp.clip(lens, 0, l_) + (block_k - 1)) // block_k
+    order = jnp.argsort(nkb_slot == 0, stable=True).astype(i32)
+    nkb = nkb_slot[order]
+    last = order[jnp.maximum(jnp.sum(nkb_slot > 0) - 1, 0)]
+    kslot = jnp.where(nkb > 0, order, last).astype(i32)
+    kblk = jnp.broadcast_to(jnp.maximum(nkb_slot[last] - 1, 0),
+                            (s_,)).astype(i32)
+    # query rows r = (c, g), lanes (kv, d) as the cache stores them:
+    # the decoder's GQA fold (Decoder._lane_attn)
+    qr = q.reshape(s_, c, kv, g, d).transpose(0, 1, 3, 2, 4) \
+        .reshape(s_, rows, 1, w)
+    # Hkv padded to whole sublane tiles of the compute dtype, so every
+    # row r's [Hkv', W] piece of Qbd is tile-aligned
+    kvp = _round_up(kv, 8 * 4 // jnp.dtype(q.dtype).itemsize)
+    hm = (np.arange(w)[None, :] // d == np.arange(kvp)[:, None])
+    n = rows * kvp
 
-    def live_j(si, j, pref):
-        # dead grid steps revisit the slot's LAST live block (same
-        # block index as the previous step -> Mosaic skips the copy;
-        # the kernel body is pl.when-gated off for them)
-        p = pref[si]
-        nkb = jnp.minimum(
-            lax.div(p + jnp.int32(c + block_k - 1),
-                    jnp.int32(block_k)),
-            jnp.int32(nb))
-        return jnp.minimum(j, nkb - 1)
+    def qmap(i, j, order, nkb, kslot, kblk, pos):
+        return (order[i], 0, 0, 0)
 
-    def qmap(si, j, pref):
-        return (si, 0, 0, 0)
+    def omap(i, j, order, nkb, kslot, kblk, pos):
+        return (order[i], 0, 0)
 
-    def kmap(si, j, pref):
-        return (si, live_j(si, j, pref), 0)
+    def kmap(i, j, order, nkb, kslot, kblk, pos):
+        live = nkb[i] > 0
+        return (kslot[i],
+                jnp.where(live, jnp.minimum(j, nkb[i] - 1), kblk[i]), 0)
+
+    def whole(i, j, order, nkb, kslot, kblk, pos):
+        return (0, 0)
 
     # the cache rides as it is stored, [S, L, Hkv*D]: a (block_k,
     # Hkv*D) block is whole on the lane axis, which the TPU lowering
-    # accepts at any head count (see the kernel docstring)
+    # accepts at any head count
     in_specs = [
-        pl.BlockSpec((1, kv, g * c, d), qmap),
-        pl.BlockSpec((1, block_k, kv * d), kmap),
-        pl.BlockSpec((1, block_k, kv * d), kmap),
+        pl.BlockSpec((1, rows, 1, w), qmap),
+        pl.BlockSpec((1, block_k, w), kmap),
+        pl.BlockSpec((1, block_k, w), kmap),
+        pl.BlockSpec((kvp, w), whole),
     ]
-    operands = [qg, k, v]
+    operands = [qr, k, v, jnp.asarray(hm, q.dtype)]
     if quant:
         # [S, L, KV] row scales: a (block_k, KV) block, one lane per
-        # kv head; the kernel broadcasts column h over head h's D
+        # kv head; ``sel`` [n, KV] maps row (r, h) to its head's lane
         operands.append(k_scale.astype(jnp.float32))
         operands.append(v_scale.astype(jnp.float32))
+        sel = np.arange(n)[:, None] % kvp == np.arange(kv)[None, :]
+        operands.append(jnp.asarray(sel, jnp.float32))
         sspec = pl.BlockSpec((1, block_k, kv), kmap)
-        in_specs.extend([sspec, sspec])
+        in_specs.extend([sspec, sspec, pl.BlockSpec((n, kv), whole)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=5,
         grid=(s_, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kv, g * c, d), qmap),
+        out_specs=pl.BlockSpec((1, rows, w), omap),
         scratch_shapes=[
-            pltpu.VMEM((kv, g * c, d), jnp.float32),   # acc
-            pltpu.VMEM((kv, g * c, 1), jnp.float32),   # l
-            pltpu.VMEM((kv, g * c, 1), jnp.float32),   # m
+            pltpu.VMEM((n, w), q.dtype),           # Qbd
+            pltpu.VMEM((n, w), jnp.float32),       # acc
+            pltpu.VMEM((n, 1), jnp.float32),       # l
+            pltpu.VMEM((n, 1), jnp.float32),       # m
         ],
     )
+    # two K and two V blocks in flight, their casts (int8), Qbd / acc
+    # and the [n, block_k] softmax temporaries
+    block_bytes = block_k * w * jnp.dtype(k.dtype).itemsize
+    need = 4 * block_bytes + 2 * block_k * w * jnp.dtype(q.dtype).itemsize \
+        + 8 * n * (w + block_k) * 4
     out = _pallas_call(
         functools.partial(_paged_attn_kernel, block_k=block_k, chunk=c,
-                          n_blocks=nb, scale=float(scale), quant=quant,
-                          kv_heads=kv, head_dim=d),
-        pos, *operands,
-        out_shape=jax.ShapeDtypeStruct((s_, kv, g * c, d), q.dtype),
+                          group=g, n_blocks=nb, scale=float(scale),
+                          quant=quant, kv_heads=kv, kv_pad=kvp),
+        order, nkb, kslot, kblk, pos, *operands,
+        out_shape=jax.ShapeDtypeStruct((s_, rows, w), q.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(max(2 * need, 32 << 20),
+                                     100 << 20))),
         interpret=interpret)
-    return out.reshape(s_, kv, g, c, d).reshape(s_, h, c, d) \
-        .transpose(0, 2, 1, 3)
+    return out.reshape(s_, c, g, kv, d).transpose(0, 1, 3, 2, 4) \
+        .reshape(s_, c, h, d)
 
 
 # -- fused quantized matmuls (ISSUE 17) -------------------------------

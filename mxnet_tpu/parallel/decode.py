@@ -184,25 +184,34 @@ class Decoder:
         string (e.g. ``"bfloat16"``) is also accepted and simply stores
         the cache at that dtype; default follows ``compute_dtype``.
     attn_impl : {"dense", "paged"}, optional
-        Cache-read strategy (default: the ``MXNET_SERVING_ATTN_IMPL``
-        env var, else ``"dense"``). ``"paged"`` computes decode/verify
-        attention with the Pallas paged kernel
-        (``ops.pallas_kernels.paged_attention``): walk only each
-        sequence's LIVE cache rows — bounded by the (per-slot)
-        position — with online-softmax accumulation and in-kernel int8
-        dequantization, so only the live rows of the K/V buffers are
-        read where the dense read streams (and masks) all ``max_len``
-        rows every step. Both take the buffers as they are stored
-        ([S, max_len, Hkv*D]: no reshape, no copy). Exact: online softmax
+        Cache-read strategy of a short chunk (decode, the speculative
+        verify and draft chunks). Default (``None``, and no
+        ``MXNET_SERVING_ATTN_IMPL`` in the environment): what the
+        code observes decides. The slot walk of the serving engine
+        (``_run_slots``) takes the BOUNDED read on a linear cache —
+        ``ops.pallas_kernels.paged_attention``: each slot fetches only
+        the blocks of rows its request holds, ``len = pos + C`` rows
+        for a slot that holds one and none at all for a slot that
+        does not, with online-softmax accumulation and the int8 row
+        scales applied in the kernel — and keeps the dense walk where
+        the bounded read has no meaning (a windowed ring, whose rows
+        live at wrapped positions; CCAttention, which has a read of
+        its own); the offline ``generate`` / ``beam_search`` (one
+        static position for the whole batch) read densely up to that
+        position. ``"paged"`` asks for the bounded read by name, in
+        the offline step too; ``"dense"`` for the walk that streams
+        (and masks) all ``max_len`` rows of every slot every step.
+        Both take the buffers as they are stored ([S, max_len,
+        Hkv*D]: no reshape, no copy). Exact: online softmax
         reassociates, it does not approximate — greedy outputs match
         the dense path (float flavors byte-identical through the
         serving gauntlet; int8 under the usual quantized-cache
-        tolerance). Mutually exclusive with ``cache_block`` (two
-        prefix-bounded read strategies); windowed ring models warn and
-        fall back to the exact dense ring walk (ring rows live at
-        wrapped positions, outside the kernel's [0, pos) contract).
-        The serving engine threads its own ``attn_impl`` through
-        ``_run_slots`` — doc/serving.md "Paged attention".
+        tolerance). ``"paged"`` is mutually exclusive with
+        ``cache_block`` (two prefix-bounded read strategies) and,
+        asked for by name over a windowed ring, warns and falls back
+        to the exact dense ring walk. The serving engine threads its
+        own ``attn_impl`` through ``_run_slots`` — doc/serving.md
+        "Paged attention".
     weight_dtype : {"float", "int8"}, optional
         Weight storage (default: the ``MXNET_SERVING_WEIGHT_DTYPE``
         env var, else ``"float"``). ``"int8"`` quantizes every matmul
@@ -234,14 +243,15 @@ class Decoder:
                              % len(self._heads))
         self.max_len = int(max_len)
         if attn_impl is None:
-            attn_impl = os.environ.get("MXNET_SERVING_ATTN_IMPL") \
-                or "dense"
-        if attn_impl not in ("dense", "paged"):
+            attn_impl = os.environ.get("MXNET_SERVING_ATTN_IMPL") or None
+        if attn_impl not in (None, "dense", "paged"):
             raise MXNetError(
                 "Decoder: attn_impl must be 'dense' or 'paged', got %r "
                 "(MXNET_SERVING_ATTN_IMPL sets the default)"
                 % (attn_impl,))
-        self._attn_impl = attn_impl
+        # the read asked for by name (None: what the code observes
+        # decides, see slots_impl and _attn_impl)
+        self._attn_given = attn_impl
         auto_block = cache_block == "auto"
         if attn_impl == "paged":
             if cache_block == "auto":
@@ -321,7 +331,7 @@ class Decoder:
                 "positions, not a [0, pos) prefix) — serving with the "
                 "exact dense ring walk instead", UserWarning,
                 stacklevel=2)
-            self._attn_impl = "dense"
+            self._attn_given = "dense"
 
         arg_names = [n.name for n in self._topo if n.is_var]
         self._data_name = "data" if "data" in arg_names else arg_names[0]
@@ -493,9 +503,32 @@ class Decoder:
         takes a vector of positions as it stands, and the routed
         experts behind it have to see every slot's token at once to
         sort them by expert; MultiHeadAttention's dense read is
-        written for one position, so a graph that holds one keeps the
-        ``vmap``."""
+        written for one position, so a graph that holds one takes the
+        batched walk with the bounded read (``slots_impl``) or keeps
+        the ``vmap``."""
         return bool(self._cca) and not self._mha
+
+    @property
+    def _attn_impl(self):
+        """The read of the offline step (one static position for the
+        whole batch): dense unless ``"paged"`` was asked for."""
+        return self._attn_given or "dense"
+
+    def slots_impl(self, impl=None):
+        """The name of the read the slot walk takes: ``impl`` if
+        given, else what the decoder was built with, else what the
+        code observes — ``"paged"`` (the read bounded by each slot's
+        length) where every cached node is a MultiHeadAttention over
+        a LINEAR cache, ``"dense"`` where one is a windowed ring (its
+        rows live at wrapped positions) or a CCAttention (its rolling
+        state rides a batched walk of its own, with its own read)."""
+        if impl is None:
+            impl = self._attn_given
+        if impl is None:
+            linear = self._mha and not self._cca and not any(
+                self._node_window(n) for n in self._mha)
+            impl = "paged" if linear else "dense"
+        return impl
 
     def _node_window(self, node):
         """Ring-buffer slot count for a windowed attention node (0 for
@@ -803,7 +836,7 @@ class Decoder:
         return out.reshape(b, 1, e), entry
 
     def _cached_mha(self, node, ins, entry, pos, valid_len=None,
-                    tp=None, mm_impl=None):
+                    tp=None, mm_impl=None, lens=None, stats=None):
         from ..ops.attention import MultiHeadAttention as _MHA
         from ..serving.quant import QuantizedTensor
 
@@ -820,6 +853,10 @@ class Decoder:
                 and wqkv.bits == wo.bits and wqkv.group == wo.group
                 and (self._attn_impl == "paged"
                      or jnp.ndim(pos) == 1)):
+            if stats is not None:
+                # the fused chain stages every slot's whole plane
+                stats["attn_rows_read"] = b * entry[0].shape[1] \
+                    + stats.get("attn_rows_read", 0)
             return self._fused_decode_mha(node, ins, entry, pos)
         if isinstance(wqkv, QuantizedTensor):
             # weight-only int8/int4: dequantized on the fly inside
@@ -884,20 +921,29 @@ class Decoder:
             return out_proj(o), entry
         entry = self._write_cache(entry, k, v, pos)
         if self._attn_impl == "paged" or jnp.ndim(pos) == 1:
-            # Pallas paged attention (ops/pallas_kernels.py): walk only
-            # rows [0, pos+C) per slot of the stored buffer, int8
-            # dequantized IN the kernel from the side scales
-            from ..ops.pallas_kernels import paged_attention
+            # Pallas paged attention (ops/pallas_kernels.py): fetch
+            # only the blocks of rows [0, lens) per slot of the stored
+            # buffer (lens: pos+C, or 0 for a slot that holds no
+            # request), the int8 side scales applied IN the kernel
+            from ..ops.pallas_kernels import (default_paged_block_k,
+                                              paged_attention,
+                                              paged_rows_fetched)
             posv = jnp.asarray(pos, jnp.int32) if jnp.ndim(pos) == 1 \
                 else jnp.full((b,), pos, jnp.int32)
+            if lens is None:
+                lens = posv + c
+            ck, cv = entry[0], entry[2 if self._cache_int8 else 1]
+            rows = ck.shape[1]
+            bk = default_paged_block_k(
+                rows, ck.shape[2] * ck.dtype.itemsize)
+            scales = dict(k_scale=entry[1], v_scale=entry[3]) \
+                if self._cache_int8 else {}
             with jax.named_scope("attend"):
-                if self._cache_int8:
-                    ck, ks, cv, vs = entry
-                    o = paged_attention(q, ck, cv, posv, kv_heads=kv,
-                                        k_scale=ks, v_scale=vs)
-                else:
-                    ck, cv = entry
-                    o = paged_attention(q, ck, cv, posv, kv_heads=kv)
+                o = paged_attention(q, ck, cv, posv, kv_heads=kv,
+                                    lens=lens, block_k=bk, **scales)
+            if stats is not None:
+                stats["attn_rows_read"] = paged_rows_fetched(
+                    lens, rows, bk) + stats.get("attn_rows_read", 0)
         elif self._cache_block is not None and c == 1:
             o = self._blocked_attn(q, entry, pos, kv)
         else:
@@ -1201,7 +1247,7 @@ class Decoder:
         return o.transpose(0, 2, 1, 3)             # [b,c,h,d]
 
     def _run(self, params, aux, caches, pos, tokens, valid_len=None,
-             tp=None, mm_impl=None, ep=None, stats=None):
+             tp=None, mm_impl=None, ep=None, stats=None, lens=None):
         """One chunk: tokens [B, C] at positions [pos, pos+C) →
         (logits [B, C, V], updated caches). ``valid_len`` marks a
         right-padded chunk's true length — only windowed ring WRITES
@@ -1226,7 +1272,15 @@ class Decoder:
         ``stats`` (a dict, optional): what the walk counts on the
         device is summed into it — ``experts_touched`` (experts given
         a token, summed over the routed MoEFFN nodes): the serving
-        engine's ``serving.moe_experts_touched``."""
+        engine's ``serving.moe_experts_touched``; ``attn_rows_read``
+        (cache rows the bounded reads fetched, block-rounded, summed
+        over the attention nodes): ``serving.attn_rows_read``.
+
+        ``lens`` ([B] int32, with a vector ``pos``): the rows of each
+        batch row's cache that the bounded read may fetch — the slot
+        walk's ``pos + C`` for a slot that holds a request, 0 for one
+        that does not (its output is then discarded by the caller).
+        Reads that are not bounded (CCAttention's) ignore it."""
         from ..ops.attention import moe_ffn_math
         from ..serving.quant import (QuantizedTensor, embedding_rows,
                                      moe_ffn_forward)
@@ -1251,7 +1305,7 @@ class Decoder:
                 if name == "MultiHeadAttention":
                     out, new_caches[mha_i] = self._cached_mha(
                         n, ins, new_caches[mha_i], pos, valid_len, tp,
-                        mm_impl=mm_impl)
+                        mm_impl=mm_impl, lens=lens, stats=stats)
                     mha_i += 1
                     env[(id(n), 0)] = out
                     continue
@@ -1268,7 +1322,11 @@ class Decoder:
                         # batch row's positions from the table
                         idx = jnp.asarray(pos, jnp.int32)[:, None] \
                             + jnp.arange(x.shape[1], dtype=jnp.int32)
-                        env[(id(n), 0)] = x + jnp.take(posp, idx, axis=0)
+                        # (clipped: a dead slot's stale position may
+                        # end the chunk past the table, and its row
+                        # must stay finite)
+                        env[(id(n), 0)] = x + jnp.take(posp, idx, axis=0,
+                                                       mode="clip")
                         continue
                     # all-int32 indices: see _write_cache on the vmapped
                     # batching rule's strict index dtypes
@@ -1336,22 +1394,29 @@ class Decoder:
     # included) with zero duplication.
 
     def _run_slots(self, params, aux, caches, pos, tokens, impl=None,
-                   tp=None, mm_impl=None, ep=None, stats=None):
+                   tp=None, mm_impl=None, ep=None, stats=None,
+                   lens=None):
         """Per-slot-position ``_run``: ``pos`` [S] int32 positions (one
         per cache slot), ``tokens`` [S, C] → (logits [S, C, V], updated
         caches).
 
-        ``impl`` (default: the decoder's own ``attn_impl``) picks the
-        read strategy. ``"dense"`` vmaps over the slot axis — each lane
-        is a b=1 ``_run`` at its own traced position, so cache writes
-        become per-slot scatters and masks follow each slot's own
-        clock, and every lane reads (and masks) all ``max_len`` cache
-        rows as they are stored. ``"paged"`` runs ONE batched walk with
-        the position VECTOR: position-wise ops see [S, C, E] directly,
+        ``impl`` (default: ``slots_impl()`` — the read the decoder was
+        built with, else the bounded read on a linear cache) picks the
+        read strategy. ``"paged"`` runs ONE batched walk with the
+        position VECTOR: position-wise ops see [S, C, E] directly,
         cache writes scatter per slot, and the attention read is the
-        Pallas paged kernel (ops/pallas_kernels.py) that touches only
-        each slot's live rows — the serving decode/verify hot path's
-        memory-traffic lever (doc/serving.md "Paged attention").
+        Pallas paged kernel (ops/pallas_kernels.py) that fetches only
+        the rows ``[0, lens)`` of each slot — ``lens`` [S] int32,
+        default ``pos + C``; the serving engine's programs hand 0 for
+        a slot that holds no request, whose stale rows are then not
+        read at all and whose logits (finite) the caller discards —
+        the serving decode/verify hot path's memory-traffic lever
+        (doc/serving.md "Paged attention"). ``"dense"`` vmaps over the
+        slot axis — each lane is a b=1 ``_run`` at its own traced
+        position, so cache writes become per-slot scatters and masks
+        follow each slot's own clock, and every lane reads (and
+        masks) all ``max_len`` cache rows as they are stored; a
+        windowed ring has no other walk.
 
         ``tp`` (``(axis_name, degree)``, optional): the call is
         running inside the serving engine's tensor-parallel shard_map
@@ -1367,9 +1432,7 @@ class Decoder:
         they stand (``slots_walk_batched``: CCAttention) runs the ONE
         batched walk under ``"dense"`` too, with its own dense read;
         ``stats`` is filled there (see ``_run``)."""
-        if impl is None:
-            impl = self._attn_impl
-        elif impl == "dense" and self._attn_impl == "paged":
+        if impl == "dense" and self._attn_given == "paged":
             # a paged decoder's _cached_mha always takes the kernel
             # path — honoring "dense" here would silently serve paged
             # anyway, so refuse (mirrors the engine's constructor
@@ -1378,12 +1441,13 @@ class Decoder:
                 "Decoder: impl='dense' requested on a decoder built "
                 "with attn_impl='paged' — build the decoder dense "
                 "(the engine threads its own attn_impl per dispatch)")
-        if impl == "paged" or self.slots_walk_batched:
+        if self.slots_impl(impl) == "paged" or self.slots_walk_batched:
             # ``stats``: only this one walk sees every slot's token,
             # so only here can experts be counted once per step
             return self._run(params, aux, caches,
                              jnp.asarray(pos, jnp.int32), tokens,
-                             tp=tp, mm_impl=mm_impl, ep=ep, stats=stats)
+                             tp=tp, mm_impl=mm_impl, ep=ep, stats=stats,
+                             lens=lens)
 
         def one(slot_caches, p, t):
             # vmap hands each lane the slot's cache WITHOUT its leading
@@ -1531,10 +1595,10 @@ class Decoder:
         k = drafts.shape[1]
         chunk = jnp.concatenate(
             [tok[:, None], drafts.astype(jnp.int32)], axis=1)
-        logits, caches = self._run_slots(params, aux, caches, pos,
-                                         chunk, impl=impl, tp=tp,
-                                         mm_impl=mm_impl,
-                                         ep=ep)             # [S,K+1,V]
+        logits, caches = self._run_slots(
+            params, aux, caches, pos, chunk, impl=impl, tp=tp,
+            mm_impl=mm_impl, ep=ep,
+            lens=jnp.where(live, pos + (k + 1), 0))         # [S,K+1,V]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def with_sampling(_):
@@ -1580,7 +1644,7 @@ class Decoder:
 
     def draft_propose_slots(self, params, aux, caches, pos, catchup,
                             clen, k, impl=None, tp=None, mm_impl=None,
-                            ep=None):
+                            ep=None, live=None):
         """Greedy k-token proposal from a DRAFT model sharing the
         slot-paged layout (the serving engine's draft program —
         ``InferenceEngine(draft="model")``).
@@ -1594,11 +1658,20 @@ class Decoder:
         valid position's logits, scan k-1 greedy single-token steps.
         Returns ``(caches, drafts [S, k])``. Greedy always: for
         sampled requests the target's verify still gates acceptance
-        against ITS sample, the draft just matches less often."""
-        logits, caches = self._run_slots(params, aux, caches, pos,
-                                         catchup, impl=impl, tp=tp,
-                                         mm_impl=mm_impl,
-                                         ep=ep)               # [S,W,V]
+        against ITS sample, the draft just matches less often.
+        ``live`` ([S] bool, optional): the slots that hold a request;
+        the others' rows are not read (``_run_slots``'s ``lens``)."""
+        pos = jnp.asarray(pos, jnp.int32)
+        if live is None:
+            live = jnp.ones(pos.shape, bool)
+
+        def lens(p, c):
+            return jnp.where(live, p + c, 0)
+
+        logits, caches = self._run_slots(
+            params, aux, caches, pos, catchup, impl=impl, tp=tp,
+            mm_impl=mm_impl, ep=ep,
+            lens=lens(pos, catchup.shape[1]))               # [S,W,V]
         idx = jnp.clip(clen - 1, 0, catchup.shape[1] - 1)
         lastlog = jnp.take_along_axis(
             logits, idx[:, None, None], axis=1)[:, 0]       # [S, V]
@@ -1609,7 +1682,8 @@ class Decoder:
             caches, p, t = carry
             lg, caches = self._run_slots(params, aux, caches, p,
                                          t[:, None], impl=impl, tp=tp,
-                                         mm_impl=mm_impl, ep=ep)
+                                         mm_impl=mm_impl, ep=ep,
+                                         lens=lens(p, 1))
             nx = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)
             return (caches, p + 1, nx), nx
 

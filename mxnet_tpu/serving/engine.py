@@ -173,6 +173,12 @@ _TM_ROUNDS = tele.counter("serving.rounds")
 # tokens: no new sync, no new transfer.
 _TM_MOE_TOUCHED = tele.counter("serving.moe_experts_touched")
 _TM_MOE_LAYER_STEPS = tele.counter("serving.moe_layer_steps")
+# cache rows the decode rounds' bounded reads fetched (block-rounded,
+# summed over slots, attention layers and steps) and the rows of the
+# pool over the same layers and steps: their quotient is the share of
+# the cache a decode step reads
+_TM_ATTN_ROWS_READ = tele.counter("serving.attn_rows_read")
+_TM_ATTN_ROWS_POOL = tele.counter("serving.attn_rows_pool")
 _TM_PREFILLS = tele.counter("serving.prefills")
 _TM_ADMITTED = tele.histogram(
     "serving.admitted_per_round", buckets=(0, 1, 2, 4, 8, 16, 32, 64))
@@ -629,16 +635,24 @@ class InferenceEngine:
         disables recording. Host-side, bounded (doc/observability.md
         "The flight recorder").
     attn_impl : {"dense", "paged"}, optional
-        Cache-read strategy for the decode / verify / draft programs
-        (default: the decoder's own ``attn_impl``, itself defaulted
-        from ``MXNET_SERVING_ATTN_IMPL``, else ``"dense"``).
+        Cache-read strategy for the decode / verify / draft programs.
+        Default (``None``): the read the decoder was built with
+        (``attn_impl``, ``MXNET_SERVING_ATTN_IMPL``), else what
+        ``Decoder.slots_impl`` observes: ``"paged"`` on a linear
+        cache, ``"dense"`` over a windowed ring or a CCAttention
+        decoder; ``engine.attn_impl`` names the one taken.
         ``"paged"`` traces them over the Pallas paged-attention kernel
         (``ops.pallas_kernels.paged_attention``): each slot's read
-        walks only its LIVE cache rows — bounded by the per-slot
-        position vector — with in-kernel int8 dequantization, cutting
-        the per-token HBM traffic that dominates decode (the dense
-        read streams and masks all ``max_len`` rows of every slot each
-        step; both read the [S, max_len, Hkv*D] buffers as stored).
+        fetches only the rows its request holds — ``len = pos + C``
+        for a slot that is live, made in the step program from the
+        state it carries, and 0 for a slot that holds no request: a
+        finished slot keeps its last position, but none of its stale
+        rows is read — with the int8 row scales applied in the kernel,
+        cutting the per-token HBM traffic that dominates decode (the
+        dense read streams and masks all ``max_len`` rows of every
+        slot each step; both read the [S, max_len, Hkv*D] buffers as
+        stored). ``serving.attn_rows_read`` / ``serving.attn_rows_pool``
+        count what the decode rounds fetched against the pool.
         Greedy outputs stay byte-identical to ``"dense"`` in float
         flavors (online softmax is a reassociation); int8 carries the
         usual quantized-cache tolerance. The compile-count contract is
@@ -1046,9 +1060,7 @@ class InferenceEngine:
         # threaded into every Decoder._run_slots dispatch, so one
         # decoder can serve under either impl (the A/B bench and the
         # identity tests share weights across engines)
-        if attn_impl is None:
-            attn_impl = decoder._attn_impl
-        if attn_impl not in ("dense", "paged"):
+        if attn_impl not in (None, "dense", "paged"):
             raise MXNetError(
                 "InferenceEngine: attn_impl must be 'dense' or "
                 "'paged', got %r (MXNET_SERVING_ATTN_IMPL sets the "
@@ -1072,6 +1084,9 @@ class InferenceEngine:
                 "with the exact dense ring walk instead", UserWarning,
                 stacklevel=2)
             attn_impl = "dense"
+        # no name given: the decoder's own, else the bounded read on a
+        # linear cache (Decoder.slots_impl)
+        attn_impl = decoder.slots_impl(attn_impl)
         # attn_impl="paged" composes with tp>1 since ISSUE 15: inside
         # the shard_map each device runs the Pallas kernel against its
         # LOCAL cache shard (the kernel's kv-head grid extent comes
@@ -1364,6 +1379,12 @@ class InferenceEngine:
         self._moe_counted = sum(
             1 for n in moe_nodes if n.params["top_k"] > 0) \
             if decoder.slots_walk_batched else 0
+        # rows of the K buffers of every attention layer, which a
+        # decode step's bounded reads are counted against (0: the read
+        # is not bounded, nothing is counted)
+        self._attn_pool_rows = sum(
+            e[0].shape[0] * e[0].shape[1] for e in self._caches) \
+            if self.attn_impl == "paged" else 0
         self._step_fn = jax.jit(
             self._wrap_tp(self._make_step(),
                           (ps, "r", cs, "r"), (cs, "r", "r")),
@@ -1387,7 +1408,7 @@ class InferenceEngine:
                 dcs = self._cache_spec(self._draft_caches)
                 self._draft_fn = jax.jit(
                     self._wrap_tp(self._make_draft(),
-                                  ("r", "r", dcs, "r", "r", "r"),
+                                  ("r", "r", dcs, "r", "r", "r", "r"),
                                   (dcs, "r")),
                     donate_argnums=(2,) if on_chip else ())
         # observability plane: watchdog/liveness state read by
@@ -1521,17 +1542,21 @@ class InferenceEngine:
         ep_ax = self._ep_ax
 
         counted = self._moe_counted
+        rows_counted = self._attn_pool_rows
 
         def one_step(caches, state, params, aux):
             pos, tok, live, temp, keys, eos, last = state
             # write each slot's pending token at ITS position, read
             # logits for the next one (frozen slots rewrite their last
-            # token in place — idempotent)
-            stats = {} if counted else None
-            logits, caches = dec._run_slots(params, aux, caches, pos,
-                                            tok[:, None], impl=impl,
-                                            tp=tp_ax, mm_impl=mm,
-                                            ep=ep_ax, stats=stats)
+            # token in place — idempotent — and, holding no request,
+            # have no row the bounded read may fetch: a finished slot
+            # keeps its last position, so a bound by ``pos`` alone
+            # would read its stale rows for ever)
+            stats = {} if counted or rows_counted else None
+            logits, caches = dec._run_slots(
+                params, aux, caches, pos, tok[:, None], impl=impl,
+                tp=tp_ax, mm_impl=mm, ep=ep_ax, stats=stats,
+                lens=jnp.where(live, pos + 1, 0))
             logits = logits[:, 0]
             nxt_pos = pos + 1
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1562,6 +1587,12 @@ class InferenceEngine:
                 out = jnp.concatenate(
                     [out, stats["experts_touched"].astype(out.dtype)
                      .reshape(1)])
+            if rows_counted:
+                # and one for the cache rows this step's bounded reads
+                # fetched, over the attention layers
+                out = jnp.concatenate(
+                    [out, jnp.asarray(stats["attn_rows_read"],
+                                      out.dtype).reshape(1)])
             live2 = live & ~done_now
             pos2 = jnp.where(live, nxt_pos, pos)
             tok2 = jnp.where(live, nxt, tok)
@@ -1583,7 +1614,7 @@ class InferenceEngine:
 
             (caches, state), outs = lax.scan(body, (caches, state),
                                              None, length=k_rounds)
-            return caches, state, outs          # outs [k, S (+1)]
+            return caches, state, outs          # outs [k, S (+1) (+1)]
 
         return step
 
@@ -1625,14 +1656,14 @@ class InferenceEngine:
         mm = self.matmul_impl
         tp_ax = self._tp_ax
 
-        def draft(params, aux, caches, pos, catchup, clen):
+        def draft(params, aux, caches, pos, catchup, clen, live):
             if not profiler.collecting():
                 self._compile_log.append("draft")
                 _TM_COMPILE_DRAFT.inc()
             return ddec.draft_propose_slots(params, aux, caches, pos,
                                             catchup, clen, k,
                                             impl=impl, tp=tp_ax,
-                                            mm_impl=mm)
+                                            mm_impl=mm, live=live)
 
         return draft
 
@@ -2975,6 +3006,12 @@ class InferenceEngine:
                 _TM_MOE_TOUCHED.inc(int(rounds[:, self.slots].sum()))
                 _TM_MOE_LAYER_STEPS.inc(
                     self._moe_counted * rounds.shape[0])
+            if self._attn_pool_rows:
+                # the last column: cache rows the step's bounded reads
+                # fetched, against the pool's rows over the same steps
+                _TM_ATTN_ROWS_READ.inc(int(rounds[:, -1].sum()))
+                _TM_ATTN_ROWS_POOL.inc(
+                    self._attn_pool_rows * rounds.shape[0])
             for row in rounds:
                 for s in range(self.slots):
                     req = self._mirror[s]
@@ -3085,6 +3122,8 @@ class InferenceEngine:
         # and have its valid proposal overwritten by noise
         final_props = np.zeros((S, K), np.int32)
         proposed = set()
+        # a slot that holds no request rides along unread
+        live = np.array([r is not None for r in self._mirror], bool)
         while True:
             pos = np.zeros((S,), np.int32)
             catchup = np.zeros((S, W), np.int32)
@@ -3107,13 +3146,13 @@ class InferenceEngine:
             with self._phase("draft_round"):
                 self._draft_caches, props = self._draft_fn(
                     self._draft_params, self._draft_aux,
-                    self._draft_caches, pos, catchup, clen)
+                    self._draft_caches, pos, catchup, clen, live)
             if "draft" not in self._prog_seen:
                 self._prog_seen.add("draft")
                 profiler.register_program(
                     "serving_draft", self._draft_fn,
                     (self._draft_params, self._draft_aux,
-                     self._draft_caches, pos, catchup, clen))
+                     self._draft_caches, pos, catchup, clen, live))
             if newly_done:
                 props = np.asarray(props)                   # [S, K]
                 for s in newly_done:

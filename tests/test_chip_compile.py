@@ -326,7 +326,8 @@ def serve_chat_engine():
 
 @pytest.mark.parametrize("program", ["decode", "verify"])
 def test_decode_program_holds_no_copy_of_the_cache(serve_chat_engine,
-                                                   one_chip, program):
+                                                   one_chip, program,
+                                                   monkeypatch):
     """The stored KV layout is the layout the decode read consumes:
     compiled for the chip, the engine's decode program (and its verify
     program, a 5-token chunk through the same read) keeps temporaries
@@ -334,8 +335,14 @@ def test_decode_program_holds_no_copy_of_the_cache(serve_chat_engine,
     cache buffer. With the cache stored [S, L, Hkv, D] this read 515 MB
     of temporaries for 256 MB of cache and 8 such copies (4 to a
     lane-padded layout on the way in, 4 back): the round's 32 ms of
-    ``copy.N`` and the reason 32 slots did not fit (PERF.md PR 27)."""
+    ``copy.N`` and the reason 32 slots did not fit (PERF.md PR 27).
+    The read is the bounded one (the engine's default on a linear
+    cache), compiled as the chip compiles it: left to the backend it
+    sees here, the kernel would be traced for the interpreter, a
+    ``while`` that stages whole cache buffers."""
     eng = serve_chat_engine
+    assert eng.attn_impl == "paged"
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
 
     def abstract(tree):
         return jax.tree_util.tree_map(
@@ -355,6 +362,8 @@ def test_decode_program_holds_no_copy_of_the_cache(serve_chat_engine,
     cache_bytes = sum(x.nbytes for x in leaves)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 0.1 * cache_bytes, (temp, cache_bytes)
+    # one kernel a layer, in the step's loop body
+    assert compiled.as_text().count("tpu_custom_call") >= len(eng._caches)
     # `%copy.N = bf16[16,1024,2048]{...} copy(...)`: the result's dims
     copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
     big = [dims for dims in copies

@@ -157,6 +157,70 @@ def test_paged_kernel_matches_dense_reference(shape):
     np.testing.assert_allclose(got8, want8, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("cache", ["bfloat16", "int8"])
+@pytest.mark.parametrize("chunk", [1, 8])
+@pytest.mark.parametrize("heads", [(4, 4, 64), (8, 2, 128)],
+                         ids=["mha_d64", "gqa_d128"])
+def test_bounded_read_matches_lane_attn(heads, chunk, cache):
+    """The read bounded by each slot's LENGTH against the dense read
+    (``Decoder._lane_attn``) on the same stored rows, at the lengths
+    that matter: 0 (a slot that holds no request, at a stale position:
+    finite output, no row counted, and none of its rows — all NaN here
+    — computed on), the shortest a chunk allows (``C`` rows), a block
+    edge, the whole cache; ``Hkv = H`` at D=64 and a GQA group of 4 at
+    D=128; bf16 rows and int8 rows with their side scales."""
+    import types
+
+    from mxnet_tpu.ops.pallas_kernels import (paged_attention,
+                                              paged_rows_fetched)
+    from mxnet_tpu.parallel.decode import fold_heads
+
+    h, kv, d = heads
+    c, l_, bk = chunk, 32, 8
+    rng = np.random.RandomState(31)
+    int8 = cache == "int8"
+    qdt = jnp.float32 if int8 else jnp.bfloat16
+    #           dead      shortest  block edge  mid        whole cache
+    pos = np.array([l_ - c - 3, 0, 16 - c, 21 - c, l_ - c], np.int32)
+    lens = np.where(np.arange(5) == 0, 0, pos + c).astype(np.int32)
+    s_ = len(pos)
+    q = jnp.asarray(rng.randn(s_, c, h, d), qdt)
+    k = rng.randn(s_, l_, kv, d).astype(np.float32)
+    v = rng.randn(s_, l_, kv, d).astype(np.float32)
+    if int8:
+        k8, ks = Decoder._quantize_rows(jnp.asarray(k))
+        v8, vs = Decoder._quantize_rows(jnp.asarray(v))
+        entry = (fold_heads(k8), ks, fold_heads(v8), vs)
+        scales = dict(k_scale=ks, v_scale=vs)
+    else:
+        entry = (fold_heads(jnp.asarray(k, jnp.bfloat16)),
+                 fold_heads(jnp.asarray(v, jnp.bfloat16)))
+        scales = {}
+    want = np.asarray(Decoder._lane_attn(
+        types.SimpleNamespace(_cache_int8=int8), q, entry,
+        jnp.asarray(pos), kv), np.float32)
+    # the dead slot's rows belong to nobody: poison them (floats only;
+    # an int8 row has no NaN, its scales do)
+    poisoned = tuple(
+        buf.at[0].set(jnp.nan) if jnp.issubdtype(buf.dtype, jnp.floating)
+        else buf for buf in entry)
+    ck, cv = poisoned[0], poisoned[2 if int8 else 1]
+    if int8:
+        scales = dict(k_scale=poisoned[1], v_scale=poisoned[3])
+    got = np.asarray(paged_attention(
+        q, ck, cv, pos, kv_heads=kv, lens=lens, block_k=bk, **scales),
+        np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[0], 0.0)
+    tol = 1e-5 if int8 else 2e-2     # bf16: p is rounded before / after
+    np.testing.assert_allclose(got[1:], want[1:], rtol=tol, atol=tol)
+    # rows counted: each length rounded up to whole blocks, none for
+    # the dead slot
+    assert int(paged_rows_fetched(lens, l_, bk)) == int(
+        sum(-(-int(n) // bk) * bk for n in lens))
+    assert int(paged_rows_fetched(lens[:1], l_, bk)) == 0
+
+
 def test_run_slots_paged_matches_dense_mixed_positions(lm):
     """Decoder level: ``_run_slots(impl="paged")`` (the batched walk +
     kernel) against the dense vmap at mixed per-slot positions, decode
@@ -179,7 +243,7 @@ def test_run_slots_paged_matches_dense_mixed_positions(lm):
     pos = jnp.asarray([4, 2, 7], jnp.int32)
     step = jnp.asarray(rng.randint(0, VOCAB, (S, 1)), jnp.int32)
     dense = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t))
+        dec._params, dec._aux, c, p, t, impl="dense"))
     paged = jax.jit(lambda c, p, t: dec._run_slots(
         dec._params, dec._aux, c, p, t, impl="paged"))
     ld, cd = dense(Decoder.clone_cache(caches), pos, step)
@@ -196,7 +260,7 @@ def test_run_slots_paged_matches_dense_mixed_positions(lm):
     # verify-width chunk [S, 3] at mixed positions
     chunk = jnp.asarray(rng.randint(0, VOCAB, (S, 3)), jnp.int32)
     densec = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t))
+        dec._params, dec._aux, c, p, t, impl="dense"))
     pagedc = jax.jit(lambda c, p, t: dec._run_slots(
         dec._params, dec._aux, c, p, t, impl="paged"))
     ldc, _ = densec(Decoder.clone_cache(caches), pos, chunk)
@@ -224,7 +288,7 @@ def test_run_slots_paged_int8_tolerance(int8_dec):
     pos = jnp.asarray([3, 5], jnp.int32)
     step = jnp.asarray(rng.randint(0, VOCAB, (S, 1)), jnp.int32)
     ld, _ = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t))(
+        dec._params, dec._aux, c, p, t, impl="dense"))(
         Decoder.clone_cache(caches), pos, step)
     lp, _ = jax.jit(lambda c, p, t: dec._run_slots(
         dec._params, dec._aux, c, p, t, impl="paged"))(

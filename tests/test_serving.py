@@ -223,6 +223,77 @@ def test_engine_multi_step_rounds_byte_identical(lm):
     assert eng.idle
 
 
+def test_engine_dead_slot_reads_no_row(lm, monkeypatch):
+    """The decode step reads only the rows its live requests hold
+    (``len = live ? pos + 1 : 0``, made in the step program). A slot
+    finishes MID-round, stays dead for rounds beside a live one, and
+    is reused by a shorter request: every token equals the dense
+    walk's (the read every row of every slot is masked by) and the
+    offline oracle's. Once the new tenant decodes, the previous
+    tenant's stale rows past its block are poisoned with NaN: none
+    reaches a score. Then the step program itself, on a state made by
+    hand: the rows a step fetches are the live slot's length in whole
+    blocks, the dead slot's stale position adds none, and the
+    telemetry counters carry the same count."""
+    sym, params, dec = lm
+    # two blocks of 8 rows a slot, so a length bounds the read at toy
+    # size too (the kernel's own default would take all 16 rows as one)
+    monkeypatch.setenv("MXNET_PAGED_BLOCK_K", "8")
+    rng = np.random.RandomState(41)
+    pa, pb, pc = (rng.randint(0, VOCAB, (n,)) for n in (8, 2, 2))
+
+    def run(poison, **kw):
+        eng = _engine(sym, params, steps_per_round=2, draft=None, **kw)
+        ra = eng.submit(pa, max_tokens=5)    # 8 + 5 rows: both blocks,
+        rb = eng.submit(pb, max_tokens=13)   # done in the middle of a
+        while not ra.done:                   # round of 2 steps
+            eng.step()
+        slot_b = eng._mirror.index(rb)
+        for _ in range(2):                   # dead beside a live slot
+            eng.step()
+        assert not rb.done
+        rc = eng.submit(pc, max_tokens=5)    # 2 + 5 rows: block 0 only
+        eng.step()                           # its prefill, densely read
+        if poison:
+            eng._caches = [
+                tuple(buf.at[1 - slot_b, 8:].set(jnp.nan) for buf in e)
+                for e in eng._caches]
+        eng.serve_forever()
+        assert eng.idle
+        return eng, [r.result() for r in (ra, rb, rc)]
+
+    read = mx.telemetry.counter("serving.attn_rows_read")
+    pool = mx.telemetry.counter("serving.attn_rows_pool")
+    before = read.value, pool.value
+    _, want = run(False, attn_impl="dense")
+    assert (read.value, pool.value) == before   # a dense read counts
+    eng, got = run(True)                        # nothing
+    assert eng.attn_impl == "paged"             # the shipped default
+    for g, w, (p, n) in zip(got, want, [(pa, 5), (pb, 13), (pc, 5)]):
+        np.testing.assert_array_equal(g, w)
+        np.testing.assert_array_equal(g, _oracle(dec, p, n))
+    rows = read.value - before[0], pool.value - before[1]
+    assert rows[1] == 2 * T * LAYERS * eng.stats["steps"] * 2
+    assert 0 < rows[0] < 0.75 * rows[1]
+    assert_compile_contract(eng)
+
+    # the step program on a hand-made state: slot 0 dead at a stale
+    # position, slot 1 live at position 9
+    pos, tok, live, temp, keys, eos, last = eng._state
+    state = (jnp.asarray([13, 9], jnp.int32), tok,
+             jnp.asarray([False, True]), temp, keys,
+             jnp.full_like(eos, -1), jnp.full_like(last, T - 1))
+    _, state, outs = eng._step_fn(eng._params, eng._aux, eng._caches,
+                                  state)
+    outs = np.asarray(outs)                     # [2 steps, S + 1]
+    assert (outs[:, 0] == -1).all() and (outs[:, 1] >= 0).all()
+    np.testing.assert_array_equal(outs[:, -1], [16 * LAYERS] * 2)
+    _, _, outs = eng._step_fn(
+        eng._params, eng._aux, eng._caches,
+        state[:2] + (jnp.asarray([False, False]),) + state[3:])
+    np.testing.assert_array_equal(np.asarray(outs)[:, -1], [0, 0])
+
+
 def test_engine_admission_order_and_midstream_submit(lm, shared_engine,
                                                      second_engine):
     """Per-request outputs are independent of admission order and of
