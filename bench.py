@@ -264,12 +264,11 @@ def bench_decode(prompt=64, layers=12, embed=768,
     serving-relevant regime. Prompts are FRESH random values every
     run.
 
-    Arms (round-5 VERDICT task 3): full-cache reads vs prefix-bounded
-    ``cache_block`` reads at b8 and a batch sweep (b1/8/32) at
-    max_len 1024, the long-cache story at max_len 4096 (full read
-    touches the whole 1.2 GB buffer every step — blocked wins 1.9x),
-    and the int8-quantized cache (measured SLOWER — kept as a memory
-    knob, see doc/performance.md). Returns a dict of arms:
+    Arms: the offline step's dense read at b8 and a batch sweep
+    (b1/8/32) at max_len 1024, the long cache at max_len 4096 (the
+    read touches the whole 1.2 GB buffer every step), and the
+    int8-quantized cache (measured SLOWER — kept as a memory knob,
+    see doc/performance.md). Returns a dict of arms:
     {name: {"ms_per_token": x, "tokens_per_sec": y}}."""
     import jax.numpy as jnp
     from mxnet_tpu.models import get_transformer_lm
@@ -322,42 +321,24 @@ def bench_decode(prompt=64, layers=12, embed=768,
                 "tokens_per_sec": round(batch / per_tok, 0)}
 
     full = Decoder(sym, params, max_len=max_len,
-                   compute_dtype="bfloat16", cache_block=None)
-    blocked = Decoder(sym, params, max_len=max_len,
-                      compute_dtype="bfloat16", cache_block=128)
-    # Pallas paged-attention arm (ISSUE 11): reads only the live cache
-    # rows per step — on CPU the kernel runs under the interpreter (so
-    # wall clock under-sells it; the honest CPU win is bytes_accessed
-    # per token from the program gauges), on TPU it runs compiled
-    paged = Decoder(sym, params, max_len=max_len,
-                    compute_dtype="bfloat16", cache_block=None,
-                    attn_impl="paged")
-    arms = {"full_b8": measure(full, steps_short, 8),
-            "block128_b8": measure(blocked, steps_short, 8),
-            "paged_b8": measure(paged, steps_short, 8)}
-    # batch sweep pinned to the full-read decoder (stable arm names
-    # across rounds; the sweep's point is batch scaling, not the
-    # read-path contest the b8 pair above decides)
+                   compute_dtype="bfloat16")
+    arms = {"full_b8": measure(full, steps_short, 8)}
     for bs in (1, 32):
         arms["full_b%d" % bs] = measure(full, steps_short, bs)
-    # long-cache story: at 4x the cache the full read pays for the
-    # whole buffer every step; "auto" resolves to block128 here
+    # long-cache story: at 4x the cache the read pays for the whole
+    # buffer every step
     long_full = Decoder(sym, params, max_len=4 * max_len,
-                        compute_dtype="bfloat16", cache_block=None)
-    long_auto = Decoder(sym, params, max_len=4 * max_len,
                         compute_dtype="bfloat16")
     arms["full_b8_L%d" % (4 * max_len)] = measure(long_full,
                                                   steps_long, 8)
-    arms["auto_b8_L%d" % (4 * max_len)] = measure(long_auto,
-                                                  steps_long, 8)
     # int8 KV (memory knob): halves cache bytes, measured slower
     int8_full = Decoder(sym, params, max_len=max_len,
-                        compute_dtype="bfloat16", cache_block=None,
+                        compute_dtype="bfloat16",
                         cache_dtype="int8")
     int8_long = Decoder(sym, params, max_len=4 * max_len,
                         compute_dtype="bfloat16", cache_dtype="int8")
     arms["int8_full_b8"] = measure(int8_full, steps_short, 8)
-    arms["int8_auto_b8_L%d" % (4 * max_len)] = measure(int8_long,
+    arms["int8_full_b8_L%d" % (4 * max_len)] = measure(int8_long,
                                                        steps_long, 8)
     # GQA (num_kv_heads=2 of 12): K/V cache 6x smaller — the grouped
     # projection also drops ~12M params, both cuts honest decode wins
@@ -366,15 +347,14 @@ def bench_decode(prompt=64, layers=12, embed=768,
                                  num_kv_heads=2, impl="flash")
     gqa_long = Decoder(gqa_sym, init_params(gqa_sym),
                        max_len=4 * max_len, compute_dtype="bfloat16")
-    arms["gqa2_auto_b8_L%d" % (4 * max_len)] = measure(gqa_long,
+    arms["gqa2_full_b8_L%d" % (4 * max_len)] = measure(gqa_long,
                                                        steps_long, 8)
     return arms
 
 
 def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
                   max_len=1024, n_requests=96, seed=0, arrival_ms=1.0,
-                  attn_impl="dense", cache_dtype=None,
-                  weight_dtype=None, matmul_impl=None):
+                  cache_dtype=None, weight_dtype=None, matmul_impl=None):
     """Continuous-batching serving engine (mxnet_tpu/serving/) under
     SATURATING load: Poisson arrivals far above service capacity (the
     queue never empties), mixed prompt lengths across the bucket set
@@ -392,15 +372,11 @@ def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
     tail is what co-residency costs a request, independent of queue
     wait (which saturating arrivals make unbounded by construction).
 
-    ``attn_impl``/``cache_dtype`` select the ISSUE 11 A/B arms: the
-    dense whole-cache read vs the Pallas paged kernel (live rows
-    only), at fp (bf16 compute) and int8-KV flavors — same workload,
-    same seeds, compile contract asserted per arm. The returned dict
-    also carries ``decode_bytes_accessed``/``decode_flops`` from the
-    XLA cost analysis of THIS arm's decode program (PR 9 program
-    gauges) — on CPU, where the Pallas interpreter's wall clock
-    under-sells the kernel, the bytes cut per dispatched round is the
-    honest win metric.
+    ``cache_dtype`` selects the fp (bf16 compute) or int8-KV flavor —
+    same workload, same seeds, compile contract asserted per arm. The
+    returned dict also carries ``decode_bytes_accessed`` /
+    ``decode_flops`` from the XLA cost analysis of THIS arm's decode
+    program (PR 9 program gauges).
 
     Returns {"tokens_per_sec", "p50_ms_per_token", "p99_ms_per_token",
     "slots", "requests", "tokens", "compile_programs", ...}.
@@ -426,7 +402,7 @@ def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
     # decoder pinned float: weight_dtype is an ENGINE-level axis here
     # (an env-int8 decoder would refuse an explicit fp arm)
     dec = Decoder(sym, params, max_len=max_len,
-                  compute_dtype="bfloat16", cache_block=None,
+                  compute_dtype="bfloat16",
                   cache_dtype=cache_dtype, weight_dtype="float")
 
     def workload(n, rs):
@@ -475,7 +451,6 @@ def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
     engine = InferenceEngine(dec, slots=slots, prefill_buckets=buckets,
                              max_queue=4 * slots, steps_per_round=8,
                              prefix_cache_mb=0, prefill_chunk=0,
-                             attn_impl=attn_impl,
                              weight_dtype=weight_dtype,
                              matmul_impl=matmul_impl)
     # warmup compiles BOTH program families for every bucket up front
@@ -493,9 +468,7 @@ def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
         and not cc["copy"], \
         "compile-count contract violated: %r" % (cc,)
     # this arm's decode-program cost analysis (the PR 9 program
-    # gauges, re-registered by THIS engine's first dispatch): the
-    # paged-vs-dense bytes_accessed delta per dispatched round is the
-    # memory-traffic cut the kernel exists for
+    # gauges, re-registered by THIS engine's first dispatch)
     from mxnet_tpu import profiler as _prof
     import mxnet_tpu as _mx
     _prof.collect_program_stats()
@@ -509,7 +482,6 @@ def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
         "requests": n_requests,
         "tokens": toks,
         "compile_programs": programs,
-        "attn_impl": attn_impl,
         "cache_dtype": cache_dtype or "bf16",
         "weight_dtype": engine.weight_dtype,
         "weight_bytes": engine.weight_bytes,
@@ -521,8 +493,7 @@ def bench_serving(slots=32, layers=12, embed=768, heads=12, vocab=32000,
 
 def bench_serving_tp(tp=1, slots=16, layers=12, embed=768, heads=12,
                      vocab=32000, max_len=1024, n_requests=48, seed=0,
-                     arrival_ms=2.0, steps_per_round=8,
-                     attn_impl="dense"):
+                     arrival_ms=2.0, steps_per_round=8):
     """Tensor-parallel serving sweep arm (ISSUE 14): the SAME workload
     and seeds at every degree — the engine contract makes greedy
     outputs byte-identical across tp, so each arm returns a digest of
@@ -554,12 +525,11 @@ def bench_serving_tp(tp=1, slots=16, layers=12, embed=768, heads=12,
     buckets = tuple(b for b in (64, 128, 256) if b <= max_len) \
         or (max_len,)
     dec = Decoder(sym, params, max_len=max_len,
-                  compute_dtype="bfloat16", cache_block=None)
+                  compute_dtype="bfloat16")
     engine = InferenceEngine(dec, slots=slots, prefill_buckets=buckets,
                              max_queue=4 * slots,
                              steps_per_round=steps_per_round,
-                             prefix_cache_mb=0, prefill_chunk=0,
-                             tp=tp, attn_impl=attn_impl)
+                             prefix_cache_mb=0, prefill_chunk=0, tp=tp)
     wrs = np.random.RandomState(seed + 1)
     for b in buckets:           # warm every program family up front
         engine.submit(wrs.randint(0, vocab, (b - 8,)), max_tokens=8)
@@ -603,7 +573,6 @@ def bench_serving_tp(tp=1, slots=16, layers=12, embed=768, heads=12,
     prog = snap.get("program", {}).get("serving_decode", {})
     return {
         "tp": tp,
-        "attn_impl": attn_impl,
         "tokens_per_sec": round(toks / dt, 1),
         "p50_ms_per_token": round(float(np.percentile(tpot, 50)), 3),
         "p99_ms_per_token": round(float(np.percentile(tpot, 99)), 3),
@@ -619,12 +588,11 @@ def bench_serving_tp(tp=1, slots=16, layers=12, embed=768, heads=12,
 
 def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
                               vocab=32000, max_len=1024, slots=32,
-                              steps_per_round=8, attn_impl="paged",
-                              cache_dtype=None, hbm_gb=16.0,
-                              wall_reps=None):
+                              steps_per_round=8, cache_dtype=None,
+                              hbm_gb=16.0, wall_reps=None):
     """Decode-bytes probe at the SERVING-BATCH geometry (ISSUE 15's
-    headline config — the 124M LM, the PR 11 premise that the KV side
-    is already cut by paged reads): lower the fp and int8-weight
+    headline config — the 124M LM, the KV side already cut by the
+    bounded read): lower the fp and int8-weight
     decode programs and read their XLA cost analysis WITHOUT running
     traffic — the PR 9 gauge arithmetic at a geometry the CPU box
     could never serve end-to-end.
@@ -653,10 +621,8 @@ def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
 
     PR 17 widens the arm set beyond the fp/int8-fori pair: the int8
     Pallas ``quant_matmul`` arm (dequant-in-VMEM, no chunk-loop HLO),
-    the int4 arm (packed nibbles + per-group scales) and the int4
-    fused-decode arm (QKV->attention->out-proj in ONE kernel dispatch
-    per layer). Three byte columns per arm, because they answer
-    different questions:
+    and the int4 arm (packed nibbles + per-group scales). Three byte
+    columns per arm, because they answer different questions:
 
     * ``weight_stream_bytes`` / ``weight_stream_ratio_*``: the
       ANALYTIC stored bytes one greedy decode step actually streams —
@@ -683,13 +649,10 @@ def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
     compiled decode forward (``wall_reps`` timed runs; default:
     skipped off-TPU, where the interpreter executes every grid step
     and a 124M compile takes tens of minutes — pass ``wall_reps=3``
-    to force) — and ``decode_dispatches``, the Pallas kernel-dispatch
-    count traced into one decode forward (the fused arm's cut is the
-    ``serving_fused_decode_dispatches`` headline)."""
+    to force)."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.models import get_transformer_lm
-    from mxnet_tpu.ops import pallas_kernels as pk
     from mxnet_tpu.parallel import Decoder
     from mxnet_tpu.serving import InferenceEngine
 
@@ -736,14 +699,13 @@ def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
 
     out = {"config": {"layers": layers, "embed": embed, "vocab": vocab,
                       "max_len": max_len, "slots": slots,
-                      "attn_impl": attn_impl,
                       "cache_dtype": cache_dtype or "bf16"}}
     # ONE float decoder serves both engine arms (the supported
     # pattern: the int8 engine quantizes its own parameter copy);
     # pinned float regardless of the env default — an env-int8
     # decoder would refuse the fp arm
     dec = Decoder(sym, params, max_len=max_len,
-                  compute_dtype="bfloat16", cache_block=None,
+                  compute_dtype="bfloat16",
                   cache_dtype=cache_dtype, weight_dtype="float")
     buckets = tuple(b for b in (64, 128, 256) if b <= max_len) \
         or (max_len,)
@@ -751,33 +713,20 @@ def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
             ("int8", "int8", "dense"),
             ("int8_pallas", "int8", "pallas"),
             ("int4", "int4", "pallas")]
-    # the fused decode kernel does not compile for the chip: the engine
-    # would refuse the arm at construction, so it is skipped by that
-    # reason and its keys stay null
-    no_fused = pk.fused_decode_unsupported()
-    if no_fused:
-        out["int4_fused"] = {"skipped": no_fused}
-    else:
-        arms.append(("int4_fused", "int4", "fused"))
     for key, wd, mi in arms:
         eng = InferenceEngine(
             dec, slots=slots, prefill_buckets=buckets,
             max_queue=4 * slots, steps_per_round=steps_per_round,
-            prefix_cache_mb=0, prefill_chunk=0, attn_impl=attn_impl,
-            weight_dtype=wd, matmul_impl=mi)
+            prefix_cache_mb=0, prefill_chunk=0, weight_dtype=wd,
+            matmul_impl=mi)
         prog = jax.jit(eng._make_step()).lower(
             eng._params, eng._aux, eng._caches, eng._state).compile()
         pos = jnp.zeros((slots,), jnp.int32)
         toks = jnp.zeros((slots, 1), jnp.int32)
-        # dispatch count is bumped at TRACE time in every Pallas
-        # kernel entry, so one lowering of the single-step forward
-        # counts the kernel dispatches a greedy round issues
-        pk.reset_dispatch_count()
         fwd = jax.jit(
             lambda p, a, c, po, t, _mi=mi: dec._run_slots(
-                p, a, c, po, t, impl=attn_impl, mm_impl=_mi)).lower(
+                p, a, c, po, t, mm_impl=_mi)).lower(
             eng._params, eng._aux, eng._caches, pos, toks).compile()
-        dispatches = pk.dispatch_count()
         kv_bytes = sum(x.nbytes for x in
                        jax.tree_util.tree_leaves(eng._caches))
         # wall clock of the compiled single-step forward: warm once,
@@ -800,7 +749,6 @@ def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
             "kv_bytes_per_slot": kv_bytes // slots,
             "slots_at_hbm": int((hbm_gb * 1e9 - eng.weight_bytes)
                                 // (kv_bytes / slots)),
-            "decode_dispatches": dispatches,
             "wall_ms": wall,
         }
     for k in ("program", "forward"):
@@ -819,8 +767,6 @@ def bench_serving_quant_bytes(layers=12, embed=768, heads=12,
         out["int8"]["weight_bytes"] / out["fp"]["weight_bytes"], 3)
     out["weight_bytes_ratio_int4"] = round(
         out["int4"]["weight_bytes"] / out["fp"]["weight_bytes"], 3)
-    out["fused_decode_dispatches"] = \
-        out["int4_fused"].get("decode_dispatches")
     return out
 
 
@@ -921,7 +867,7 @@ def bench_serving_prefix(slots=16, layers=12, embed=768, heads=12,
     if not buckets or buckets[-1] < min(max_len, 512):
         buckets += (max_len,)
     dec = Decoder(sym, params, max_len=max_len,
-                  compute_dtype="bfloat16", cache_block=None)
+                  compute_dtype="bfloat16")
     engine = InferenceEngine(dec, slots=slots, prefill_buckets=buckets,
                              max_queue=4 * slots,
                              steps_per_round=steps_per_round,
@@ -1052,7 +998,7 @@ def bench_serving_spec(slots=16, layers=12, embed=768, heads=12,
     buckets = tuple(b for b in (64, 128, 256) if b <= max_len) \
         or (max_len,)
     dec = Decoder(sym, params, max_len=max_len,
-                  compute_dtype="bfloat16", cache_block=None)
+                  compute_dtype="bfloat16")
     engine = InferenceEngine(
         dec, slots=slots, prefill_buckets=buckets,
         max_queue=4 * slots, steps_per_round=steps_per_round,
@@ -1195,7 +1141,7 @@ def bench_serving_overload(slots=16, layers=12, embed=768, heads=12,
     bucket = next(b for b in (64, 128, 256, max_len)
                   if b >= prompt_len and b <= max_len)
     dec = Decoder(sym, params, max_len=max_len,
-                  compute_dtype="bfloat16", cache_block=None)
+                  compute_dtype="bfloat16")
     engine = InferenceEngine(dec, slots=slots,
                              prefill_buckets=(bucket,),
                              max_queue=4 * n_requests,
@@ -1328,7 +1274,7 @@ def bench_serving_replay(slots=8, layers=12, embed=768, heads=12,
 
     def decoder():
         return Decoder(sym, params, max_len=max_len,
-                       compute_dtype="bfloat16", cache_block=None)
+                       compute_dtype="bfloat16")
 
     base_cfg = dict(slots=slots, prefill_buckets=buckets,
                     max_queue=4 * max(slots, burst),
@@ -1525,7 +1471,7 @@ def bench_serving_fleet(replicas=2, slots=4, layers=2, embed=128,
     buckets = (32, 64)
 
     def decoder():
-        return Decoder(sym, params, max_len=max_len, cache_block=None)
+        return Decoder(sym, params, max_len=max_len)
 
     base_cfg = dict(slots=slots, prefill_buckets=buckets,
                     max_queue=4 * slots, prefix_cache_mb=1,
@@ -1661,7 +1607,7 @@ def bench_serving_disagg(slots=4, layers=2, embed=128, heads=4,
                     prefill_chunk=16)
 
     def decoder():
-        return Decoder(sym, params, max_len=max_len, cache_block=None)
+        return Decoder(sym, params, max_len=max_len)
 
     # one fixed adversarial schedule, shared by every arm
     traffic = []
@@ -2001,7 +1947,7 @@ def bench_telemetry_overhead(batch=256, chain_steps=10, pairs=40,
 
     def _feng(role):
         return InferenceEngine(
-            Decoder(fsym, fparams, max_len=flen, cache_block=None),
+            Decoder(fsym, fparams, max_len=flen),
             slots=2, prefill_buckets=(4, 8), max_queue=8,
             prefix_cache_mb=0.0042, role=role)
 
@@ -2276,45 +2222,6 @@ def main():
     except Exception:
         arm_failed("serving_overload")
         serving_overload = None
-    # paged-attention A/B (ISSUE 11): dense whole-cache reads vs the
-    # Pallas live-row kernel, fp and int8-KV flavors, same workload
-    # and seeds per pair; the compile contract is asserted inside each
-    # arm. bytes_accessed per decode dispatch (program gauges) is the
-    # traffic cut; tokens/s + cadence p99 are the wall-clock read.
-    try:
-        paged_pairs = {}
-        for flavor, cdt in (("fp", None), ("int8", "int8")):
-            dense_arm = bench_serving(attn_impl="dense",
-                                      cache_dtype=cdt)
-            paged_arm = bench_serving(attn_impl="paged",
-                                      cache_dtype=cdt)
-            paged_pairs["dense_%s" % flavor] = dense_arm
-            paged_pairs["paged_%s" % flavor] = paged_arm
-            paged_pairs["speedup_%s" % flavor] = \
-                None if not dense_arm["tokens_per_sec"] \
-                else round(paged_arm["tokens_per_sec"]
-                           / dense_arm["tokens_per_sec"], 2)
-            ba_d = dense_arm.get("decode_bytes_accessed")
-            ba_p = paged_arm.get("decode_bytes_accessed")
-            paged_pairs["bytes_accessed_ratio_%s" % flavor] = \
-                None if not ba_d or not ba_p else round(ba_p / ba_d, 3)
-        serving_paged = {
-            **paged_pairs,
-            "note": "attn_impl='paged' (Pallas paged-attention kernel "
-                    "— reads only each slot's live KV rows, int8 "
-                    "dequantized in-kernel) vs the dense whole-cache "
-                    "read, identical workload/seeds per pair, greedy "
-                    "outputs byte-identical (fp) by the engine "
-                    "contract; bytes_accessed_ratio = paged/dense "
-                    "decode-program bytes per dispatched round (XLA "
-                    "cost analysis) — the memory-traffic cut, the "
-                    "honest metric where the CPU interpreter blurs "
-                    "wall clock; tools/bench_serving.py --attn-impls "
-                    "sweeps this axis",
-        }
-    except Exception:
-        arm_failed("serving_paged")
-        serving_paged = None
     # weight-only int8 quantization A/B (ISSUE 15): fp vs int8
     # weights on the same saturating workload; the decode-program
     # bytes_accessed ratio is the serving-batch weight-stream cut
@@ -2335,7 +2242,7 @@ def main():
                     "— doc/serving.md 'Quantized weights') vs float "
                     "weights, identical workload/seeds, compile "
                     "contract asserted per arm; serving_batch_probe "
-                    "lowers the 124M decode programs at the paged "
+                    "lowers the 124M decode programs at the "
                     "serving-batch geometry and reads their cost "
                     "analysis: forward_ratio = int8/fp bytes of the "
                     "decode forward a greedy round actually executes "
@@ -2352,9 +2259,7 @@ def main():
                     "TPU lever (PR 11/14 precedent); PR 17 arms: "
                     "int8_pallas/int4 = the quant_matmul kernel "
                     "(dequant-in-VMEM, int4 = packed nibbles + "
-                    "per-group scales), int4_fused = the one-dispatch "
-                    "QKV->attention->out-proj decode kernel, each "
-                    "with wall_ms and traced decode_dispatches; "
+                    "per-group scales), each with wall_ms; "
                     "tools/bench_serving.py --weight-dtypes / "
                     "--matmul-impls sweep these axes; "
                     "weight_stream_ratio_* = the analytic stored "
@@ -2486,10 +2391,8 @@ def main():
             "arms": dec_arms,
             "note": "greedy KV-cache decode, whole loop one compiled "
                     "lax.scan program, bf16; full = attends all "
-                    "max_len cache rows each step, block128 = "
-                    "prefix-bounded online-softmax reads "
-                    "(cache_block=128); batch sweep on the faster "
-                    "variant",
+                    "max_len cache rows each step (the offline "
+                    "step's one read)",
         },
         "serving_124M_continuous_batching": None if serving is None else {
             **serving,
@@ -2507,7 +2410,6 @@ def main():
         },
         "serving_prefix_cache_chunked_prefill": serving_prefix,
         "serving_speculative_decoding": serving_spec,
-        "serving_paged_attention": serving_paged,
         "serving_weight_quant": serving_quant,
         "serving_tensor_parallel": serving_tp,
         "serving_time_machine_replay": None if serving_replay is None
@@ -2667,14 +2569,6 @@ def main():
             "serving_spec_speedup":
                 None if serving_spec is None
                 else serving_spec["speedup_k4"],
-            "decode_paged_speedup":
-                None if not dec_arms or not dec_arms.get("full_b8")
-                or not dec_arms.get("paged_b8")
-                else round(dec_arms["full_b8"]["ms_per_token"]
-                           / dec_arms["paged_b8"]["ms_per_token"], 2),
-            "serving_paged_p99_ms":
-                None if serving_paged is None
-                else serving_paged["paged_fp"]["p99_ms_per_token"],
             "serving_replay_verified":
                 None if serving_replay is None
                 else serving_replay["verified_total"],
@@ -2689,10 +2583,6 @@ def main():
                 None if serving_quant is None
                 else (serving_quant.get("serving_batch_probe")
                       or {}).get("weight_stream_ratio_int4"),
-            "serving_fused_decode_dispatches":
-                None if serving_quant is None
-                else (serving_quant.get("serving_batch_probe")
-                      or {}).get("fused_decode_dispatches"),
             "serving_tp2_bytes_ratio":
                 None if serving_tp is None
                 else serving_tp.get("bytes_per_shard_ratio_tp2"),

@@ -23,8 +23,7 @@ each through the public API (``import mxnet_tpu as mx``):
   requests must come out byte-identical to ``Decoder.generate``
   (``serve_float32``: rounding cannot flip an argmax there, so a read
   of a donated buffer or of a padded bucket row would show); then the
-  opt-in kernels (paged attention, int8/int4 weights through the
-  Pallas matmul).
+  opt-in kernels (int8/int4 weights through the Pallas matmul).
 
 ``--chips 4`` runs only what exists across chips: a dp=2 x tp=2
 ``ParallelTrainer`` step against the one-device step, and an
@@ -497,7 +496,7 @@ def identical(got, want, what):
 
 def float32_decoder(mx, sym, params):
     return mx.parallel.Decoder(sym, params, max_len=SIZES["max_len"],
-                               compute_dtype="float32", cache_block=None,
+                               compute_dtype="float32",
                                weight_dtype="float")
 
 
@@ -506,22 +505,29 @@ def phase_serve(mx, seed):
     sz = SIZES
     sym, params = lm_params(mx, seed)
     dec = mx.parallel.Decoder(sym, params, max_len=sz["max_len"],
-                              compute_dtype="bfloat16", cache_block=None,
+                              compute_dtype="bfloat16",
                               weight_dtype="float")
     reqs = make_prompts(sz["requests"], seed)
+    # what the quantized engines' tokens are set beside, further down
+    arm = make_prompts(sz["arm_requests"], seed + 10)
 
     engine = make_engine(mx, dec)
     on_platform((engine._params, engine._caches), "serve engine state")
     got = serve_requests(engine, reqs, late=2)
+    float_got = serve_requests(engine, arm, late=1)
     cc = compile_contract(engine, "serve")
+    # a linear cache: the decode read is the bounded one, a kernel
+    n = has_kernels(decode_text(engine), "decode program",
+                    at_least=sz["layers"])
     engine.close()
     said = judge(dec, reqs, got, "serve",
                  want=[offline(dec, p, n) for p, n in reqs])
-    print("[serve] bf16, attn_impl=%s, against Decoder.generate: %d "
-          "requests, every token within the allowance of the reference "
-          "argmax, %s; compiles=%s peak_gb=%s"
-          % (engine.attn_impl, len(reqs), said,
-             json.dumps(cc, default=str), peak_gb()), flush=True)
+    judge(dec, arm, float_got, "serve/arm")
+    print("[serve] bf16 against Decoder.generate: %d requests, every "
+          "token within the allowance of the reference argmax, %s; "
+          "pallas_kernels=%d compiles=%s peak_gb=%s"
+          % (len(reqs), said, n, json.dumps(cc, default=str),
+             peak_gb()), flush=True)
 
     # -- float32, full matmul precision: byte-identity -----------------
     with jax.default_matmul_precision("highest"):
@@ -539,20 +545,6 @@ def phase_serve(mx, seed):
              json.dumps(cc, default=str)), flush=True)
 
     # -- the opt-in kernels --------------------------------------------
-    arm = make_prompts(sz["arm_requests"], seed + 10)
-
-    paged = make_engine(mx, dec, attn_impl="paged")
-    got = serve_requests(paged, arm, late=1)
-    compile_contract(paged, "serve/paged")
-    n = has_kernels(decode_text(paged), "paged decode program",
-                    at_least=sz["layers"])
-    paged.close()
-    said = judge(dec, arm, got, "serve/paged")
-    float_got = got
-    print("[serve] paged against the dense reference decoder: %d "
-          "requests, %s, pallas_kernels=%d" % (len(arm), said, n),
-          flush=True)
-
     for wd in ("int8", "int4"):
         # a quantized engine is held to the quantized offline decoder;
         # against float weights the contract is argmax stability, which
@@ -560,7 +552,7 @@ def phase_serve(mx, seed):
         # prefix per request is printed, not asserted
         qdec = mx.parallel.Decoder(
             sym, params, max_len=sz["max_len"],
-            compute_dtype="bfloat16", cache_block=None,
+            compute_dtype="bfloat16",
             weight_dtype=wd, matmul_impl="pallas")
         qeng = make_engine(mx, dec, weight_dtype=wd,
                            matmul_impl="pallas")
@@ -653,7 +645,7 @@ def phase_tp_serve(mx, seed):
     sz = SIZES
     sym, params = lm_params(mx, seed)
     dec = mx.parallel.Decoder(sym, params, max_len=sz["max_len"],
-                              compute_dtype="bfloat16", cache_block=None,
+                              compute_dtype="bfloat16",
                               weight_dtype="float")
     reqs = make_prompts(sz["requests"], seed)
     e1 = make_engine(mx, dec, slots=sz["tp_slots"])

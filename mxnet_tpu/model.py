@@ -1109,7 +1109,7 @@ class FeedForward(BASE_ESTIMATOR):
                           prefix_cache_mb=None, prefill_chunk=None,
                           overload=None, round_timeout_ms=None,
                           spec_k=None, draft=None, draft_decoder=None,
-                          attn_impl=None, capture_dir=None, tp=None,
+                          capture_dir=None, tp=None,
                           weight_dtype=None, **decoder_kwargs):
         """Trained estimator → continuous-batching inference engine
         (``mxnet_tpu.serving.InferenceEngine``, doc/serving.md): the
@@ -1122,13 +1122,11 @@ class FeedForward(BASE_ESTIMATOR):
         knobs (load shedding policy, round watchdog — doc/serving.md
         "Serving under hostile traffic"); ``spec_k``/``draft``/
         ``draft_decoder`` arm speculative decoding (doc/serving.md
-        "Speculative decoding"); ``attn_impl`` names the decode /
-        verify cache read — left out, a linear cache is read through
-        the Pallas paged-attention kernel, which fetches only the rows
-        live requests hold and nothing of a slot that holds none
-        (doc/serving.md "Paged attention"); ``tp=N`` shards the KV cache and every compiled
-        serving program over an N-device mesh's model axis
-        (doc/serving.md "Tensor-parallel serving");
+        "Speculative decoding"); the decode / verify cache read
+        follows the cache kind (doc/serving.md "The decode read");
+        ``tp=N`` shards the KV cache and every compiled serving
+        program over an N-device mesh's model axis (doc/serving.md
+        "Tensor-parallel serving");
         ``weight_dtype="int8"`` quantizes the engine's copy of the
         matmul weights to int8 with per-output-channel scales —
         1 byte/elem weight reads, on-the-fly dequant (doc/serving.md
@@ -1144,7 +1142,6 @@ class FeedForward(BASE_ESTIMATOR):
         def to_np(v):
             return v.asnumpy() if hasattr(v, "asnumpy") else v
 
-        decoder_kwargs.setdefault("cache_block", None)
         # weight_dtype goes to the DECODER (the env-default owner) and
         # the engine inherits: an explicit "float" must override
         # MXNET_SERVING_WEIGHT_DTYPE=int8 (an env-quantized decoder
@@ -1167,8 +1164,7 @@ class FeedForward(BASE_ESTIMATOR):
                                round_timeout_ms=round_timeout_ms,
                                spec_k=spec_k, draft=draft,
                                draft_decoder=draft_decoder,
-                               capture_dir=capture_dir,
-                               attn_impl=attn_impl, tp=tp)
+                               capture_dir=capture_dir, tp=tp)
 
     @staticmethod
     def load(prefix, epoch, ctx=None, **kwargs):

@@ -35,9 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_attention", "fused_linear", "striped_pair_attention",
            "matmul_stats", "paged_attention", "default_paged_block_k",
            "paged_rows_fetched",
-           "quant_matmul", "grouped_matmul", "fused_decode_attention",
-           "fused_decode_unsupported", "dispatch_count",
-           "reset_dispatch_count"]
+           "quant_matmul", "grouped_matmul"]
 
 
 def _use_interpret():
@@ -62,32 +60,6 @@ def _pallas_call(kernel, *operands, **kw):
     dtypes."""
     with jax.enable_x64(False):
         return pl.pallas_call(kernel, **kw)(*operands)
-
-
-# Trace-time kernel-dispatch accounting: every public kernel entry
-# bumps this when it STAGES a pallas_call (i.e. once per appearance in
-# a traced program — each appearance is one device dispatch per
-# execution of that program). bench.py's serving probes read it around
-# a decode-program trace to report dispatches-per-round, the headline
-# the fused decode chain exists to cut (HLO-level counting cannot see
-# kernels under the CPU interpreter, which inlines them).
-_DISPATCHES = 0
-
-
-def _count_dispatch(n=1):
-    global _DISPATCHES
-    _DISPATCHES += n
-
-
-def dispatch_count():
-    """Pallas kernel dispatches staged since the last
-    :func:`reset_dispatch_count` (trace-time count; see above)."""
-    return _DISPATCHES
-
-
-def reset_dispatch_count():
-    global _DISPATCHES
-    _DISPATCHES = 0
 
 
 def _round_up(x, m):
@@ -458,7 +430,6 @@ def _flash_attention_local(q, k, v, *, causal, scale, block_q, block_k,
         block_k = dk
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     b, tq, h, d = q.shape
     tk = k.shape[1]
     if scale is None:
@@ -737,7 +708,6 @@ def striped_pair_attention(q, k, v, q_off, k_off, *, n_stride, scale=None,
     """
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     bh, tq, d = q.shape
     tk = k.shape[1]
     if scale is None:
@@ -882,7 +852,6 @@ def fused_linear(x, w, b, act="linear", *, block_m=256, block_n=256,
     """
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     if act not in _ACTS:
         raise ValueError("unknown activation %r" % act)
     if act == "gelu":
@@ -909,7 +878,6 @@ def fused_conv_bn_act(x, w, scale, bias, stride=(1, 1), pad=(0, 0),
     """
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     n, c, h, wdim = x.shape
     nf, _, kh, kw = w.shape
     patches = lax.conv_general_dilated_patches(
@@ -1038,7 +1006,6 @@ def matmul_stats(x, w, *, block_m=256, block_n=256, block_k=512,
     backward is the usual two MXU dots."""
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     return _matmul_stats_core(x, w, block_m, block_n, block_k, interpret)
 
 
@@ -1064,11 +1031,11 @@ def matmul_stats(x, w, *, block_m=256, block_n=256, block_k=512,
 # approximation), int8 caches apply their side scales to the scores and
 # the weights in the kernel, and C > 1 serves the chunked-query
 # flavors: the speculative verify step's [S, K+1] chunk and the draft
-# model's catch-up window (doc/serving.md "Paged attention").
+# model's catch-up window (doc/serving.md "The decode read").
 #
 # NOT ring-safe: a windowed ring stores rows at wrapped positions, so
-# "rows [0, pos+C)" is not the live set — those models keep the exact
-# dense ring walk.
+# "rows [0, pos+C)" is not the live set — a ring keeps its own walk
+# (Decoder._window_attn).
 
 # a K or V block in flight holds at most this many bytes (two of each
 # are in flight: 4 MB of the chip's VMEM at the cap). On the chip, at
@@ -1080,27 +1047,14 @@ _PAGED_BLOCK_BYTES = 1 << 20
 
 
 def default_paged_block_k(max_len, row_bytes=None):
-    """KV rows per block for ``paged_attention``: the largest of
-    (512, 256, 128, 64, 32, 16, 8) that divides ``max_len`` (whole
-    blocks keep the in-kernel slices static) and, where the stored
-    row's ``row_bytes`` are given, keeps a block within 1 MB — a slot
-    of a few hundred live rows is then two to four grid steps; else
-    ``max_len`` itself: a cache too short/odd to block degenerates to
-    one block, still bounded by the position mask.
-    ``MXNET_PAGED_BLOCK_K`` overrides."""
-    import os
-    override = os.environ.get("MXNET_PAGED_BLOCK_K")
-    if override:
-        b = int(override)
-        # validate HERE, naming the knob: an unvalidated 0/negative
-        # dies later inside a jitted serving trace (ZeroDivisionError
-        # at the divisibility check; negative iota shapes in Pallas)
-        # with no pointer back to the env var
-        if b <= 0 or max_len % b:
-            raise ValueError(
-                "MXNET_PAGED_BLOCK_K=%s must be a positive divisor of "
-                "the cache length %d" % (override, max_len))
-        return b
+    """KV rows per block for ``paged_attention``, from the shapes
+    alone: the largest of (512, 256, 128, 64, 32, 16, 8) that divides
+    ``max_len`` (whole blocks keep the in-kernel slices static) and,
+    where the stored row's ``row_bytes`` are given, keeps a block
+    within 1 MB — a slot of a few hundred live rows is then two to
+    four grid steps; else ``max_len`` itself: a cache too short/odd to
+    block degenerates to one block, still bounded by the position
+    mask."""
     fits = [b for b in (512, 256, 128, 64, 32, 16, 8)
             if max_len % b == 0]
     for b in fits:
@@ -1269,7 +1223,6 @@ def paged_attention(q, k, v, pos, *, kv_heads, lens=None, k_scale=None,
     and XLA cost analysis both under-sell the bound."""
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     s_, c, h, d = q.shape
     l_ = k.shape[1]
     kv = int(kv_heads)
@@ -1401,28 +1354,6 @@ def _unpack4_halves(u):
     return signed(u & 0xF), signed((u >> 4) & 0xF)
 
 
-def _unpack4_block(u):
-    """The two planes of :func:`_unpack4_halves` re-interleaved to f32
-    [rows, E] in element order. Only the interpret-only fused decode
-    kernel uses it: the lane interleave lowers on the chip to ~95 MB
-    of VMEM re-layout for one [256, 768] tile, which is why
-    ``quant_matmul`` contracts the two planes separately instead."""
-    lo, hi = _unpack4_halves(u)
-    return jnp.stack([lo, hi], axis=-1).reshape(
-        u.shape[:-1] + (2 * u.shape[-1],))
-
-
-def _dequant_w(w_ref, s_ref, bits, group):
-    """Dequantize one weight tile in VMEM. int4: unpack + per-group
-    contraction-axis scales (must precede the dot). int8: raw cast —
-    the per-row scale folds into the OUTPUT (callers multiply the
-    accumulator by ``s^T`` instead, exactly like the fori fallback)."""
-    if bits == 4:
-        v = _unpack4_block(w_ref[...])
-        return v * jnp.repeat(s_ref[...], group, axis=-1)
-    return w_ref[...].astype(jnp.float32)
-
-
 _NT = (((1,), (1,)), ((), ()))      # x [M, E] . w [F, E]^T
 
 
@@ -1481,7 +1412,6 @@ def quant_matmul(x, q, scale, *, bits=8, group=None, block_f=None,
     Pallas interpreter (tests)."""
     if interpret is None:
         interpret = _use_interpret()
-    _count_dispatch()
     m, e = x.shape
     f = q.shape[0]
     ew = q.shape[1]
@@ -1645,195 +1575,7 @@ def grouped_matmul(x, w, block_e, used, rows, *, block_n=None,
                          "of %d" % (x.shape[0], rows))
     if interpret is None and _use_interpret():
         return _grouped_mm_xla(x, w, block_e, used, rows)
-    _count_dispatch()
     if interpret:
         return _grouped_mm_pallas(x, w, block_e, used, rows, block_n,
                                   True)
     return _grouped_mm_chip(x, w, block_e, used, rows, block_n)
-
-
-def fused_decode_unsupported():
-    """Why ``fused_decode_attention`` cannot run here, or None.
-
-    The kernel runs only under the Pallas interpreter. The TPU
-    compiler refuses it as written: its ``(1, E)`` per-slot activation
-    block over the ``[S, E]`` operand breaks the lowering's rule that
-    the last two block dimensions be divisible by (8, 128) or equal
-    the array's, and behind that stand in-kernel reshapes of the
-    ``[L, Hkv*D]`` cache plane to three dimensions, a lane-axis
-    concatenate to ``L+1`` scores and the int4 lane interleave
-    (``_unpack4_block``). ``InferenceEngine`` asks here at
-    construction and refuses ``matmul_impl="fused"`` by this reason
-    (ROADMAP S3/D2 decide whether the kernel is rewritten or goes)."""
-    if _use_interpret():
-        return None
-    return ("the TPU compiler does not accept the Pallas kernel "
-            "fused_decode_attention (ops/pallas_kernels.py): \"The "
-            "Pallas TPU lowering currently requires that the last two "
-            "dimensions of your block shape are divisible by 8 and 128 "
-            "respectively, or be equal to the respective dimensions of "
-            "the overall array\" — its per-slot (1, E) activation "
-            "block over [S, E] is neither. Serve quantized weights "
-            "with matmul_impl='pallas' (the unfused Pallas product) "
-            "or 'dense'")
-
-
-def _fused_decode_kernel(pos_ref, x_ref, k_ref, v_ref, wq_ref, sq_ref,
-                         bq_ref, wo_ref, so_ref, bo_ref, cs_ref,
-                         sn_ref, o_ref, kn_ref, vn_ref, *, heads,
-                         kv_heads, head_dim, max_len, bits, group,
-                         scale):
-    s = pl.program_id(0)
-    p = pos_ref[s]
-    e = x_ref.shape[1]
-    kv, d, g = kv_heads, head_dim, heads // kv_heads
-    xv = x_ref[...]                                    # [1, E]
-    # QKV projection, dequantized in VMEM
-    wq = _dequant_w(wq_ref, sq_ref, bits, group)
-    qkv = lax.dot_general(xv, wq, (((1,), (1,)), ((), ())),
-                          preferred_element_type=jnp.float32)
-    if bits == 8:
-        qkv = qkv * jnp.transpose(sq_ref[...])
-    qkv = qkv + bq_ref[...]
-    qh = qkv[0, :e].reshape(heads, d)
-    kh = qkv[0, e:e + kv * d].reshape(kv, d)
-    vh = qkv[0, e + kv * d:e + 2 * kv * d].reshape(kv, d)
-    # rope (half-split form), angles precomputed host-side per slot
-    cos, sin = cs_ref[...], sn_ref[...]                # [1, d/2]
-    half = d // 2
-
-    def rot(t):
-        t1, t2 = t[..., :half], t[..., half:]
-        return jnp.concatenate([t1 * cos - t2 * sin,
-                                t2 * cos + t1 * sin], -1)
-
-    qh, kh = rot(qh), rot(kh)
-    # attention: live cache rows [0, p) plus the current token's
-    # in-register (kh, vh) at position p — the cache write happens
-    # AFTER the kernel, equivalent to the dense path's write-then-read
-    qg = qh.reshape(kv, g, d)
-    ck = k_ref[...].reshape(max_len, kv, d).astype(jnp.float32)
-    cv = v_ref[...].reshape(max_len, kv, d).astype(jnp.float32)
-    s_cache = jnp.einsum("kgd,lkd->kgl", qg, ck) * scale
-    live = lax.broadcasted_iota(jnp.int32, (1, 1, max_len), 2) < p
-    s_cache = jnp.where(live, s_cache, -1e30)
-    s_new = jnp.einsum("kgd,kd->kg", qg, kh)[..., None] * scale
-    full = jnp.concatenate([s_cache, s_new], axis=-1)  # [kv, g, L+1]
-    mx = jnp.max(full, axis=-1, keepdims=True)
-    w = jnp.exp(full - mx)
-    denom = jnp.sum(w, axis=-1, keepdims=True)
-    o = jnp.einsum("kgl,lkd->kgd", w[..., :max_len], cv) \
-        + w[..., max_len:] * vh[:, None, :]
-    o = (o / denom).reshape(1, heads * d)
-    # output projection
-    wo = _dequant_w(wo_ref, so_ref, bits, group)
-    out = lax.dot_general(o, wo, (((1,), (1,)), ((), ())),
-                          preferred_element_type=jnp.float32)
-    if bits == 8:
-        out = out * jnp.transpose(so_ref[...])
-    o_ref[...] = (out + bo_ref[...]).astype(o_ref.dtype)
-    kn_ref[...] = kh.reshape(1, kv, d).astype(kn_ref.dtype)
-    vn_ref[...] = vh.reshape(1, kv, d).astype(vn_ref.dtype)
-
-
-def fused_decode_attention(x, pos, k_cache, v_cache, wqkv, sqkv, bqkv,
-                           wo, so, bo, *, heads, kv_heads, bits=8,
-                           group=None, rope=True, rope_base=10000.0,
-                           scale=None, cache_dtype=None,
-                           interpret=None):
-    """The decode step's QKV-projection -> rope -> paged attention ->
-    out-projection chain as ONE kernel dispatch per round
-    (``matmul_impl="fused"``, paged path, chunk==1).
-
-    Per slot the kernel: dequantizes the QKV weight tile in VMEM and
-    projects the token, applies rotary embedding to q/k at the slot's
-    position, attends over the slot's LIVE cache rows plus the
-    current token's in-register k/v (so the cache scatter-write can
-    stay OUTSIDE — the returned ``(k_new, v_new)`` rows are written
-    after the kernel, which is read-equivalent to the dense path's
-    write-then-read), and runs the dequantized output projection. The
-    weight index maps ignore the slot grid index, so Mosaic keeps the
-    tiles resident across slots instead of re-fetching per grid step.
-
-    x: [S, E] current-token activations; pos: [S] int32;
-    k_cache/v_cache: the stored [S, L, KV*D] float caches (int8 KV composes
-    with ``matmul_impl="pallas"`` instead — the fused path wants the
-    unquantized read). ``wqkv``/``wo`` + scales/biases as in
-    :func:`quant_matmul` (one ``bits`` for both). Returns
-    ``(out [S, E], k_new [S, KV, D], v_new [S, KV, D])`` with k_new
-    already roped. Numerics: plain (not streaming) softmax in f32
-    over L+1 scores — token-stable vs the unfused path, not bitwise
-    (different contraction blocking), which is why "fused" is its own
-    knob value rather than an automatic upgrade of "pallas"."""
-    if interpret is None:
-        interpret = _use_interpret()
-    _count_dispatch()
-    s_, e = x.shape
-    l_, kv = k_cache.shape[1], int(kv_heads)
-    d = k_cache.shape[2] // kv
-    fq = wqkv.shape[0]
-    if scale is None:
-        scale = 1.0 / float(np.sqrt(d))
-    half = d // 2
-    pos = jnp.asarray(pos, jnp.int32)
-    if rope:
-        freq = rope_base ** (-jnp.arange(half,
-                                         dtype=jnp.float32) / half)
-        ang = pos[:, None].astype(jnp.float32) * freq[None, :]
-        cs, sn = jnp.cos(ang), jnp.sin(ang)
-    else:
-        # identity rotation: cos=1/sin=0 make rot() exact pass-through
-        cs = jnp.ones((s_, half), jnp.float32)
-        sn = jnp.zeros((s_, half), jnp.float32)
-    if bits == 4:
-        sq2, so2 = sqkv, so
-    else:
-        sq2, so2 = sqkv.reshape(fq, 1), so.reshape(e, 1)
-    bq2 = bqkv.reshape(1, fq).astype(jnp.float32)
-    bo2 = bo.reshape(1, e).astype(jnp.float32)
-    cdt = jnp.dtype(cache_dtype) if cache_dtype else k_cache.dtype
-
-    def full(i, pref):
-        return (0, 0)
-
-    def slot2(i, pref):
-        return (i, 0)
-
-    def slot3(i, pref):
-        return (i, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s_,),
-        in_specs=[
-            pl.BlockSpec((1, e), slot2),                   # x
-            pl.BlockSpec((1, l_, kv * d), slot3),          # k cache
-            pl.BlockSpec((1, l_, kv * d), slot3),          # v cache
-            pl.BlockSpec((fq, wqkv.shape[1]), full),       # wqkv
-            pl.BlockSpec((fq, sq2.shape[1]), full),        # sqkv
-            pl.BlockSpec((1, fq), full),                   # bqkv
-            pl.BlockSpec((e, wo.shape[1]), full),          # wo
-            pl.BlockSpec((e, so2.shape[1]), full),         # so
-            pl.BlockSpec((1, e), full),                    # bo
-            pl.BlockSpec((1, half), slot2),                # cos
-            pl.BlockSpec((1, half), slot2),                # sin
-        ],
-        out_specs=[
-            pl.BlockSpec((1, e), slot2),
-            pl.BlockSpec((1, kv, d), slot3),
-            pl.BlockSpec((1, kv, d), slot3),
-        ],
-    )
-    out, kn, vn = _pallas_call(
-        functools.partial(_fused_decode_kernel, heads=heads,
-                          kv_heads=kv, head_dim=d, max_len=l_,
-                          bits=bits, group=group, scale=float(scale)),
-        pos, x, k_cache, v_cache, wqkv, sq2, bq2, wo, so2, bo2, cs, sn,
-        out_shape=[
-            jax.ShapeDtypeStruct((s_, e), x.dtype),
-            jax.ShapeDtypeStruct((s_, kv, d), cdt),
-            jax.ShapeDtypeStruct((s_, kv, d), cdt),
-        ],
-        grid_spec=grid_spec,
-        interpret=interpret)
-    return out, kn, vn

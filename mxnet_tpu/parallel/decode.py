@@ -35,7 +35,6 @@ char-rnn inference); attention-era decoding is a TPU-build extension.
 from __future__ import annotations
 
 import os
-import warnings
 
 import numpy as np
 import jax
@@ -151,22 +150,6 @@ class Decoder:
     compute_dtype : str, optional
         Cast floating parameters (and caches) for the decode math, e.g.
         ``"bfloat16"``; token ids are integer-semantic and never cast.
-    cache_block : int, None, or "auto"
-        Prefix-bounded cache reads for single-token steps: attend over
-        only the ``ceil((pos+1)/cache_block)`` leading cache blocks via
-        an online-softmax ``lax.fori_loop`` (dynamic trip count) instead
-        of reading all ``max_len`` K/V rows every step. EXACT — online
-        softmax is a reassociation, not an approximation. Saves HBM
-        traffic proportional to the unfilled cache suffix (the K/V
-        buffers rival the parameters in bytes at long ``max_len``).
-        Must divide ``max_len``. ``None`` keeps the one-shot full-cache
-        read. Default ``"auto"``: ``None`` up to 512 slots, 128 beyond
-        — long-chain measurements on the 124M LM at b8
-        (doc/performance.md round 5): blocked reads win 15% at
-        ``max_len`` 1024 (1.52 vs 1.79 ms/token, cache filling to 960)
-        and 1.9x at 4096 (2.78 vs 5.15, the full read touching the
-        whole 1.2 GB buffer every step); at a few hundred slots the
-        dynamic loop's serialization outweighs the read it saves.
     cache_dtype : str, optional
         ``"int8"`` stores K/V quantized — symmetric per-(position, head)
         row scales (``amax/127``, f32, D-fold smaller than the rows they
@@ -183,35 +166,6 @@ class Decoder:
         practice but bit-parity tests use the default. Any float dtype
         string (e.g. ``"bfloat16"``) is also accepted and simply stores
         the cache at that dtype; default follows ``compute_dtype``.
-    attn_impl : {"dense", "paged"}, optional
-        Cache-read strategy of a short chunk (decode, the speculative
-        verify and draft chunks). Default (``None``, and no
-        ``MXNET_SERVING_ATTN_IMPL`` in the environment): what the
-        code observes decides. The slot walk of the serving engine
-        (``_run_slots``) takes the BOUNDED read on a linear cache —
-        ``ops.pallas_kernels.paged_attention``: each slot fetches only
-        the blocks of rows its request holds, ``len = pos + C`` rows
-        for a slot that holds one and none at all for a slot that
-        does not, with online-softmax accumulation and the int8 row
-        scales applied in the kernel — and keeps the dense walk where
-        the bounded read has no meaning (a windowed ring, whose rows
-        live at wrapped positions; CCAttention, which has a read of
-        its own); the offline ``generate`` / ``beam_search`` (one
-        static position for the whole batch) read densely up to that
-        position. ``"paged"`` asks for the bounded read by name, in
-        the offline step too; ``"dense"`` for the walk that streams
-        (and masks) all ``max_len`` rows of every slot every step.
-        Both take the buffers as they are stored ([S, max_len,
-        Hkv*D]: no reshape, no copy). Exact: online softmax
-        reassociates, it does not approximate — greedy outputs match
-        the dense path (float flavors byte-identical through the
-        serving gauntlet; int8 under the usual quantized-cache
-        tolerance). ``"paged"`` is mutually exclusive with
-        ``cache_block`` (two prefix-bounded read strategies) and,
-        asked for by name over a windowed ring, warns and falls back
-        to the exact dense ring walk. The serving engine threads its
-        own ``attn_impl`` through ``_run_slots`` — doc/serving.md
-        "Paged attention".
     weight_dtype : {"float", "int8"}, optional
         Weight storage (default: the ``MXNET_SERVING_WEIGHT_DTYPE``
         env var, else ``"float"``). ``"int8"`` quantizes every matmul
@@ -232,8 +186,8 @@ class Decoder:
     """
 
     def __init__(self, symbol, params, max_len, aux_params=None,
-                 compute_dtype=None, cache_block="auto",
-                 cache_dtype=None, attn_impl=None, weight_dtype=None,
+                 compute_dtype=None, cache_block=None,
+                 cache_dtype=None, weight_dtype=None,
                  weight_group=None, matmul_impl=None):
         symbol = _logits_symbol(symbol)
         self._topo = symbol._topo()
@@ -242,38 +196,14 @@ class Decoder:
             raise MXNetError("Decoder needs a single-output symbol, got %d"
                              % len(self._heads))
         self.max_len = int(max_len)
-        if attn_impl is None:
-            attn_impl = os.environ.get("MXNET_SERVING_ATTN_IMPL") or None
-        if attn_impl not in (None, "dense", "paged"):
+        # the keyword stays only because benchmark/drivers/serve.py:32
+        # passes cache_block=None and a PR may not edit benchmark/
+        # (PERF.md section 7, "for the next benchmark issue")
+        if cache_block is not None:
             raise MXNetError(
-                "Decoder: attn_impl must be 'dense' or 'paged', got %r "
-                "(MXNET_SERVING_ATTN_IMPL sets the default)"
-                % (attn_impl,))
-        # the read asked for by name (None: what the code observes
-        # decides, see slots_impl and _attn_impl)
-        self._attn_given = attn_impl
-        auto_block = cache_block == "auto"
-        if attn_impl == "paged":
-            if cache_block == "auto":
-                # paged reads are already prefix-bounded; the blocked
-                # fori-loop read would be a second, slower strategy
-                cache_block = None
-            elif cache_block is not None:
-                raise MXNetError(
-                    "Decoder: attn_impl='paged' and cache_block are "
-                    "two prefix-bounded read strategies — pass "
-                    "cache_block=None with the paged kernel")
-        if cache_block == "auto":
-            cache_block = None if self.max_len <= 512 else 128
-            if cache_block is not None and self.max_len % cache_block:
-                cache_block = None  # odd max_len: keep the exact default
-        self._cache_block = None if cache_block is None else int(cache_block)
-        if self._cache_block is not None and (
-                self._cache_block < 1
-                or self.max_len % self._cache_block != 0):
-            raise MXNetError(
-                "Decoder: cache_block=%r must be a positive divisor of "
-                "max_len=%d" % (cache_block, self.max_len))
+                "Decoder: cache_block=%r: the blocked cache read was "
+                "removed; the read follows the cache kind (see "
+                "_cached_mha). Pass nothing." % (cache_block,))
 
         self._mha = []      # MultiHeadAttention nodes
         self._cca = []      # CCAttention nodes (K/V rows + rolling state)
@@ -308,30 +238,6 @@ class Decoder:
                     "position-wise; the decode transform supports the "
                     "standard LM ops (%s)"
                     % (name, n.name, ", ".join(sorted(_POSITIONWISE))))
-
-        if self._cca:
-            # what the rolling state cannot ride yet refuses here, by
-            # the op's name (ROADMAP D4: no silent fallback)
-            if self._attn_impl == "paged":
-                self.refuse_rolling_state("attn_impl='paged'")
-            if self._cache_block is not None and not auto_block:
-                self.refuse_rolling_state(
-                    "cache_block=%r (blocked reads)" % (cache_block,))
-            self._cache_block = None
-        if self._attn_impl == "paged" \
-                and any(self._node_window(n) for n in self._mha):
-            # refuse LOUDLY, then serve exactly: ring rows live at
-            # WRAPPED positions, so "rows [0, pos+C)" is not the live
-            # set and the paged kernel cannot hold exactness — the
-            # dense ring walk (already O(window)) serves instead
-            # (UserWarning precedent: speculation, prefix cache)
-            warnings.warn(
-                "Decoder: attn_impl='paged' does not compose with "
-                "windowed ring caches (ring rows live at wrapped "
-                "positions, not a [0, pos) prefix) — serving with the "
-                "exact dense ring walk instead", UserWarning,
-                stacklevel=2)
-            self._attn_given = "dense"
 
         arg_names = [n.name for n in self._topo if n.is_var]
         self._data_name = "data" if "data" in arg_names else arg_names[0]
@@ -406,17 +312,11 @@ class Decoder:
         if matmul_impl is None:
             matmul_impl = os.environ.get(
                 "MXNET_SERVING_MATMUL_IMPL") or "dense"
-        if matmul_impl not in ("dense", "pallas", "fused"):
+        if matmul_impl not in ("dense", "pallas"):
             raise MXNetError(
-                "Decoder: matmul_impl must be 'dense', 'pallas' or "
-                "'fused', got %r (MXNET_SERVING_MATMUL_IMPL sets the "
-                "default)" % (matmul_impl,))
-        if matmul_impl == "fused":
-            from ..ops.pallas_kernels import fused_decode_unsupported
-            why = fused_decode_unsupported()
-            if why:
-                raise MXNetError(
-                    "Decoder: matmul_impl='fused' is refused: " + why)
+                "Decoder: matmul_impl must be 'dense' or 'pallas', got "
+                "%r (MXNET_SERVING_MATMUL_IMPL sets the default)"
+                % (matmul_impl,))
         self._matmul_impl = matmul_impl
         if weight_dtype in ("int8", "int4"):
             from ..serving.quant import (quantize_params,
@@ -496,39 +396,12 @@ class Decoder:
                        bool(n.params.get("gated")), n.name))
 
     @property
-    def slots_walk_batched(self):
-        """Whether the slot-addressed walk (``_run_slots``) runs ONE
-        batched walk over all slots, each at its own position, rather
-        than a ``vmap`` of one-slot walks. CCAttention's cached form
-        takes a vector of positions as it stands, and the routed
-        experts behind it have to see every slot's token at once to
-        sort them by expert; MultiHeadAttention's dense read is
-        written for one position, so a graph that holds one takes the
-        batched walk with the bounded read (``slots_impl``) or keeps
-        the ``vmap``."""
-        return bool(self._cca) and not self._mha
-
-    @property
-    def _attn_impl(self):
-        """The read of the offline step (one static position for the
-        whole batch): dense unless ``"paged"`` was asked for."""
-        return self._attn_given or "dense"
-
-    def slots_impl(self, impl=None):
-        """The name of the read the slot walk takes: ``impl`` if
-        given, else what the decoder was built with, else what the
-        code observes — ``"paged"`` (the read bounded by each slot's
-        length) where every cached node is a MultiHeadAttention over
-        a LINEAR cache, ``"dense"`` where one is a windowed ring (its
-        rows live at wrapped positions) or a CCAttention (its rolling
-        state rides a batched walk of its own, with its own read)."""
-        if impl is None:
-            impl = self._attn_given
-        if impl is None:
-            linear = self._mha and not self._cca and not any(
-                self._node_window(n) for n in self._mha)
-            impl = "paged" if linear else "dense"
-        return impl
+    def _slots_batched(self):
+        """Whether the slot-addressed walk (``_run_slots``) is ONE
+        batched walk with the position vector: it is, unless a cached
+        node is a windowed ring, whose read is written for one
+        position (see the table above ``_cached_mha``)."""
+        return not any(self._node_window(n) for n in self._mha)
 
     def _node_window(self, node):
         """Ring-buffer slot count for a windowed attention node (0 for
@@ -650,7 +523,7 @@ class Decoder:
         slots, where a batched dynamic-update-slice becomes a loop of
         one small update a slot (on the chip 0.4 ms an array a round
         of 16 slots x 8 steps against 0.03). A VECTOR ``pos`` ([B]
-        int32 — the paged ``_run_slots`` batched walk) is the same
+        int32 — the batched ``_run_slots`` walk) is the same
         scatter with each batch row's own positions. A LONG chunk
         (prefill) goes in as one dynamic-update-slice block.
 
@@ -789,7 +662,7 @@ class Decoder:
         """One quantized matmul ``x [..., E] @ qt [F, E]^T`` under the
         decoder's ``matmul_impl``. ``"dense"`` (default) is the
         chunked host-level ``fori_loop`` (``scale_fused_matmul``);
-        ``"pallas"``/``"fused"`` dispatch ``quant_matmul`` — the same
+        ``"pallas"`` dispatches ``quant_matmul`` — the same
         output-channel partition at the SAME chunk size
         (``resolve_chunk``, lane-legal heights only), so the two impls
         stage identically and agree to f32 rounding; what "pallas" is
@@ -806,35 +679,29 @@ class Decoder:
                            out_dtype=x.dtype)
         return out.reshape(x.shape[:-1] + (f,))
 
-    def _fused_decode_mha(self, node, ins, entry, pos):
-        """``matmul_impl="fused"`` decode chain: QKV projection →
-        rope → attention over the live cache rows + the in-register
-        new token → output projection as ONE Pallas dispatch
-        (ops/pallas_kernels.py ``fused_decode_attention``). The
-        returned k/v rows are scattered into the cache AFTER the
-        kernel — read-equivalent to the unfused write-then-read.
-        Token-stable vs the unfused path, not bitwise (one plain-
-        softmax contraction instead of the paged kernel's streaming
-        blocks), which is why "fused" is its own knob value."""
-        from ..ops.attention import MultiHeadAttention as _MHA
-        from ..ops.pallas_kernels import fused_decode_attention
-        x, wqkv, bqkv, wo, bo = ins
-        b, c, e = x.shape
-        h = node.params["num_heads"]
-        kv = _MHA.kv_heads(node.params)
-        posv = jnp.asarray(pos, jnp.int32) if jnp.ndim(pos) == 1 \
-            else jnp.full((b,), pos, jnp.int32)
-        with jax.named_scope("attend"):
-            out, kn, vn = fused_decode_attention(
-                x.reshape(b, e), posv, entry[0], entry[1],
-                wqkv.q, wqkv.scale, bqkv, wo.q, wo.scale, bo,
-                heads=h, kv_heads=kv, bits=wqkv.bits, group=wqkv.group,
-                rope=bool(node.params.get("rope")),
-                rope_base=float(node.params.get("rope_base") or 10000.0))
-        entry = self._write_cache(entry, kn[:, None], vn[:, None],
-                                  posv)
-        return out.reshape(b, 1, e), entry
-
+    # THE READ FOLLOWS THE CACHE KIND. Which read a cached node takes is
+    # decided here (``_cached_mha`` / ``_cached_cca``: from the node, the
+    # chunk's length and whether ``pos`` is a vector) and in
+    # ``_run_slots`` (``_slots_batched``), and nowhere else. No option
+    # names a read.
+    #
+    # MultiHeadAttention, windowed ring, any chunk:
+    #     ``_window_attn``. The slot walk is the ``vmap`` of one-slot
+    #     walks: a ring's read is written for one position, it has no
+    #     other.
+    # MultiHeadAttention, linear cache, short chunk, position VECTOR
+    # (the slot walk: decode, verify, draft):
+    #     the bounded read ``paged_attention(lens=...)``, rows
+    #     [0, lens) of each slot, in ONE batched walk.
+    # MultiHeadAttention, linear cache, short chunk, one scalar position
+    # (the offline step / ``generate`` / ``beam_search``):
+    #     ``_lane_attn``, all rows masked by position; clamped
+    #     statically where the position is a Python int.
+    # MultiHeadAttention, linear cache, long chunk (prefill):
+    #     ``_head_attn``.
+    # CCAttention, any chunk:
+    #     ``_cached_cca`` (``_lane_attn`` for a short chunk at a scalar
+    #     or a vector of positions, ``_head_attn`` for a long one).
     def _cached_mha(self, node, ins, entry, pos, valid_len=None,
                     tp=None, mm_impl=None, lens=None, stats=None):
         from ..ops.attention import MultiHeadAttention as _MHA
@@ -845,19 +712,6 @@ class Decoder:
         h = node.params["num_heads"]
         d = e // h
         kv = _MHA.kv_heads(node.params)
-        if (mm_impl == "fused" and c == 1 and tp is None
-                and len(entry) == 2
-                and not self._node_window(node)
-                and isinstance(wqkv, QuantizedTensor)
-                and isinstance(wo, QuantizedTensor)
-                and wqkv.bits == wo.bits and wqkv.group == wo.group
-                and (self._attn_impl == "paged"
-                     or jnp.ndim(pos) == 1)):
-            if stats is not None:
-                # the fused chain stages every slot's whole plane
-                stats["attn_rows_read"] = b * entry[0].shape[1] \
-                    + stats.get("attn_rows_read", 0)
-            return self._fused_decode_mha(node, ins, entry, pos)
         if isinstance(wqkv, QuantizedTensor):
             # weight-only int8/int4: dequantized on the fly inside
             # the product (serving/quant.py; matmul_impl picks the
@@ -876,7 +730,7 @@ class Decoder:
             # their group broadcast equals the full forward's
             # rotate-after-repeat)
             from ..ops.attention import rope_rotate
-            if jnp.ndim(pos) == 1:   # per-slot clocks (paged walk)
+            if jnp.ndim(pos) == 1:   # per-slot clocks (the slot walk)
                 posv = jnp.asarray(pos, jnp.int32)[:, None] \
                     + jnp.arange(c, dtype=jnp.int32)
             else:
@@ -910,26 +764,24 @@ class Decoder:
         if win:
             if jnp.ndim(pos) == 1:
                 raise MXNetError(
-                    "Decoder: the paged batched walk does not support "
-                    "windowed ring caches — serve windowed models with "
-                    "attn_impl='dense' (the construction-time fallback "
-                    "does this automatically)")
+                    "Decoder: a windowed ring (node %r) is read at one "
+                    "position; a position vector has no ring read"
+                    % node.name)
             o, entry = self._window_attn(q, k, v, entry, pos, win,
                                          valid_len)
             if tp is not None:
                 o = lax.all_gather(o, tp[0], axis=2, tiled=True)
             return out_proj(o), entry
         entry = self._write_cache(entry, k, v, pos)
-        if self._attn_impl == "paged" or jnp.ndim(pos) == 1:
-            # Pallas paged attention (ops/pallas_kernels.py): fetch
-            # only the blocks of rows [0, lens) per slot of the stored
-            # buffer (lens: pos+C, or 0 for a slot that holds no
-            # request), the int8 side scales applied IN the kernel
+        if jnp.ndim(pos) == 1:
+            # the bounded read (ops/pallas_kernels.py): fetch only the
+            # blocks of rows [0, lens) per slot of the stored buffer
+            # (lens: pos+C, or 0 for a slot that holds no request),
+            # the int8 side scales applied IN the kernel
             from ..ops.pallas_kernels import (default_paged_block_k,
                                               paged_attention,
                                               paged_rows_fetched)
-            posv = jnp.asarray(pos, jnp.int32) if jnp.ndim(pos) == 1 \
-                else jnp.full((b,), pos, jnp.int32)
+            posv = jnp.asarray(pos, jnp.int32)
             if lens is None:
                 lens = posv + c
             ck, cv = entry[0], entry[2 if self._cache_int8 else 1]
@@ -944,8 +796,6 @@ class Decoder:
             if stats is not None:
                 stats["attn_rows_read"] = paged_rows_fetched(
                     lens, rows, bk) + stats.get("attn_rows_read", 0)
-        elif self._cache_block is not None and c == 1:
-            o = self._blocked_attn(q, entry, pos, kv)
         else:
             # dense read. A STATIC dispatch position (offline
             # generate/beam prefill call _run with a python-int pos)
@@ -1177,75 +1027,6 @@ class Decoder:
                          cpos.at[:, slots].set(posb, mode="drop"))
         return o, entry
 
-    @jax.named_scope("attend")
-    def _blocked_attn(self, q, entry, pos, kvh):
-        """Single-token attention reading only the filled cache prefix.
-
-        Online-softmax (flash-decoding) accumulation over the
-        ``ceil((pos+1)/cache_block)`` leading blocks of the K/V cache
-        (``kvh`` kv heads in its stored rows) —
-        a ``lax.fori_loop`` whose trip count is the TRACED ``pos``, so
-        the compiled program's HBM reads grow with the decoded prefix
-        instead of always touching all ``max_len`` rows. Exact: the
-        running max/denominator reassociates the softmax, it does not
-        approximate it."""
-        b, c, h, d = q.shape
-        bl = self._cache_block
-        qf = q.astype(jnp.float32)
-        nblocks = (pos + bl) // bl  # ceil((pos+1)/bl), pos is traced
-        if self._cache_int8:
-            ck, ks, cv, vs = entry
-        else:
-            ck, cv = entry
-        g = h // kvh  # kvh < h under grouped-query attention
-        qg = qf.reshape(b, c, kvh, g, d)
-
-        @jax.named_scope("cache")
-        def _block(buf, scale, i):
-            # one block of stored rows, unfolded per head (small)
-            z = unfold_heads(lax.dynamic_slice(
-                buf, (0, i * bl, 0), (b, bl, kvh * d)), kvh)
-            z = z.astype(jnp.float32)
-            if scale is not None:
-                sb = lax.dynamic_slice(scale, (0, i * bl, 0),
-                                       (b, bl, kvh))
-                z = z * sb[..., None]
-            return z
-
-        def body(i, carry):
-            m, s, acc = carry
-            kb = _block(ck, ks if self._cache_int8 else None, i)
-            vb = _block(cv, vs if self._cache_int8 else None, i)
-            if g == 1:
-                sc = jnp.einsum("bqhd,bkhd->bhqk", qf,
-                                kb) / float(np.sqrt(d))
-            else:  # grouped: query heads share their kv head's block
-                sc = jnp.einsum("bqKgd,bkKd->bKgqk", qg, kb) \
-                    .reshape(b, h, c, bl) / float(np.sqrt(d))
-            kpos = i * bl + jnp.arange(bl)[None, None, None, :]
-            sc = jnp.where(kpos <= pos, sc, -jnp.inf)
-            m2 = jnp.maximum(m, sc.max(axis=-1))
-            alpha = jnp.exp(m - m2)
-            p = jnp.exp(sc - m2[..., None])       # masked lanes -> 0
-            s2 = s * alpha + p.sum(axis=-1)
-            if g == 1:
-                upd = jnp.einsum("bhqk,bkhd->bhqd", p, vb)
-            else:
-                upd = jnp.einsum("bKgqk,bkKd->bKgqd",
-                                 p.reshape(b, kvh, g, c, bl),
-                                 vb).reshape(b, h, c, d)
-            acc2 = acc * alpha[..., None] + upd
-            return m2, s2, acc2
-
-        m0 = jnp.full((b, h, c), -jnp.inf, jnp.float32)
-        s0 = jnp.zeros((b, h, c), jnp.float32)
-        a0 = jnp.zeros((b, h, c, d), jnp.float32)
-        # slot `pos` was just written, so block 0 always contributes:
-        # the denominator is never zero
-        _, s, acc = lax.fori_loop(0, nblocks, body, (m0, s0, a0))
-        o = (acc / s[..., None]).astype(q.dtype)   # [b,h,c,d]
-        return o.transpose(0, 2, 1, 3)             # [b,c,h,d]
-
     def _run(self, params, aux, caches, pos, tokens, valid_len=None,
              tp=None, mm_impl=None, ep=None, stats=None, lens=None):
         """One chunk: tokens [B, C] at positions [pos, pos+C) →
@@ -1318,7 +1099,7 @@ class Decoder:
                 if name == "PositionalEmbedding":
                     x, posp = ins
                     if jnp.ndim(pos) == 1:
-                        # per-slot clocks (paged batched walk): gather each
+                        # per-slot clocks (the batched slot walk): gather each
                         # batch row's positions from the table
                         idx = jnp.asarray(pos, jnp.int32)[:, None] \
                             + jnp.arange(x.shape[1], dtype=jnp.int32)
@@ -1393,57 +1174,40 @@ class Decoder:
     # reuse the exact decode math above (quantized, windowed, GQA, rope
     # included) with zero duplication.
 
-    def _run_slots(self, params, aux, caches, pos, tokens, impl=None,
-                   tp=None, mm_impl=None, ep=None, stats=None,
-                   lens=None):
+    def _run_slots(self, params, aux, caches, pos, tokens, tp=None,
+                   mm_impl=None, ep=None, stats=None, lens=None):
         """Per-slot-position ``_run``: ``pos`` [S] int32 positions (one
         per cache slot), ``tokens`` [S, C] → (logits [S, C, V], updated
         caches).
 
-        ``impl`` (default: ``slots_impl()`` — the read the decoder was
-        built with, else the bounded read on a linear cache) picks the
-        read strategy. ``"paged"`` runs ONE batched walk with the
-        position VECTOR: position-wise ops see [S, C, E] directly,
-        cache writes scatter per slot, and the attention read is the
-        Pallas paged kernel (ops/pallas_kernels.py) that fetches only
-        the rows ``[0, lens)`` of each slot — ``lens`` [S] int32,
-        default ``pos + C``; the serving engine's programs hand 0 for
-        a slot that holds no request, whose stale rows are then not
-        read at all and whose logits (finite) the caller discards —
-        the serving decode/verify hot path's memory-traffic lever
-        (doc/serving.md "Paged attention"). ``"dense"`` vmaps over the
-        slot axis — each lane is a b=1 ``_run`` at its own traced
-        position, so cache writes become per-slot scatters and masks
-        follow each slot's own clock, and every lane reads (and
-        masks) all ``max_len`` cache rows as they are stored; a
-        windowed ring has no other walk.
+        Unless a cached node is a windowed ring (``_slots_batched``)
+        this is ONE batched walk with the position VECTOR:
+        position-wise ops see [S, C, E] directly, cache writes scatter
+        per slot, a MultiHeadAttention's read is the bounded one (the
+        Pallas kernel of ops/pallas_kernels.py) that fetches only the
+        rows ``[0, lens)`` of each slot — ``lens`` [S] int32, default
+        ``pos + C``; the serving engine's programs hand 0 for a slot
+        that holds no request, whose stale rows are then not read at
+        all and whose logits (finite) the caller discards — and a
+        CCAttention's its own (doc/serving.md "The decode read").
+        ``stats`` is filled there (see ``_run``): only this one walk
+        sees every slot's token, so only here can experts be counted
+        once per step.
+
+        A windowed ring's read is written for one position, so a graph
+        that holds one takes the ``vmap`` over the slot axis — each
+        lane is a b=1 ``_run`` at its own traced position, cache
+        writes become per-slot scatters and masks follow each slot's
+        own clock.
 
         ``tp`` (``(axis_name, degree)``, optional): the call is
         running inside the serving engine's tensor-parallel shard_map
         and ``caches`` are this shard's kv-head slice — see ``_run``.
-        Composes with both impls: under ``"paged"`` each shard runs
-        the Pallas kernel against its LOCAL cache shard — it is
-        handed the shard's own lanes and local kv-head count — and
-        the per-attention-node
-        all-gather rebuilds the head output exactly as in the dense
-        branch (doc/serving.md "Paged attention").
-
-        A graph whose temporal nodes all take a position vector as
-        they stand (``slots_walk_batched``: CCAttention) runs the ONE
-        batched walk under ``"dense"`` too, with its own dense read;
-        ``stats`` is filled there (see ``_run``)."""
-        if impl == "dense" and self._attn_given == "paged":
-            # a paged decoder's _cached_mha always takes the kernel
-            # path — honoring "dense" here would silently serve paged
-            # anyway, so refuse (mirrors the engine's constructor
-            # check): build a dense decoder to serve dense
-            raise MXNetError(
-                "Decoder: impl='dense' requested on a decoder built "
-                "with attn_impl='paged' — build the decoder dense "
-                "(the engine threads its own attn_impl per dispatch)")
-        if self.slots_impl(impl) == "paged" or self.slots_walk_batched:
-            # ``stats``: only this one walk sees every slot's token,
-            # so only here can experts be counted once per step
+        Each shard runs the bounded read against its LOCAL cache
+        shard — it is handed the shard's own lanes and local kv-head
+        count — and the per-attention-node all-gather rebuilds the
+        head output (doc/serving.md "Tensor-parallel serving")."""
+        if self._slots_batched:
             return self._run(params, aux, caches,
                              jnp.asarray(pos, jnp.int32), tokens,
                              tp=tp, mm_impl=mm_impl, ep=ep, stats=stats,
@@ -1553,8 +1317,7 @@ class Decoder:
         return tuple(out)
 
     def verify_step_slots(self, params, aux, caches, state, drafts,
-                          dlen, impl=None, tp=None, mm_impl=None,
-                          ep=None):
+                          dlen, tp=None, mm_impl=None, ep=None):
         """Speculative draft-and-verify decode step over all S slots
         (the serving engine's verify program — doc/serving.md
         "Speculative decoding").
@@ -1596,9 +1359,8 @@ class Decoder:
         chunk = jnp.concatenate(
             [tok[:, None], drafts.astype(jnp.int32)], axis=1)
         logits, caches = self._run_slots(
-            params, aux, caches, pos, chunk, impl=impl, tp=tp,
-            mm_impl=mm_impl, ep=ep,
-            lens=jnp.where(live, pos + (k + 1), 0))         # [S,K+1,V]
+            params, aux, caches, pos, chunk, tp=tp, mm_impl=mm_impl,
+            ep=ep, lens=jnp.where(live, pos + (k + 1), 0))  # [S,K+1,V]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def with_sampling(_):
@@ -1643,8 +1405,8 @@ class Decoder:
         return caches, state2, jnp.stack(outs)              # [K+1, S]
 
     def draft_propose_slots(self, params, aux, caches, pos, catchup,
-                            clen, k, impl=None, tp=None, mm_impl=None,
-                            ep=None, live=None):
+                            clen, k, tp=None, mm_impl=None, ep=None,
+                            live=None):
         """Greedy k-token proposal from a DRAFT model sharing the
         slot-paged layout (the serving engine's draft program —
         ``InferenceEngine(draft="model")``).
@@ -1669,9 +1431,8 @@ class Decoder:
             return jnp.where(live, p + c, 0)
 
         logits, caches = self._run_slots(
-            params, aux, caches, pos, catchup, impl=impl, tp=tp,
-            mm_impl=mm_impl, ep=ep,
-            lens=lens(pos, catchup.shape[1]))               # [S,W,V]
+            params, aux, caches, pos, catchup, tp=tp, mm_impl=mm_impl,
+            ep=ep, lens=lens(pos, catchup.shape[1]))        # [S,W,V]
         idx = jnp.clip(clen - 1, 0, catchup.shape[1] - 1)
         lastlog = jnp.take_along_axis(
             logits, idx[:, None, None], axis=1)[:, 0]       # [S, V]
@@ -1681,7 +1442,7 @@ class Decoder:
         def body(carry, _):
             caches, p, t = carry
             lg, caches = self._run_slots(params, aux, caches, p,
-                                         t[:, None], impl=impl, tp=tp,
+                                         t[:, None], tp=tp,
                                          mm_impl=mm_impl, ep=ep,
                                          lens=lens(p, 1))
             nx = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)

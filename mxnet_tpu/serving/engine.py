@@ -186,11 +186,6 @@ _TM_SLOTS_BUSY = tele.histogram(
     "serving.slots_busy_per_round",
     buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128))
 _TM_OCCUPANCY = tele.gauge("serving.slot_occupancy")
-# info gauge: which attention impl the decode/verify programs trace —
-# 1 = paged (Pallas live-row kernel), 0 = dense. Set at construction;
-# with several engines in one process the gauge reflects the engine
-# built last (the one-engine-per-process SLO note applies).
-_TM_ATTN_IMPL = tele.gauge("serving.attn_impl")
 # prefix cache + chunked prefill (all host-side: the lookup is a trie
 # walk, the copy/chunk spans time dispatches — nothing crosses the
 # device boundary beyond the programs themselves)
@@ -224,8 +219,9 @@ _TM_SPEC_MODEL = tele.counter("serving.spec_drafts_model")
 # info gauges set at construction — the sharding degree (1 = unsharded)
 # and each shard's slice of the serving KV cache in bytes (the
 # multi-chip win condition: decode is memory-bound, so bytes/shard is
-# what scales down with chips). Engine-last-built semantics like
-# serving.attn_impl.
+# what scales down with chips). With several engines in one process
+# an info gauge reflects the engine built last (the
+# one-engine-per-process SLO note applies).
 _TM_TP = tele.gauge("serving.tp_degree")
 _TM_TP_KV_BYTES = tele.gauge("serving.kv_bytes_per_shard")
 # weight-only quantization (doc/serving.md "Quantized weights"): info
@@ -233,20 +229,20 @@ _TM_TP_KV_BYTES = tele.gauge("serving.kv_bytes_per_shard")
 # 1 = int8) and the engine's total stored weight bytes (quantized
 # entries count int8 values + scales; the draft model's weights, when
 # present, are included — they ride the same programs). Engine-last-
-# built semantics like serving.attn_impl.
+# built semantics like serving.tp_degree.
 _TM_WEIGHT_DTYPE = tele.gauge("serving.weight_dtype")
 _TM_WEIGHT_BYTES = tele.gauge("serving.weight_bytes")
 # fused quantized kernels (doc/serving.md "Fused quantized kernels"):
 # info gauges set at construction — which matmul impl the quantized
-# products trace (0 = dense fori loop, 1 = pallas, 2 = pallas + fused
-# decode chain) and the int4 per-group scale width (0 = not int4 /
-# auto). Engine-last-built semantics like serving.attn_impl.
+# products trace (0 = dense fori loop, 1 = pallas) and the int4
+# per-group scale width (0 = not int4 / auto). Engine-last-built
+# semantics like serving.tp_degree.
 _TM_MATMUL_IMPL = tele.gauge("serving.matmul_impl")
 _TM_WEIGHT_GROUP = tele.gauge("serving.weight_group_size")
 # disaggregated prefill/decode (doc/serving.md "Disaggregated
 # prefill/decode"): info gauge for the engine's role (0 = unified,
 # 1 = prefill, 2 = decode; engine-last-built semantics like
-# serving.attn_impl) and the time a FINISHED prefill's package waited
+# serving.tp_degree) and the time a FINISHED prefill's package waited
 # between export-ready and decode-side admission — the queueing cost
 # the split adds in front of decode, observed by the router at
 # delivery
@@ -489,10 +485,9 @@ class InferenceEngine:
         ``cache_dtype``, sliding-window models, GQA, rope). Build one
         with ``Decoder(symbol, params, max_len=...)`` or use
         :meth:`from_checkpoint` / ``FeedForward.as_serving_engine``.
-        ``cache_block`` prefix-bounded reads are not supported under
-        slot addressing (each slot has its own clock) — construct the
-        decoder with ``cache_block=None`` (the engine refuses
-        otherwise rather than silently decoding differently).
+        Which read the decode / verify / draft programs take follows
+        the decoder's cache kind (the table above
+        ``Decoder._cached_mha``; doc/serving.md "The decode read").
     slots : int
         ``S``, the resident-sequence capacity — the continuous batch
         size and the cache's slot-axis length. Throughput knob: decode
@@ -622,9 +617,8 @@ class InferenceEngine:
         The draft model for ``draft="model"`` (e.g. the 124M config
         drafting for a 350M target, loaded from its own checkpoint —
         ``from_checkpoint(draft_prefix=..., draft_epoch=...)`` builds
-        it for you). Must share ``max_len``, be non-windowed, and use
-        ``cache_block=None``; its vocabulary must cover the target's
-        token ids.
+        it for you). Must share ``max_len`` and be non-windowed; its
+        vocabulary must cover the target's token ids.
     flight_recorder : int, optional
         How many RETIRED requests keep their full flight-recorder
         timeline (submit → staged → admitted → prefix hit/copy →
@@ -634,33 +628,6 @@ class InferenceEngine:
         ``MXNET_SERVING_FLIGHT_RECORDER`` env var, else 256; 0
         disables recording. Host-side, bounded (doc/observability.md
         "The flight recorder").
-    attn_impl : {"dense", "paged"}, optional
-        Cache-read strategy for the decode / verify / draft programs.
-        Default (``None``): the read the decoder was built with
-        (``attn_impl``, ``MXNET_SERVING_ATTN_IMPL``), else what
-        ``Decoder.slots_impl`` observes: ``"paged"`` on a linear
-        cache, ``"dense"`` over a windowed ring or a CCAttention
-        decoder; ``engine.attn_impl`` names the one taken.
-        ``"paged"`` traces them over the Pallas paged-attention kernel
-        (``ops.pallas_kernels.paged_attention``): each slot's read
-        fetches only the rows its request holds — ``len = pos + C``
-        for a slot that is live, made in the step program from the
-        state it carries, and 0 for a slot that holds no request: a
-        finished slot keeps its last position, but none of its stale
-        rows is read — with the int8 row scales applied in the kernel,
-        cutting the per-token HBM traffic that dominates decode (the
-        dense read streams and masks all ``max_len`` rows of every
-        slot each step; both read the [S, max_len, Hkv*D] buffers as
-        stored). ``serving.attn_rows_read`` / ``serving.attn_rows_pool``
-        count what the decode rounds fetched against the pool.
-        Greedy outputs stay byte-identical to ``"dense"`` in float
-        flavors (online softmax is a reassociation); int8 carries the
-        usual quantized-cache tolerance. The compile-count contract is
-        unchanged — same program families, different kernels inside.
-        Windowed-ring decoders warn and serve dense (ring rows live at
-        wrapped positions); prefill keeps the dense bucketed programs
-        (compute-bound, traced start). ``snapshot()``/``restore()``
-        carry the knob. doc/serving.md "Paged attention".
     capture_dir : str, optional
         Traffic capture (the serving time machine's record half —
         doc/observability.md): when set (default: the
@@ -694,10 +661,10 @@ class InferenceEngine:
         host-side sampling identity is untouched); the compile-count
         contract is unchanged. Every attention node's kv heads must
         divide ``tp`` evenly (GQA groups stay whole per shard —
-        refused loudly otherwise). ``attn_impl="paged"`` composes:
-        each shard runs the Pallas kernel against its local cache
-        shard (a per-shard kv-head grid), so the live-rows cut and
-        the per-shard cut multiply. ``snapshot()``/``restore()``
+        refused loudly otherwise). The bounded read composes: each
+        shard runs the Pallas kernel against its local cache shard
+        (a per-shard kv-head grid), so the live-rows cut and the
+        per-shard cut multiply. ``snapshot()``/``restore()``
         carry the degree.
     mesh : jax.sharding.Mesh, optional
         Serve over an existing mesh instead of building one: must
@@ -725,8 +692,8 @@ class InferenceEngine:
         the int8-KV contract); quantized engines stay byte-identical
         ACROSS their own gauntlet (tp degrees, admission orders,
         speculation, snapshot/restore). Composes with everything:
-        tp>1 (scales replicate with their weights), int8 KV, paged
-        attention, prefix cache, chunked prefill, both speculation
+        tp>1 (scales replicate with their weights), int8 KV, the
+        bounded read, prefix cache, chunked prefill, both speculation
         modes, capture/replay. ``snapshot()``/``restore()`` and the
         capture header carry the knob. doc/serving.md "Quantized
         weights".
@@ -739,7 +706,7 @@ class InferenceEngine:
                  round_timeout_ms=None, slo_ttft_ms=None,
                  slo_cadence_ms=None, slo_target=0.99,
                  flight_recorder=None, spec_k=None, draft=None,
-                 draft_decoder=None, attn_impl=None, capture_dir=None,
+                 draft_decoder=None, capture_dir=None,
                  capture_mb=None, tp=None, mesh=None,
                  weight_dtype=None, weight_group=None, matmul_impl=None,
                  ep=None, engine_id=None, migrated_from=None,
@@ -747,11 +714,6 @@ class InferenceEngine:
         if not isinstance(decoder, Decoder):
             raise MXNetError("InferenceEngine needs a Decoder, got %r"
                              % type(decoder).__name__)
-        if decoder._cache_block is not None:
-            raise MXNetError(
-                "InferenceEngine: slot-paged decoding does not support "
-                "cache_block prefix-bounded reads (per-slot positions); "
-                "build the Decoder with cache_block=None")
         self._dec = decoder
         self._t0 = time.perf_counter()   # ledger/capture time origin
         # fleet identity: engine_id names this replica (FleetRouter
@@ -1055,73 +1017,20 @@ class InferenceEngine:
                              "be >= 0 (0 disables the prefix cache)")
         self._windowed = any(decoder._node_window(n)
                              for n in decoder._mha)
-        # attention impl (doc/serving.md "Paged attention"): which
-        # cache-read strategy the decode/verify/draft programs trace —
-        # threaded into every Decoder._run_slots dispatch, so one
-        # decoder can serve under either impl (the A/B bench and the
-        # identity tests share weights across engines)
-        if attn_impl not in (None, "dense", "paged"):
-            raise MXNetError(
-                "InferenceEngine: attn_impl must be 'dense' or "
-                "'paged', got %r (MXNET_SERVING_ATTN_IMPL sets the "
-                "default)" % (attn_impl,))
-        if attn_impl == "dense" and decoder._attn_impl == "paged":
-            raise MXNetError(
-                "InferenceEngine: attn_impl='dense' over a Decoder "
-                "built with attn_impl='paged' — build the decoder "
-                "dense; the engine threads its own attn_impl into the "
-                "slot programs")
-        if attn_impl == "paged" and rolling:
-            decoder.refuse_rolling_state("attn_impl='paged'")
-        if attn_impl == "paged" and self._windowed:
-            # refuse LOUDLY, then serve exactly (prefix-cache /
-            # speculation precedent): ring rows live at wrapped
-            # positions, outside the paged kernel's [0, pos) contract
-            warnings.warn(
-                "InferenceEngine: windowed-ring decoders do not "
-                "compose with attn_impl='paged' (ring rows live at "
-                "wrapped positions, not a [0, pos) prefix) — serving "
-                "with the exact dense ring walk instead", UserWarning,
-                stacklevel=2)
-            attn_impl = "dense"
-        # no name given: the decoder's own, else the bounded read on a
-        # linear cache (Decoder.slots_impl)
-        attn_impl = decoder.slots_impl(attn_impl)
-        # attn_impl="paged" composes with tp>1 since ISSUE 15: inside
-        # the shard_map each device runs the Pallas kernel against its
-        # LOCAL cache shard (the kernel's kv-head grid extent comes
-        # from the cache operand, so it is per-shard automatically)
-        # and the usual per-attention-node all-gather rebuilds the
-        # head output — the PR 11 live-rows cut and the PR 14
-        # per-shard cut multiply (doc/serving.md "Paged attention").
-        self.attn_impl = attn_impl
-        _TM_ATTN_IMPL.set(1 if attn_impl == "paged" else 0)
         # fused quantized kernels (doc/serving.md "Fused quantized
         # kernels"): which impl the quantized matmuls trace — threaded
-        # into every Decoder._run_slots/_run dispatch like attn_impl.
-        # "pallas" runs the same output-channel partition as "dense"
-        # through the Pallas kernel and agrees with it to f32 rounding
-        # (token-level identity is what the gauntlet pins, not bits —
-        # tests/test_pallas_quant.py); "fused" additionally collapses
-        # each decode step's QKV→attention→out-proj chain into one
-        # dispatch where eligible (paged, c==1, tp=1, float KV) —
-        # token-stable, so it is its OWN knob value
+        # into every Decoder._run_slots/_run dispatch. "pallas" runs the same
+        # output-channel partition as "dense" through the Pallas
+        # kernel and agrees with it to f32 rounding (token-level
+        # identity is what the gauntlet pins, not bits —
+        # tests/test_pallas_quant.py)
         if matmul_impl is None:
             matmul_impl = decoder._matmul_impl
-        if matmul_impl not in ("dense", "pallas", "fused"):
+        if matmul_impl not in ("dense", "pallas"):
             raise MXNetError(
-                "InferenceEngine: matmul_impl must be 'dense', "
-                "'pallas' or 'fused', got %r (MXNET_SERVING_MATMUL_"
-                "IMPL sets the default)" % (matmul_impl,))
-        if matmul_impl == "fused":
-            from ..ops.pallas_kernels import fused_decode_unsupported
-            why = fused_decode_unsupported()
-            if why:
-                # refused HERE, by name — never a quiet switch to the
-                # unfused product inside a program
-                raise MXNetError(
-                    "InferenceEngine: matmul_impl='fused' is refused: "
-                    + why)
+                "InferenceEngine: matmul_impl must be 'dense' or "
+                "'pallas', got %r (MXNET_SERVING_MATMUL_IMPL sets the "
+                "default)" % (matmul_impl,))
         self.matmul_impl = matmul_impl
         # disaggregated prefill/decode (doc/serving.md "Disaggregated
         # prefill/decode"): role gates which program families ever
@@ -1239,10 +1148,6 @@ class InferenceEngine:
                     "equal the target's max_len=%d (the draft cache "
                     "mirrors the slot clocks)"
                     % (draft_decoder.max_len, self.max_len))
-            if draft_decoder._cache_block is not None:
-                raise MXNetError(
-                    "InferenceEngine: draft_decoder must be built "
-                    "with cache_block=None (slot addressing)")
             if any(draft_decoder._node_window(n)
                    for n in draft_decoder._mha):
                 raise MXNetError(
@@ -1300,7 +1205,7 @@ class InferenceEngine:
         self.weight_bytes = wbytes
         _TM_WEIGHT_BYTES.set(wbytes)
         _TM_MATMUL_IMPL.set(
-            {"dense": 0, "pallas": 1, "fused": 2}[self.matmul_impl])
+            {"dense": 0, "pallas": 1}[self.matmul_impl])
         _TM_WEIGHT_GROUP.set(int(self.weight_group or 0))
 
         # host-side scheduler state
@@ -1375,16 +1280,19 @@ class InferenceEngine:
         self._copy_donate = (0, 1) if on_chip else ()
         cs = self._cache_spec(self._caches)
         # routed MoEFFN layers whose touched experts the decode program
-        # counts (0: none, or a walk that never sees all slots at once)
+        # counts (0: none; counted for a CCAttention decoder's walk,
+        # whose routed experts take their routing from the graph)
         self._moe_counted = sum(
             1 for n in moe_nodes if n.params["top_k"] > 0) \
-            if decoder.slots_walk_batched else 0
-        # rows of the K buffers of every attention layer, which a
-        # decode step's bounded reads are counted against (0: the read
-        # is not bounded, nothing is counted)
+            if rolling and not decoder._mha else 0
+        # rows of the K buffers of every MultiHeadAttention layer,
+        # which a decode step's bounded reads are counted against (0:
+        # no read is bounded — a ring, CCAttention — nothing is counted)
         self._attn_pool_rows = sum(
-            e[0].shape[0] * e[0].shape[1] for e in self._caches) \
-            if self.attn_impl == "paged" else 0
+            e[0].shape[0] * e[0].shape[1]
+            for n, e in zip(decoder._cached, self._caches)
+            if n.spec.name == "MultiHeadAttention") \
+            if decoder._slots_batched else 0
         self._step_fn = jax.jit(
             self._wrap_tp(self._make_step(),
                           (ps, "r", cs, "r"), (cs, "r", "r")),
@@ -1447,9 +1355,8 @@ class InferenceEngine:
                         slo_target=0.99, flight_recorder=None,
                         spec_k=None, draft=None, draft_decoder=None,
                         draft_prefix=None, draft_epoch=None,
-                        attn_impl=None, capture_dir=None, tp=None,
-                        mesh=None, weight_dtype=None,
-                        **decoder_kwargs):
+                        capture_dir=None, tp=None, mesh=None,
+                        weight_dtype=None, **decoder_kwargs):
         """Checkpoint → serving engine in one call
         (``prefix-symbol.json`` + ``prefix-NNNN.params``, the reference
         format): builds the :class:`Decoder` via
@@ -1460,7 +1367,6 @@ class InferenceEngine:
         ``draft="model"`` unless overridden; the draft decoder
         inherits ``compute_dtype`` but none of the cache-flavor
         kwargs."""
-        decoder_kwargs.setdefault("cache_block", None)
         # weight_dtype goes to the DECODER (which owns the env-default
         # resolution) and the engine inherits it: an explicit "float"
         # must be able to override MXNET_SERVING_WEIGHT_DTYPE=int8 —
@@ -1472,7 +1378,7 @@ class InferenceEngine:
         if draft_prefix is not None and draft_decoder is None:
             draft_decoder = Decoder.from_checkpoint(
                 draft_prefix, 0 if draft_epoch is None else draft_epoch,
-                max_len, cache_block=None,
+                max_len,
                 compute_dtype=decoder_kwargs.get("compute_dtype"),
                 weight_dtype=decoder_kwargs["weight_dtype"])
             if draft is None:
@@ -1488,8 +1394,7 @@ class InferenceEngine:
                    slo_cadence_ms=slo_cadence_ms, slo_target=slo_target,
                    flight_recorder=flight_recorder, spec_k=spec_k,
                    draft=draft, draft_decoder=draft_decoder,
-                   attn_impl=attn_impl, capture_dir=capture_dir,
-                   tp=tp, mesh=mesh)
+                   capture_dir=capture_dir, tp=tp, mesh=mesh)
 
     # -- compiled programs ----------------------------------------------
     def _cache_spec(self, tree):
@@ -1536,7 +1441,6 @@ class InferenceEngine:
     def _make_step(self):
         dec = self._dec
         k_rounds = self.steps_per_round
-        impl = self.attn_impl
         mm = self.matmul_impl
         tp_ax = self._tp_ax
         ep_ax = self._ep_ax
@@ -1554,8 +1458,8 @@ class InferenceEngine:
             # would read its stale rows for ever)
             stats = {} if counted or rows_counted else None
             logits, caches = dec._run_slots(
-                params, aux, caches, pos, tok[:, None], impl=impl,
-                tp=tp_ax, mm_impl=mm, ep=ep_ax, stats=stats,
+                params, aux, caches, pos, tok[:, None], tp=tp_ax,
+                mm_impl=mm, ep=ep_ax, stats=stats,
                 lens=jnp.where(live, pos + 1, 0))
             logits = logits[:, 0]
             nxt_pos = pos + 1
@@ -1629,7 +1533,6 @@ class InferenceEngine:
         token; rounds with NO drafts at all dispatch the plain decode
         program instead (the fallback path, counted)."""
         dec = self._dec
-        impl = self.attn_impl
         mm = self.matmul_impl
         tp_ax = self._tp_ax
         ep_ax = self._ep_ax
@@ -1639,9 +1542,8 @@ class InferenceEngine:
                 self._compile_log.append("verify")
                 _TM_COMPILE_VERIFY.inc()
             return dec.verify_step_slots(params, aux, caches, state,
-                                         drafts, dlen, impl=impl,
-                                         tp=tp_ax, mm_impl=mm,
-                                         ep=ep_ax)
+                                         drafts, dlen, tp=tp_ax,
+                                         mm_impl=mm, ep=ep_ax)
 
         return verify
 
@@ -1652,7 +1554,6 @@ class InferenceEngine:
         (``Decoder.draft_propose_slots``)."""
         ddec = self._draft_dec
         k = self.spec_k
-        impl = self.attn_impl
         mm = self.matmul_impl
         tp_ax = self._tp_ax
 
@@ -1661,8 +1562,7 @@ class InferenceEngine:
                 self._compile_log.append("draft")
                 _TM_COMPILE_DRAFT.inc()
             return ddec.draft_propose_slots(params, aux, caches, pos,
-                                            catchup, clen, k,
-                                            impl=impl, tp=tp_ax,
+                                            catchup, clen, k, tp=tp_ax,
                                             mm_impl=mm, live=live)
 
         return draft
@@ -3622,7 +3522,6 @@ class InferenceEngine:
             "flight_recorder": self.flight.retain,
             "spec_k": self.spec_k,
             "draft": self.spec_draft,
-            "attn_impl": self.attn_impl,
             "tp": self.tp,
             "ep": self.ep,
             "weight_dtype": self.weight_dtype,
@@ -3658,6 +3557,9 @@ class InferenceEngine:
                 "InferenceEngine.restore: not an engine snapshot "
                 "(want the dict snapshot() returned)")
         cfg = dict(snap["engine"])
+        # a snapshot written by an older tree names the decode read
+        # it took; the read follows the cache kind now
+        cfg.pop("attn_impl", None)
         cfg["prefill_buckets"] = tuple(cfg["prefill_buckets"])
         cfg.update(overrides)
         # migration provenance: the successor's capture header names

@@ -5,8 +5,8 @@ Decode is memory-bound, and at serving batch sizes the WEIGHT stream —
 not the KV stream — dominates bytes per token: every matmul reads its
 full weight matrix once per fused step however many slots share it.
 Storing those weights int8 with per-output-channel f32 scales cuts the
-stream to 1 byte/elem (the int8-KV lesson of doc/serving.md "Paged
-attention", applied to the other half of the traffic).
+stream to 1 byte/elem (the int8-KV lesson of doc/serving.md "The
+decode read", applied to the other half of the traffic).
 
 Scheme — the same symmetric amax/127 discipline the int8 KV cache uses
 (``parallel/decode.py`` ``_quantize_rows``), one scale per OUTPUT

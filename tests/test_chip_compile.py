@@ -255,48 +255,6 @@ def test_rtc_user_kernel(compile_for_chip):
     compile_for_chip(rtc.apply, ((256, 512), F32))
 
 
-def test_fused_decode_refused_loudly(monkeypatch, one_chip):
-    """``fused_decode_attention`` does not compile for the chip, and
-    where kernels are compiled the engine says so at construction — by
-    the kernel's name and the compiler's reason — instead of giving
-    way to the unfused product."""
-    def lower():
-        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                for s, d in (((S, E), BF16), ((S,), I32),
-                             ((S, L, H * D), BF16), ((S, L, H * D), BF16),
-                             ((3 * E, E), I8), ((3 * E,), F32),
-                             ((3 * E,), F32), ((E, E), I8), ((E,), F32),
-                             ((E,), F32))]
-        return jax.jit(lambda *a: pk.fused_decode_attention(
-            *a, heads=H, kv_heads=H, bits=8, interpret=False)
-        ).lower(*args).compile()
-
-    with pytest.raises(Exception, match="last two dimensions"):
-        lower()
-
-    import mxnet_tpu as mx
-    from mxnet_tpu.models import get_transformer_lm
-    sym = get_transformer_lm(17, num_layers=1, embed_dim=16, num_heads=2,
-                             impl="dense")
-    shapes = {"data": (2, 8), "softmax_label": (2, 8)}
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    rng = np.random.RandomState(0)
-    params = {n: jnp.asarray(rng.uniform(-0.3, 0.3, s).astype(np.float32))
-              for n, s in zip(sym.list_arguments(), arg_shapes)
-              if n not in shapes}
-    dec = mx.parallel.Decoder(sym, params, max_len=8)
-    # as on the chip: kernels compiled, not interpreted
-    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
-    with pytest.raises(MXNetError,
-                       match="fused_decode_attention.*last two "
-                             "dimensions"):
-        mx.serving.InferenceEngine(dec, slots=2, weight_dtype="int8",
-                                   matmul_impl="fused")
-    with pytest.raises(MXNetError, match="fused_decode_attention"):
-        mx.parallel.Decoder(sym, params, max_len=8, weight_dtype="int8",
-                            matmul_impl="fused")
-
-
 @pytest.fixture(scope="module")
 def serve_chat_engine():
     """The benchmark's serve-chat engine at OPT-1.3B widths (hidden
@@ -317,7 +275,7 @@ def serve_chat_engine():
               if n not in shapes}
     params["pos_embed"] = jnp.zeros((2048, e), BF16)
     dec = mx.parallel.Decoder(sym, params, max_len=1024,
-                              compute_dtype="bfloat16", cache_block=None,
+                              compute_dtype="bfloat16",
                               weight_dtype="float")
     return mx.serving.InferenceEngine(
         dec, slots=16, prefill_buckets=(512, 768), steps_per_round=8,
@@ -341,7 +299,6 @@ def test_decode_program_holds_no_copy_of_the_cache(serve_chat_engine,
     sees here, the kernel would be traced for the interpreter, a
     ``while`` that stages whole cache buffers."""
     eng = serve_chat_engine
-    assert eng.attn_impl == "paged"
     monkeypatch.setattr(pk, "_use_interpret", lambda: False)
 
     def abstract(tree):
@@ -405,7 +362,7 @@ def test_zaya_decode_program(one_chip, monkeypatch):
               for n, s in zip(sym.list_arguments(), arg_shapes)
               if n not in shapes}
     dec = mx.parallel.Decoder(sym, params, max_len=2048,
-                              compute_dtype="bfloat16", cache_block=None)
+                              compute_dtype="bfloat16")
     eng = mx.serving.InferenceEngine(
         dec, slots=32, prefill_buckets=(128, 512), steps_per_round=8,
         prefix_cache_mb=0, prefill_chunk=0)

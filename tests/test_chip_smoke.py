@@ -203,22 +203,3 @@ def test_bench_caught_arm_fails_the_run(quant_ab, monkeypatch, capsys,
     else:
         assert extra["serving_weight_quant"] is None
         assert str(e.value).count(",") >= 15            # every guarded arm
-
-
-def test_bench_quant_probe_skips_fused_by_reason(monkeypatch):
-    """Where ``fused_decode_attention`` cannot run (the chip, the only
-    platform bench.py accepts) the probe's ``int4_fused`` arm is
-    skipped by that reason, its keys stay null and the other arms
-    still report."""
-    import bench
-    from mxnet_tpu.ops import pallas_kernels as pk
-    reason = "the compiler's reason"
-    monkeypatch.setattr(pk, "fused_decode_unsupported", lambda: reason)
-    out = bench.bench_serving_quant_bytes(
-        layers=1, embed=32, heads=4, vocab=61, max_len=32, slots=4)
-    assert 0 < out["weight_stream_ratio_int4"] \
-        < out["weight_stream_ratio_int8_pallas"] < 1
-    assert out["fp"]["forward_bytes"] > 0
-    assert out["int4_fused"] == {"skipped": reason}
-    assert out["fused_decode_dispatches"] is None
-    assert "weight_stream_ratio_int4_fused" not in out

@@ -193,70 +193,48 @@ def test_decode_step_prefill_bounds():
         dec.step(caches, -1, np.zeros((1,), np.int64))
 
 
-def test_decode_cache_block_matches_full_read():
-    """cache_block (prefix-bounded online-softmax reads) is a
-    reassociation of the same attention — step logits must agree with
-    the full-cache-read path and greedy generate must emit identical
-    tokens."""
+@pytest.mark.parametrize("max_len", [1024, 2000])
+def test_offline_step_long_cache_matches_full_forward(max_len):
+    """The offline step reads densely at every ``max_len`` (at 1024 the
+    old "auto" picked a blocked read, at 2000 it did not): its logits
+    match the full forward at positions on both sides of a 128-row
+    edge, early in the cache and late."""
     rng = np.random.RandomState(12)
-    T = 12
-    sym = _lm()
-    params = _init_params(sym, T, 2, rng)
-    full = Decoder(sym, params, max_len=T)
-    blocked = Decoder(sym, params, max_len=T, cache_block=4)
-
-    toks = rng.randint(0, VOCAB, (2, T))
-    cf, cb = full.init_cache(2), blocked.init_cache(2)
-    _, cf = full.prefill(cf, toks[:, :5])
-    _, cb = blocked.prefill(cb, toks[:, :5])
-    for pos in range(5, T):  # crosses 4-slot block boundaries at 8, 12
-        lf, cf = full.step(cf, pos, toks[:, pos])
-        lb, cb = blocked.step(cb, pos, toks[:, pos])
-        np.testing.assert_allclose(np.asarray(lb), np.asarray(lf),
-                                   rtol=2e-5, atol=2e-5)
-
-    prompt = rng.randint(0, VOCAB, (2, 3))
-    np.testing.assert_array_equal(
-        np.asarray(blocked.generate(prompt, num_steps=7)),
-        np.asarray(full.generate(prompt, num_steps=7)))
-
-    with pytest.raises(mx.MXNetError, match="cache_block"):
-        Decoder(sym, params, max_len=T, cache_block=5)  # not a divisor
-
-
-def test_decode_cache_block_auto_resolution():
-    """The "auto" default keeps the one-shot full read up to 512
-    slots, switches to 128-blocks beyond (the measured crossover), and
-    falls back to the exact full read when 128 does not divide
-    max_len. The auto-blocked decoder must emit the same greedy tokens
-    as an explicit full-read decoder."""
-    rng = np.random.RandomState(13)
-    T = 2048
     sym = get_transformer_lm(VOCAB, num_layers=1, embed_dim=EMBED,
                              num_heads=HEADS, impl="dense",
-                             seq_len=T)
+                             seq_len=max_len)
+    params = _init_params(sym, max_len, 1, rng)
+    dec = Decoder(sym, params, max_len=max_len)
+    toks = rng.randint(0, VOCAB, (1, max_len))
+    want = _full_logits(sym, params, toks)
+    late = (max_len - 8) // 128 * 128   # the last edge with room after
+    for start in (126, late - 2):
+        _, caches = dec.prefill(dec.init_cache(1), toks[:, :start])
+        for pos in range(start, start + 4):
+            logits, caches = dec.step(caches, pos, toks[:, pos])
+            np.testing.assert_allclose(np.asarray(logits), want[:, pos],
+                                       rtol=2e-5, atol=2e-5,
+                                       err_msg=str(pos))
+
+
+def test_decode_cache_block_keyword_refused():
+    """``cache_block`` is accepted as ``None`` only (the benchmark's
+    driver still passes it); any value names the removal."""
+    rng = np.random.RandomState(13)
+    T = 12
+    sym = _lm()
     params = _init_params(sym, T, 1, rng)
-
-    assert Decoder(sym, params, max_len=512)._cache_block is None
-    assert Decoder(sym, params, max_len=1024)._cache_block == 128
-    auto = Decoder(sym, params, max_len=2048)
-    assert auto._cache_block == 128          # beyond the crossover
-    assert Decoder(sym, params, max_len=2000)._cache_block is None
-
-    full = Decoder(sym, params, max_len=2048, cache_block=None)
-    prompt = rng.randint(0, VOCAB, (1, 3))
-    np.testing.assert_array_equal(
-        np.asarray(auto.generate(prompt, num_steps=5)),
-        np.asarray(full.generate(prompt, num_steps=5)))
+    assert Decoder(sym, params, max_len=T, cache_block=None).max_len == T
+    with pytest.raises(mx.MXNetError, match="cache_block=4.*removed"):
+        Decoder(sym, params, max_len=T, cache_block=4)
 
 
 def test_decode_int8_kv_cache():
     """cache_dtype="int8": per-(position, head)-row symmetric quantized
     K/V. Not exact, but the error is bounded by the row amax/254 per
     element, so step logits on this O(1)-logit model stay within a
-    small absolute band of the exact decoder — for both the full-read
-    and blocked-read paths — and generate/clone_cache compose with the
-    4-leaf cache entries."""
+    small absolute band of the exact decoder, and generate/clone_cache
+    compose with the 4-leaf cache entries."""
     rng = np.random.RandomState(21)
     T = 16
     sym = _lm()
@@ -264,18 +242,15 @@ def test_decode_int8_kv_cache():
 
     toks = rng.randint(0, VOCAB, (2, T))
     want = _full_logits(sym, params, toks)
-    for block in (None, 4):
-        q = Decoder(sym, params, max_len=T, cache_dtype="int8",
-                    cache_block=block)
-        caches = q.init_cache(2)
-        assert len(caches[0]) == 4 and caches[0][0].dtype == jnp.int8
-        got, caches = q.prefill(caches, toks[:, :8])
-        np.testing.assert_allclose(np.asarray(got), want[:, :8],
+    q = Decoder(sym, params, max_len=T, cache_dtype="int8")
+    caches = q.init_cache(2)
+    assert len(caches[0]) == 4 and caches[0][0].dtype == jnp.int8
+    got, caches = q.prefill(caches, toks[:, :8])
+    np.testing.assert_allclose(np.asarray(got), want[:, :8], atol=0.05)
+    for pos in range(8, T):
+        logits, caches = q.step(caches, pos, toks[:, pos])
+        np.testing.assert_allclose(np.asarray(logits), want[:, pos],
                                    atol=0.05)
-        for pos in range(8, T):
-            logits, caches = q.step(caches, pos, toks[:, pos])
-            np.testing.assert_allclose(np.asarray(logits), want[:, pos],
-                                       atol=0.05)
 
     dec = Decoder(sym, params, max_len=T, cache_dtype="int8")
     prompt = rng.randint(0, VOCAB, (2, 4))
@@ -302,8 +277,8 @@ def test_decode_int8_kv_cache():
 
 def _gqa_kv_cache_case(h, kv, extra, rng):
     """One grouped-query decode identity case: kv-head-sized cache,
-    logits vs the iterated full-forward oracle at every step, blocked
-    reads byte-equal, int8 prefill within tolerance."""
+    logits vs the iterated full-forward oracle at every step, int8
+    prefill within tolerance."""
     T = 12
     sym = get_transformer_lm(VOCAB, num_layers=2, embed_dim=EMBED,
                              num_heads=h, impl="dense",
@@ -323,14 +298,7 @@ def _gqa_kv_cache_case(h, kv, extra, rng):
         np.testing.assert_allclose(np.asarray(logits), want[:, pos],
                                    rtol=1e-5, atol=1e-5, err_msg=str(pos))
 
-    blocked = Decoder(sym, params, max_len=T, cache_block=4)
-    prompt = rng.randint(0, VOCAB, (2, 3))
-    np.testing.assert_array_equal(
-        np.asarray(blocked.generate(prompt, num_steps=7)),
-        np.asarray(dec.generate(prompt, num_steps=7)))
-
-    q8 = Decoder(sym, params, max_len=T, cache_dtype="int8",
-                 cache_block=4)
+    q8 = Decoder(sym, params, max_len=T, cache_dtype="int8")
     got8, _ = q8.prefill(q8.init_cache(2), toks[:, :6])
     np.testing.assert_allclose(np.asarray(got8), want[:, :6],
                                atol=0.05)

@@ -282,7 +282,7 @@ def test_long_context_generate():
 
     Slow sweep (tier-1 budget, PR 10): ~13s train+generate subprocess;
     KV-cache generate keeps dense tier-1 coverage in test_decode.py
-    (full-forward identity, cache_block, resume, sampling) and
+    (full-forward identity, resume, sampling) and
     end-to-end via the serving tests' offline oracles."""
     r = _run("long-context", "generate.py", "--batches", "60")
     assert r.returncode == 0, r.stderr[-2000:]

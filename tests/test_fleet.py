@@ -69,7 +69,7 @@ def lm():
 
 def _mkdec(lm):
     sym, params, _ = lm
-    return Decoder(sym, params, max_len=T, cache_block=None)
+    return Decoder(sym, params, max_len=T)
 
 
 def _mkeng(lm, **kw):

@@ -44,7 +44,7 @@ def lm():
 
 def _mkeng(lm):
     sym, params, _ = lm
-    dec = Decoder(sym, params, max_len=T, cache_block=None)
+    dec = Decoder(sym, params, max_len=T)
     return InferenceEngine(dec, slots=2, prefill_buckets=(4, 8),
                            prefix_cache_mb=0, max_queue=8)
 

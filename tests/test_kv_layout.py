@@ -24,7 +24,7 @@ def _decoder(h, kv, rng, **kw):
     params = {n: jnp.asarray(rng.uniform(-0.3, 0.3, s).astype(np.float32))
               for n, s in zip(sym.list_arguments(), arg_shapes)
               if n not in shapes}
-    return Decoder(sym, params, max_len=L, cache_block=None, **kw)
+    return Decoder(sym, params, max_len=L, **kw)
 
 
 def _reference(q, k, v, pos):

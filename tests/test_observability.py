@@ -574,7 +574,7 @@ def test_env_knob_lint_is_clean():
     """Every MXNET_* env var the package reads has a doc/env_var.md
     row and every documented knob is still read somewhere — the knob
     catalog can't rot either (ISSUE 13 satellite; the check found
-    MXNET_CONV_NHWC / MXNET_PAGED_BLOCK_K / MXNET_TPU_INIT_TIMEOUT
+    MXNET_CONV_NHWC / MXNET_TPU_INIT_TIMEOUT
     undocumented on arrival)."""
     from tools import lint_metrics
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

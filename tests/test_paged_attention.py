@@ -5,7 +5,9 @@ online-softmax accumulation, int8 dequantized IN the kernel from the
 side scales (the cache is read once at 1 byte/elem instead of being
 dequantized to a full float copy first).
 
-Identity contract (the dense path is the oracle): float flavors are
+Identity contract (the dense read of the offline step is the oracle:
+``Decoder._run`` at one scalar position, ``Decoder.generate``): float
+flavors are
 byte-identical at the TOKEN level through the engine gauntlet (greedy
 argmax — online softmax is a reassociation of the same f32 math);
 int8 flavors carry the quantized-cache tolerance contract of the
@@ -63,10 +65,9 @@ def paged_engine(lm):
     programs."""
     sym, params, _ = lm
     return InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None),
+        Decoder(sym, params, max_len=T),
         slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0.0021,
-        prefill_chunk=3, draft="ngram", spec_k=3, steps_per_round=2,
-        attn_impl="paged")
+        prefill_chunk=3, draft="ngram", spec_k=3, steps_per_round=2)
 
 
 @pytest.fixture(scope="module")
@@ -74,7 +75,7 @@ def int8_dec(lm):
     """ONE int8 decoder shared by the int8-tolerance and
     read-cache-clamp tests (compile frugality)."""
     sym, params, _ = lm
-    return Decoder(sym, params, max_len=T, cache_block=None,
+    return Decoder(sym, params, max_len=T,
                    cache_dtype="int8")
 
 
@@ -221,97 +222,148 @@ def test_bounded_read_matches_lane_attn(heads, chunk, cache):
     assert int(paged_rows_fetched(lens[:1], l_, bk)) == 0
 
 
+def _one_slot_walks(dec, caches, pos, tokens):
+    """The oracle of the slot walk, written here: one ``_run`` a slot
+    at ITS scalar position on that slot's rows (the dense read of the
+    offline step). Returns (logits [S, C, V], the slots' caches)."""
+    logits, subs = [], []
+    for s in range(tokens.shape[0]):
+        sub = Decoder.slot_slice(caches, jnp.int32(s))
+        lg, sub = dec._run(dec._params, dec._aux, sub, int(pos[s]),
+                           tokens[s:s + 1])
+        logits.append(np.asarray(lg)[0])
+        subs.append(sub)
+    return np.stack(logits), subs
+
+
 def test_run_slots_paged_matches_dense_mixed_positions(lm):
-    """Decoder level: ``_run_slots(impl="paged")`` (the batched walk +
-    kernel) against the dense vmap at mixed per-slot positions, decode
-    width AND verify width — logits match to f32 tolerance, argmax
-    exactly (greedy byte-identity's microscopic form). Composes with
-    rope via the GQA+rope symbol."""
+    """Decoder level: ``_run_slots`` (the batched walk + kernel)
+    against a loop of one-slot dense walks at mixed per-slot
+    positions, decode width AND verify width — logits match to f32
+    tolerance, argmax exactly (greedy byte-identity's microscopic
+    form). Composes with rope via the GQA+rope symbol."""
     rng = np.random.RandomState(3)
     sym = _lm(pos_encoding="rope", num_kv_heads=1)
     params = _init_params(sym, rng)
-    dec = Decoder(sym, params, max_len=T, cache_block=None)
+    dec = Decoder(sym, params, max_len=T)
     S = 3
     caches = dec.init_cache(S)
-    # fill every slot with the same 8-token prefix (one dense compile),
-    # then step at MIXED per-slot positions so the paged block bound
-    # differs per lane
+    # fill every slot with the same 8-token prefix (one compile), then
+    # step at MIXED per-slot positions so the block bound differs per
+    # lane
     toks = jnp.asarray(rng.randint(0, VOCAB, (S, 8)), jnp.int32)
-    fill = jax.jit(lambda c, t: dec._run_slots(
-        dec._params, dec._aux, c, jnp.zeros((S,), jnp.int32), t))
-    _, caches = fill(caches, toks)
+    walk = jax.jit(lambda c, p, t: dec._run_slots(
+        dec._params, dec._aux, c, p, t))
+    _, caches = walk(caches, jnp.zeros((S,), jnp.int32), toks)
     pos = jnp.asarray([4, 2, 7], jnp.int32)
     step = jnp.asarray(rng.randint(0, VOCAB, (S, 1)), jnp.int32)
-    dense = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t, impl="dense"))
-    paged = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t, impl="paged"))
-    ld, cd = dense(Decoder.clone_cache(caches), pos, step)
-    lp, cp = paged(Decoder.clone_cache(caches), pos, step)
-    np.testing.assert_allclose(np.asarray(ld), np.asarray(lp),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(ld).argmax(-1),
+    ld, subs = _one_slot_walks(dec, caches, pos, step)
+    lp, cp = walk(Decoder.clone_cache(caches), pos, step)
+    np.testing.assert_allclose(ld, np.asarray(lp), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(ld.argmax(-1),
                                   np.asarray(lp).argmax(-1))
-    # the caches written by both impls are identical (same write math)
-    for a, b in zip(jax.tree_util.tree_leaves(cd),
-                    jax.tree_util.tree_leaves(cp)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
+    # the rows written by both walks are identical (same write math)
+    for s_, sub in enumerate(subs):
+        for a, b in zip(jax.tree_util.tree_leaves(sub),
+                        jax.tree_util.tree_leaves(cp)):
+            np.testing.assert_allclose(np.asarray(a)[0], np.asarray(b)[s_],
+                                       rtol=1e-6, atol=1e-6)
     # verify-width chunk [S, 3] at mixed positions
     chunk = jnp.asarray(rng.randint(0, VOCAB, (S, 3)), jnp.int32)
-    densec = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t, impl="dense"))
-    pagedc = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t, impl="paged"))
-    ldc, _ = densec(Decoder.clone_cache(caches), pos, chunk)
-    lpc, _ = pagedc(Decoder.clone_cache(caches), pos, chunk)
-    np.testing.assert_allclose(np.asarray(ldc), np.asarray(lpc),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(ldc).argmax(-1),
+    ldc, _ = _one_slot_walks(dec, caches, pos, chunk)
+    lpc, _ = walk(Decoder.clone_cache(caches), pos, chunk)
+    np.testing.assert_allclose(ldc, np.asarray(lpc), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(ldc.argmax(-1),
                                   np.asarray(lpc).argmax(-1))
 
 
 def test_run_slots_paged_int8_tolerance(int8_dec):
-    """int8 flavor at the decoder level: the paged kernel dequantizes
-    in-kernel from the side scales; logits match the dense
-    dequantize-first read within the quantized-cache tolerance (the
-    arithmetic is the same dequant — only the materialization
-    differs), argmax exactly on this config."""
+    """int8 flavor at the decoder level: the kernel applies the side
+    scales in-kernel; logits match the one-slot dense walks (the same
+    scales on scores and weights) within the quantized-cache
+    tolerance, argmax exactly on this config."""
     dec = int8_dec
     S = 2
     rng = np.random.RandomState(5)
     caches = dec.init_cache(S)
     toks = jnp.asarray(rng.randint(0, VOCAB, (S, 6)), jnp.int32)
-    fill = jax.jit(lambda c, t: dec._run_slots(
-        dec._params, dec._aux, c, jnp.zeros((S,), jnp.int32), t))
-    _, caches = fill(caches, toks)
+    walk = jax.jit(lambda c, p, t: dec._run_slots(
+        dec._params, dec._aux, c, p, t))
+    _, caches = walk(caches, jnp.zeros((S,), jnp.int32), toks)
     pos = jnp.asarray([3, 5], jnp.int32)
     step = jnp.asarray(rng.randint(0, VOCAB, (S, 1)), jnp.int32)
-    ld, _ = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t, impl="dense"))(
-        Decoder.clone_cache(caches), pos, step)
-    lp, _ = jax.jit(lambda c, p, t: dec._run_slots(
-        dec._params, dec._aux, c, p, t, impl="paged"))(
-        Decoder.clone_cache(caches), pos, step)
-    np.testing.assert_allclose(np.asarray(ld), np.asarray(lp),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(ld).argmax(-1),
+    ld, _ = _one_slot_walks(dec, caches, pos, step)
+    lp, _ = walk(Decoder.clone_cache(caches), pos, step)
+    np.testing.assert_allclose(ld, np.asarray(lp), rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(ld.argmax(-1),
                                   np.asarray(lp).argmax(-1))
+
+
+# -- the read follows the cache kind ------------------------------------
+
+def _kind_decoder(kind):
+    """(decoder, whether its slot walk takes the bounded read)."""
+    rng = np.random.RandomState(31)
+    if kind == "cca":
+        from mxnet_tpu.models import get_zaya_lm
+        sym = get_zaya_lm(VOCAB, 1, 16, 4, 2, 8, 2, 16, 8, rotary_dim=4,
+                          impl="dense")
+        return Decoder(sym, _init_params(sym, rng), max_len=T), False
+    lm_kw, dec_kw = {
+        "linear": ({}, {}),
+        "gqa_rope": (dict(pos_encoding="rope", num_kv_heads=1), {}),
+        "int8_kv": ({}, dict(cache_dtype="int8")),
+        "ring": (dict(window=6, pos_encoding="rope"), {}),
+        "moe": (dict(num_experts=2, moe_top_k=1), {}),
+    }[kind]
+    sym = _lm(**lm_kw)
+    return (Decoder(sym, _init_params(sym, rng), max_len=T, **dec_kw),
+            kind != "ring")
+
+
+@pytest.mark.parametrize("kind", ["linear", "gqa_rope", "int8_kv", "ring",
+                                  "cca", "moe"])
+def test_read_follows_the_cache_kind(kind):
+    """The table above ``Decoder._cached_mha``, by what is traced: the
+    bounded read (a ``pallas_call``) is in the slot walk exactly where
+    the cached nodes are MultiHeadAttention over a linear cache, and
+    never in the offline step. No option chooses."""
+    dec, bounded = _kind_decoder(kind)
+    S = 2
+    i32 = jnp.int32
+    walk = jax.make_jaxpr(lambda c, p, t: dec._run_slots(
+        dec._params, dec._aux, c, p, t))(
+        dec.init_cache(S), jnp.zeros((S,), i32), jnp.zeros((S, 1), i32))
+    assert ("pallas_call" in str(walk)) == bounded
+    offline = jax.make_jaxpr(lambda c, p, t: dec._run(
+        dec._params, dec._aux, c, p, t))(
+        dec.init_cache(S), i32(3), jnp.zeros((S, 1), i32))
+    assert "pallas_call" not in str(offline)
+
+
+@pytest.mark.parametrize("rows,row_bytes,want", [
+    (1024, 4096, 256), (2048, 512, 512), (1000, 4096, 8),
+    (1001, 4096, 1001)])
+def test_default_paged_block_k_from_shapes(rows, row_bytes, want):
+    """The block is a pure function of the stored shapes: the largest
+    listed divisor of the rows within 1 MB a block, else the rows."""
+    from mxnet_tpu.ops.pallas_kernels import default_paged_block_k
+    assert default_paged_block_k(rows, row_bytes) == want
 
 
 # -- the engine gauntlet ----------------------------------------------
 
 def test_engine_paged_identity_gauntlet(lm, paged_engine):
-    """Greedy serving outputs byte-identical between attn_impl="paged"
-    and the dense oracle (the offline decoder = every dense engine's
-    pinned output) across the identity gauntlet: prefix-cache hits +
+    """Greedy serving outputs byte-identical between the engine (the
+    bounded read) and the offline ``Decoder.generate``, which reads
+    densely, across the identity gauntlet: prefix-cache hits +
     eviction, chunked prefill, speculation on (the accepting prompt),
     steps_per_round>1, mixed admission — and the compile contract is
     unchanged."""
     sym, params, dec = lm
     rng = np.random.RandomState(13)
     eng = paged_engine
-    assert eng.attn_impl == "paged"
+    assert eng._attn_pool_rows                # the read is bounded
     base = rng.randint(0, VOCAB, (7,))
     cases = {
         "miss_long": (base, 3),
@@ -333,15 +385,15 @@ def test_engine_paged_identity_gauntlet(lm, paged_engine):
     assert eng.stats["prefill_chunks"] > len(cases)
     assert eng.stats["spec_rounds"] >= 1
     assert eng.stats["spec_accepted"] >= 1
-    # the info gauge names the active impl (doc/observability.md)
-    assert mx.telemetry.snapshot()["serving"]["attn_impl"] == 1
     assert eng.idle
 
 
-def test_engine_paged_snapshot_restore_carries_impl(lm, paged_engine):
-    """snapshot() carries attn_impl; restore() rebuilds a PAGED engine
-    and continues byte-identically (mid-flight crash point, prefix
-    cache + chunking + speculation still on)."""
+def test_engine_restores_a_snapshot_with_a_stale_attn_impl_key(
+        lm, paged_engine):
+    """A snapshot written by an older tree names the read it took
+    (``"attn_impl"``): restore() reads the key and ignores it, and
+    continues byte-identically (mid-flight crash point, prefix cache +
+    chunking + speculation still on)."""
     sym, params, dec = lm
     rng = np.random.RandomState(17)
     eng = paged_engine
@@ -352,9 +404,9 @@ def test_engine_paged_snapshot_restore_carries_impl(lm, paged_engine):
     for _ in range(3):
         eng.step()                       # mid-flight
     snap = eng.snapshot()
-    assert snap["engine"]["attn_impl"] == "paged"
+    assert "attn_impl" not in snap["engine"]
+    snap["engine"]["attn_impl"] = "dense"
     eng2, handles = InferenceEngine.restore(snap, eng._dec)
-    assert eng2.attn_impl == "paged"
     eng2.serve_forever()
     np.testing.assert_array_equal(handles[r1.id].result(),
                                   _oracle(dec, p1, 6))
@@ -365,56 +417,23 @@ def test_engine_paged_snapshot_restore_carries_impl(lm, paged_engine):
     assert eng.idle
 
 
-def test_engine_paged_windowed_warns_and_serves_dense(lm):
-    """Ring flavor: the paged kernel addresses rows by absolute
-    position — a windowed RING stores wrapped rows, so exactness
-    cannot be held and the engine refuses LOUDLY (UserWarning, the
-    speculation/prefix-cache precedent) and serves with the exact
-    dense ring walk instead. Construction compiles nothing, so this
-    costs no programs; windowed dense identity itself is pinned by
-    test_serving's flavor test."""
+def test_engine_windowed_ring_takes_its_walk(lm, recwarn):
+    """Ring flavor: a windowed RING stores rows at wrapped positions,
+    so the bounded read has no meaning there. The decoder and the
+    engine build without a warning, the slot walk is the ring's own,
+    and the engine matches the offline oracle."""
     rng = np.random.RandomState(19)
     sym = _lm(window=6, pos_encoding="rope")
     params = _init_params(sym, rng)
-    with pytest.warns(UserWarning, match="paged"):
-        dec = Decoder(sym, params, max_len=T, cache_block=None,
-                      attn_impl="paged")
-    assert dec._attn_impl == "dense"     # fell back, loudly
-    with pytest.warns(UserWarning, match="paged"):
-        eng = InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None),
-            slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0,
-            attn_impl="paged")
-    assert eng.attn_impl == "dense"
-
-
-def test_offline_paged_decoder_generate_identity(lm):
-    """Decoder(attn_impl="paged") offline: generate() byte-matches the
-    dense decoder (the module oracle), prompt prefill included —
-    bench_decode's paged arm rides exactly this path. Also pins the
-    knob validation: bad impl name, cache_block conflict."""
-    sym, params, dec = lm
-    rng = np.random.RandomState(23)
-    dp = Decoder(sym, params, max_len=T, cache_block=None,
-                 attn_impl="paged")
-    p = rng.randint(0, VOCAB, (4,))
-    got = np.asarray(dp.generate(p[None], num_steps=6))[0, 4:]
-    np.testing.assert_array_equal(got, _oracle(dec, p, 6))
-    with pytest.raises(MXNetError, match="attn_impl"):
-        Decoder(sym, params, max_len=T, attn_impl="blocked")
-    with pytest.raises(MXNetError, match="cache_block"):
-        Decoder(sym, params, max_len=T, cache_block=8,
-                attn_impl="paged")
-    # a paged decoder refuses an explicit dense _run_slots request
-    # (silently serving paged would contradict the caller)
-    with pytest.raises(MXNetError, match="dense"):
-        dp._run_slots(dp._params, dp._aux, dp.init_cache(1),
-                      jnp.zeros((1,), jnp.int32),
-                      jnp.zeros((1, 1), jnp.int32), impl="dense")
-    with pytest.raises(MXNetError, match="attn_impl"):
-        InferenceEngine(Decoder(sym, params, max_len=T,
-                                cache_block=None),
-                        slots=2, attn_impl="bogus")
+    dec = Decoder(sym, params, max_len=T)
+    eng = InferenceEngine(dec, slots=2, prefill_buckets=(4, 8),
+                          prefix_cache_mb=0)
+    assert not recwarn.list
+    assert not dec._slots_batched and not eng._attn_pool_rows
+    p = rng.randint(0, VOCAB, (5,))
+    r = eng.submit(p, max_tokens=8)
+    eng.serve_forever()
+    np.testing.assert_array_equal(r.result(), _oracle(dec, p, 8))
 
 
 # -- satellite: dense _read_cache clamp --------------------------------

@@ -16,10 +16,7 @@ even-element and an odd-element product — the chip's compiler cannot
 afford the lane re-interleave — where the fori form runs one dot.
 What ``matmul_impl="pallas"`` is held to end to end is the serving
 engine's token-level gauntlet (tests/test_serving_quant.py); this
-file pins the kernel side, zero engine compiles. The fused decode
-kernel is pinned against a composed fp reference — its plain-softmax
-attention is token-stable, not bitwise, vs the unfused path (why
-"fused" is its own knob value).
+file pins the kernel side, zero engine compiles.
 """
 import os
 
@@ -31,8 +28,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import pallas_kernels as pk
-from mxnet_tpu.parallel.decode import fold_heads
-from mxnet_tpu.serving.quant import (dequantize, pack_int4,
+from mxnet_tpu.serving.quant import (pack_int4,
                                      quantize_tensor, resolve_chunk,
                                      scale_fused_matmul, unpack_int4)
 
@@ -114,9 +110,6 @@ def test_int4_pack_unpack_bitwise():
     assert packed.shape == (4, 8) and packed.dtype == jnp.uint8
     back = unpack_int4(packed)
     np.testing.assert_array_equal(np.asarray(back), vals)
-    in_kernel = pk._unpack4_block(packed)
-    np.testing.assert_array_equal(np.asarray(in_kernel),
-                                  vals.astype(np.float32))
     lo, hi = pk._unpack4_halves(packed)
     np.testing.assert_array_equal(np.asarray(lo), vals[:, 0::2])
     np.testing.assert_array_equal(np.asarray(hi), vals[:, 1::2])
@@ -194,102 +187,3 @@ def test_quant_chunk_env_knob():
             del os.environ["MXNET_QUANT_CHUNK"]
         else:
             os.environ["MXNET_QUANT_CHUNK"] = old
-
-
-# -- fused decode kernel vs a composed fp reference -------------------
-
-def _rot(v, cs, sn):
-    half = v.shape[-1] // 2
-    x1, x2 = v[..., :half], v[..., half:]
-    return np.concatenate([x1 * cs - x2 * sn, x2 * cs + x1 * sn],
-                          axis=-1)
-
-
-def _fused_ref(x, pos, kc, vc, wqkv, bqkv, wo, bo, heads, kv, rope,
-               rope_base=10000.0):
-    """Slot-by-slot numpy reference: QKV proj -> rope -> masked
-    attention over live rows + the in-register current token ->
-    out proj. Mirrors the kernel's kv-major head fold."""
-    s_, e = x.shape
-    l_ = kc.shape[1]
-    d = kc.shape[3]
-    g = heads // kv
-    half = d // 2
-    scale = 1.0 / np.sqrt(d)
-    outs, kns, vns = [], [], []
-    for i in range(s_):
-        p = int(pos[i])
-        qkv = x[i] @ wqkv.T + bqkv
-        qh = qkv[:heads * d].reshape(kv, g, d)
-        kh = qkv[heads * d:(heads + kv) * d].reshape(kv, d)
-        vh = qkv[(heads + kv) * d:].reshape(kv, d)
-        if rope:
-            freq = rope_base ** (-np.arange(half, dtype=np.float32)
-                                 / half)
-            cs, sn = np.cos(p * freq), np.sin(p * freq)
-            qh, kh = _rot(qh, cs, sn), _rot(kh, cs, sn)
-        sc = np.einsum("kgd,lkd->kgl", qh, kc[i]) * scale
-        sc = np.where(np.arange(l_)[None, None, :] < p, sc, -1e30)
-        s_new = np.einsum("kgd,kd->kg", qh, kh)[..., None] * scale
-        allsc = np.concatenate([sc, s_new], axis=-1)
-        w = np.exp(allsc - allsc.max(-1, keepdims=True))
-        w /= w.sum(-1, keepdims=True)
-        o = np.einsum("kgl,lkd->kgd", w[..., :l_], vc[i]) \
-            + w[..., l_:] * vh[:, None, :]
-        o = o.reshape(heads * d)
-        outs.append(o @ wo.T + bo)
-        kns.append(kh)
-        vns.append(vh)
-    return np.stack(outs), np.stack(kns), np.stack(vns)
-
-
-@pytest.mark.parametrize("bits,rope", [(8, True), (8, False),
-                                       (4, True)])
-def test_fused_decode_attention_vs_composed(bits, rope):
-    rng = np.random.RandomState(6)
-    heads, kv, d, l_, s_ = 4, 2, 8, 8, 2
-    e = heads * d
-    fq = (heads + 2 * kv) * d
-    group = 8 if bits == 4 else None
-    wq = quantize_tensor(
-        jnp.asarray(rng.randn(fq, e).astype(np.float32) * 0.2),
-        bits=bits, group=group)
-    wo = quantize_tensor(
-        jnp.asarray(rng.randn(e, e).astype(np.float32) * 0.2),
-        bits=bits, group=group)
-    bq = rng.randn(fq).astype(np.float32) * 0.1
-    bo = rng.randn(e).astype(np.float32) * 0.1
-    x = rng.randn(s_, e).astype(np.float32)
-    kc = rng.randn(s_, l_, kv, d).astype(np.float32)
-    vc = rng.randn(s_, l_, kv, d).astype(np.float32)
-    pos = np.array([3, 7], np.int32)
-    out, kn, vn = pk.fused_decode_attention(
-        jnp.asarray(x), jnp.asarray(pos),
-        fold_heads(jnp.asarray(kc)), fold_heads(jnp.asarray(vc)),
-        wq.q, wq.scale, jnp.asarray(bq), wo.q,
-        wo.scale, jnp.asarray(bo), heads=heads, kv_heads=kv,
-        bits=bits, group=group, rope=rope)
-    ro, rk, rv = _fused_ref(x, pos, kc, vc,
-                            np.asarray(dequantize(wq)), bq,
-                            np.asarray(dequantize(wo)), bo,
-                            heads, kv, rope)
-    np.testing.assert_allclose(np.asarray(out), ro, rtol=2e-5,
-                               atol=2e-5)
-    np.testing.assert_allclose(np.asarray(kn), rk, rtol=2e-5,
-                               atol=2e-5)
-    np.testing.assert_allclose(np.asarray(vn), rv, rtol=2e-5,
-                               atol=2e-5)
-
-
-def test_dispatch_counter():
-    """Every public kernel entry bumps the trace-time dispatch
-    counter — the bench's fused-vs-pallas dispatch cut reads it."""
-    rng = np.random.RandomState(7)
-    qt = _qt(rng, 16, 8)
-    x = jnp.asarray(rng.randn(2, 8).astype(np.float32))
-    pk.reset_dispatch_count()
-    pk.quant_matmul(x, qt.q, qt.scale, bits=8)
-    pk.quant_matmul(x, qt.q, qt.scale, bits=8)
-    assert pk.dispatch_count() == 2
-    pk.reset_dispatch_count()
-    assert pk.dispatch_count() == 0

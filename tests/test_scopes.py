@@ -113,7 +113,7 @@ def toy_engine():
         mesh=mx.parallel.data_parallel_mesh(1))
     tr.init_params()
     params = {k: np.asarray(v) for k, v in tr.params.items()}
-    dec = mx.parallel.Decoder(sym, params, max_len=32, cache_block=None)
+    dec = mx.parallel.Decoder(sym, params, max_len=32)
     eng = mx.serving.InferenceEngine(dec, slots=2, prefill_buckets=(8,),
                                      steps_per_round=2, prefix_cache_mb=0)
     eng.submit(np.arange(5), max_tokens=4)

@@ -80,8 +80,7 @@ def _engine(sym, params, **kw):
     # speculation byte-identity pin
     kw.setdefault("draft", "ngram")
     kw.setdefault("spec_k", 3)
-    return InferenceEngine(Decoder(sym, params, max_len=T,
-                                   cache_block=None), **kw)
+    return InferenceEngine(Decoder(sym, params, max_len=T), **kw)
 
 
 @pytest.fixture(scope="module")
@@ -227,9 +226,9 @@ def test_engine_dead_slot_reads_no_row(lm, monkeypatch):
     """The decode step reads only the rows its live requests hold
     (``len = live ? pos + 1 : 0``, made in the step program). A slot
     finishes MID-round, stays dead for rounds beside a live one, and
-    is reused by a shorter request: every token equals the dense
-    walk's (the read every row of every slot is masked by) and the
-    offline oracle's. Once the new tenant decodes, the previous
+    is reused by a shorter request: every token equals the offline
+    ``Decoder.generate``'s, which reads densely (every row masked by
+    position). Once the new tenant decodes, the previous
     tenant's stale rows past its block are poisoned with NaN: none
     reaches a score. Then the step program itself, on a state made by
     hand: the rows a step fetches are the live slot's length in whole
@@ -237,13 +236,15 @@ def test_engine_dead_slot_reads_no_row(lm, monkeypatch):
     telemetry counters carry the same count."""
     sym, params, dec = lm
     # two blocks of 8 rows a slot, so a length bounds the read at toy
-    # size too (the kernel's own default would take all 16 rows as one)
-    monkeypatch.setenv("MXNET_PAGED_BLOCK_K", "8")
+    # size too (within the kernel's own 1 MB a block all 16 rows are
+    # one): the cap set to 8 of this cache's float32 rows
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_PAGED_BLOCK_BYTES", 8 * EMBED * 4)
     rng = np.random.RandomState(41)
     pa, pb, pc = (rng.randint(0, VOCAB, (n,)) for n in (8, 2, 2))
 
-    def run(poison, **kw):
-        eng = _engine(sym, params, steps_per_round=2, draft=None, **kw)
+    def run():
+        eng = _engine(sym, params, steps_per_round=2, draft=None)
         ra = eng.submit(pa, max_tokens=5)    # 8 + 5 rows: both blocks,
         rb = eng.submit(pb, max_tokens=13)   # done in the middle of a
         while not ra.done:                   # round of 2 steps
@@ -254,10 +255,9 @@ def test_engine_dead_slot_reads_no_row(lm, monkeypatch):
         assert not rb.done
         rc = eng.submit(pc, max_tokens=5)    # 2 + 5 rows: block 0 only
         eng.step()                           # its prefill, densely read
-        if poison:
-            eng._caches = [
-                tuple(buf.at[1 - slot_b, 8:].set(jnp.nan) for buf in e)
-                for e in eng._caches]
+        eng._caches = [
+            tuple(buf.at[1 - slot_b, 8:].set(jnp.nan) for buf in e)
+            for e in eng._caches]
         eng.serve_forever()
         assert eng.idle
         return eng, [r.result() for r in (ra, rb, rc)]
@@ -265,12 +265,8 @@ def test_engine_dead_slot_reads_no_row(lm, monkeypatch):
     read = mx.telemetry.counter("serving.attn_rows_read")
     pool = mx.telemetry.counter("serving.attn_rows_pool")
     before = read.value, pool.value
-    _, want = run(False, attn_impl="dense")
-    assert (read.value, pool.value) == before   # a dense read counts
-    eng, got = run(True)                        # nothing
-    assert eng.attn_impl == "paged"             # the shipped default
-    for g, w, (p, n) in zip(got, want, [(pa, 5), (pb, 13), (pc, 5)]):
-        np.testing.assert_array_equal(g, w)
+    eng, got = run()
+    for g, (p, n) in zip(got, [(pa, 5), (pb, 13), (pc, 5)]):
         np.testing.assert_array_equal(g, _oracle(dec, p, n))
     rows = read.value - before[0], pool.value - before[1]
     assert rows[1] == 2 * T * LAYERS * eng.stats["steps"] * 2
@@ -393,7 +389,7 @@ def test_engine_cache_flavors_match_offline(flavor):
     else:
         sym, deckw = _lm(window=6, pos_encoding="rope"), {}
     params = _init_params(sym, rng)
-    dec = Decoder(sym, params, max_len=T, cache_block=None, **deckw)
+    dec = Decoder(sym, params, max_len=T, **deckw)
     # speculation requested on BOTH flavors: int8 verifies through the
     # quantized cache; the windowed model must refuse LOUDLY (the
     # verify chunk would wrap rejected drafts onto live ring rows —
@@ -402,7 +398,7 @@ def test_engine_cache_flavors_match_offline(flavor):
            if flavor == "window" else _noop_ctx())
     with ctx:
         eng = InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None, **deckw),
+            Decoder(sym, params, max_len=T, **deckw),
             slots=2, prefill_buckets=(4, 8),
             prefix_cache_mb=0.01, prefill_chunk=4,
             spec_k=3, draft="ngram")
@@ -442,8 +438,7 @@ def test_engine_draft_model_speculation(lm):
     sym, params, dec = lm
     rng = np.random.RandomState(21)
     eng = _engine(sym, params, draft="model",
-                  draft_decoder=Decoder(sym, params, max_len=T,
-                                        cache_block=None))
+                  draft_decoder=Decoder(sym, params, max_len=T))
     cases = [(rng.randint(0, VOCAB, (2,)), 5),
              (rng.randint(0, VOCAB, (4,)), 6),
              (rng.randint(0, VOCAB, (7,)), 3),
@@ -572,7 +567,7 @@ def test_window_prefill_pad_rows_do_not_corrupt_ring():
     win = 4
     sym = _lm(window=win, pos_encoding="rope")
     params = _init_params(sym, rng)
-    dec = Decoder(sym, params, max_len=T, cache_block=None)
+    dec = Decoder(sym, params, max_len=T)
     P, L = 6, 8                   # 2 pad rows; win < P: both modes bite
     toks = rng.randint(0, VOCAB, (1, P)).astype(np.int32)
     padded = np.zeros((1, L), np.int32)
@@ -723,8 +718,6 @@ def test_engine_validation(lm, shared_engine):
     eng = shared_engine
     with pytest.raises(MXNetError, match="needs a Decoder"):
         InferenceEngine(object())
-    with pytest.raises(MXNetError, match="cache_block"):
-        InferenceEngine(Decoder(sym, params, max_len=T, cache_block=8))
     with pytest.raises(MXNetError, match="ascending"):
         _engine(sym, params, prefill_buckets=(8, 4))
     with pytest.raises(MXNetError, match="empty prompt"):

@@ -74,7 +74,7 @@ def qdec(lm):
     quantization — generate() runs the quantized numerics the engine
     must reproduce byte-identically."""
     sym, params, _ = lm
-    return Decoder(sym, params, max_len=T, cache_block=None,
+    return Decoder(sym, params, max_len=T,
                    weight_dtype="int8")
 
 
@@ -88,7 +88,7 @@ def quant_engine(lm):
     oracle."""
     sym, params, _ = lm
     return InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None),
+        Decoder(sym, params, max_len=T),
         slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0.0021,
         prefill_chunk=3, draft="ngram", spec_k=3, steps_per_round=2,
         weight_dtype="int8")
@@ -265,7 +265,7 @@ def test_quant_tp2_byte_identical_int8_kv(lm, qdec):
 
     def mkeng(**kw):
         return InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None,
+            Decoder(sym, params, max_len=T,
                     cache_dtype="int8"),
             slots=2, prefill_buckets=(4,), prefix_cache_mb=0,
             weight_dtype="int8", **kw)
@@ -300,9 +300,9 @@ def test_quant_draft_model_engine(lm, qdec):
     draft), and outputs stay byte-identical to the quantized offline
     oracle. Draft program families join the compile contract."""
     sym, params, _ = lm
-    draft = Decoder(sym, params, max_len=T, cache_block=None)
+    draft = Decoder(sym, params, max_len=T)
     eng = InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None),
+        Decoder(sym, params, max_len=T),
         slots=2, prefill_buckets=(4,), prefix_cache_mb=0,
         draft="model", spec_k=3, draft_decoder=draft,
         weight_dtype="int8")
@@ -332,7 +332,7 @@ def test_quant_moe_decode_matches_fp(lm):
                              num_experts=3, moe_top_k=2)
     params = _init_params(sym, rng)
     dec = Decoder(sym, params, max_len=T)
-    dq = Decoder(sym, params, max_len=T, cache_block=None,
+    dq = Decoder(sym, params, max_len=T,
                  weight_dtype="int8")
     assert isinstance(dq._params["layer0_expert_w2"], QuantizedTensor)
     p = rng.randint(0, VOCAB, (4,))
@@ -357,11 +357,10 @@ def test_quant_validation_and_env_default(lm):
     with pytest.raises(MXNetError, match="weight_dtype"):
         Decoder(sym, params, max_len=T, weight_dtype="int2")
     with pytest.raises(MXNetError, match="weight_dtype"):
-        InferenceEngine(Decoder(sym, params, max_len=T,
-                                cache_block=None),
+        InferenceEngine(Decoder(sym, params, max_len=T),
                         slots=2, prefill_buckets=(4,),
                         prefix_cache_mb=0, weight_dtype="fp8")
-    qd = Decoder(sym, params, max_len=T, cache_block=None,
+    qd = Decoder(sym, params, max_len=T,
                  weight_dtype="int8")
     with pytest.raises(MXNetError, match="float weights are gone"):
         InferenceEngine(qd, slots=2, prefill_buckets=(4,),
@@ -374,7 +373,7 @@ def test_quant_validation_and_env_default(lm):
     old = os.environ.get("MXNET_SERVING_WEIGHT_DTYPE")
     os.environ["MXNET_SERVING_WEIGHT_DTYPE"] = "int8"
     try:
-        d = Decoder(sym, params, max_len=T, cache_block=None)
+        d = Decoder(sym, params, max_len=T)
         assert d.weight_dtype == "int8"
         assert isinstance(d._params["lm_head_weight"], QuantizedTensor)
         e = InferenceEngine(d, slots=2, prefill_buckets=(4,),
@@ -400,7 +399,7 @@ def test_engine_pallas_byte_identical(lm, qdec, quant_engine):
     the matmul_impl gauge and geometry carry the knob."""
     sym, params, dec = lm
     eng = InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None),
+        Decoder(sym, params, max_len=T),
         slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0.0021,
         prefill_chunk=3, draft="ngram", spec_k=3, steps_per_round=2,
         weight_dtype="int8", matmul_impl="pallas")
@@ -422,53 +421,19 @@ def test_engine_pallas_byte_identical(lm, qdec, quant_engine):
     assert eng._geometry()["matmul_impl"] == "pallas"
     # knob validation + env default, compile-free
     with pytest.raises(MXNetError, match="matmul_impl"):
-        InferenceEngine(Decoder(sym, params, max_len=T,
-                                cache_block=None),
+        InferenceEngine(Decoder(sym, params, max_len=T),
                         slots=2, prefill_buckets=(4,),
                         prefix_cache_mb=0, matmul_impl="triton")
     old = os.environ.get("MXNET_SERVING_MATMUL_IMPL")
     os.environ["MXNET_SERVING_MATMUL_IMPL"] = "pallas"
     try:
-        d = Decoder(sym, params, max_len=T, cache_block=None)
+        d = Decoder(sym, params, max_len=T)
         assert d._matmul_impl == "pallas"
     finally:
         if old is None:
             del os.environ["MXNET_SERVING_MATMUL_IMPL"]
         else:
             os.environ["MXNET_SERVING_MATMUL_IMPL"] = old
-
-
-def test_engine_fused_decode_token_equal(lm, qdec):
-    """matmul_impl="fused" on the paged path (the one-dispatch
-    QKV->attention->out-proj decode kernel): token-equal to the
-    pallas engine on the same stream. Fused is token-stable, NOT
-    bitwise — its plain-softmax attention blocks the contraction
-    differently — which is exactly why it is a distinct knob value
-    instead of an automatic upgrade of "pallas". Compile contract
-    holds per arm (the fused chain replaces dispatches, it never adds
-    program families)."""
-    sym, params, _ = lm
-
-    def mkeng(mi):
-        return InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None),
-            slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0,
-            attn_impl="paged", weight_dtype="int8", matmul_impl=mi)
-
-    ep, ef = mkeng("pallas"), mkeng("fused")
-    rng = np.random.RandomState(23)
-    cases = [(rng.randint(0, VOCAB, (pl,)), n)
-             for pl, n in [(3, 6), (5, 5), (2, 4)]]
-    rp = [ep.submit(p, max_tokens=n) for p, n in cases]
-    rf = [ef.submit(p, max_tokens=n) for p, n in cases]
-    ep.serve_forever()
-    ef.serve_forever()
-    for a, b in zip(rp, rf):
-        np.testing.assert_array_equal(a.result(), b.result())
-    assert_compile_contract(ep, copy={})
-    assert_compile_contract(ef, copy={})
-    assert mx.telemetry.snapshot()["serving"]["matmul_impl"] == 2
-    assert ef._geometry()["matmul_impl"] == "fused"
 
 
 def test_engine_int4_gauntlet_and_restore():
@@ -485,14 +450,14 @@ def test_engine_int4_gauntlet_and_restore():
     sym = _lm()
     params = _init_params(sym, rng)
     dec = Decoder(sym, params, max_len=T)                 # fp oracle
-    dq4 = Decoder(sym, params, max_len=T, cache_block=None,
+    dq4 = Decoder(sym, params, max_len=T,
                   weight_dtype="int4")
     qt = dq4._params["layer0_qkv_weight"]
     assert isinstance(qt, QuantizedTensor)
     assert qt.bits == 4 and qt.q.dtype == jnp.uint8
     assert qt.q.shape[-1] == EMBED // 2
     eng = InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None),
+        Decoder(sym, params, max_len=T),
         slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0,
         weight_dtype="int4", matmul_impl="pallas")
     assert eng.weight_dtype == "int4"
@@ -508,7 +473,7 @@ def test_engine_int4_gauntlet_and_restore():
     assert snap["weight_dtype"] == 2
     assert snap["weight_group_size"] == eng.weight_group > 0
     e8 = InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None),
+        Decoder(sym, params, max_len=T),
         slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0,
         weight_dtype="int8")
     assert eng.weight_bytes < e8.weight_bytes
@@ -545,7 +510,7 @@ def test_engine_expert_parallel_moe(lm):
 
     def mkeng(**kw):
         return InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None),
+            Decoder(sym, params, max_len=T),
             slots=2, prefill_buckets=(4,), prefix_cache_mb=0,
             weight_dtype="int8", **kw)
 
@@ -570,8 +535,7 @@ def test_engine_expert_parallel_moe(lm):
     # construction contracts
     sym_plain, params_plain, _ = lm
     with pytest.raises(MXNetError, match="MoE"):
-        InferenceEngine(Decoder(sym_plain, params_plain, max_len=T,
-                                cache_block=None),
+        InferenceEngine(Decoder(sym_plain, params_plain, max_len=T),
                         slots=2, prefill_buckets=(4,),
                         prefix_cache_mb=0, ep=2)
     with pytest.raises(MXNetError, match="num_experts"):

@@ -80,7 +80,7 @@ def _oracle(dec, prompt, n):
 
 def _dec(lm):
     sym, params, _ = lm
-    return Decoder(sym, params, max_len=T, cache_block=None)
+    return Decoder(sym, params, max_len=T)
 
 
 # the capture-source config: speculation ON (n-gram), 1-slot prefix
@@ -238,6 +238,20 @@ def test_replay_verify_spec_off_byte_identical(lm, captured,
     # against (the capture's own retire timings)
     assert report["recorded"]["ttft_p50_ms"] > 0
     assert report["requests"] == report["replayed"]
+
+
+def test_replay_header_with_a_stale_attn_impl_key(lm, captured):
+    """A capture header written by an older tree names the decode read
+    it took (``"attn_impl"``): build_engine reads the key and ignores
+    it, and the replay verifies byte for byte."""
+    cap = load_capture(captured["path"])
+    assert "attn_impl" not in cap["engine"]
+    cap["engine"]["attn_impl"] = "paged"
+    eng = replay_serving.build_engine(cap, _dec(lm), draft="off")
+    report = replay_serving.replay(cap, eng, timing="max", verify=True)
+    assert report["verified"] == len(captured["cases"])
+    assert report["verified_prefix"] == 1
+    assert report["mismatches"] == []
 
 
 def test_replay_verify_different_round_geometry(lm, captured):

@@ -57,8 +57,7 @@ def _engine(sym, params, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("prefill_buckets", (4, 8))
     kw.setdefault("prefix_cache_mb", 0)
-    return InferenceEngine(Decoder(sym, params, max_len=T,
-                                   cache_block=None), **kw)
+    return InferenceEngine(Decoder(sym, params, max_len=T), **kw)
 
 
 @pytest.fixture(scope="module")
@@ -197,7 +196,7 @@ def test_tp4_multi_step_rounds_snapshot_restore(lm):
     snap = eng.snapshot()
     assert snap["engine"]["tp"] == 4
     eng2, handles = InferenceEngine.restore(
-        snap, Decoder(sym, params, max_len=T, cache_block=None))
+        snap, Decoder(sym, params, max_len=T))
     assert eng2.tp == 4 and eng2._mesh is not None
     eng.serve_forever()
     eng2.serve_forever()
@@ -219,7 +218,7 @@ def test_tp2_int8_kv_byte_identical(lm):
     rng = np.random.RandomState(5)
     dec8 = Decoder(sym, params, max_len=T, cache_dtype="int8")
     eng = InferenceEngine(
-        Decoder(sym, params, max_len=T, cache_block=None,
+        Decoder(sym, params, max_len=T,
                 cache_dtype="int8"),
         slots=2, prefill_buckets=(4,), prefix_cache_mb=0, tp=2)
     cases = [(rng.randint(0, VOCAB, (pl,)), n)
@@ -251,7 +250,7 @@ def test_tp2_windowed_ring_byte_identical():
     dec = Decoder(sym, params, max_len=T)
     with pytest.warns(UserWarning, match="windowed"):
         eng = InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None),
+            Decoder(sym, params, max_len=T),
             slots=2, prefill_buckets=(4, 8), prefill_chunk=4,
             spec_k=3, draft="ngram", tp=2)
     assert eng.spec_draft == "off" and eng._prefix is None
@@ -275,23 +274,22 @@ def test_tp2_windowed_ring_byte_identical():
 
 
 def test_tp2_paged_byte_identical_to_tp1_paged(lm):
-    """Paged attention under tensor parallelism (ISSUE 15, closing
-    the PR 14 follow-up): a tp=2 engine with ``attn_impl="paged"``
-    serves the Pallas kernel against its LOCAL cache shard — the
-    kernel takes its kv-head count from the cache operand, so inside
-    the shard_map it walks the shard's own kv heads — with NO
-    dense-fallback warning, byte-identical to
-    the tp=1 paged engine AND to the dense offline oracle (fp paged
-    == dense is the PR 11 contract). Cache sharding asserted; compile
-    contract unchanged at both degrees."""
+    """The bounded read under tensor parallelism (ISSUE 15, closing
+    the PR 14 follow-up): a tp=2 engine serves the Pallas kernel
+    against its LOCAL cache shard — the kernel takes its kv-head count
+    from the cache operand, so inside the shard_map it walks the
+    shard's own kv heads — with no warning, byte-identical to the
+    tp=1 engine on the one read there is AND to the offline oracle,
+    which reads densely. Cache sharding asserted; compile contract
+    unchanged at both degrees."""
     import warnings
 
     sym, params, dec = lm
-    e1 = _engine(sym, params, attn_impl="paged")
+    e1 = _engine(sym, params)
     with warnings.catch_warnings():
-        warnings.simplefilter("error")     # no dense-fallback warning
-        e2 = _engine(sym, params, tp=2, attn_impl="paged")
-    assert e2.attn_impl == "paged" and e2.tp == 2
+        warnings.simplefilter("error")
+        e2 = _engine(sym, params, tp=2)
+    assert e2._attn_pool_rows and e2.tp == 2    # the read is bounded
     rng = np.random.RandomState(23)
     cases = [(rng.randint(0, VOCAB, (pl,)), n)
              for pl, n in [(3, 5), (6, 4), (4, 6)]]
@@ -309,15 +307,14 @@ def test_tp2_paged_byte_identical_to_tp1_paged(lm):
             == leaf.shape[2] // 2
     assert_compile_contract(e1, verify=0, copy={})
     assert_compile_contract(e2, verify=0, copy={})
-    assert mx.telemetry.snapshot()["serving"]["attn_impl"] == 1
 
 
 def test_tp_validation_and_refusals(lm):
     """Construction-time contracts, all compile-free: uneven kv-head
     splits refuse loudly (GQA groups must stay whole per shard), bad
-    tp/mesh combinations refuse with pointers, paged attention
-    COMPOSES with tp since ISSUE 15 (no warning, no dense fallback —
-    construction compiles nothing, the serving identity is
+    tp/mesh combinations refuse with pointers, the bounded read
+    COMPOSES with tp since ISSUE 15 (no warning — construction
+    compiles nothing, the serving identity is
     test_tp2_paged_byte_identical's), and MXNET_SERVING_TP is the env
     default for the knob."""
     import warnings
@@ -337,17 +334,11 @@ def test_tp_validation_and_refusals(lm):
     # an explicit mesh works and wins the degree
     eng = _engine(sym, params, mesh=model_parallel_mesh(2))
     assert eng.tp == 2
-    # paged x tp composes — no dense-fallback warning, either for an
-    # engine-level paged over a dense decoder or a paged-built decoder
+    # the bounded read x tp composes: no warning
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        ep = _engine(sym, params, tp=2, attn_impl="paged")
-        ep2 = InferenceEngine(
-            Decoder(sym, params, max_len=T, cache_block=None,
-                    attn_impl="paged"),
-            slots=2, prefill_buckets=(4, 8), prefix_cache_mb=0, tp=2)
-    assert ep.attn_impl == "paged" and ep.tp == 2
-    assert ep2.attn_impl == "paged" and ep2.tp == 2
+        ep = _engine(sym, params, tp=2)
+    assert ep._attn_pool_rows and ep.tp == 2
     # env default (ctor only — nothing dispatches)
     import os
     old = os.environ.get("MXNET_SERVING_TP")

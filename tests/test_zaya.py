@@ -54,7 +54,7 @@ def reference(toy, seqs):
 @pytest.fixture(scope="module")
 def decoder(toy):
     _, _, sym, w, _ = toy
-    return mx.parallel.Decoder(sym, w, max_len=MAX_LEN, cache_block=None)
+    return mx.parallel.Decoder(sym, w, max_len=MAX_LEN)
 
 
 def worst_gap(toy, prompt, served):
@@ -309,7 +309,7 @@ def test_decoder_cache_declares_the_rolling_state(decoder):
     specs = decoder.cache_specs(caches)
     from jax.sharding import PartitionSpec as P
     assert specs[0][0] == P(None, None, "model") and specs[0][2] == P()
-    assert decoder.slots_walk_batched
+    assert decoder._slots_batched
 
 
 # -- the engine -------------------------------------------------------------------
@@ -390,7 +390,6 @@ REFUSED = {
     "prefix pool": dict(prefix_cache_mb=4),
     "speculation": dict(draft="ngram"),
     "handoff": dict(role="prefill"),
-    "paged read": dict(attn_impl="paged"),
     "tp": dict(tp=2),
     "ep": dict(ep=2),
     "int8 weights": dict(weight_dtype="int8"),
@@ -406,15 +405,11 @@ def test_engine_refuses_by_name_what_cannot_carry_the_state(decoder,
 
 
 @pytest.mark.parametrize("option", [dict(cache_dtype="int8"),
-                                    dict(attn_impl="paged"),
-                                    dict(cache_block=16),
                                     dict(weight_dtype="int4")])
 def test_decoder_refuses_by_name(toy, option):
     _, _, sym, w, _ = toy
-    kw = dict(cache_block=None)
-    kw.update(option)
     with pytest.raises(MXNetError, match="CCAttention"):
-        mx.parallel.Decoder(sym, w, max_len=MAX_LEN, **kw)
+        mx.parallel.Decoder(sym, w, max_len=MAX_LEN, **option)
 
 
 def test_default_prefix_pool_is_off_for_a_rolling_state(decoder):
@@ -430,7 +425,7 @@ def test_bfloat16_serving_stays_near_the_reference(toy):
     part of a sigma); the served tokens still lie near the top."""
     fam, cfg, sym, w, _ = toy
     wb = {k: v.astype(jnp.bfloat16) for k, v in w.items()}
-    dec = mx.parallel.Decoder(sym, wb, max_len=MAX_LEN, cache_block=None,
+    dec = mx.parallel.Decoder(sym, wb, max_len=MAX_LEN,
                               compute_dtype="bfloat16")
     assert dec.init_cache(1)[0][2].dtype == jnp.bfloat16
     eng = make_engine(dec)

@@ -17,12 +17,6 @@ hit-rate axis shows where prefix-copy reuse starts paying off over
 re-prefilling, the chunk axis what bounding decode stalls costs in
 throughput. ``--no-prefix-sweep`` skips it.
 
-``--attn-impls dense paged`` adds one ``bench.bench_serving`` cell per
-attention impl (ISSUE 11): the dense whole-cache read vs the Pallas
-paged kernel that walks only each slot's live KV rows, same stream per
-seed — each cell reports tokens/s, cadence p50/p99, and the decode
-program's ``bytes_accessed`` per dispatch (the traffic-cut metric).
-
 ``--weight-dtypes float int8 int4`` adds one cell per weight storage
 dtype (ISSUE 15/17): float weights vs int8 + per-output-channel scales
 vs int4 packed nibbles + per-group scales, same stream per seed — each
@@ -31,11 +25,10 @@ the decode program's ``bytes_accessed`` per dispatch (the
 weight-stream cut — at serving batch the weights, not the KV, dominate
 decode bytes; doc/serving.md "Quantized weights").
 
-``--matmul-impls dense pallas fused`` adds one cell per quantized
-matmul lowering (PR 17) with int8 weights and paged attention pinned:
-the chunked host-level fori loop vs the Pallas ``quant_matmul`` kernel
-(dequant-in-VMEM) vs the fused one-dispatch QKV->attention->out-proj
-decode kernel (doc/serving.md "Fused quantized kernels").
+``--matmul-impls dense pallas`` adds one cell per quantized matmul
+lowering (PR 17) with int8 weights pinned: the chunked host-level
+fori loop vs the Pallas ``quant_matmul`` kernel (dequant-in-VMEM;
+doc/serving.md "Fused quantized kernels").
 
 ``--tps 1 2 4`` adds a tensor-parallel sweep over
 ``bench.bench_serving_tp`` (ISSUE 14): one cell per degree on the
@@ -144,23 +137,14 @@ def main():
                          "the decode program's bytes_accessed per "
                          "dispatch (the weight-stream cut)")
     ap.add_argument("--matmul-impls", nargs="+", default=[],
-                    choices=("dense", "pallas", "fused"),
+                    choices=("dense", "pallas"),
                     help="quantized-matmul impl sweep axis (PR 17): "
                          "one bench_serving cell per impl at the "
                          "first slots/arrival setting, int8 weights "
                          "pinned so the cells compare like-for-like "
                          "— dense = the chunked host-level fori "
                          "loop, pallas = the quant_matmul kernel "
-                         "(dequant-in-VMEM), fused = the one-dispatch "
-                         "QKV->attention->out-proj decode kernel "
-                         "(paged attention path)")
-    ap.add_argument("--attn-impls", nargs="+", default=[],
-                    help="attention-impl sweep axis (e.g. dense "
-                         "paged): one bench_serving cell per impl at "
-                         "the first slots/arrival setting — paged = "
-                         "the Pallas live-row kernel; cells report "
-                         "tokens/s, cadence p50/p99, and the decode "
-                         "program's bytes_accessed per dispatch")
+                         "(dequant-in-VMEM)")
     args = ap.parse_args()
 
     import bench
@@ -246,23 +230,6 @@ def main():
                      "compile_programs")}
             out["spec_k%d" % k] = cell
             print("spec_k%d: %r" % (k, cell), file=sys.stderr)
-    # attention-impl sweep (ISSUE 11): dense whole-cache reads vs the
-    # Pallas paged kernel on the same stream/seed — the
-    # bytes_accessed cell is the per-dispatch decode traffic from the
-    # XLA cost analysis (the honest CPU metric; wall clock under the
-    # Pallas interpreter under-sells the kernel)
-    for impl in args.attn_impls:
-        r = bench.bench_serving(
-            slots=args.slots[0], layers=args.layers, embed=args.embed,
-            heads=args.heads, vocab=args.vocab, max_len=args.max_len,
-            n_requests=args.requests, seed=3,
-            arrival_ms=args.arrival_ms[0], attn_impl=impl)
-        cell = {k: r[k] for k in
-                ("tokens_per_sec", "p50_ms_per_token",
-                 "p99_ms_per_token", "decode_bytes_accessed",
-                 "compile_programs")}
-        out["impl_%s" % impl] = cell
-        print("impl_%s: %r" % (impl, cell), file=sys.stderr)
     # weight-dtype sweep (ISSUE 15): float vs int8 weights on the
     # same stream/seed — bytes_accessed and weight_bytes are the
     # traffic/footprint cuts (the honest CPU metrics; the chunked
@@ -280,17 +247,15 @@ def main():
         out["weights_%s" % wd] = cell
         print("weights_%s: %r" % (wd, cell), file=sys.stderr)
     # quantized-matmul impl sweep (PR 17): dense fori vs the Pallas
-    # quant_matmul kernel vs the fused decode kernel, int8 weights and
-    # the paged attention path pinned so cells differ only in the
-    # matmul lowering — dense and pallas cells are byte-identical by
-    # the kernel contract, the fused cell is token-stable
+    # quant_matmul kernel, int8 weights pinned so cells differ only in
+    # the matmul lowering
     for mi in args.matmul_impls:
         r = bench.bench_serving(
             slots=args.slots[0], layers=args.layers, embed=args.embed,
             heads=args.heads, vocab=args.vocab, max_len=args.max_len,
             n_requests=args.requests, seed=3,
-            arrival_ms=args.arrival_ms[0], attn_impl="paged",
-            weight_dtype="int8", matmul_impl=mi)
+            arrival_ms=args.arrival_ms[0], weight_dtype="int8",
+            matmul_impl=mi)
         cell = {k: r[k] for k in
                 ("tokens_per_sec", "p50_ms_per_token",
                  "p99_ms_per_token", "decode_bytes_accessed",

@@ -164,26 +164,20 @@ def print_serving(snap, out=None):
                   % ("int8" if wd else "float",
                      "n/a" if s.get("weight_bytes") is None
                      else "%d" % s["weight_bytes"]))
-    # attention impl + decode memory traffic (ISSUE 11): the
-    # serving.attn_impl info gauge names the cache-read strategy; the
-    # PR 9 program gauges give the decode program's bytes per
-    # dispatched round, and tokens/rounds approximates tokens per
-    # dispatch — their quotient is the ~bytes/token the paged kernel
-    # exists to cut (compare a dense and a paged snapshot directly)
-    impl_g = s.get("attn_impl")
+    # decode memory traffic: the PR 9 program gauges give the decode
+    # program's bytes per dispatched round, and tokens/rounds
+    # approximates tokens per dispatch — their quotient is the
+    # ~bytes/token the bounded read exists to cut
     prog = snap.get("program") if isinstance(snap, dict) else None
     decp = (prog or {}).get("serving_decode", {})
     ba = decp.get("bytes_accessed")
-    if impl_g is not None or ba is not None:
+    if ba is not None:
         rounds = s.get("rounds", 0)
         toks = s.get("tokens", 0)
         per_tok = ("%.3g" % (ba * rounds / toks)
                    if ba and rounds and toks else "n/a")
-        out.write("attention:        impl=%s decode bytes_accessed=%s"
-                  "/dispatch ~%s/token\n"
-                  % ("n/a" if impl_g is None
-                     else ("paged" if impl_g else "dense"),
-                     "n/a" if ba is None else "%.6g" % ba, per_tok))
+        out.write("attention:        decode bytes_accessed=%.6g"
+                  "/dispatch ~%s/token\n" % (ba, per_tok))
     if s.get("capture_records", 0) or s.get("capture_skipped", 0):
         out.write("capture:          records=%s skipped=%s bytes=%s\n"
                   % (s.get("capture_records", 0),

@@ -2,29 +2,23 @@
 
 Times ONE layer's read of a step at serve-chat's shapes (bf16 K/V
 [16, 1024, 2048], 32 heads of 64, a few live slots of 600-1,000 rows,
-the other slots dead at stale positions) in each form the decode
-program could take, and checks every bounded form against the dense
-one on the same rows:
+the other slots dead at stale positions) in the two forms the tree
+has, and checks the bounded one against the dense one on the same rows
+(the forms that lost, bounded by pos alone, an XLA while over live
+slots and the old kernel, are answered in PERF.md section 6, PR 29):
 
   dense        Decoder._lane_attn over the whole pool (every row read
                and masked)
   bounded      ops.pallas_kernels.paged_attention with lens = live ?
                pos + 1 : 0, at each block size given
-  by_pos       the same kernel bounded by pos alone (a dead slot's
-               stale position still bounds its read)
-  xla_while    XLA only: live slots first, a while over their count,
-               each trip the dense read of one slot's rows
-  old          the kernel of a parent checkout (--old <dir>), bounded
-               by pos alone as it was
 
     python tools/probe_attn_read.py [--live 5] [--blocks 256,512]
-        [--layers 4] [--old .checkout/parent]
+        [--layers 4] [--shape 16,1024,32,64]
 
 Prints one JSON line per form: ms a layer and step, the rows read,
 and the largest gap to the dense read's output.
 """
 import argparse
-import importlib.util
 import json
 import os
 import sys
@@ -46,15 +40,6 @@ from mxnet_tpu.parallel.decode import Decoder             # noqa: E402
 STEPS = 8
 
 
-def _load_old(root):
-    spec = importlib.util.spec_from_file_location(
-        "old_pallas_kernels",
-        os.path.join(root, "mxnet_tpu", "ops", "pallas_kernels.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--live", type=int, default=5)
@@ -62,7 +47,6 @@ def main():
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--dtype", default="bfloat16")
-    ap.add_argument("--old", default=None)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--shape", default="16,1024,32,64",
                     help="S,L,H,D (smaller for a rehearsal off the chip)")
@@ -86,48 +70,22 @@ def main():
         layers.append((jax.random.normal(kk, (S, L, w), dt),
                        jax.random.normal(kv_, (S, L, w), dt)))
     q0 = jax.random.normal(key, (S, 1, H, D), dt)
-    posj, lensj, livej = (jnp.asarray(pos), jnp.asarray(lens),
-                          jnp.asarray(live))
+    posj, lensj = jnp.asarray(pos), jnp.asarray(lens)
     plain = types.SimpleNamespace(_cache_int8=False)
 
     def dense(q, k, v):
         return Decoder._lane_attn(plain, q, (k, v), posj, H)
 
-    def bounded(bk, by_pos=False):
+    def bounded(bk):
         def f(q, k, v):
-            return pk.paged_attention(
-                q, k, v, posj, kv_heads=H,
-                lens=None if by_pos else lensj, block_k=bk)
+            return pk.paged_attention(q, k, v, posj, kv_heads=H,
+                                      lens=lensj, block_k=bk)
         return f
-
-    def xla_while(q, k, v):
-        order = jnp.argsort(~livej, stable=True).astype(jnp.int32)
-
-        def body(i, out):
-            s = order[i]
-            z = jnp.int32(0)
-            ks = lax.dynamic_slice(k, (s, z, z), (1, L, w))
-            vs = lax.dynamic_slice(v, (s, z, z), (1, L, w))
-            qs = lax.dynamic_slice(q, (s, z, z, z), (1, 1, H, D))
-            o = Decoder._lane_attn(plain, qs, (ks, vs), posj[s], H)
-            return lax.dynamic_update_slice(out, o, (s, z, z, z))
-
-        return lax.fori_loop(0, jnp.sum(livej).astype(jnp.int32), body,
-                             jnp.zeros_like(q))
 
     forms = [("dense", dense, S * L)]
     for bk in [int(b) for b in args.blocks.split(",")]:
         forms.append(("bounded_%d" % bk, bounded(bk),
                       int(pk.paged_rows_fetched(lens, L, bk))))
-        forms.append(("by_pos_%d" % bk, bounded(bk, True),
-                      int(pk.paged_rows_fetched(pos + 1, L, bk))))
-    forms.append(("xla_while", xla_while, int(live.sum()) * L))
-    if args.old:
-        old = _load_old(args.old)
-        forms.append(("old_by_pos_128",
-                      lambda q, k, v: old.paged_attention(
-                          q, k, v, posj, kv_heads=H),
-                      int(pk.paged_rows_fetched(pos + 1, L, 128))))
 
     def chain(f):
         # STEPS steps of every layer, each read fed the one before it

@@ -85,9 +85,11 @@ from mxnet_tpu.serving.capture import load_capture  # noqa: E402
 # role is a TOPOLOGY axis, not request content: a capture recorded on
 # a prefill specialist replays fine on a unified engine (outputs are
 # role-independent by the disaggregation contract), and ``--roles``
-# decides the replay topology explicitly
+# decides the replay topology explicitly. "attn_impl": a header written
+# by an older tree names the decode read it took; the read follows the
+# cache kind now, and the stale key is read and ignored
 _NON_CTOR_KEYS = ("max_len", "capture_dir", "engine_id",
-                  "migrated_from", "role")
+                  "migrated_from", "role", "attn_impl")
 
 
 def build_engine(cap, decoder, **overrides):
@@ -355,8 +357,6 @@ def main(argv=None):
                     choices=("off", "ngram", "model"))
     ap.add_argument("--prefill-chunk", type=int, default=None)
     ap.add_argument("--prefix-cache-mb", type=float, default=None)
-    ap.add_argument("--attn-impl", default=None,
-                    choices=("dense", "paged"))
     ap.add_argument("--tp", type=int, default=None,
                     help="tensor-parallel degree override: replay the "
                          "capture on a KV-cache-sharded engine "
@@ -412,7 +412,7 @@ def main(argv=None):
     # the capture header (or --weight-dtype) decides the ENGINE's
     # dtype, and an env-quantized decoder could not serve a
     # float-header capture (the float weights are gone)
-    deckw = {"cache_block": None, "weight_dtype": "float"}
+    deckw = {"weight_dtype": "float"}
     if args.compute_dtype:
         deckw["compute_dtype"] = args.compute_dtype
 
@@ -426,7 +426,6 @@ def main(argv=None):
         ("draft", args.draft),
         ("prefill_chunk", args.prefill_chunk),
         ("prefix_cache_mb", args.prefix_cache_mb),
-        ("attn_impl", args.attn_impl),
         ("tp", args.tp),
         ("weight_dtype", args.weight_dtype),
     ) if v is not None}
