@@ -1042,20 +1042,23 @@ def matmul_stats(x, w, *, block_m=256, block_n=256, block_k=512,
 # bf16 rows of 2048 lanes with 5 of 16 slots live, blocks of 128 / 256 /
 # 512 rows read 0.069 / 0.064 / 0.066 ms a layer and step (PERF.md
 # section 6, PR 29): smaller blocks round a slot's length up by less,
-# larger ones take fewer grid steps
+# larger ones take fewer grid steps. At rows of 256 lanes (512 B) with
+# 12 of 32 slots live the grid steps lead: blocks of 256 / 512 / 1024 /
+# 2048 rows read 0.065 / 0.046 / 0.043 / 0.042 ms (PERF.md section 6,
+# PR 31), so narrow rows take blocks of up to 1024 rows
 _PAGED_BLOCK_BYTES = 1 << 20
 
 
 def default_paged_block_k(max_len, row_bytes=None):
     """KV rows per block for ``paged_attention``, from the shapes
-    alone: the largest of (512, 256, 128, 64, 32, 16, 8) that divides
-    ``max_len`` (whole blocks keep the in-kernel slices static) and,
-    where the stored row's ``row_bytes`` are given, keeps a block
-    within 1 MB — a slot of a few hundred live rows is then two to
-    four grid steps; else ``max_len`` itself: a cache too short/odd to
-    block degenerates to one block, still bounded by the position
-    mask."""
-    fits = [b for b in (512, 256, 128, 64, 32, 16, 8)
+    alone: the largest of (1024, 512, 256, 128, 64, 32, 16, 8) that
+    divides ``max_len`` (whole blocks keep the in-kernel slices static)
+    and, where the stored row's ``row_bytes`` are given, keeps a block
+    within 1 MB — 256 rows of 4 KB, 1024 rows of 512 B: a slot of a
+    few hundred live rows is then one to four grid steps; else
+    ``max_len`` itself: a cache too short/odd to block degenerates to
+    one block, still bounded by the position mask."""
+    fits = [b for b in (1024, 512, 256, 128, 64, 32, 16, 8)
             if max_len % b == 0]
     for b in fits:
         if row_bytes is None or b * row_bytes <= _PAGED_BLOCK_BYTES:

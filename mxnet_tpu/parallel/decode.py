@@ -647,6 +647,35 @@ class Decoder:
         return o.reshape(b, c, g, kv, d).transpose(0, 1, 3, 2, 4) \
             .reshape(b, c, h, d).astype(q.dtype)
 
+    def _paged_read(self, q, entry, pos, kv, lens=None, stats=None):
+        """Attention of a SHORT query chunk at a VECTOR of positions
+        (the slot walk: decode, verify, draft) through the bounded read
+        (ops/pallas_kernels.py ``paged_attention``): only the blocks of
+        rows [0, lens) of each slot of the stored buffers are fetched
+        (``lens``: ``pos + C``, or 0 for a slot that holds no request,
+        whose output is zeros), the int8 side scales applied IN the
+        kernel. ``entry`` is a linear cache entry, K/V [B, L, Hkv*D]
+        as ``_lane_attn`` takes it, the chunk's rows already written.
+        ``stats["attn_rows_read"]`` grows by the rows fetched."""
+        from ..ops.pallas_kernels import (default_paged_block_k,
+                                          paged_attention,
+                                          paged_rows_fetched)
+        posv = jnp.asarray(pos, jnp.int32)
+        if lens is None:
+            lens = posv + q.shape[1]
+        ck, cv = entry[0], entry[2 if self._cache_int8 else 1]
+        rows = ck.shape[1]
+        bk = default_paged_block_k(rows, ck.shape[2] * ck.dtype.itemsize)
+        scales = dict(k_scale=entry[1], v_scale=entry[3]) \
+            if self._cache_int8 else {}
+        with jax.named_scope("attend"):
+            o = paged_attention(q, ck, cv, posv, kv_heads=kv,
+                                lens=lens, block_k=bk, **scales)
+        if stats is not None:
+            stats["attn_rows_read"] = paged_rows_fetched(
+                lens, rows, bk) + stats.get("attn_rows_read", 0)
+        return o
+
     def _embedding_weight_names(self):
         """Parameter names consumed as Embedding tables — always
         per-row int8 under quantization (``row_quant``): a
@@ -689,19 +718,20 @@ class Decoder:
     #     ``_window_attn``. The slot walk is the ``vmap`` of one-slot
     #     walks: a ring's read is written for one position, it has no
     #     other.
-    # MultiHeadAttention, linear cache, short chunk, position VECTOR
-    # (the slot walk: decode, verify, draft):
+    # MultiHeadAttention on a linear cache and CCAttention (its K and V
+    # rows are a linear cache), short chunk, position VECTOR (the slot
+    # walk: decode, verify, draft):
     #     the bounded read ``paged_attention(lens=...)``, rows
-    #     [0, lens) of each slot, in ONE batched walk.
+    #     [0, lens) of each slot, in ONE batched walk (``_paged_read``).
     # MultiHeadAttention, linear cache, short chunk, one scalar position
     # (the offline step / ``generate`` / ``beam_search``):
     #     ``_lane_attn``, all rows masked by position; clamped
     #     statically where the position is a Python int.
     # MultiHeadAttention, linear cache, long chunk (prefill):
     #     ``_head_attn``.
-    # CCAttention, any chunk:
-    #     ``_cached_cca`` (``_lane_attn`` for a short chunk at a scalar
-    #     or a vector of positions, ``_head_attn`` for a long one).
+    # CCAttention, one scalar position:
+    #     ``_cached_cca`` (``_lane_attn`` for a short chunk,
+    #     ``_head_attn`` for a long one).
     def _cached_mha(self, node, ins, entry, pos, valid_len=None,
                     tp=None, mm_impl=None, lens=None, stats=None):
         from ..ops.attention import MultiHeadAttention as _MHA
@@ -774,28 +804,7 @@ class Decoder:
             return out_proj(o), entry
         entry = self._write_cache(entry, k, v, pos)
         if jnp.ndim(pos) == 1:
-            # the bounded read (ops/pallas_kernels.py): fetch only the
-            # blocks of rows [0, lens) per slot of the stored buffer
-            # (lens: pos+C, or 0 for a slot that holds no request),
-            # the int8 side scales applied IN the kernel
-            from ..ops.pallas_kernels import (default_paged_block_k,
-                                              paged_attention,
-                                              paged_rows_fetched)
-            posv = jnp.asarray(pos, jnp.int32)
-            if lens is None:
-                lens = posv + c
-            ck, cv = entry[0], entry[2 if self._cache_int8 else 1]
-            rows = ck.shape[1]
-            bk = default_paged_block_k(
-                rows, ck.shape[2] * ck.dtype.itemsize)
-            scales = dict(k_scale=entry[1], v_scale=entry[3]) \
-                if self._cache_int8 else {}
-            with jax.named_scope("attend"):
-                o = paged_attention(q, ck, cv, posv, kv_heads=kv,
-                                    lens=lens, block_k=bk, **scales)
-            if stats is not None:
-                stats["attn_rows_read"] = paged_rows_fetched(
-                    lens, rows, bk) + stats.get("attn_rows_read", 0)
+            o = self._paged_read(q, entry, pos, kv, lens, stats)
         else:
             # dense read. A STATIC dispatch position (offline
             # generate/beam prefill call _run with a python-int pos)
@@ -826,13 +835,18 @@ class Decoder:
             o = lax.all_gather(o, tp[0], axis=2, tiled=True)
         return out_proj(o), entry
 
-    def _cached_cca(self, node, ins, entry, pos, valid_len=None):
+    def _cached_cca(self, node, ins, entry, pos, valid_len=None,
+                    lens=None, stats=None):
         """CCAttention on a chunk at ``pos`` (a scalar, or a [B] vector:
         every batch row at its own position) against its cache entry
         ``(K rows, V rows, rolling state)``. The mixing is the op's own
         (``ops.attention.cca_qkv``), fed the two positions before the
         chunk from the state; K and V go into the stored rows like
-        MultiHeadAttention's and are read the same two ways; the
+        MultiHeadAttention's and are read the same three ways
+        (``_paged_read`` with ``lens`` and ``stats`` for a short chunk
+        at a position vector: read densely there, a layer's whole
+        buffer is an operand small enough for the compiler to stage
+        through fast memory and back on every step, PERF.md PR 31); the
         state then takes the chunk's last STATE_ROWS REAL positions
         (``valid_len``, absolute: a right-padded prefill bucket must
         leave the state of its last real token, not of its padding)."""
@@ -864,14 +878,16 @@ class Decoder:
         limit = self.max_len
         if isinstance(pos, (int, np.integer)):
             limit = min(self.max_len, int(pos) + c)
-        if c <= _SHORT_CHUNK:
-            o = self._lane_attn(q, self._live_rows(kvrows, limit), pos, kv)
-        else:
-            if jnp.ndim(pos) == 1:
+        if jnp.ndim(pos) == 1:
+            if c > _SHORT_CHUNK:
                 raise MXNetError(
                     "Decoder: a chunk of %d tokens at per-slot "
-                    "positions has no dense read (CCAttention node %r)"
+                    "positions has no read (CCAttention node %r)"
                     % (c, node.name))
+            o = self._paged_read(q, kvrows, pos, kv, lens, stats)
+        elif c <= _SHORT_CHUNK:
+            o = self._lane_attn(q, self._live_rows(kvrows, limit), pos, kv)
+        else:
             o = self._head_attn(
                 q, *self._read_cache(kvrows, q.dtype, kv, limit), pos)
         with jax.named_scope("cache"):
@@ -1060,8 +1076,7 @@ class Decoder:
         ``lens`` ([B] int32, with a vector ``pos``): the rows of each
         batch row's cache that the bounded read may fetch — the slot
         walk's ``pos + C`` for a slot that holds a request, 0 for one
-        that does not (its output is then discarded by the caller).
-        Reads that are not bounded (CCAttention's) ignore it."""
+        that does not (its output is then discarded by the caller)."""
         from ..ops.attention import moe_ffn_math
         from ..serving.quant import (QuantizedTensor, embedding_rows,
                                      moe_ffn_forward)
@@ -1092,7 +1107,8 @@ class Decoder:
                     continue
                 if name == "CCAttention":
                     out, new_caches[mha_i] = self._cached_cca(
-                        n, ins, new_caches[mha_i], pos, valid_len)
+                        n, ins, new_caches[mha_i], pos, valid_len,
+                        lens=lens, stats=stats)
                     mha_i += 1
                     env[(id(n), 0)] = out
                     continue
@@ -1183,13 +1199,14 @@ class Decoder:
         Unless a cached node is a windowed ring (``_slots_batched``)
         this is ONE batched walk with the position VECTOR:
         position-wise ops see [S, C, E] directly, cache writes scatter
-        per slot, a MultiHeadAttention's read is the bounded one (the
-        Pallas kernel of ops/pallas_kernels.py) that fetches only the
-        rows ``[0, lens)`` of each slot — ``lens`` [S] int32, default
+        per slot, and an attention node's read (MultiHeadAttention's
+        and CCAttention's alike) is the bounded one (the Pallas kernel
+        of ops/pallas_kernels.py) that fetches only the rows
+        ``[0, lens)`` of each slot — ``lens`` [S] int32, default
         ``pos + C``; the serving engine's programs hand 0 for a slot
         that holds no request, whose stale rows are then not read at
-        all and whose logits (finite) the caller discards — and a
-        CCAttention's its own (doc/serving.md "The decode read").
+        all and whose logits (finite) the caller discards
+        (doc/serving.md "The decode read").
         ``stats`` is filled there (see ``_run``): only this one walk
         sees every slot's token, so only here can experts be counted
         once per step.

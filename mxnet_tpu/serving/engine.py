@@ -1285,13 +1285,12 @@ class InferenceEngine:
         self._moe_counted = sum(
             1 for n in moe_nodes if n.params["top_k"] > 0) \
             if rolling and not decoder._mha else 0
-        # rows of the K buffers of every MultiHeadAttention layer,
-        # which a decode step's bounded reads are counted against (0:
-        # no read is bounded — a ring, CCAttention — nothing is counted)
+        # rows of the K buffers of every attention layer (a
+        # CCAttention's K rows too), which a decode step's bounded
+        # reads are counted against (0: the walk is not batched — a
+        # ring — so no read is bounded and nothing is counted)
         self._attn_pool_rows = sum(
-            e[0].shape[0] * e[0].shape[1]
-            for n, e in zip(decoder._cached, self._caches)
-            if n.spec.name == "MultiHeadAttention") \
+            e[0].shape[0] * e[0].shape[1] for e in self._caches) \
             if decoder._slots_batched else 0
         self._step_fn = jax.jit(
             self._wrap_tp(self._make_step(),

@@ -347,10 +347,17 @@ def test_zaya_decode_program(one_chip, monkeypatch):
     """The engine's decode program for ZAYA1-8B's block at its
     published widths (2 layers, a cut vocabulary, 32 slots x 2048
     rows), compiled for the chip: the routed experts run as the Pallas
-    kernel, two calls a layer; nothing as large as a layer's expert
-    stack is copied (XLA's own grouped product lays out a 134 MB
-    temporary and multiplies every expert); the temporaries stay under
-    a tenth of the cache."""
+    kernel, two calls a layer, and CCAttention's read as the bounded
+    one, a third; nothing as large as a layer's expert stack is copied
+    (XLA's own grouped product lays out a 134 MB temporary and
+    multiplies every expert); the temporaries stay under a tenth of
+    the cache; and no K/V buffer is staged through fast memory. Read
+    densely, a layer's whole 33.5 MB buffer was an operand the compiler
+    fetched into fast memory (``S(1)``) in four ``slice-start``s of a
+    quarter each, scattered and read THERE, and sent back by a
+    ``copy-start`` / ``copy-done`` of the whole buffer, on every step:
+    25 ms of a 123 ms decode round under ``copy-done`` (PERF.md
+    section 6, PR 31)."""
     import mxnet_tpu as mx
     from mxnet_tpu.models import get_zaya_lm
     layers = 2
@@ -374,7 +381,20 @@ def test_zaya_decode_program(one_chip, monkeypatch):
     compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
         .lower(*args).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 2 * layers
+    assert text.count("tpu_custom_call") == 3 * layers
+    # `%copy-start = (bf16[32,2048,256]{...}, bf16[32,2048,256]{...S(1)},
+    # ...) copy-start(...)`, `%slice-start.4 = (..., bf16[8,2048,256]
+    # {...S(1)}, ...) slice-start(...)`: a shape in fast memory on the
+    # line of an asynchronous copy, of a buffer's rows: all 32 slots
+    # or some of them (a weight's prefetch is the size of a quarter)
+    rows = ",%d,%d" % eng._caches[0][0].shape[1:]
+    staged = [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(r"\b(copy-start|copy-done|slice-start)\(", line)
+        for dims in re.findall(r"\w+\[([\d,]+)\]\{[^}]*S\(1\)[^}]*\}",
+                               line)
+        if dims.endswith(rows)]
+    assert not staged, staged
     stack = 16 * 4096 * 2048
     copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
     assert not [d for d in copies

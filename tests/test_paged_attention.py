@@ -308,7 +308,7 @@ def _kind_decoder(kind):
         from mxnet_tpu.models import get_zaya_lm
         sym = get_zaya_lm(VOCAB, 1, 16, 4, 2, 8, 2, 16, 8, rotary_dim=4,
                           impl="dense")
-        return Decoder(sym, _init_params(sym, rng), max_len=T), False
+        return Decoder(sym, _init_params(sym, rng), max_len=T), True
     lm_kw, dec_kw = {
         "linear": ({}, {}),
         "gqa_rope": (dict(pos_encoding="rope", num_kv_heads=1), {}),
@@ -326,8 +326,9 @@ def _kind_decoder(kind):
 def test_read_follows_the_cache_kind(kind):
     """The table above ``Decoder._cached_mha``, by what is traced: the
     bounded read (a ``pallas_call``) is in the slot walk exactly where
-    the cached nodes are MultiHeadAttention over a linear cache, and
-    never in the offline step. No option chooses."""
+    the cached nodes hold linear rows (MultiHeadAttention over a linear
+    cache, CCAttention), and never in the offline step. No option
+    chooses."""
     dec, bounded = _kind_decoder(kind)
     S = 2
     i32 = jnp.int32
@@ -342,8 +343,8 @@ def test_read_follows_the_cache_kind(kind):
 
 
 @pytest.mark.parametrize("rows,row_bytes,want", [
-    (1024, 4096, 256), (2048, 512, 512), (1000, 4096, 8),
-    (1001, 4096, 1001)])
+    (1024, 4096, 256), (2048, 512, 1024), (2048, 2048, 512),
+    (2048, 4096, 256), (1000, 4096, 8), (1001, 4096, 1001)])
 def test_default_paged_block_k_from_shapes(rows, row_bytes, want):
     """The block is a pure function of the stored shapes: the largest
     listed divisor of the rows within 1 MB a block, else the rows."""

@@ -299,6 +299,59 @@ def test_decoder_prefill_then_decode_matches_the_full_forward(
     assert worst < TOL * ref.std()
 
 
+@pytest.mark.parametrize("live,chunk", [
+    ((1, 1, 1, 1, 1), 1), ((1, 0, 1, 0, 1), 1), ((0, 0, 0, 1, 0), 1),
+    ((1, 0, 1, 0, 1), 3)],
+    ids=["all_live", "some_dead", "one_live", "chunk_of_3"])
+def test_bounded_read_equals_the_dense_read(decoder, monkeypatch, live,
+                                            chunk):
+    """The slot walk's read of CCAttention's rows (``_paged_read``,
+    blocks of 16 of the toy's 48 rows) against the dense read of the
+    same rows (``_lane_attn``, every row read and masked), each slot at
+    its own position: lengths of under a block, of a block and a part,
+    and up to the last row. Live slots agree within the toy limit and
+    write the same rows and state; a slot that holds no request (length
+    0, a stale position) gets zeros from the read, and finite logits."""
+    S, C = len(live), chunk
+    live = np.asarray(live, bool)
+    rng = np.random.default_rng(5)
+    caches = [tuple(jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+                    for x in entry) for entry in decoder.init_cache(S)]
+    pos = jnp.asarray([20, 31, MAX_LEN - C, 15, 5], jnp.int32)
+    lens = jnp.where(live, pos + C, 0)
+    toks = jnp.asarray(rng.integers(0, 320, (S, C)), jnp.int32)
+    reads = []
+
+    def spy(read):
+        def wrapped(*a, **kw):
+            reads.append(read(*a, **kw))
+            return reads[-1]
+        return wrapped
+
+    stats = {}
+    monkeypatch.setattr(decoder, "_paged_read", spy(decoder._paged_read))
+    got, cb = decoder._run_slots(decoder._params, decoder._aux, caches,
+                                 pos, toks, lens=lens, stats=stats)
+    assert len(reads) == 2                       # one a CCA node
+    assert int(stats["attn_rows_read"]) == 2 * int(
+        pk.paged_rows_fetched(lens, MAX_LEN, 16))
+    monkeypatch.setattr(
+        decoder, "_paged_read",
+        lambda q, entry, pos, kv, lens=None, stats=None:
+        decoder._lane_attn(q, entry, pos, kv))
+    want, cd = decoder._run_slots(decoder._params, decoder._aux, caches,
+                                  pos, toks, lens=lens)
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.abs(got - want)[live].max() < TOL * want[live].std()
+    assert np.isfinite(got).all()
+    for o in reads:
+        assert not np.asarray(o)[~live].any()
+    for a, b in zip(jax.tree_util.tree_leaves(cb),
+                    jax.tree_util.tree_leaves(cd)):
+        np.testing.assert_allclose(np.asarray(a)[live], np.asarray(b)[live],
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_decoder_cache_declares_the_rolling_state(decoder):
     from mxnet_tpu.parallel.decode import STATE_ROWS
     caches = decoder.init_cache(3)
@@ -352,6 +405,27 @@ def test_engine_end_to_end_two_buckets_staggered_arrivals(toy, decoder):
     assert counts["prefill"] == {8: 1, 16: 1}
     steps = mx.telemetry.counter("serving.moe_layer_steps").value - before
     assert steps == 2 * 4 * eng.stats["steps"]      # layers x steps x rounds
+    eng.close()
+
+
+def test_engine_counts_the_rows_its_bounded_reads_fetch(decoder):
+    """``serving.attn_rows_read`` / ``serving.attn_rows_pool`` cover
+    CCAttention's K rows: the pool is slots x rows x layers for every
+    decode step, and the reads fetch some of it (a request's rows,
+    block-rounded), never more."""
+    read = mx.telemetry.counter("serving.attn_rows_read")
+    pool = mx.telemetry.counter("serving.attn_rows_pool")
+    r0, p0 = read.value, pool.value
+    eng = make_engine(decoder)
+    assert eng._attn_pool_rows == 3 * MAX_LEN * 2
+    rng = np.random.default_rng(6)
+    for n in (9, 2):
+        eng.submit(rng.integers(0, 320, n).astype(np.int32), max_tokens=10)
+    eng.serve_forever()
+    assert pool.value - p0 == 3 * MAX_LEN * 2 * 4 * eng.stats["steps"]
+    assert 0 < read.value - r0 <= pool.value - p0
+    # two of three slots ever hold a request, of at most two blocks
+    assert read.value - r0 <= 2 * 32 * 2 * 4 * eng.stats["steps"]
     eng.close()
 
 

@@ -13,7 +13,13 @@ slots and the old kernel, are answered in PERF.md section 6, PR 29):
                pos + 1 : 0, at each block size given
 
     python tools/probe_attn_read.py [--live 5] [--blocks 256,512]
-        [--layers 4] [--shape 16,1024,32,64]
+        [--layers 4] [--shape 16,1024,32,64] [--kv 32] [--fill 0.6,1.0]
+    python tools/probe_attn_read.py --shape 32,2048,8,128 --kv 2
+        --live 12 --blocks 256,512,1024,2048
+
+The second is CCAttention's read in ``zaya1-8b.serve-reason``: 8 query
+heads over 2 kv heads of 128 (``--kv``: grouped-query heads; default
+H), rows of 512 B where serve-chat's hold 4 KB.
 
 Prints one JSON line per form: ms a layer and step, the rows read,
 and the largest gap to the dense read's output.
@@ -50,16 +56,23 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--shape", default="16,1024,32,64",
                     help="S,L,H,D (smaller for a rehearsal off the chip)")
+    ap.add_argument("--fill", default="0.6,1.0",
+                    help="a slot's position is drawn between these "
+                         "shares of L")
+    ap.add_argument("--kv", type=int, default=None,
+                    help="kv heads the H query heads share (default H)")
     args = ap.parse_args()
     S, L, H, D = (int(x) for x in args.shape.split(","))
+    KV = args.kv or H
     dev = jax.devices()[0]
     print(json.dumps({"device": dev.platform, "kind": dev.device_kind}))
     rng = np.random.RandomState(args.seed)
     dt = jnp.dtype(args.dtype)
-    w = H * D
+    w = KV * D
     # every slot has held a request: stale positions everywhere, a few
     # slots live
-    pos = rng.randint(L * 6 // 10, L - 24, (S,)).astype(np.int32)
+    lo, hi = (float(x) for x in args.fill.split(","))
+    pos = rng.randint(int(L * lo), int(L * hi) - 24, (S,)).astype(np.int32)
     live = np.zeros((S,), bool)
     live[rng.permutation(S)[:args.live]] = True
     lens = np.where(live, pos + 1, 0).astype(np.int32)
@@ -74,11 +87,11 @@ def main():
     plain = types.SimpleNamespace(_cache_int8=False)
 
     def dense(q, k, v):
-        return Decoder._lane_attn(plain, q, (k, v), posj, H)
+        return Decoder._lane_attn(plain, q, (k, v), posj, KV)
 
     def bounded(bk):
         def f(q, k, v):
-            return pk.paged_attention(q, k, v, posj, kv_heads=H,
+            return pk.paged_attention(q, k, v, posj, kv_heads=KV,
                                       lens=lensj, block_k=bk)
         return f
 
