@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """Read the two ends a limit is set between, on the chip at the cell's own
 size: the program's numbers over many seeds (the lower reading), and the
-control's and the planted faults' over a few (the upper reading).
+control's and the planted faults' over a few (the upper reading; for a
+serving cell the fault is one served token altered, read at the same
+positions as the control).
 
     python3 benchmark/calibrate.py --workload <cell> --seeds 1,2,3,... \
         --control-seeds 1,2,3 [--seconds 12]
@@ -100,7 +102,7 @@ def serve(cell, seeds, control_seeds, seconds):
     from benchmark.drivers import serve as D
     fam, cfg, traffic = cell["family"], cell["cfg"], cell["traffic"]
     control = cell["limits"].get("control_precision", "fp8")
-    out = {"program": [], "control": []}
+    out = {"program": [], "control": [], "altered": []}
     for seed in seeds:
         engine, _ = D.build_engine(mx, fam, cfg, traffic, seed)
         reqs = G.requests(traffic, cfg["vocab_size"], seed, seconds)
@@ -121,18 +123,22 @@ def serve(cell, seeds, control_seeds, seconds):
         engine.close()
         del engine
         gc.collect()
-        got = D.judge(cell, seed, picked,
-                      control if seed in control_seeds else None)
-        nums = {"logit_gap": got["logit_gap"]}
+        ends = seed in control_seeds
+        got = D.judge(cell, seed, picked, control if ends else None,
+                      altered=ends)
+        nums = {k: got[k] for k in D.GAP_NUMBERS}
         say("program", seed, dict(nums, tokens=got["tokens"],
                                   requests=len(recs)))
         through_limits(cell, "program", seed, nums)
         out["program"].append(nums)
-        if "control_gap" in got:
-            nums = {"logit_gap": got["control_gap"]}
-            say("control", seed, dict(nums, tokens=got["tokens"]))
-            through_limits(cell, "control", seed, nums)
-            out["control"].append(nums)
+        # the two upper ends: the control's tokens, and the served ones
+        # with one of them altered (drivers/serve.altered_token_gaps)
+        for kind in ("control", "altered"):
+            if got.get(kind):
+                say(kind, seed, dict(got[kind], tokens=got["tokens"]))
+                nums = {k: got[kind][k] for k in D.GAP_NUMBERS}
+                through_limits(cell, kind, seed, nums)
+                out[kind].append(nums)
     return out
 
 

@@ -29,7 +29,7 @@ def build_engine(mx, fam, cfg, traffic, seed):
                              jnp.dtype(cfg["compute_dtype"]))
     dec = mx.parallel.Decoder(sym, weights, max_len=traffic["max_len"],
                               compute_dtype=cfg["compute_dtype"],
-                              cache_block=None, weight_dtype="float")
+                              weight_dtype="float")
     weight_bytes = sum(int(v.nbytes) for v in weights.values())
     del weights
     engine = mx.serving.InferenceEngine(
@@ -206,41 +206,85 @@ def pack(picked, length):
     return seqs, nxt, judged
 
 
-def logit_gap(logits, tokens, judged):
-    """The widest gap by which a token's logit lies below the reference's
-    best at its position, in units of that row's standard deviation, over
-    the judged positions. ``tokens`` are the served ones, or those the
-    control puts first."""
+GAP_NUMBERS = ("logit_gap", "logit_gap_mean", "logit_gap_p99")
+
+
+def token_gaps(logits, tokens):
+    """By how far each token's logit lies below the reference's best at
+    its position, in units of that row's standard deviation: [K, L]."""
     import jax
     import jax.numpy as jnp
 
-    def widest(lg, tok, m):
+    def gaps(lg, tok):
         got = jnp.take_along_axis(lg, tok[..., None], -1)[..., 0]
         gap = (jnp.max(lg, -1) - got) / jnp.std(lg, -1)
-        gap = jnp.where(jnp.isfinite(gap), gap, jnp.inf)
-        return jnp.max(jnp.where(m, gap, 0.0))
-    return float(jax.jit(widest)(logits, jnp.asarray(tokens),
-                                 jnp.asarray(judged)))
+        return jnp.where(jnp.isfinite(gap), gap, jnp.inf)
+    return np.asarray(jax.jit(gaps)(logits, jnp.asarray(tokens)), np.float64)
 
 
-def judge(cell, seed, picked, control=None):
+def logit_gaps(logits, tokens, judged):
+    """``token_gaps`` over the judged positions: the widest gap
+    (``logit_gap``), the mean and the 99th percentile (an observed
+    value). ``tokens`` are the served ones, or those the control puts
+    first. Every serving cell is held to the widest: it is one token's,
+    so it is the number that sees ONE wrong token among thousands (and,
+    in a routed model, one routing cascade). The other two read what a
+    precision does to all tokens; a limits file may hold its cell to
+    them as well, never instead."""
+    return _numbers(token_gaps(logits, tokens)[np.asarray(judged, bool)])
+
+
+def _numbers(g):
+    if g.size == 0:
+        return dict.fromkeys(GAP_NUMBERS, 0.0)
+    return {"logit_gap": float(g.max()), "logit_gap_mean": float(g.mean()),
+            "logit_gap_p99": float(np.percentile(g, 99, method="higher"))}
+
+
+def logit_gap(logits, tokens, judged):
+    """The widest of ``logit_gaps``."""
+    return logit_gaps(logits, tokens, judged)["logit_gap"]
+
+
+def altered_token_gaps(logits, tokens, judged, vocab):
+    """The fault "one token altered where it is produced", read without a
+    run of its own: ``tokens`` with ONE of them replaced by the next id
+    up. The altered token's gap depends on where it falls, so the three
+    numbers are given at the position where it reads the 1st percentile
+    (an observed value: at 99 of 100 positions it reads that or more),
+    with the least and the median over the positions beside them."""
+    judged = np.asarray(judged, bool)
+    own = token_gaps(logits, tokens)[judged]
+    alt = token_gaps(logits, (np.asarray(tokens) + 1) % vocab)[judged]
+    if alt.size == 0:
+        return {}
+    i = int(np.argsort(alt)[alt.size // 100])
+    own[i] = alt[i]
+    return dict(_numbers(own), altered_least=float(alt.min()),
+                altered_median=float(np.median(alt)))
+
+
+def judge(cell, seed, picked, control=None, altered=False):
     """Hold ``picked`` (finished requests) to the reference. Returns
-    {"logit_gap", "tokens", and with ``control`` (a precision)
-    "control_gap": the gap of the token that the reference in that
-    precision puts first, at the same positions}."""
+    ``logit_gaps`` of the served tokens, "tokens", with ``control`` (a
+    precision) "control": the gaps of the token that the reference in
+    that precision puts first, at the same positions, and with
+    ``altered`` "altered": ``altered_token_gaps`` of the served tokens."""
     import jax.numpy as jnp
     fam, cfg, traffic = cell["family"], cell["cfg"], cell["traffic"]
     if not picked:
-        return {"logit_gap": float("inf"), "tokens": 0}
+        return dict(dict.fromkeys(GAP_NUMBERS, float("inf")), tokens=0)
     length = traffic["prompt"]["max"] + traffic["output"]["max"]
     seqs, nxt, judged = pack(picked, length)
     logits = reference_logits(fam, cfg, seed, seqs)
-    out = {"logit_gap": logit_gap(logits, nxt, judged),
-           "tokens": int(judged.sum())}
+    out = dict(logit_gaps(logits, nxt, judged), tokens=int(judged.sum()))
+    if altered:
+        out["altered"] = altered_token_gaps(logits, nxt, judged,
+                                            cfg["vocab_size"])
     if control is not None:
         low = reference_logits(fam, cfg, seed, seqs, precision=control)
-        out["control_gap"] = logit_gap(logits, jnp.argmax(low, axis=-1),
-                                       judged)
+        out["control"] = logit_gaps(logits, jnp.argmax(low, axis=-1),
+                                    judged)
     return out
 
 
@@ -329,10 +373,16 @@ def run(ctx):
     checks = H.Checks(cell["limits"])
     t_ref = time.perf_counter()
     got = judge(cell, seed, picked)
-    print("serve: reference judged %d tokens of %d requests in %.1f s"
-          % (got["tokens"], len(picked), time.perf_counter() - t_ref),
+    print("serve: reference judged %d tokens of %d requests in %.1f s; %s"
+          % (got["tokens"], len(picked), time.perf_counter() - t_ref,
+             " ".join("%s=%.6g" % (k, got[k]) for k in GAP_NUMBERS)),
           flush=True)
+    # the widest gap is held in every serving cell; the mean and the 99th
+    # percentile beside it where the limits file names them
     checks.add("logit_gap", got["logit_gap"])
+    for k in GAP_NUMBERS[1:]:
+        if k in cell["limits"]["limits"]:
+            checks.add(k, got[k])
     checks.add("never_answered", unanswered + errored + short, 0)
     checks.add("compiles_in_window", compiles_in_window, 0)
 

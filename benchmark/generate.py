@@ -1,16 +1,19 @@
 """The one general traffic generator for serving cells. It reads a traffic
-file's parameters (lengths, rate, arrival law) and makes the requests of
-one run from the seed.
+file's parameters (lengths, rate, arrival law, order) and makes the
+requests of one run from the seed.
 
-Every seed gets the SAME multiset of prompt lengths, of output lengths and
+Every run gets the SAME multiset of prompt lengths, of output lengths and
 of inter-arrival gaps -- the quantiles of the laws the file names -- each
-shuffled by the seed over the WHOLE window, independently of the others,
-and its own token ids. So the offered work of a window is the same for
-every seed (the same requests, the same total of gaps) while the order is
-free: short gaps fall together by chance as they do in a Poisson stream,
-a long prompt meets a burst or a lull, and what the close of the window
-cuts off differs from seed to seed. This is not an i.i.d. draw: the
-empirical laws are exact in every run, only the order is random.
+shuffled over the WHOLE window, independently of the others. The shuffles
+are the traffic file's (``order_seed``, which every serving file carries):
+every seed offers the SAME requests at the SAME times, one sample path of
+the stream with its bursts and lulls where that constant put them, short
+gaps falling together as they do in a Poisson stream; the run's seed makes
+the token ids (and the weights). This is not an i.i.d. draw: the empirical
+laws are exact in every run. Until PR 33 the run's seed drew the order
+too; since PR 29 a step's time follows the occupancy, the order then IS
+the work, and a free order moved a tail by more than any bound (PERF.md
+section 6, PR 33), so there is one path and no other.
 """
 from __future__ import annotations
 
@@ -53,12 +56,17 @@ def gaps(traffic, n, seconds):
 
 def requests(traffic, vocab, seed, seconds):
     """[(due_s, prompt ids int32, max_tokens)] for one open-loop window
-    of ``seconds`` at the file's ``rate_per_s``, in order of arrival."""
+    of ``seconds`` at the file's ``rate_per_s``, in order of arrival: the
+    lengths and due times are the file's (``order_seed``), the ids the
+    seed's."""
+    if traffic.get("order_seed") is None:
+        raise BenchError("generate: the traffic file names no order_seed")
     n = max(1, int(math.floor(traffic["rate_per_s"] * seconds + 0.5)))
     rng = np.random.default_rng(int(seed))
-    plen = rng.permutation(lengths(traffic["prompt"], n))
-    olen = rng.permutation(lengths(traffic["output"], n))
-    due = np.cumsum(rng.permutation(gaps(traffic, n, seconds)))
+    order = np.random.default_rng(int(traffic["order_seed"]))
+    plen = order.permutation(lengths(traffic["prompt"], n))
+    olen = order.permutation(lengths(traffic["output"], n))
+    due = np.cumsum(order.permutation(gaps(traffic, n, seconds)))
     due -= due[0] / 2.0          # the first a half-gap in, the last inside
     out = []
     for i in range(n):

@@ -52,6 +52,73 @@ def test_serving_control_is_not_correct(toy_harness):
     assert gap > c["limits"]["limits"]["logit_gap"]
 
 
+def test_gap_numbers_by_hand():
+    """``logit_gaps`` over the judged positions only: the widest gap, the
+    mean and the 99th percentile (an observed value), in standard
+    deviations of each row; ``altered_token_gaps`` is the same three with
+    ONE token replaced by the next id up."""
+    from benchmark.drivers import serve as D
+    row = [0.0, 1.0, 2.0]                      # std sqrt(2/3)
+    logits = np.array([[row, row, row, [0.0, 0.0, 9.0]]], np.float32)
+    tokens = np.array([[2, 1, 0, 0]])          # gaps 0, 1, 2 and unjudged
+    judged = np.array([[True, True, True, False]])
+    got = D.logit_gaps(logits, tokens, judged)
+    sd = float(np.std(row))
+    assert got["logit_gap"] == pytest.approx(2.0 / sd, rel=1e-6)
+    assert got["logit_gap_mean"] == pytest.approx(1.0 / sd, rel=1e-6)
+    assert got["logit_gap_p99"] == got["logit_gap"]
+    assert D.logit_gap(logits, tokens, judged) == got["logit_gap"]
+    assert set(got) == set(D.GAP_NUMBERS)
+    none = D.logit_gaps(logits, tokens, np.zeros_like(judged))
+    assert none == dict.fromkeys(D.GAP_NUMBERS, 0.0)
+    # every token the best but for the one that is altered: the next id
+    # up of 2 is 0 (two below the best), of the others one or none below
+    best = np.array([[2, 2, 2, 2]])
+    alt = D.altered_token_gaps(logits, best, judged, 3)
+    assert alt["altered_least"] == alt["altered_median"] \
+        == alt["logit_gap"] == pytest.approx(2.0 / sd, rel=1e-6)
+    assert alt["logit_gap_mean"] == pytest.approx(2.0 / sd / 3, rel=1e-6)
+
+
+SERVE_CELLS = ["opt-1.3b.serve-chat", "zaya1-8b.serve-reason"]
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+def test_every_serving_cell_is_held_to_the_widest_gap(cell):
+    """The cell's own limits, not the toy's: the widest gap is among them
+    (the driver refuses a limits file without it), any other is one of
+    the driver's numbers, and the toy block holds the toy cell to the
+    same names, so what decides ``correct`` in the tests is what decides
+    it on the chip."""
+    from benchmark import harness as H
+    from benchmark.drivers import serve as D
+    real = H.load_json(H.CODE, "limits", cell + ".json")
+    assert "logit_gap" in real["limits"]
+    assert set(real["limits"]) <= set(D.GAP_NUMBERS)
+    assert set(real["toy"]["limits"]) == set(real["limits"])
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+def test_one_wrong_token_among_thousands_fails_the_cells_own_limits(cell):
+    """Three thousand judged tokens, each the reference's best but one
+    that lies 2.4 standard deviations under it (on the chip one altered
+    token reads 2.2-3.5 at the 1st percentile of positions and 4-6 at
+    the median: limits/<cell>.json, ``altered``). By the cell's own
+    limits, not the toy's, the run is not correct, and the widest gap is
+    the only number that says so: the mean moves by 0.0008."""
+    from benchmark import harness as H
+    from benchmark.drivers import serve as D
+    limits = H.load_json(H.CODE, "limits", cell + ".json")["limits"]
+    logits = np.tile(np.array([0.0, 1.0, 2.0], np.float32), (3, 1000, 1))
+    tokens = np.full((3, 1000), 2)
+    judged = np.ones((3, 1000), bool)
+    got = D.logit_gaps(logits, tokens, judged)
+    assert all(got[k] <= limits[k] for k in limits)
+    tokens[1, 517] = 0
+    got = D.logit_gaps(logits, tokens, judged)
+    assert [k for k in limits if got[k] > limits[k]] == ["logit_gap"]
+
+
 TRAIN_CELLS = ["resnet50.train-b256", "opt-1.3b.train-2k"]
 
 
@@ -139,5 +206,33 @@ def test_token_altered_where_it_is_produced(toy_harness, capsys,
         return real(self, req, slot, t, now)
     monkeypatch.setattr(InferenceEngine, "_push_token", altered)
     line, err = _run("opt-1.3b.serve-chat", capsys, seconds="3")
+    assert line["correct"] is False
+    assert "logit_gap" in _failed(line), err
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+def test_one_token_altered_where_it_is_produced(toy_harness, capsys,
+                                                monkeypatch, cell):
+    """ONE token of the whole run, in the middle of the window's longest
+    request (the sample always holds it), replaced by the next id up as
+    the engine hands it out."""
+    from benchmark import generate as G
+    from mxnet_tpu.serving.engine import InferenceEngine
+    c = toy_harness.load_cell(cell)
+    vocab = c["cfg"]["vocab_size"]
+    reqs = G.requests(c["traffic"], vocab, 77, 3.0)
+    _, prompt, n = max(reqs, key=lambda r: len(r[1]) + r[2])
+    real = InferenceEngine._push_token
+    seen = {"n": 0}
+
+    def altered(self, req, slot, t, now):
+        if len(req.tokens) == n // 2 and req.limit == n \
+                and np.array_equal(req.prompt, prompt) and not seen["n"]:
+            seen["n"] += 1
+            t = (int(t) + 1) % vocab
+        return real(self, req, slot, t, now)
+    monkeypatch.setattr(InferenceEngine, "_push_token", altered)
+    line, err = _run(cell, capsys, seconds="3")
+    assert seen["n"] == 1
     assert line["correct"] is False
     assert "logit_gap" in _failed(line), err

@@ -190,7 +190,7 @@ def test_of_run_parses_once_and_follows_the_newest_trace(as_run,
 def test_every_new_entry_names_its_cells_and_resolves():
     bench = json.load(open(os.path.join(H.ROOT, "BENCHMARK.json")))
     rows = {m["name"]: m for m in bench["per_layer"]}
-    assert list(rows)[-len(NEW):] == NEW      # appended, in this order
+    assert set(NEW) <= set(rows)      # by name: later PRs append their own
     for name in NEW:
         assert callable(load(name).read)
         moved = {e["name"]: e for e in bench["end_to_end"]}[

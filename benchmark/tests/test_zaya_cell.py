@@ -22,6 +22,11 @@ def test_toy_cell_runs_end_to_end(toy_harness, capsys):
     assert line["correct"] is True, err
     assert line["failed"] == 0 and line["attempted"] > 0
     assert set(line["metrics"]) == {"tpot_p90_ms", "setup_s"}
+    # held to the widest gap, the mean and the 99th percentile, as the
+    # cell is on the chip (limits/<cell>.json)
+    assert set(line["checks"]) == {
+        "logit_gap", "logit_gap_mean", "logit_gap_p99", "never_answered",
+        "compiles_in_window"}
     assert line["checks"]["logit_gap"]["value"] <= 1e-3
     assert line["checks"]["never_answered"]["value"] == 0
     assert line["checks"]["compiles_in_window"]["value"] == 0
@@ -44,8 +49,10 @@ def test_control_is_not_correct(toy_harness):
     low = D.reference_logits(fam, cfg, 77, seqs, precision="fp8")
     judged = np.ones(seqs.shape, bool)
     assert D.logit_gap(ref, np.asarray(ref).argmax(-1), judged) == 0.0
-    gap = D.logit_gap(ref, np.asarray(low).argmax(-1), judged)
-    assert gap > c["limits"]["limits"]["logit_gap"]
+    got = D.logit_gaps(ref, np.asarray(low).argmax(-1), judged)
+    limits = c["limits"]["limits"]
+    assert set(limits) == set(D.GAP_NUMBERS)
+    assert all(got[k] > limits[k] for k in limits), got
 
 
 def test_counters_by_hand():
