@@ -128,6 +128,24 @@
   mx.symbol.internal.create("FullyConnected", name, list(...))
 }
 
+#' Causal grouped-query softmax attention with a head size of its
+#'
+#' Parameters: eps (float, default 1e-06); head_dim (int); impl (str, default flash); num_heads (int); num_kv_heads (int); rope_base (float, default 10000.0); rotary_dim (int, default 0)
+#' Symbol inputs: data, q_weight, k_weight, v_weight, q_norm, k_norm, out_weight
+#' @export
+`mx.symbol.GatedAttention` <- function(..., name = "") {
+  mx.symbol.internal.create("GatedAttention", name, list(...))
+}
+
+#' A Gated DeltaNet mixer (linear attention; Yang et al.,
+#'
+#' Parameters: conv_kernel (int, default 4); eps (float, default 1e-06); head_k_dim (int); head_v_dim (int); num_k_heads (int); num_v_heads (int)
+#' Symbol inputs: data, qkvz_weight, ba_weight, conv_weight, a_log, dt_bias, norm_weight, out_weight
+#' @export
+`mx.symbol.GatedDeltaNet` <- function(..., name = "") {
+  mx.symbol.internal.create("GatedDeltaNet", name, list(...))
+}
+
 #' Identity forward that attaches a KL sparsity penalty gradient
 #'
 #' Parameters: momentum (float, default 0.9); penalty (float, default 0.001); sparseness_target (float, default 0.1)
@@ -193,7 +211,7 @@
 
 #' Mixture-of-experts position-wise FFN (soft or top-k routing).
 #'
-#' Parameters: gated (bool, default False); hidden (int); num_experts (int); router (str, default linear); top_k (int, default 0)
+#' Parameters: expert_first (int, default 0); experts_held (int, default 0); gated (bool, default False); hidden (int); num_experts (int); router (str, default linear); shared_hidden (int, default 0); top_k (int, default 0)
 #' Symbol inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2
 #' @export
 `mx.symbol.MoEFFN` <- function(..., name = "") {

@@ -13,7 +13,8 @@ Parity map (reference ``example/``):
 * ``example/rnn/lstm.py`` (unroll + bucketing)          -> :mod:`.lstm`
 * ``example/fcn-xs/symbol_fcnxs.py``                    -> :mod:`.fcn`
 * no reference counterpart (decoder-only LMs):
-  ``get_transformer_lm``, ``get_zaya_lm``                 -> :mod:`.transformer`
+  ``get_transformer_lm``, ``get_zaya_lm``,
+  ``get_qwen3_next_lm``                                 -> :mod:`.transformer`
 
 Every constructor returns a :class:`mxnet_tpu.symbol.Symbol` whose single
 head is a ``SoftmaxOutput`` (classification) so it drops straight into
@@ -33,7 +34,8 @@ from .lstm import lstm_unroll, LSTMState, LSTMParam
 from .fcn import get_fcn_symbol
 from . import transformer
 from .transformer import (get_transformer_lm, transformer_block,
-                          moe_transformer_block, get_zaya_lm, zaya_block)
+                          moe_transformer_block, get_zaya_lm, zaya_block,
+                          get_qwen3_next_lm, qwen3_next_block)
 
 _REGISTRY = {
     "mlp": get_mlp,

@@ -314,6 +314,93 @@ def get_zaya_lm(vocab_size, num_layers, embed_dim, num_heads, num_kv_heads,
     return _lm_loss(logits, vocab_size, loss_layout)
 
 
+def qwen3_next_block(data, name, attention, num_heads, num_kv_heads,
+                     head_dim, linear_k_heads, linear_v_heads,
+                     linear_k_dim, linear_v_dim, conv_kernel, num_experts,
+                     expert_hidden, top_k, shared_hidden, experts_held=0,
+                     expert_first=0, rotary_dim=0, rope_base=1e7,
+                     eps=1e-6, impl="flash"):
+    """One Qwen3-Next layer: ``x + mixer(RMSNorm(x))`` then
+    ``x + moe(RMSNorm(x))``, the mixer a ``GatedAttention``
+    (``attention``) or a ``GatedDeltaNet``, the experts routed top-k by
+    a linear gate over all ``num_experts`` with one shared expert
+    (``MoEFFN``; ``experts_held`` / ``expert_first``: the share of the
+    routed experts that lives here, 0 = all). RMSNorm's scale is
+    stored as ``gamma = 1 + w`` for the published zero-centred weight
+    ``w``: a storage choice, the same function."""
+    h = _rms(data, name + "_mixer_norm", eps)
+
+    def var(tag):
+        return sym.Variable("%s_%s" % (name, tag))
+
+    if attention:
+        mix = sym.GatedAttention(
+            data=h, q_weight=var("attn_q_weight"),
+            k_weight=var("attn_k_weight"), v_weight=var("attn_v_weight"),
+            q_norm=var("attn_q_norm"), k_norm=var("attn_k_norm"),
+            out_weight=var("attn_out_weight"), num_heads=num_heads,
+            num_kv_heads=num_kv_heads, head_dim=head_dim,
+            rotary_dim=rotary_dim, rope_base=rope_base, eps=eps,
+            impl=impl, name=name + "_attn")
+    else:
+        mix = sym.GatedDeltaNet(
+            data=h, qkvz_weight=var("gdn_qkvz_weight"),
+            ba_weight=var("gdn_ba_weight"),
+            conv_weight=var("gdn_conv_weight"), a_log=var("gdn_a_log"),
+            dt_bias=var("gdn_dt_bias"),
+            norm_weight=var("gdn_norm_weight"),
+            out_weight=var("gdn_out_weight"),
+            num_k_heads=linear_k_heads, num_v_heads=linear_v_heads,
+            head_k_dim=linear_k_dim, head_v_dim=linear_v_dim,
+            conv_kernel=conv_kernel, eps=eps, name=name + "_gdn")
+    x = data + mix
+    moe = sym.MoEFFN(
+        data=_rms(x, name + "_moe_norm", eps),
+        gate_weight=var("moe_gate_weight"), expert_w1=var("expert_w1"),
+        expert_w2=var("expert_w2"), shared_w1=var("shared_w1"),
+        shared_w2=var("shared_w2"), shared_gate=var("shared_gate"),
+        num_experts=num_experts, hidden=expert_hidden, top_k=top_k,
+        gated=True, experts_held=experts_held, expert_first=expert_first,
+        shared_hidden=shared_hidden, name=name + "_moe")
+    return x + moe
+
+
+def get_qwen3_next_lm(vocab_size, num_layers, embed_dim, num_heads,
+                      num_kv_heads, head_dim, linear_k_heads,
+                      linear_v_heads, linear_k_dim, linear_v_dim,
+                      num_experts, expert_hidden, top_k, shared_hidden,
+                      full_attention_interval=4, conv_kernel=4,
+                      experts_held=0, expert_first=0, rotary_dim=0,
+                      rope_base=1e7, eps=1e-6, impl="flash",
+                      loss_layout="reference"):
+    """Qwen3-Next-shaped decoder-only LM: ``num_layers`` of
+    ``qwen3_next_block``, layer ``i`` a ``GatedAttention`` layer where
+    ``(i + 1) % full_attention_interval == 0`` and a ``GatedDeltaNet``
+    layer otherwise, between an embedding and an untied head behind a
+    final RMSNorm; no biases, no positional table (rotary inside the
+    attention layers). Built from registered Symbol ops like every zoo
+    model: it binds, and is served by ``Decoder`` / ``InferenceEngine``,
+    which keep K/V rows for the attention layers and a recurrent state
+    for the others. ``loss_layout`` as in ``get_transformer_lm``."""
+    net = sym.Embedding(data=sym.Variable("data"),
+                        weight=sym.Variable("embed_weight"),
+                        input_dim=vocab_size, output_dim=embed_dim,
+                        name="embed")
+    for i in range(num_layers):
+        net = qwen3_next_block(
+            net, "layer%d" % i, (i + 1) % full_attention_interval == 0,
+            num_heads, num_kv_heads, head_dim, linear_k_heads,
+            linear_v_heads, linear_k_dim, linear_v_dim, conv_kernel,
+            num_experts, expert_hidden, top_k, shared_hidden,
+            experts_held=experts_held, expert_first=expert_first,
+            rotary_dim=rotary_dim, rope_base=rope_base, eps=eps,
+            impl=impl)
+    logits = sym.FullyConnected(
+        data=_rms(net, "final_norm", eps), num_hidden=vocab_size,
+        no_bias=True, flatten=False, name="lm_head")
+    return _lm_loss(logits, vocab_size, loss_layout)
+
+
 def tp_rules():
     """Tensor-parallel sharding rules for transformer params (Megatron
     layout: QKV/FFN1 column-parallel, proj/FFN2 row-parallel) — pass to

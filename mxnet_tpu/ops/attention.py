@@ -181,20 +181,56 @@ class MoEFFN(OpSpec):
     per expert) the experts run in the dense form: every local expert
     computes every token and the gates, zero outside the top k, pick
     the result; the combine ends in one ``psum`` over ``ep``.
+
+    A SHARE of the experts (``experts_held`` > 0): this node holds
+    the experts ``[expert_first, expert_first + experts_held)`` of
+    ``num_experts`` and no others, as one chip of an expert-parallel
+    deployment does. The router keeps all ``num_experts`` rows and
+    routes over all of them; the expert stacks have ``experts_held``
+    leading rows. The node computes the token-expert pairs that fall
+    on its own experts, weighs them with gates normalized over ALL of
+    a token's ``top_k`` choices, and leaves out what the absent experts
+    would have added: the shares of all holders add up to the whole
+    layer. Routed form only (``top_k`` > 0, plain products, no ``ep``).
+
+    A SHARED expert (``shared_hidden`` = its width > 0): one more
+    SiLU-gated expert that every token takes, behind a sigmoid gate:
+    ``out += sigmoid(shared_gate . x) * shared_w2 (silu(shared_w1[:H]
+    x) * (shared_w1[H:] x))``, ``shared_w1`` [2H, E], ``shared_w2``
+    [E, H], ``shared_gate`` [1, E]. Every holder of a share computes
+    it alike, so it counts once in their sum.
     """
 
     name = "MoEFFN"
     params = {"num_experts": Param("int"), "hidden": Param("int"),
               "top_k": Param("int", 0),
               "router": Param("str", "linear"),
-              "gated": Param("bool", False)}
+              "gated": Param("bool", False),
+              "experts_held": Param("int", 0),
+              "expert_first": Param("int", 0),
+              "shared_hidden": Param("int", 0)}
 
     def arguments(self, p):
         route = ["probs", "select_bias"] if self.given(p) \
             else ["gate_weight"]
         experts = ["expert_w1", "expert_w2"] if p["gated"] else \
             ["expert_w1", "expert_b1", "expert_w2", "expert_b2"]
-        return ["data"] + route + experts
+        shared = ["shared_w1", "shared_w2", "shared_gate"] \
+            if p.get("shared_hidden") else []
+        return ["data"] + route + experts + shared
+
+    @staticmethod
+    def held(p):
+        """(first, count) of the experts this node holds: all of them
+        unless ``experts_held`` says otherwise."""
+        nx = int(p["num_experts"])
+        n = int(p.get("experts_held") or 0) or nx
+        first = int(p.get("expert_first") or 0)
+        if first < 0 or n < 1 or first + n > nx:
+            raise MXNetError(
+                "MoEFFN: experts [%d, %d) are not among num_experts=%d"
+                % (first, first + n, nx))
+        return first, n
 
     @staticmethod
     def given(p):
@@ -213,11 +249,14 @@ class MoEFFN(OpSpec):
             e = d[2]
             x, h = p["num_experts"], p["hidden"]
             names = self.arguments(p)
+            _, n = self.held(p)
+            sh = p.get("shared_hidden") or 0
             want = {"gate_weight": (x, e), "probs": (d[0], d[1], x),
                     "select_bias": (x,),
-                    "expert_w1": (x, 2 * h if p["gated"] else h, e),
-                    "expert_b1": (x, h), "expert_w2": (x, e, h),
-                    "expert_b2": (x, e)}
+                    "expert_w1": (n, 2 * h if p["gated"] else h, e),
+                    "expert_b1": (n, h), "expert_w2": (n, e, h),
+                    "expert_b2": (n, e), "shared_w1": (2 * sh, e),
+                    "shared_w2": (e, sh), "shared_gate": (1, e)}
             for i, n in enumerate(names[1:], 1):
                 ins[i] = shape_assign(ins[i], want[n], "MoEFFN " + n)
         return ins, [d], []
@@ -255,8 +294,10 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     contract family).
 
     ``stats`` (a dict, optional): the routed form leaves
-    ``stats["experts_touched"]`` there, the number of experts that
-    were given a token (a traced int32 scalar)."""
+    ``stats["experts_touched"]`` there, the number of (held) experts
+    that were given a token, and ``stats["pairs_held"]``, the
+    token-expert pairs that fell on held experts (traced int32
+    scalars)."""
     given, gated = MoEFFN.given(p), bool(p.get("gated", False))
     it = iter(ins)
     x = next(it)
@@ -272,8 +313,11 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     b1 = None if gated else next(it)
     w2 = next(it)
     b2 = None if gated else next(it)
+    shared = (next(it), next(it), next(it)) \
+        if p.get("shared_hidden") else None
     k = int(p["top_k"])
     nx = int(p["num_experts"])
+    first, held = MoEFFN.held(p)
     if k >= nx:
         raise MXNetError(
             "MoEFFN: top_k=%d must be < num_experts=%d (use "
@@ -283,8 +327,11 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
         if given:
             score = probs + select_bias
         else:
+            # float32 sums kept as they are: rounded to bfloat16 the
+            # scores of 512 experts tie at the k-th place
             score = gate_mm(x, gate_w) if gate_mm is not None \
-                else jnp.einsum("bte,xe->btx", x, gate_w)
+                else jnp.einsum("bte,xe->btx", x, gate_w,
+                                preferred_element_type=jnp.float32)
             nloc = gate_w.shape[0] if hasattr(gate_w, "shape") else nx
             if sharded:
                 # full gate row for routing; this shard's slice of
@@ -320,12 +367,21 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     if k > 0 and plain and not sharded:
         b, t, e = x.shape
         picked = jnp.take_along_axis(gates, idx, axis=-1)     # [b,t,k]
-        y = _routed_experts(x.reshape(b * t, e), idx.reshape(b * t, k),
-                            nx, w1, b1, w2, b2, gated, stats)
-        out = jnp.sum(y.astype(jnp.float32)
-                      * picked.reshape(b * t, k, 1).astype(jnp.float32),
-                      axis=1)
-        return out.reshape(b, t, e).astype(x.dtype)
+        # the held experts count from 0; a pair on an absent expert
+        # gets the index ``held``, which no group has
+        local = idx - first
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        out = _routed_sum(x.reshape(b * t, e), local.reshape(b * t, k),
+                          picked.reshape(b * t, k), held, w1, b1, w2, b2,
+                          gated, stats).reshape(b, t, e)
+        if shared is not None:
+            out = out + _shared_expert(x, *shared)
+        return out.astype(x.dtype)
+    if held != nx or shared is not None:
+        raise MXNetError(
+            "MoEFFN: a share of the experts (experts_held=%d of %d) and "
+            "a shared expert have the routed form only: top_k > 0, "
+            "plain products, no ep (ROADMAP R-M2)" % (held, nx))
     with jax.named_scope("experts"):
         up = up_mm(x, w1) if up_mm is not None \
             else jnp.einsum("bte,xhe->btxh", x, w1)
@@ -361,6 +417,71 @@ def routed_block_rows(pairs, num_experts):
     return rows
 
 
+# a pass of the routed experts lays out at most this many token-expert
+# pairs. The padded rows of a pass are a buffer the chip's compiler keeps
+# in fast memory: at 20,480 pairs (a prefill piece of 2,048 tokens with
+# ten choices) it is 28,672 rows of 4 KB, 112 MiB of the 128, and the
+# eight-layer prefill program did not come back in twelve minutes (the
+# layer alone runs, 5.9 ms); at 10,240 pairs the program ran (PERF.md
+# section 6, PR 34)
+_ROUTED_PASS_PAIRS = 8192
+
+
+def _routed_sum(x, idx, gates, nx, w1, b1, w2, b2, gated, stats=None):
+    """``x`` [N, E] through each token's experts ``idx`` [N, k] (``nx``
+    marks a pair whose expert is not here), weighted by ``gates`` [N, k]
+    and summed over the k: float32 [N, E]. A long chunk goes in passes
+    of a power-of-two number of tokens, at most ``_ROUTED_PASS_PAIRS``
+    pairs each, one after the other (``lax.map``); every pass streams
+    the experts it touches again, so a decode step and a short chunk are
+    one pass. A chunk that is not whole passes is filled up with tokens
+    whose pairs are all absent: no pass is ever larger than the limit."""
+    n, k = idx.shape
+    f32 = jnp.float32
+
+    def one(x, idx, gates):
+        seen = {}
+        y = _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, seen)
+        out = jnp.sum(y.astype(f32) * gates[..., None].astype(f32), axis=1)
+        return out, seen["experts_touched"], seen["pairs_held"]
+
+    per = 1
+    while per * 2 * k <= _ROUTED_PASS_PAIRS:
+        per *= 2
+    if n <= per:
+        out, touched, pairs = one(x, idx, gates)
+    else:
+        fill = -n % per
+
+        def passes(z, value):
+            z = jnp.pad(z, [(0, fill)] + [(0, 0)] * (z.ndim - 1),
+                        constant_values=value)
+            return z.reshape(((n + fill) // per, per) + z.shape[1:])
+
+        out, touched, pairs = jax.lax.map(
+            lambda a: one(*a),
+            (passes(x, 0), passes(idx, nx), passes(gates, 0)))
+        out = out.reshape(n + fill, -1)[:n]
+        touched, pairs = jnp.sum(touched), jnp.sum(pairs)
+    if stats is not None:
+        stats["experts_touched"] = touched
+        stats["pairs_held"] = pairs
+    return out
+
+
+def _shared_expert(x, w1, w2, gate):
+    """The shared expert's part, float32 [B, T, E]: the SiLU-gated
+    pair on every token, times ``sigmoid(gate . x)``."""
+    f32 = jnp.float32
+    with jax.named_scope("shared"):
+        up = jnp.einsum("bte,he->bth", x, w1)
+        hid = up.shape[-1] // 2
+        h = jax.nn.silu(up[..., :hid]) * up[..., hid:]
+        y = jnp.einsum("bth,eh->bte", h, w2, preferred_element_type=f32)
+        g = jnp.einsum("bte,oe->bto", x, gate, preferred_element_type=f32)
+        return y * jax.nn.sigmoid(g)
+
+
 def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
     """``x`` [N, E] through each token's own experts ``idx`` [N, k]:
     returns [N, k, E]. The N*k token-expert pairs are sorted by expert
@@ -369,7 +490,10 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
     then run block by block against that block's expert
     (``pallas_kernels.grouped_matmul``), and the rows go back to their
     pairs. The number of blocks is static: ``pairs // rows`` full ones
-    at most, and one partly filled one per expert."""
+    at most, and one partly filled one per expert. An index of ``nx``
+    (one past the last) marks a pair whose expert is not here, where
+    the ``nx`` experts are a share of the routed ones: it joins no
+    group, takes no row, and comes back as zeros."""
     from .pallas_kernels import grouped_matmul
     n, k = idx.shape
     pairs = n * k
@@ -378,17 +502,20 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
     i32 = jnp.int32
     with jax.named_scope("route"):
         expert = idx.reshape(pairs).astype(i32)
-        sizes = jnp.zeros((nx,), i32).at[expert].add(1)
+        sizes = jnp.zeros((nx + 1,), i32).at[expert].add(1)[:nx]
         if stats is not None:
             stats["experts_touched"] = jnp.sum(sizes > 0).astype(i32)
+            stats["pairs_held"] = jnp.sum(sizes).astype(i32)
         padded = (sizes + rows - 1) // rows * rows
         ends = jnp.cumsum(padded)                 # padded group ends
         order = jnp.argsort(expert, stable=True).astype(i32)
-        sorted_e = expert[order]
+        sorted_e = expert[order]                  # absent pairs last
+        here = jnp.minimum(sorted_e, nx - 1)
         first = jnp.cumsum(sizes) - sizes         # group starts, packed
-        rank = jnp.arange(pairs, dtype=i32) - first[sorted_e]
-        dest = jnp.zeros((pairs,), i32).at[order].set(
-            (ends - padded)[sorted_e] + rank)     # pair -> padded row
+        rank = jnp.arange(pairs, dtype=i32) - first[here]
+        row = (ends - padded)[here] + rank        # pair -> padded row
+        row = jnp.where(sorted_e < nx, row, nb * rows)   # absent: outside
+        dest = jnp.zeros((pairs,), i32).at[order].set(row)
         used = (ends[-1] // rows).astype(i32)     # blocks that hold rows
         # block -> its expert; the blocks past the used ones repeat the
         # last used block's expert, so the kernel fetches nothing new
@@ -398,7 +525,7 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
             jnp.searchsorted(ends, at, side="right",
                              method="compare_all").astype(i32), nx - 1)
         xp = jnp.zeros((nb * rows, x.shape[1]), x.dtype).at[dest].set(
-            jnp.repeat(x, k, axis=0) if k > 1 else x)
+            jnp.repeat(x, k, axis=0) if k > 1 else x, mode="drop")
     with jax.named_scope("experts"):
         def per_row(v):             # an expert's vector for its blocks
             return jnp.repeat(v[block_e], rows, axis=0)
@@ -412,7 +539,7 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
         if not gated:
             y = y + per_row(b2)
     with jax.named_scope("route"):
-        return y[dest].reshape(n, k, -1)
+        return y.at[dest].get(mode="fill", fill_value=0).reshape(n, k, -1)
 
 
 def rope_rotate(x, positions, base=10000.0, rotary_dim=None):
@@ -871,3 +998,374 @@ class MultiHeadAttention(OpSpec):
             mask = jax.random.bernoulli(rng, keep, out.shape)
             out = jnp.where(mask, out / keep, 0.0)
         return [out], []
+
+
+# -- Gated DeltaNet: linear attention with a matrix state ---------------------
+# (Yang et al., arXiv:2412.06464, as Qwen3-Next runs it.) Per value head
+# a float32 state S [Dk, Dv]; with g_t <= 0 the log of the decay and
+# beta_t the write strength:
+#     S' = exp(g_t) S_{t-1};  d_t = beta_t (v_t - S'^T k_t);
+#     S_t = S' + k_t d_t^T;   o_t = S_t^T q_t.
+# ``gdn_step`` is that recurrence for one position (the decode step, and
+# under ``lax.scan`` the definition the chunked form is held to);
+# ``gdn_chunked`` is the same recurrence over a sequence in chunks.
+
+_HI = jax.lax.Precision.HIGHEST     # the state is float32 and stays it
+
+
+def gdn_step(state, q, k, v, beta, g):
+    """One position of the recurrence for every (batch row, head):
+    ``state`` [B, H, Dk, Dv] float32, ``q``/``k`` [B, H, Dk], ``v``
+    [B, H, Dv], ``beta``/``g`` [B, H], all float32. Returns (the new
+    state, o [B, H, Dv]). Two passes over the state: one reads it for
+    ``S'^T k`` and ``S'^T q`` together (``o = S'^T q + d (k . q)``, so
+    the output does not wait for the new state), one reads it again and
+    writes ``S' + k d^T``."""
+    sd = state * jnp.exp(g)[..., None, None]
+    ks = jnp.sum(sd * k[..., None], axis=-2)
+    qs = jnp.sum(sd * q[..., None], axis=-2)
+    d = beta[..., None] * (v - ks)
+    o = qs + d * jnp.sum(k * q, axis=-1, keepdims=True)
+    return sd + k[..., None] * d[..., None, :], o
+
+
+def gdn_sequential(state, q, k, v, beta, g):
+    """The recurrence position by position (``lax.scan`` of
+    ``gdn_step``): ``q``/``k`` [B, T, H, Dk], ``v`` [B, T, H, Dv],
+    ``beta``/``g`` [B, T, H]. The definition; a prefill takes
+    ``gdn_chunked``. Returns (state, o [B, T, H, Dv])."""
+    state, o = jax.lax.scan(
+        lambda s, xs: gdn_step(s, *xs), state,
+        tuple(jnp.moveaxis(z, 1, 0) for z in (q, k, v, beta, g)))
+    return state, jnp.moveaxis(o, 0, 1)
+
+
+def gdn_chunked(state, q, k, v, beta, g, chunk=64):
+    """The same recurrence in chunks of ``chunk`` positions (the
+    chunkwise form of the gated delta rule). Inside a chunk, with
+    ``gamma_i = sum_{j<=i} g_j``: ``A_ij = -beta_i (k_i . k_j)
+    exp(gamma_i - gamma_j)`` for ``j < i``; ``W`` and ``U`` solve
+    ``(I - A) [W U] = [beta k exp(gamma), beta v]`` (forward
+    substitution, the matrix is unit lower triangular); then for the
+    state ``S`` the chunk starts from, ``V' = U - W S``,
+    ``o = (q exp(gamma)) S + tril(q k^T exp(gamma_i - gamma_j)) V'``,
+    ``S <- exp(gamma_C) S + (k exp(gamma_C - gamma))^T V'``. Everything
+    that does not need ``S`` is computed for all chunks at once; the
+    chunks then follow each other in a ``lax.scan`` of three small
+    products. A sequence that is not whole chunks is padded with
+    positions of ``beta = 0, g = 0``, which leave the state as it is.
+    Shapes as ``gdn_sequential``; float32 at full product precision."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(int(chunk), t)
+    n = -(-t // c)
+    pad = n * c - t
+
+    def chunks(z):                      # [B, T, H, ...] -> [B, H, N, C, ...]
+        if pad:
+            z = jnp.pad(z, [(0, 0), (0, pad)] + [(0, 0)] * (z.ndim - 2))
+        z = z.reshape((b, n, c) + z.shape[2:])
+        return jnp.moveaxis(z, 3, 1)
+
+    q, k, v, beta, g = (chunks(z) for z in (q, k, v, beta, g))
+    gam = jnp.cumsum(g, axis=-1)                            # [B,H,N,C]
+    low = jnp.tril(jnp.ones((c, c), bool))
+    # exp only where j <= i: above the diagonal the difference is
+    # positive and may overflow
+    decay = jnp.exp(jnp.where(low, gam[..., :, None] - gam[..., None, :],
+                              -jnp.inf))
+    kb = k * beta[..., None]
+    kk = jnp.einsum("bhnik,bhnjk->bhnij", kb, k, precision=_HI)
+    lower = jnp.where(jnp.tril(low, -1), kk * decay, 0.0) \
+        + jnp.eye(c, dtype=kk.dtype)                        # I - A
+    rhs = jnp.concatenate([kb * jnp.exp(gam)[..., None],
+                           v * beta[..., None]], axis=-1)
+    wu = jax.lax.linalg.triangular_solve(
+        lower, rhs, left_side=True, lower=True, unit_diagonal=True)
+    w, u = wu[..., :dk], wu[..., dk:]
+    qk = jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=_HI) * decay
+    qg = q * jnp.exp(gam)[..., None]
+    last = gam[..., -1:]                                    # [B,H,N,1]
+    kg = k * jnp.exp(last - gam)[..., None]
+
+    def body(s, xs):
+        w_, u_, qk_, qg_, kg_, last_ = xs
+        vn = u_ - jnp.einsum("bhik,bhkv->bhiv", w_, s, precision=_HI)
+        o = jnp.einsum("bhik,bhkv->bhiv", qg_, s, precision=_HI) \
+            + jnp.einsum("bhij,bhjv->bhiv", qk_, vn, precision=_HI)
+        s = s * jnp.exp(last_)[..., None] \
+            + jnp.einsum("bhik,bhiv->bhkv", kg_, vn, precision=_HI)
+        return s, o
+
+    state, o = jax.lax.scan(
+        body, state, tuple(jnp.moveaxis(z, 2, 0)
+                           for z in (w, u, qk, qg, kg, last)))
+    o = jnp.moveaxis(o, 0, 2)                               # [B,H,N,C,Dv]
+    o = jnp.moveaxis(o, 1, 3).reshape(b, n * c, h, dv)
+    return state, o[:, :t]
+
+
+@register
+class GatedDeltaNet(OpSpec):
+    """A Gated DeltaNet mixer (linear attention; Yang et al.,
+    arXiv:2412.06464) as Qwen3-Next's ``linear_attention`` layers run
+    it. data [B, T, E] (already normalized). With ``Hk`` key heads of
+    ``Dk``, ``Hv`` value heads of ``Dv`` (``Hk`` divides ``Hv``: key
+    head j serves value heads ``[j Hv/Hk, (j+1) Hv/Hk)``),
+    ``K = Hk Dk``, ``V = Hv Dv``, ``F = 2K + V``:
+
+    - ``qkvz_weight`` [F + V, E]: ``[q ; k ; v ; z] = W x`` (the
+      checkpoint interleaves these rows per key head; this flat order
+      is a permutation of it); ``ba_weight`` [2 Hv, E]: ``[b ; a]``;
+    - ``conv_weight`` [F, kernel]: depthwise and causal over
+      ``u = [q ; k ; v]``, no bias, zeros before position 0,
+      ``c_t = silu(sum_j w[:, j] u_{t-(kernel-1)+j})``;
+    - ``beta = sigmoid(b)``, ``g = -exp(a_log) softplus(a + dt_bias)``
+      per value head (``a_log``, ``dt_bias`` [Hv]), float32;
+    - q and k repeated to ``Hv`` heads, each ``x / sqrt(sum x^2 +
+      1e-6)``, q times ``Dk ** -0.5``;
+    - the recurrence at the top of this section, state float32;
+    - ``y = out_weight [rmsnorm(o) norm_weight silu(z)]``, the norm
+      over ``Dv`` per head, ``norm_weight`` [Dv], ``out_weight``
+      [E, V].
+
+    The full-sequence forward starts from the zero state and runs the
+    chunked form; the decoder's cached form (``parallel/decode.py``)
+    keeps NO rows: per sequence the state [Hv, Dk, Dv] and the last
+    ``kernel - 1`` positions' ``u``."""
+
+    name = "GatedDeltaNet"
+    params = {"num_k_heads": Param("int"), "num_v_heads": Param("int"),
+              "head_k_dim": Param("int"), "head_v_dim": Param("int"),
+              "conv_kernel": Param("int", 4),
+              "eps": Param("float", 1e-6)}
+
+    def arguments(self, p):
+        return ["data", "qkvz_weight", "ba_weight", "conv_weight",
+                "a_log", "dt_bias", "norm_weight", "out_weight"]
+
+    @staticmethod
+    def widths(p):
+        """(K, V, F): the key, value and convolved widths."""
+        hk, hv = p["num_k_heads"], p["num_v_heads"]
+        if hk < 1 or hv % hk:
+            raise MXNetError(
+                "GatedDeltaNet: num_k_heads=%d must divide num_v_heads="
+                "%d" % (hk, hv))
+        k, v = hk * p["head_k_dim"], hv * p["head_v_dim"]
+        return k, v, 2 * k + v
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        if len(d) != 3:
+            raise MXNetError("GatedDeltaNet: data must be [B, T, E]")
+        _, v, f = self.widths(p)
+        e, hv = d[2], p["num_v_heads"]
+        want = [d, (f + v, e), (2 * hv, e), (f, p["conv_kernel"]), (hv,),
+                (hv,), (p["head_v_dim"],), (e, v)]
+        return [shape_assign(s, w, "GatedDeltaNet " + n)
+                for s, w, n in zip(in_shapes, want,
+                                   self.arguments(p))], [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x = ins[0]
+        b = x.shape[0]
+        _, _, f = self.widths(p)
+        state = jnp.zeros((b, p["num_v_heads"], p["head_k_dim"],
+                           p["head_v_dim"]), jnp.float32)
+        prev = jnp.zeros((b, p["conv_kernel"] - 1, f), x.dtype)
+        y, _, _ = gdn_mix(p, x, ins[1:], state, prev)
+        return [y], []
+
+
+def gdn_mix(p, x, weights, state, prev, real=None, live=None):
+    """The mixer on a chunk ``x`` [B, C, E] that continues a sequence:
+    ``state`` [B, Hv, Dk, Dv] float32 and ``prev`` [B, kernel-1, F],
+    the ``u`` of the positions before the chunk (zeros at the start of
+    a sequence). One function for the full forward and the decoder's
+    cached walk. ``real`` ([B, 1] int32, optional): only the chunk's
+    first ``real`` positions are tokens, the rest padding, which must
+    leave the state and ``prev`` of the last real one (``beta = 0, g =
+    0`` there). ``live`` ([B] bool, optional): a row that is not live
+    keeps its state and ``prev`` untouched. Returns (y [B, C, E], the
+    new state, the new prev)."""
+    wqkvz, wba, wconv, a_log, dt_bias, wnorm, wout = weights
+    kw, vw, f = GatedDeltaNet.widths(p)
+    hk, hv = p["num_k_heads"], p["num_v_heads"]
+    dk, dv = p["head_k_dim"], p["head_v_dim"]
+    b, c, _ = x.shape
+    f32 = jnp.float32
+    with jax.named_scope("proj"):
+        qkvz = jnp.einsum("bte,fe->btf", x, wqkvz)
+        ba = jnp.einsum("bte,fe->btf", x, wba,
+                        preferred_element_type=f32)
+        u, z = qkvz[..., :f], qkvz[..., f:]
+    with jax.named_scope("conv"):
+        win = jnp.concatenate([prev.astype(u.dtype), u], axis=1)
+        wf = wconv.astype(f32)
+        taps = wconv.shape[1]
+        cv = jax.nn.silu(sum(wf[:, j] * win[:, j:j + c].astype(f32)
+                             for j in range(taps)))
+        # the window the NEXT chunk starts from: the last kernel-1
+        # real positions (of ``prev`` too, where the chunk holds fewer)
+        if real is None:
+            nxt = win[:, c:]
+        else:
+            at = real + jnp.arange(taps - 1, dtype=jnp.int32)
+            nxt = jnp.take_along_axis(win, at[..., None], axis=1)
+
+        def unit(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+
+        rep = hv // hk
+        q = jnp.repeat(unit(cv[..., :kw].reshape(b, c, hk, dk)), rep,
+                       axis=2) * (float(dk) ** -0.5)
+        k = jnp.repeat(unit(cv[..., kw:2 * kw].reshape(b, c, hk, dk)),
+                       rep, axis=2)
+        v = cv[..., 2 * kw:].reshape(b, c, hv, dv)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(a_log.astype(f32)) \
+            * jax.nn.softplus(ba[..., hv:] + dt_bias.astype(f32))
+        if real is not None:
+            pad = (jnp.arange(c, dtype=jnp.int32) >= real)[..., None]
+            beta = jnp.where(pad, 0.0, beta)
+            g = jnp.where(pad, 0.0, g)
+    with jax.named_scope("state"):
+        if c == 1:
+            new, o = gdn_step(state, q[:, 0], k[:, 0], v[:, 0],
+                              beta[:, 0], g[:, 0])
+            o = o[:, None]
+        else:
+            new, o = gdn_chunked(state, q, k, v, beta, g)
+        if live is not None:
+            new = jnp.where(live[:, None, None, None], new, state)
+            nxt = jnp.where(live[:, None, None], nxt,
+                            prev.astype(nxt.dtype))
+    with jax.named_scope("norm"):
+        ms = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        o = o * jax.lax.rsqrt(ms + p["eps"]) * wnorm.astype(f32) \
+            * jax.nn.silu(z.astype(f32).reshape(b, c, hv, dv))
+    with jax.named_scope("proj"):
+        y = jnp.einsum("btv,ev->bte", o.reshape(b, c, vw).astype(x.dtype),
+                       wout)
+    return y, new, nxt.astype(prev.dtype)
+
+
+# -- gated softmax attention -------------------------------------------------
+
+@register
+class GatedAttention(OpSpec):
+    """Causal grouped-query softmax attention with a head size of its
+    own, per-head q/k norms, partial rotary and a sigmoid gate on its
+    output, no biases (Qwen3-Next's ``full_attention`` layers). data
+    [B, T, E] (already normalized), ``H`` query heads, ``Hkv`` kv heads
+    of ``D``:
+
+    - ``q_weight`` [H 2D, E]: per head ``[q_h ; gate_h]``;
+      ``k_weight``, ``v_weight`` [Hkv D, E];
+    - ``q_norm``, ``k_norm`` [D]: RMSNorm over ``D`` on q and k per
+      head, ``x / rms(x) * gamma`` (a checkpoint's zero-centred weight
+      ``w`` is stored here as ``gamma = 1 + w``);
+    - rotary on the first ``rotary_dim`` dims at ``rope_base``;
+    - causal softmax(q . k / sqrt(D)) over v, grouped;
+      ``y = out_weight [attn * sigmoid(gate)]``, ``out_weight``
+      [E, H D].
+
+    The full forward attends with the flash kernel or densely
+    (``impl``); the decoder's cached form keeps K and V rows like
+    MultiHeadAttention's and reads them the same ways."""
+
+    name = "GatedAttention"
+    params = {"num_heads": Param("int"), "num_kv_heads": Param("int"),
+              "head_dim": Param("int"), "rotary_dim": Param("int", 0),
+              "rope_base": Param("float", 10000.0),
+              "eps": Param("float", 1e-6),
+              "impl": Param("str", "flash")}
+
+    def arguments(self, p):
+        return ["data", "q_weight", "k_weight", "v_weight", "q_norm",
+                "k_norm", "out_weight"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        if len(d) != 3:
+            raise MXNetError("GatedAttention: data must be [B, T, E]")
+        h, kv, hd = p["num_heads"], p["num_kv_heads"], p["head_dim"]
+        if kv < 1 or h % kv:
+            raise MXNetError(
+                "GatedAttention: num_kv_heads=%d must divide num_heads="
+                "%d" % (kv, h))
+        e = d[2]
+        want = [d, (2 * h * hd, e), (kv * hd, e), (kv * hd, e), (hd,),
+                (hd,), (e, h * hd)]
+        return [shape_assign(s, w, "GatedAttention " + n)
+                for s, w, n in zip(in_shapes, want,
+                                   self.arguments(p))], [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x = ins[0]
+        b, t, _ = x.shape
+        h, kv, d = p["num_heads"], p["num_kv_heads"], p["head_dim"]
+        q, k, v, gate = gattn_qkv(p, x, ins[1:6],
+                                  jnp.arange(t, dtype=jnp.int32)[None])
+        with jax.named_scope("attend"):
+            k = jnp.repeat(k, h // kv, axis=2)
+            v = jnp.repeat(v, h // kv, axis=2)
+            if p["impl"] == "flash":
+                from .pallas_kernels import flash_attention
+                o = flash_attention(q, k, v, causal=True)
+            elif p["impl"] == "dense":
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / float(np.sqrt(d))
+                mask = jnp.tril(jnp.ones((t, t), bool))
+                s = jnp.where(mask[None, None], s, -jnp.inf)
+                o = jnp.einsum("bhqk,bkhd->bqhd",
+                               jax.nn.softmax(s, axis=-1), v)
+            else:
+                raise MXNetError("GatedAttention: unknown impl %r (flash "
+                                 "or dense)" % (p["impl"],))
+        return [gattn_out(o, gate, ins[6])], []
+
+
+def gattn_qkv(p, x, weights, positions):
+    """GatedAttention's queries, keys, values and gate of a chunk ``x``
+    [B, C, E] at ``positions`` ([1 or B, C] int32, absolute): the
+    projections, the per-head norms and the rotary turn. One function
+    for the full forward and the decoder's cached walk. Returns q
+    [B, C, H, D], k and v [B, C, Hkv, D], gate [B, C, H, D] in ``x``'s
+    dtype."""
+    wq, wk, wv, qn, kn = weights
+    h, kv, d = p["num_heads"], p["num_kv_heads"], p["head_dim"]
+    b, c, _ = x.shape
+    f32 = jnp.float32
+    with jax.named_scope("proj"):
+        qg = jnp.einsum("bte,fe->btf", x, wq).reshape(b, c, h, 2 * d)
+        q, gate = qg[..., :d], qg[..., d:]
+        k = jnp.einsum("bte,fe->btf", x, wk).reshape(b, c, kv, d)
+        v = jnp.einsum("bte,fe->btf", x, wv).reshape(b, c, kv, d)
+
+        def norm(z, gamma):
+            zf = z.astype(f32)
+            ms = jnp.mean(jnp.square(zf), axis=-1, keepdims=True)
+            return zf * jax.lax.rsqrt(ms + p["eps"]) * gamma.astype(f32)
+
+        rot = p.get("rotary_dim") or d
+        positions = jnp.asarray(positions, jnp.int32)
+        q = rope_rotate(norm(q, qn), positions, p["rope_base"], rot)
+        k = rope_rotate(norm(k, kn), positions, p["rope_base"], rot)
+    return q.astype(x.dtype), k.astype(x.dtype), v, gate
+
+
+def gattn_out(o, gate, wo):
+    """``out_weight [o * sigmoid(gate)]``: ``o`` and ``gate``
+    [B, C, H, D]."""
+    with jax.named_scope("proj"):
+        b, c, h, d = gate.shape
+        o = o.reshape(b, c, h, d).astype(jnp.float32) \
+            * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return jnp.einsum("btq,eq->bte",
+                          o.reshape(b, c, h * d).astype(gate.dtype), wo)

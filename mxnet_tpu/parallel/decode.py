@@ -11,7 +11,10 @@ node swapped for a cached variant (new tokens' K/V written into
 queries attend to the cache under the mask ``key_pos <= query_pos``),
 every ``CCAttention`` node likewise (its K/V rows the same, and beside
 them a short ring of the last positions' raw projections: the second
-kind of cache leaf, ``STATE_ROWS`` below) and
+kind of cache leaf, ``STATE_ROWS`` below), every ``GatedAttention``
+node likewise (K/V rows and nothing else), every ``GatedDeltaNet``
+node for a variant that keeps NO rows at all (a recurrent state per
+sequence: the third kind, "THE STATE KIND" below) and
 ``PositionalEmbedding`` sliced at the current position. Every other LM op
 (Embedding, LayerNorm, RMSNorm, FullyConnected, activations, elementwise
 arithmetic, ResidualMerge, MoEFFN, BatchNorm-on-rank-2-data) is
@@ -57,7 +60,8 @@ _POSITIONWISE = {
     "_MulScalar", "_DivScalar", "_RMinusScalar", "_RDivScalar",
 }
 # handled specially
-_TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention"}
+_TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention",
+             "GatedAttention", "GatedDeltaNet"}
 
 # CCAttention's rolling state, the second kind of cache leaf: per
 # sequence the last STATE_ROWS positions' [u ; v2] (the raw q/k
@@ -71,6 +75,27 @@ _TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention"}
 # before 0 are never read (``ops.attention.cca_qkv`` masks by
 # position), so a reused slot needs no clearing.
 STATE_ROWS = 3
+
+# THE STATE KIND: a GatedDeltaNet node's cache entry holds no rows. Per
+# sequence it is (S [Hv, Dk, Dv] float32, the recurrence's matrix state
+# whatever the compute dtype; the last ``kernel - 1`` positions'
+# convolution inputs, flat [(kernel-1) * F] in the cache dtype, oldest
+# first). Nothing re-derives it from a few positions, so the three
+# contracts that rows (and CCAttention's ring) lean on do not hold and
+# it has its own:
+# - a decode step ADVANCES the state, so re-running one is not
+#   idempotent: a batch row that holds no request (``lens`` 0 in the
+#   slot walk: a finished slot, one parked between prefill pieces)
+#   leaves its state exactly as it was;
+# - a chunk that starts at position 0 starts from the ZERO state,
+#   whatever the slot held: a reused slot is cleared by its position,
+#   not by the caller;
+# - a right-padded chunk leaves the state of its last REAL token
+#   (``valid_len``): padding runs the recurrence with ``beta = 0,
+#   g = 0`` and does not enter the convolution's window.
+# What cannot carry such a leaf refuses by name
+# (``Decoder.refuse_rolling_state``): int8 rows, quantized weights, tp,
+# the prefix pool, the KV handoff, speculation.
 
 _LOSS_HEADS = {"SoftmaxOutput", "SoftmaxCELoss"}
 
@@ -207,7 +232,8 @@ class Decoder:
 
         self._mha = []      # MultiHeadAttention nodes
         self._cca = []      # CCAttention nodes (K/V rows + rolling state)
-        self._cached = []   # both, in graph order: one cache entry each
+        self._gdn = []      # GatedDeltaNet nodes (a state, no rows)
+        self._cached = []   # all, in graph order: one cache entry each
         for n in self._topo:
             if n.is_var:
                 continue
@@ -222,6 +248,11 @@ class Decoder:
                 self._cached.append(n)
             elif name == "CCAttention":
                 self._cca.append(n)
+                self._cached.append(n)
+            elif name == "GatedAttention":      # K/V rows, nothing else
+                self._cached.append(n)
+            elif name == "GatedDeltaNet":
+                self._gdn.append(n)
                 self._cached.append(n)
             elif name == "SoftmaxActivation" \
                     and n.params["mode"] != "instance":
@@ -276,7 +307,7 @@ class Decoder:
                     "dtype, got %r" % (cache_dtype,))
             self._cache_dtype = cdt
 
-        if self._cca and self._cache_int8:
+        if self.has_state and self._cache_int8:
             self.refuse_rolling_state("cache_dtype='int8'")
 
         # pos_embed bounds the decodable length
@@ -303,7 +334,7 @@ class Decoder:
                 "'int4', got %r (MXNET_SERVING_WEIGHT_DTYPE sets the "
                 "default)" % (weight_dtype,))
         if weight_dtype != "float":
-            if self._cca:
+            if self.has_state:
                 self.refuse_rolling_state("weight_dtype=%r"
                                           % (weight_dtype,))
             self.refuse_given_router("weight_dtype=%r" % (weight_dtype,))
@@ -367,17 +398,34 @@ class Decoder:
                                for k, v in aux_params.items()},
                    **kwargs)
 
+    @property
+    def has_state(self):
+        """Whether a cached node keeps a per-sequence state leaf (a
+        CCAttention's ring, a GatedDeltaNet's recurrent state) beside
+        or instead of rows."""
+        return bool(self._cca or self._gdn)
+
     def refuse_rolling_state(self, feature):
-        """Raise for a feature that cannot carry CCAttention's rolling
-        state (the per-sequence ring beside its K/V rows), naming the
-        node: never a silent wrong answer (the decoder's own options,
-        and the serving engine's)."""
+        """Raise for a feature that cannot carry a per-sequence state
+        leaf, naming the first node that keeps one by its own kind and
+        saying what its state is: never a silent wrong answer (the
+        decoder's own options, and the serving engine's)."""
+        node = next(n for n in self._cached
+                    if n.spec.name in ("CCAttention", "GatedDeltaNet"))
+        if node.spec.name == "CCAttention":
+            what = ("its rolling state, the last %d positions' [u ; v2] "
+                    "per sequence beside its K/V rows," % STATE_ROWS)
+        else:
+            p = node.params
+            what = ("its recurrent state, a float32 matrix [%d, %d, %d] "
+                    "and the last %d positions' convolution inputs per "
+                    "sequence, which no rows re-derive,"
+                    % (p["num_v_heads"], p["head_k_dim"],
+                       p["head_v_dim"], p["conv_kernel"] - 1))
         raise MXNetError(
-            "%s does not compose with CCAttention (node %r): its "
-            "rolling state, the last %d positions' [u ; v2] per "
-            "sequence, is a cache leaf that %s cannot carry yet "
-            "(ROADMAP R-M5 / D3)"
-            % (feature, self._cca[0].name, STATE_ROWS, feature))
+            "%s does not compose with %s (node %r): %s is a cache leaf "
+            "that %s cannot carry yet (ROADMAP R-M5 / D3)"
+            % (feature, node.spec.name, node.name, what, feature))
 
     def refuse_given_router(self, feature):
         """Raise if a MoEFFN node takes its routing from the graph
@@ -423,7 +471,10 @@ class Decoder:
         written) — decode memory O(window) regardless of generation
         length. A CCAttention node gets K and V rows of the same
         layout and its rolling state, [B, STATE_ROWS * W] (rank 2: no
-        head axis; see ``STATE_ROWS``).
+        head axis; see ``STATE_ROWS``). A GatedAttention node gets K
+        and V rows. A GatedDeltaNet node gets NO rows: (S
+        [B, Hv, Dk, Dv] float32, [B, (kernel-1) * F] convolution
+        inputs): "THE STATE KIND" at the top of this module.
 
         ``kv_sharding`` (optional ``jax.sharding.NamedSharding`` whose
         spec names dimension 2, e.g.
@@ -436,10 +487,22 @@ class Decoder:
         "Tensor-parallel serving"); the matching compute runs through
         ``_run_slots``'s ``tp=`` axis."""
         from ..ops.attention import CCAttention as _CCA
+        from ..ops.attention import GatedDeltaNet as _GDN
         from ..ops.attention import MultiHeadAttention as _MHA
 
+        if kv_sharding is not None and self._gdn:
+            self.refuse_rolling_state("init_cache(kv_sharding=...)")
         caches = []
         for n in self._cached:
+            if n.spec.name == "GatedDeltaNet":
+                p = n.params
+                caches.append((
+                    jnp.zeros((batch_size, p["num_v_heads"],
+                               p["head_k_dim"], p["head_v_dim"]),
+                              jnp.float32),
+                    jnp.zeros((batch_size, (p["conv_kernel"] - 1)
+                               * _GDN.widths(p)[2]), self._cache_dtype)))
+                continue
             if n.spec.name == "CCAttention":
                 # K and V rows in the stored layout, and the rolling
                 # state (STATE_ROWS above): [B, STATE_ROWS * (W + K/2)]
@@ -452,12 +515,14 @@ class Decoder:
                                STATE_ROWS * (qw + kw + kw // 2)),
                               self._cache_dtype)))
                 continue
-            e = self._params[n.inputs[1][0].name].shape[1]  # qkv [F, E]
-            h = n.params["num_heads"]
             win = self._node_window(n)
             slots = win or self.max_len
-            kv = _MHA.kv_heads(n.params)
-            shape = (batch_size, slots, kv * (e // h))
+            if n.spec.name == "GatedAttention":
+                kv, d = n.params["num_kv_heads"], n.params["head_dim"]
+            else:
+                e = self._params[n.inputs[1][0].name].shape[1]  # [F, E]
+                kv, d = _MHA.kv_heads(n.params), e // n.params["num_heads"]
+            shape = (batch_size, slots, kv * d)
             if self._cache_int8:
                 scales = (batch_size, slots, kv)
                 entry = (jnp.zeros(shape, jnp.int8),
@@ -484,16 +549,24 @@ class Decoder:
         """Per-leaf ``PartitionSpec`` tree for a cache pytree: K/V and
         scale buffers (rank 3) shard dimension 2 — the kv-major lanes
         [Hkv*D], or the [Hkv] scales — over ``axis``, so a shard holds
-        whole kv heads; leaves without a head axis (rank 2: the rings'
-        position buffers, CCAttention's rolling state) replicate.
+        whole kv heads; every other leaf replicates (rank 2: the rings'
+        position buffers, CCAttention's rolling state; rank 4: a
+        GatedDeltaNet's state, which tp refuses anyway).
         Shared by ``init_cache(kv_sharding=...)`` and the
         serving engine's shard_map program specs, so the two can never
         drift."""
         from jax.sharding import PartitionSpec as P
 
         return jax.tree_util.tree_map(
-            lambda c: P(None, None, axis) if jnp.ndim(c) >= 3 else P(),
+            lambda c: P(None, None, axis) if jnp.ndim(c) == 3 else P(),
             caches)
+
+    @staticmethod
+    def row_buffers(caches):
+        """The K buffer [B, rows, Hkv*D] of every cache entry that
+        holds rows (a GatedDeltaNet's entry holds none: its first leaf
+        is the rank-4 state)."""
+        return [e[0] for e in caches if jnp.ndim(e[0]) == 3]
 
     @staticmethod
     def _quantize_rows(x):
@@ -909,6 +982,114 @@ class Decoder:
             out = jnp.einsum("btq,eq->bte", o.reshape(b, c, qw), wo)
         return out, kvrows + (state.reshape(flat.shape),)
 
+    def _cached_gattn(self, node, ins, entry, pos, lens=None, stats=None):
+        """GatedAttention on a chunk at ``pos`` (a scalar, or a [B]
+        vector) against its cache entry, K and V rows. Queries, keys,
+        values and the gate are the op's own (``ops.attention.
+        gattn_qkv``); the rows are written like MultiHeadAttention's
+        (``_write_cache``) and read its ways: the bounded read for a
+        short chunk at a position vector, ``_lane_attn`` for a short
+        chunk at one position, and for a long chunk (prefill) per-head
+        products a block of queries at a time (``_gqa_attn_blocks``: a
+        piece of 2,048 queries against a slot of 9,216 rows is 1.2 GB
+        of float32 scores at once)."""
+        from ..ops.attention import gattn_out, gattn_qkv
+        x = ins[0]
+        p = node.params
+        c = x.shape[1]
+        kv = p["num_kv_heads"]
+        i32 = jnp.int32
+        positions = jnp.asarray(pos, i32).reshape(-1, 1) \
+            + jnp.arange(c, dtype=i32)
+        q, k, v, gate = gattn_qkv(p, x, ins[1:6], positions)
+        entry = self._write_cache(entry, k, v, pos)
+        if jnp.ndim(pos) == 1:
+            if c > _SHORT_CHUNK:
+                raise MXNetError(
+                    "Decoder: a chunk of %d tokens at per-slot "
+                    "positions has no read (GatedAttention node %r)"
+                    % (c, node.name))
+            o = self._paged_read(q, entry, pos, kv, lens, stats)
+        else:
+            limit = self.max_len
+            if isinstance(pos, (int, np.integer)):
+                limit = min(self.max_len, int(pos) + c)
+            if c <= _SHORT_CHUNK:
+                o = self._lane_attn(q, self._live_rows(entry, limit),
+                                    pos, kv)
+            else:
+                o = self._gqa_attn_blocks(
+                    q, *self._read_cache(entry, q.dtype, kv, limit), pos)
+        return gattn_out(o, gate, ins[6]), entry
+
+    def _cached_gdn(self, node, ins, entry, pos, valid_len=None,
+                    lens=None, stats=None):
+        """GatedDeltaNet on a chunk at ``pos`` against its cache entry
+        ``(state, convolution inputs)``: the op's own mixer
+        (``ops.attention.gdn_mix``) continued from what the entry
+        holds, under the state kind's three contracts (the top of this
+        module): a batch row with ``lens`` 0 keeps its entry as it was,
+        a chunk at position 0 starts from zeros, and a right-padded
+        chunk (``valid_len``, absolute) leaves the state of its last
+        real token. ``stats["state_advanced"]`` grows by the rows whose
+        state this chunk advanced."""
+        from ..ops.attention import gdn_mix
+        x = ins[0]
+        b, c, _ = x.shape
+        i32 = jnp.int32
+        state, flat = entry
+        first = jnp.broadcast_to(
+            jnp.asarray(pos, i32).reshape(-1, 1), (b, 1))     # [B, 1]
+        live = None if lens is None else jnp.asarray(lens, i32) > 0
+        with jax.named_scope("state"):
+            fresh = first == 0
+            if live is not None:
+                fresh = fresh & live[:, None]
+            prev = jnp.where(fresh[..., None], 0,
+                             flat.reshape(b, node.params["conv_kernel"]
+                                          - 1, -1))
+            state = jnp.where(fresh[..., None, None], 0, state)
+        real = None if valid_len is None else \
+            jnp.clip(jnp.asarray(valid_len, i32) - first, 0, c)
+        y, state, prev = gdn_mix(node.params, x, ins[1:], state, prev,
+                                 real=real, live=live)
+        if stats is not None:
+            n = jnp.int32(b) if live is None else jnp.sum(live, dtype=i32)
+            stats["state_advanced"] = n + stats.get("state_advanced", 0)
+        return y, (state, prev.reshape(flat.shape))
+
+    @staticmethod
+    @jax.named_scope("attend")
+    def _gqa_attn_blocks(q, ck, cv, pos, block=256):
+        """Grouped-query attention of a LONG query chunk against K/V
+        unfolded to [B, rows, Hkv, D], masked by position, ``block``
+        queries at a time (``lax.map``): scores and softmax in float32,
+        the weights in the values' dtype for their product."""
+        b, c, h, d = q.shape
+        rows, kv = ck.shape[1], ck.shape[2]
+        f32 = jnp.float32
+        kpos = jnp.arange(rows)[None, None, None, None, :]
+
+        def attend(qb, start):
+            n = qb.shape[1]
+            qg = qb.reshape(b, n, kv, h // kv, d)
+            s = jnp.einsum("bqKgd,bkKd->bKgqk", qg, ck,
+                           preferred_element_type=f32) \
+                * f32(1.0 / float(np.sqrt(d)))
+            qpos = start + jnp.arange(n)[None, None, None, :, None]
+            p = jax.nn.softmax(jnp.where(kpos <= qpos, s, f32(-1e30)),
+                               axis=-1)
+            return jnp.einsum("bKgqk,bkKd->bqKgd", p.astype(cv.dtype), cv,
+                              preferred_element_type=f32).astype(q.dtype)
+
+        if c <= block or c % block:
+            return attend(q, pos)
+        nb = c // block
+        qb = jnp.moveaxis(q.reshape(b, nb, block, h, d), 1, 0)
+        starts = pos + jnp.arange(nb, dtype=jnp.int32) * block
+        o = lax.map(lambda a: attend(*a), (qb, starts))
+        return jnp.moveaxis(o, 0, 1).reshape(b, c, kv, h // kv, d)
+
     @staticmethod
     @jax.named_scope("attend")
     def _head_attn(q, ck, cv, pos):
@@ -1047,9 +1228,10 @@ class Decoder:
              tp=None, mm_impl=None, ep=None, stats=None, lens=None):
         """One chunk: tokens [B, C] at positions [pos, pos+C) →
         (logits [B, C, V], updated caches). ``valid_len`` marks a
-        right-padded chunk's true length — only windowed ring WRITES
-        honor it (see ``_window_attn``); linear-cache pad rows are
-        self-correcting (masked until decode overwrites them).
+        right-padded chunk's true length — windowed ring WRITES
+        honor it (see ``_window_attn``) and so do the state leaves
+        (CCAttention's ring, a GatedDeltaNet's state); linear-cache pad
+        rows are self-correcting (masked until decode overwrites them).
 
         ``tp`` (optional ``(axis_name, degree)``): the walk is running
         INSIDE a tensor-parallel shard_map and ``caches`` hold only
@@ -1069,14 +1251,21 @@ class Decoder:
         ``stats`` (a dict, optional): what the walk counts on the
         device is summed into it — ``experts_touched`` (experts given
         a token, summed over the routed MoEFFN nodes): the serving
-        engine's ``serving.moe_experts_touched``; ``attn_rows_read``
+        engine's ``serving.moe_experts_touched``; ``pairs_held``
+        (token-expert pairs that fell on experts the nodes hold):
+        ``serving.moe_pairs_held``; ``attn_rows_read``
         (cache rows the bounded reads fetched, block-rounded, summed
-        over the attention nodes): ``serving.attn_rows_read``.
+        over the attention nodes): ``serving.attn_rows_read``;
+        ``state_advanced`` (batch rows whose recurrent state a
+        GatedDeltaNet node advanced, summed over those nodes):
+        ``serving.state_slots_advanced``.
 
         ``lens`` ([B] int32, with a vector ``pos``): the rows of each
         batch row's cache that the bounded read may fetch — the slot
         walk's ``pos + C`` for a slot that holds a request, 0 for one
-        that does not (its output is then discarded by the caller)."""
+        that does not (its output is then discarded by the caller, and
+        a GatedDeltaNet node leaves its state untouched: the state
+        kind's "not live")."""
         from ..ops.attention import moe_ffn_math
         from ..serving.quant import (QuantizedTensor, embedding_rows,
                                      moe_ffn_forward)
@@ -1109,6 +1298,22 @@ class Decoder:
                     out, new_caches[mha_i] = self._cached_cca(
                         n, ins, new_caches[mha_i], pos, valid_len,
                         lens=lens, stats=stats)
+                    mha_i += 1
+                    env[(id(n), 0)] = out
+                    continue
+                if name in ("GatedAttention", "GatedDeltaNet"):
+                    if tp is not None:
+                        raise MXNetError(
+                            "Decoder: %s (node %r) has no tensor-"
+                            "parallel form" % (name, n.name))
+                    if name == "GatedAttention":
+                        out, new_caches[mha_i] = self._cached_gattn(
+                            n, ins, new_caches[mha_i], pos, lens=lens,
+                            stats=stats)
+                    else:
+                        out, new_caches[mha_i] = self._cached_gdn(
+                            n, ins, new_caches[mha_i], pos, valid_len,
+                            lens=lens, stats=stats)
                     mha_i += 1
                     env[(id(n), 0)] = out
                     continue
@@ -1157,8 +1362,8 @@ class Decoder:
                     seen = {}
                     env[(id(n), 0)] = moe_ffn_math(n.params, ins,
                                                    stats=seen)
-                    stats["experts_touched"] = seen["experts_touched"] \
-                        + stats.get("experts_touched", 0)
+                    for key, val in seen.items():
+                        stats[key] = val + stats.get(key, 0)
                     continue
                 if name == "BatchNorm" and ins[0].ndim >= 3:
                     # BatchNorm normalizes axis 1, which for rank>=3 LM data

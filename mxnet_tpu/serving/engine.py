@@ -173,6 +173,18 @@ _TM_ROUNDS = tele.counter("serving.rounds")
 # tokens: no new sync, no new transfer.
 _TM_MOE_TOUCHED = tele.counter("serving.moe_experts_touched")
 _TM_MOE_LAYER_STEPS = tele.counter("serving.moe_layer_steps")
+# the same walk's token-expert pairs that fell on experts the nodes
+# hold (a node may hold a share of its experts), counted on the device
+# like the experts touched, beside all the pairs routed over the same
+# layers and steps (slots x top_k each: the host's product; a slot
+# that holds no request routes its stale token too)
+_TM_MOE_PAIRS_HELD = tele.counter("serving.moe_pairs_held")
+_TM_MOE_PAIRS_ROUTED = tele.counter("serving.moe_pairs_routed")
+# recurrent state (GatedDeltaNet nodes): slots whose state a decode
+# step advanced, summed on the device over those layers and steps (one
+# more column), beside slots x layers x steps, the host's product
+_TM_STATE_ADVANCED = tele.counter("serving.state_slots_advanced")
+_TM_STATE_POOL = tele.counter("serving.state_slots_pool")
 # cache rows the decode rounds' bounded reads fetched (block-rounded,
 # summed over slots, attention layers and steps) and the rows of the
 # pool over the same layers and steps: their quotient is the share of
@@ -843,10 +855,11 @@ class InferenceEngine:
                              "(1 = no expert sharding; "
                              "MXNET_SERVING_EP sets the default), "
                              "got %d" % ep)
-        # a CCAttention decoder keeps a rolling state beside its rows;
-        # every feature that cannot carry it refuses by the op's name,
-        # here and below (ROADMAP D4: no silent fallback)
-        rolling = bool(decoder._cca)
+        # the decoder has a state leaf (a CCAttention's rolling state
+        # beside its rows, a GatedDeltaNet's recurrent state instead of
+        # rows); every feature that cannot carry it refuses by the
+        # op's name, here and below (ROADMAP D4: no silent fallback)
+        rolling = decoder.has_state
         if rolling and tp > 1:
             decoder.refuse_rolling_state("tp=%d" % tp)
         if ep > 1:
@@ -1280,17 +1293,24 @@ class InferenceEngine:
         self._copy_donate = (0, 1) if on_chip else ()
         cs = self._cache_spec(self._caches)
         # routed MoEFFN layers whose touched experts the decode program
-        # counts (0: none; counted for a CCAttention decoder's walk,
-        # whose routed experts take their routing from the graph)
-        self._moe_counted = sum(
-            1 for n in moe_nodes if n.params["top_k"] > 0) \
-            if rolling and not decoder._mha else 0
+        # counts (0: none; counted for the walk of a decoder with a
+        # state leaf, whose experts run in the routed form), and the
+        # token-expert pairs a step routes in them
+        counted = [n for n in moe_nodes if n.params["top_k"] > 0] \
+            if rolling and not decoder._mha else []
+        self._moe_counted = len(counted)
+        self._moe_pairs_step = self.slots * sum(
+            n.params["top_k"] for n in counted)
+        # GatedDeltaNet layers, whose advanced slots it counts
+        self._state_layers = len(decoder._gdn)
         # rows of the K buffers of every attention layer (a
-        # CCAttention's K rows too), which a decode step's bounded
-        # reads are counted against (0: the walk is not batched — a
-        # ring — so no read is bounded and nothing is counted)
+        # CCAttention's K rows too; a GatedDeltaNet layer has none),
+        # which a decode step's bounded reads are counted against (0:
+        # the walk is not batched — a ring — so no read is bounded and
+        # nothing is counted)
         self._attn_pool_rows = sum(
-            e[0].shape[0] * e[0].shape[1] for e in self._caches) \
+            k.shape[0] * k.shape[1]
+            for k in Decoder.row_buffers(self._caches)) \
             if decoder._slots_batched else 0
         self._step_fn = jax.jit(
             self._wrap_tp(self._make_step(),
@@ -1445,17 +1465,23 @@ class InferenceEngine:
         ep_ax = self._ep_ax
 
         counted = self._moe_counted
+        state_counted = self._state_layers
         rows_counted = self._attn_pool_rows
 
         def one_step(caches, state, params, aux):
             pos, tok, live, temp, keys, eos, last = state
             # write each slot's pending token at ITS position, read
-            # logits for the next one (frozen slots rewrite their last
-            # token in place — idempotent — and, holding no request,
-            # have no row the bounded read may fetch: a finished slot
-            # keeps its last position, so a bound by ``pos`` alone
-            # would read its stale rows for ever)
-            stats = {} if counted or rows_counted else None
+            # logits for the next one. Two contracts, by the kind of
+            # cache. ROWS: a frozen slot rewrites its last token in
+            # place — idempotent — and, holding no request, has no row
+            # the bounded read may fetch: a finished slot keeps its
+            # last position, so a bound by ``pos`` alone would read
+            # its stale rows for ever. A recurrent STATE: a step
+            # advances it, so a slot that is not live (``lens`` 0)
+            # leaves its state untouched (doc/serving.md "The decode
+            # round")
+            stats = {} if counted or rows_counted or state_counted \
+                else None
             logits, caches = dec._run_slots(
                 params, aux, caches, pos, tok[:, None], tp=tp_ax,
                 mm_impl=mm, ep=ep_ax, stats=stats,
@@ -1484,18 +1510,18 @@ class InferenceEngine:
                            lambda _: greedy, None)
             done_now = (nxt == eos) | (nxt_pos >= last)
             out = jnp.where(live, nxt, -1)     # -1: slot had no token
-            if counted:
-                # one more column beside the S tokens: the experts
-                # this step touched, over the routed layers
+            # more columns beside the S tokens, in this order: the
+            # experts this step touched and the pairs that fell on held
+            # experts, over the routed layers; the slots whose state
+            # it advanced, over the GatedDeltaNet layers; the cache
+            # rows its bounded reads fetched, over the attention layers
+            cols = (["experts_touched", "pairs_held"] if counted else []) \
+                + (["state_advanced"] if state_counted else []) \
+                + (["attn_rows_read"] if rows_counted else [])
+            if cols:
                 out = jnp.concatenate(
-                    [out, stats["experts_touched"].astype(out.dtype)
-                     .reshape(1)])
-            if rows_counted:
-                # and one for the cache rows this step's bounded reads
-                # fetched, over the attention layers
-                out = jnp.concatenate(
-                    [out, jnp.asarray(stats["attn_rows_read"],
-                                      out.dtype).reshape(1)])
+                    [out] + [jnp.asarray(stats[c], out.dtype).reshape(1)
+                             for c in cols])
             live2 = live & ~done_now
             pos2 = jnp.where(live, nxt_pos, pos)
             tok2 = jnp.where(live, nxt, tok)
@@ -1517,7 +1543,7 @@ class InferenceEngine:
 
             (caches, state), outs = lax.scan(body, (caches, state),
                                              None, length=k_rounds)
-            return caches, state, outs          # outs [k, S (+1) (+1)]
+            return caches, state, outs          # outs [k, S + columns]
 
         return step
 
@@ -2898,13 +2924,22 @@ class InferenceEngine:
                 self.stats["spec_accepted"] += acc
                 _TM_SPEC_ACCEPTED.inc(acc)
         else:
-            rounds = np.asarray(entry[1])        # [steps_per_round, S]
+            rounds = np.asarray(entry[1])   # [steps_per_round, S + columns]
+            col = self.slots
             if self._moe_counted:
-                # [steps, S + 1]: the last column is the device's
-                # count of experts touched, step by step
-                _TM_MOE_TOUCHED.inc(int(rounds[:, self.slots].sum()))
+                # the device's counts, step by step: experts touched,
+                # then pairs on held experts
+                _TM_MOE_TOUCHED.inc(int(rounds[:, col].sum()))
                 _TM_MOE_LAYER_STEPS.inc(
                     self._moe_counted * rounds.shape[0])
+                _TM_MOE_PAIRS_HELD.inc(int(rounds[:, col + 1].sum()))
+                _TM_MOE_PAIRS_ROUTED.inc(
+                    self._moe_pairs_step * rounds.shape[0])
+                col += 2
+            if self._state_layers:
+                _TM_STATE_ADVANCED.inc(int(rounds[:, col].sum()))
+                _TM_STATE_POOL.inc(self.slots * self._state_layers
+                                   * rounds.shape[0])
             if self._attn_pool_rows:
                 # the last column: cache rows the step's bounded reads
                 # fetched, against the pool's rows over the same steps

@@ -110,6 +110,20 @@ object GeneratedOps {
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("FullyConnected", name, attrs, inputs)
 
+  /** Causal grouped-query softmax attention with a head size of its
+    * params: eps: float = 1e-06; head_dim: int; impl: str = flash; num_heads: int; num_kv_heads: int; rope_base: float = 10000.0; rotary_dim: int = 0
+    * inputs: data, q_weight, k_weight, v_weight, q_norm, k_norm, out_weight */
+  def GatedAttention(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("GatedAttention", name, attrs, inputs)
+
+  /** A Gated DeltaNet mixer (linear attention; Yang et al.,
+    * params: conv_kernel: int = 4; eps: float = 1e-06; head_k_dim: int; head_v_dim: int; num_k_heads: int; num_v_heads: int
+    * inputs: data, qkvz_weight, ba_weight, conv_weight, a_log, dt_bias, norm_weight, out_weight */
+  def GatedDeltaNet(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("GatedDeltaNet", name, attrs, inputs)
+
   /** Identity forward that attaches a KL sparsity penalty gradient
     * params: momentum: float = 0.9; penalty: float = 0.001; sparseness_target: float = 0.1
     * inputs: data */
@@ -160,7 +174,7 @@ object GeneratedOps {
     Symbol.createFromNamedArgs("MAERegressionOutput", name, attrs, inputs)
 
   /** Mixture-of-experts position-wise FFN (soft or top-k routing).
-    * params: gated: bool = False; hidden: int; num_experts: int; router: str = linear; top_k: int = 0
+    * params: expert_first: int = 0; experts_held: int = 0; gated: bool = False; hidden: int; num_experts: int; router: str = linear; shared_hidden: int = 0; top_k: int = 0
     * inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2 */
   def MoEFFN(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =

@@ -259,7 +259,8 @@ def test_rtc_user_kernel(compile_for_chip):
 def serve_chat_engine():
     """The benchmark's serve-chat engine at OPT-1.3B widths (hidden
     2048, 32 heads of 64, ffn 8192, the whole vocabulary, bf16) cut to
-    2 layers: 16 slots x 1024 rows, 8 steps a round, and the
+    2 layers: 32 slots x 1024 rows (the cell's since PR 33), 8 steps a
+    round, and the
     speculative verify program beside the decode program. Weights are
     zeros: only shapes reach the compiler."""
     import mxnet_tpu as mx
@@ -278,7 +279,7 @@ def serve_chat_engine():
                               compute_dtype="bfloat16",
                               weight_dtype="float")
     return mx.serving.InferenceEngine(
-        dec, slots=16, prefill_buckets=(512, 768), steps_per_round=8,
+        dec, slots=32, prefill_buckets=(512, 768), steps_per_round=8,
         prefix_cache_mb=0, prefill_chunk=0, spec_k=4, draft="ngram")
 
 
@@ -404,3 +405,104 @@ def test_zaya_decode_program(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 0.1 * cache_bytes
     eng.close()
+
+
+# -- Qwen3-Next: a recurrent state and K/V rows in one decode program ----
+
+@pytest.fixture(scope="module")
+def longdoc_engine():
+    """The benchmark's serve-longdoc engine at Qwen3-Next-80B-A3B's
+    published widths cut to 2 layers, one of each kind (a Gated DeltaNet
+    layer, a gated D=256 attention layer), 128 of 512 experts held, a
+    cut vocabulary: 64 slots x 9216 rows, 8 steps a round, prompts in
+    pieces of 2048. Weights are zeros: only shapes reach the compiler."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_qwen3_next_lm
+    sym = get_qwen3_next_lm(
+        8192, 2, 2048, num_heads=16, num_kv_heads=2, head_dim=256,
+        linear_k_heads=16, linear_v_heads=32, linear_k_dim=128,
+        linear_v_dim=128, num_experts=512, expert_hidden=512, top_k=10,
+        shared_hidden=512, full_attention_interval=2, experts_held=128,
+        rotary_dim=64, rope_base=1e7)
+    shapes = {"data": (1, 8), "softmax_label": (1, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    params = {n: jnp.zeros(s, BF16)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    dec = mx.parallel.Decoder(sym, params, max_len=9216,
+                              compute_dtype="bfloat16")
+    eng = mx.serving.InferenceEngine(
+        dec, slots=64, prefill_buckets=(512, 1024, 2048),
+        steps_per_round=8, prefix_cache_mb=0, prefill_chunk=2048)
+    yield eng
+    eng.close()
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=sharding), tree)
+
+
+def test_longdoc_decode_program(longdoc_engine, one_chip, monkeypatch):
+    """The decode program of the cell's two kinds of layer, compiled for
+    the chip: the attention layer's read is the bounded kernel and the
+    routed experts two grouped products (five kernels in the step's
+    body); the 134 MB float32 state leaf is updated where it lies: no
+    ``copy`` of it, not staged through fast memory, and the temporaries
+    stay under a tenth of the caches (the step reads the state in two
+    fusions, the second of which writes it)."""
+    eng = longdoc_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    args = _abstract([eng._params, eng._aux, eng._caches, eng._state],
+                     one_chip)
+    compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    state = eng._caches[0][0]
+    assert state.shape == (64, 32, 128, 128) and state.dtype == F32
+    dims = ",".join(str(d) for d in state.shape)
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert dims not in copies, copies
+    staged = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"\b(copy-start|copy-done|slice-start)\(", line)
+              and re.search(r"f32\[[\d,]*32,128,128\]\{[^}]*S\(1\)", line)]
+    assert not staged, staged
+    cache_bytes = sum(x.nbytes for x in
+                      jax.tree_util.tree_leaves(eng._caches))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.1 * cache_bytes
+
+
+def test_longdoc_prefill_program(longdoc_engine, one_chip, monkeypatch):
+    """The largest prefill program of the cell (the 2,048-token piece),
+    compiled for the chip: the chunked recurrence's triangular solve, the
+    blocked attention read against 9,216 rows and the routed experts in
+    four passes of 512 tokens compile, and the temporaries stay under
+    0.5 GB (2,048 queries against 9,216 rows at once would be 1.2 GB of
+    scores alone). No buffer the compiler keeps in fast memory is over
+    64 MiB of the chip's 128 (the chunked recurrence's products, which
+    run): the routed layout of all 20,480 pairs at once was 112 MiB
+    there and the eight-layer program never came back (PERF.md section
+    6, PR 34)."""
+    from mxnet_tpu.serving.engine import _raw_key
+    eng = longdoc_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    bucket = 2048
+    fn = eng._prefill_fn(bucket)
+    i32 = np.int32
+    args = [eng._params, eng._aux, eng._caches, eng._state, i32(0),
+            jnp.zeros((1, bucket), I32), i32(0), i32(bucket),
+            np.bool_(True), np.float32(0), _raw_key(0), i32(-1), i32(8)]
+    compiled = jax.jit(fn, donate_argnums=(2, 3)) \
+        .lower(*_abstract(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    fast = [(int(np.prod([int(d) for d in dims.split(",")])) * width[kind],
+             kind, dims)
+            for kind, dims in re.findall(
+                r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
+    assert fast and max(fast)[0] <= 64 << 20, max(fast)
