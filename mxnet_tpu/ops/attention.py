@@ -266,7 +266,7 @@ class MoEFFN(OpSpec):
 
 
 def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
-                 ep=None, stats=None):
+                 ep=None, stats=None, live=None):
     """The ONE MoE routing + combine implementation, parameterized
     over its three matmuls (``None`` = the plain products). The
     serving engine's weight-quantized path (``serving/quant.py``)
@@ -297,7 +297,20 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     ``stats["experts_touched"]`` there, the number of (held) experts
     that were given a token, and ``stats["pairs_held"]``, the
     token-expert pairs that fell on held experts (traced int32
-    scalars)."""
+    scalars).
+
+    ``live`` (bool [B], optional; every token of a batch row alike): a
+    row that is not live routes nothing in the routed form. Its pairs
+    are marked absent before they are sorted into groups, so they take
+    no row and touch no expert, and its output is the shared expert's
+    part alone (zeros without one): finite, and for a caller that
+    discards it (the serving engine's slot that holds no request). The
+    gates, the choice and the shared expert are computed as ever, and a
+    live row's values do not change. ``stats["rows_masked"]`` is then
+    the number of rows that were not live. The dense forms ignore
+    ``live``: every expert computes every token there whatever the
+    gates say, so masking saves nothing. With ``live=None`` the traced
+    program is the one without the argument."""
     given, gated = MoEFFN.given(p), bool(p.get("gated", False))
     it = iter(ins)
     x = next(it)
@@ -371,6 +384,10 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
         # gets the index ``held``, which no group has
         local = idx - first
         local = jnp.where((local >= 0) & (local < held), local, held)
+        if live is not None:
+            local = jnp.where(live[:, None, None], local, held)
+            if stats is not None:
+                stats["rows_masked"] = jnp.sum(~live).astype(jnp.int32)
         out = _routed_sum(x.reshape(b * t, e), local.reshape(b * t, k),
                           picked.reshape(b * t, k), held, w1, b1, w2, b2,
                           gated, stats).reshape(b, t, e)

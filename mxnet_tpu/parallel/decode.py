@@ -1253,7 +1253,9 @@ class Decoder:
         a token, summed over the routed MoEFFN nodes): the serving
         engine's ``serving.moe_experts_touched``; ``pairs_held``
         (token-expert pairs that fell on experts the nodes hold):
-        ``serving.moe_pairs_held``; ``attn_rows_read``
+        ``serving.moe_pairs_held``; ``rows_masked`` (batch rows that
+        were not live, whose pairs those nodes dropped: under ``lens``
+        only): ``serving.moe_rows_masked``; ``attn_rows_read``
         (cache rows the bounded reads fetched, block-rounded, summed
         over the attention nodes): ``serving.attn_rows_read``;
         ``state_advanced`` (batch rows whose recurrent state a
@@ -1263,9 +1265,10 @@ class Decoder:
         ``lens`` ([B] int32, with a vector ``pos``): the rows of each
         batch row's cache that the bounded read may fetch — the slot
         walk's ``pos + C`` for a slot that holds a request, 0 for one
-        that does not (its output is then discarded by the caller, and
-        a GatedDeltaNet node leaves its state untouched: the state
-        kind's "not live")."""
+        that does not (its output is then discarded by the caller, a
+        GatedDeltaNet node leaves its state untouched: the state
+        kind's "not live", and a routed MoEFFN node gives its tokens
+        no expert: ``moe_ffn_math``'s ``live``)."""
         from ..ops.attention import moe_ffn_math
         from ..serving.quant import (QuantizedTensor, embedding_rows,
                                      moe_ffn_forward)
@@ -1274,6 +1277,8 @@ class Decoder:
             mm_impl = self._matmul_impl
         qmm = None if mm_impl == "dense" \
             else (lambda x, qt: self._qmm(x, qt, mm_impl))
+        # a batch row that holds no request routes nothing (MoEFFN)
+        live = None if lens is None else jnp.asarray(lens, jnp.int32) > 0
         env = {}
         new_caches = list(caches)
         mha_i = 0
@@ -1357,13 +1362,14 @@ class Decoder:
                     env[(id(n), 0)] = moe_ffn_forward(n.params, ins,
                                                       mm=qmm, ep=ep)
                     continue
-                if name == "MoEFFN" and stats is not None \
-                        and n.params["top_k"] > 0:
+                if name == "MoEFFN" and n.params["top_k"] > 0 \
+                        and (stats is not None or live is not None):
                     seen = {}
                     env[(id(n), 0)] = moe_ffn_math(n.params, ins,
-                                                   stats=seen)
-                    for key, val in seen.items():
-                        stats[key] = val + stats.get(key, 0)
+                                                   stats=seen, live=live)
+                    if stats is not None:
+                        for key, val in seen.items():
+                            stats[key] = val + stats.get(key, 0)
                     continue
                 if name == "BatchNorm" and ins[0].ndim >= 3:
                     # BatchNorm normalizes axis 1, which for rank>=3 LM data

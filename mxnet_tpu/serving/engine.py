@@ -176,10 +176,13 @@ _TM_MOE_LAYER_STEPS = tele.counter("serving.moe_layer_steps")
 # the same walk's token-expert pairs that fell on experts the nodes
 # hold (a node may hold a share of its experts), counted on the device
 # like the experts touched, beside all the pairs routed over the same
-# layers and steps (slots x top_k each: the host's product; a slot
-# that holds no request routes its stale token too)
+# layers and steps (slots x top_k each: the host's product), and the
+# slots that held no request there, whose pairs the walk dropped (they
+# route nothing and stream no expert): over slots x layer-steps the
+# dead share, 1 - the occupancy
 _TM_MOE_PAIRS_HELD = tele.counter("serving.moe_pairs_held")
 _TM_MOE_PAIRS_ROUTED = tele.counter("serving.moe_pairs_routed")
+_TM_MOE_ROWS_MASKED = tele.counter("serving.moe_rows_masked")
 # recurrent state (GatedDeltaNet nodes): slots whose state a decode
 # step advanced, summed on the device over those layers and steps (one
 # more column), beside slots x layers x steps, the host's product
@@ -1511,11 +1514,13 @@ class InferenceEngine:
             done_now = (nxt == eos) | (nxt_pos >= last)
             out = jnp.where(live, nxt, -1)     # -1: slot had no token
             # more columns beside the S tokens, in this order: the
-            # experts this step touched and the pairs that fell on held
-            # experts, over the routed layers; the slots whose state
-            # it advanced, over the GatedDeltaNet layers; the cache
-            # rows its bounded reads fetched, over the attention layers
-            cols = (["experts_touched", "pairs_held"] if counted else []) \
+            # experts this step touched, the pairs that fell on held
+            # experts and the slots that routed nothing, over the
+            # routed layers; the slots whose state it advanced, over
+            # the GatedDeltaNet layers; the cache rows its bounded
+            # reads fetched, over the attention layers
+            cols = (["experts_touched", "pairs_held", "rows_masked"]
+                    if counted else []) \
                 + (["state_advanced"] if state_counted else []) \
                 + (["attn_rows_read"] if rows_counted else [])
             if cols:
@@ -2928,14 +2933,15 @@ class InferenceEngine:
             col = self.slots
             if self._moe_counted:
                 # the device's counts, step by step: experts touched,
-                # then pairs on held experts
+                # pairs on held experts, slots that routed nothing
                 _TM_MOE_TOUCHED.inc(int(rounds[:, col].sum()))
                 _TM_MOE_LAYER_STEPS.inc(
                     self._moe_counted * rounds.shape[0])
                 _TM_MOE_PAIRS_HELD.inc(int(rounds[:, col + 1].sum()))
                 _TM_MOE_PAIRS_ROUTED.inc(
                     self._moe_pairs_step * rounds.shape[0])
-                col += 2
+                _TM_MOE_ROWS_MASKED.inc(int(rounds[:, col + 2].sum()))
+                col += 3
             if self._state_layers:
                 _TM_STATE_ADVANCED.inc(int(rounds[:, col].sum()))
                 _TM_STATE_POOL.inc(self.slots * self._state_layers
