@@ -146,6 +146,24 @@
   mx.symbol.internal.create("GatedDeltaNet", name, list(...))
 }
 
+#' The writing half of a hyper-connection: ``X <- H_res X +
+#'
+#' Parameters: lanes (int)
+#' Symbol inputs: data, branch, mix
+#' @export
+`mx.symbol.HyperConnectionPost` <- function(..., name = "") {
+  mx.symbol.internal.create("HyperConnectionPost", name, list(...))
+}
+
+#' The reading half of a hyper-connection (the section above): from
+#'
+#' Parameters: clamp (float, default 30.0); eps (float, default 1e-06); iters (int, default 20); lanes (int); norm_eps (float, default 1e-06); res_diag (float, default 0.0)
+#' Symbol inputs: data, phi, alpha, bias
+#' @export
+`mx.symbol.HyperConnectionPre` <- function(..., name = "") {
+  mx.symbol.internal.create("HyperConnectionPre", name, list(...))
+}
+
 #' Identity forward that attaches a KL sparsity penalty gradient
 #'
 #' Parameters: momentum (float, default 0.9); penalty (float, default 0.001); sparseness_target (float, default 0.1)
@@ -162,6 +180,15 @@
 #' @export
 `mx.symbol.LRN` <- function(..., name = "") {
   mx.symbol.internal.create("LRN", name, list(...))
+}
+
+#' Causal multi-head latent attention (MLA), no biases. data
+#'
+#' Parameters: eps (float, default 1e-06); impl (str, default flash); kv_lora_rank (int); mscale_all_dim (float, default 0.0); nope_dim (int); num_heads (int); q_lora_rank (int); rope_base (float, default 10000.0); rope_dim (int); v_dim (int); yarn_beta_fast (float, default 32.0); yarn_beta_slow (float, default 1.0); yarn_factor (float, default 0.0); yarn_original_max (int, default 4096)
+#' Symbol inputs: data, q_down_weight, q_norm, q_up_weight, kv_down_weight, kv_norm, kv_up_weight, out_weight
+#' @export
+`mx.symbol.LatentAttention` <- function(..., name = "") {
+  mx.symbol.internal.create("LatentAttention", name, list(...))
 }
 
 #' Layer normalization over the trailing axis: gamma/beta learnable.
@@ -211,7 +238,7 @@
 
 #' Mixture-of-experts position-wise FFN (soft or top-k routing).
 #'
-#' Parameters: expert_first (int, default 0); experts_held (int, default 0); gated (bool, default False); hidden (int); num_experts (int); router (str, default linear); shared_hidden (int, default 0); top_k (int, default 0)
+#' Parameters: expert_first (int, default 0); experts_held (int, default 0); gated (bool, default False); hidden (int); num_experts (int); renormalize (bool, default False); route_scale (float, default 1.0); router (str, default linear); shared_gated (bool, default True); shared_hidden (int, default 0); top_k (int, default 0)
 #' Symbol inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2
 #' @export
 `mx.symbol.MoEFFN` <- function(..., name = "") {
@@ -315,6 +342,15 @@
 #' @export
 `mx.symbol.SpaceToDepth` <- function(..., name = "") {
   mx.symbol.internal.create("SpaceToDepth", name, list(...))
+}
+
+#' The ends of a several-lane residual stream. ``mode="copy"``:
+#'
+#' Parameters: lanes (int); mode (str)
+#' Symbol inputs: data
+#' @export
+`mx.symbol.StreamLanes` <- function(..., name = "") {
+  mx.symbol.internal.create("StreamLanes", name, list(...))
 }
 
 #' Swap two axes (``swapaxis-inl.h``).

@@ -14,7 +14,7 @@ Parity map (reference ``example/``):
 * ``example/fcn-xs/symbol_fcnxs.py``                    -> :mod:`.fcn`
 * no reference counterpart (decoder-only LMs):
   ``get_transformer_lm``, ``get_zaya_lm``,
-  ``get_qwen3_next_lm``                                 -> :mod:`.transformer`
+  ``get_qwen3_next_lm``, ``get_xing4_lm``               -> :mod:`.transformer`
 
 Every constructor returns a :class:`mxnet_tpu.symbol.Symbol` whose single
 head is a ``SoftmaxOutput`` (classification) so it drops straight into
@@ -35,7 +35,8 @@ from .fcn import get_fcn_symbol
 from . import transformer
 from .transformer import (get_transformer_lm, transformer_block,
                           moe_transformer_block, get_zaya_lm, zaya_block,
-                          get_qwen3_next_lm, qwen3_next_block)
+                          get_qwen3_next_lm, qwen3_next_block,
+                          get_xing4_lm, xing4_block)
 
 _REGISTRY = {
     "mlp": get_mlp,

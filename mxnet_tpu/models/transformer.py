@@ -401,6 +401,135 @@ def get_qwen3_next_lm(vocab_size, num_layers, embed_dim, num_heads,
     return _lm_loss(logits, vocab_size, loss_layout)
 
 
+def _hyper(data, name, branch_fn, lanes, iters, hc_eps, clamp, res_diag,
+           eps):
+    """One sublayer inside its hyper-connection: ``u = H_pre X``,
+    ``y = branch_fn(RMSNorm(u))`` under the sublayer's own scale,
+    ``X <- H_res X + outer(H_post, y)`` (``ops.attention``, the
+    hyper-connection section)."""
+    pre = sym.HyperConnectionPre(
+        data=data, phi=sym.Variable(name + "_hc_phi"),
+        alpha=sym.Variable(name + "_hc_alpha"),
+        bias=sym.Variable(name + "_hc_bias"), lanes=lanes, iters=iters,
+        eps=hc_eps, clamp=clamp, res_diag=res_diag, norm_eps=eps,
+        name=name + "_hc_pre")
+    y = branch_fn(_rms(pre[0], name + "_norm", eps))
+    return sym.HyperConnectionPost(data=data, branch=y, mix=pre[1],
+                                   lanes=lanes, name=name + "_hc_post")
+
+
+def xing4_block(data, name, dense, embed_dim, num_heads, q_lora_rank,
+                kv_lora_rank, nope_dim, rope_dim, v_dim, ffn_hidden,
+                num_experts, expert_hidden, top_k, shared_hidden,
+                route_scale=1.0, experts_held=0, expert_first=0, lanes=4,
+                hc_iters=20, hc_eps=1e-6, hc_clamp=30.0, hc_res_diag=0.0,
+                rope_base=10000.0, yarn=None, mscale_all_dim=0.0, eps=1e-6,
+                impl="flash"):
+    """One Xing4.0-shaped layer on a stream of ``lanes`` lanes
+    [B, T, lanes E]: latent attention, then a SiLU-gated FFN of width
+    ``ffn_hidden`` (``dense``) or routed experts (top-k of
+    ``num_experts`` by sigmoid scores under a balancing bias,
+    renormalized over the chosen, times ``route_scale``, plus an
+    ungated shared expert; ``experts_held`` / ``expert_first``: the
+    share of the routed experts that lives here, 0 = all), each
+    sublayer inside its own hyper-connection (``_hyper``;
+    ``hc_res_diag``: ``HyperConnectionPre``'s ``res_diag``). ``yarn``:
+    ``(factor, original_max, beta_fast, beta_slow)`` or None."""
+    def var(tag):
+        return sym.Variable("%s_%s" % (name, tag))
+
+    def fc(z, width, tag, **kw):
+        return sym.FullyConnected(data=z, num_hidden=width, no_bias=True,
+                                  flatten=False, name="%s_%s" % (name, tag),
+                                  **kw)
+
+    def attention(h):
+        scaling = {} if yarn is None else dict(
+            yarn_factor=yarn[0], yarn_original_max=yarn[1],
+            yarn_beta_fast=yarn[2], yarn_beta_slow=yarn[3],
+            mscale_all_dim=mscale_all_dim)
+        return sym.LatentAttention(
+            data=h, q_down_weight=var("attn_q_down_weight"),
+            q_norm=var("attn_q_norm"), q_up_weight=var("attn_q_up_weight"),
+            kv_down_weight=var("attn_kv_down_weight"),
+            kv_norm=var("attn_kv_norm"),
+            kv_up_weight=var("attn_kv_up_weight"),
+            out_weight=var("attn_out_weight"), num_heads=num_heads,
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+            rope_base=rope_base, eps=eps, impl=impl, name=name + "_attn",
+            **scaling)
+
+    def dense_ffn(h):
+        gate = sym.Activation(data=fc(h, ffn_hidden, "ffn_gate"),
+                              act_type="silu", name=name + "_ffn_act")
+        return fc(gate * fc(h, ffn_hidden, "ffn_up"), embed_dim,
+                  "ffn_down")
+
+    def experts(h):
+        # the router in float32 at full product precision whatever the
+        # model computes in: what it decides is discrete
+        scores = sym.Activation(
+            data=fc(sym.Cast(data=h, dtype="float32",
+                             name=name + "_router_in"),
+                    num_experts, "router", precision="highest"),
+            act_type="sigmoid", name=name + "_router_scores")
+        return sym.MoEFFN(
+            data=h, probs=scores, select_bias=var("router_balance"),
+            expert_w1=var("expert_w1"), expert_w2=var("expert_w2"),
+            shared_w1=var("shared_w1"), shared_w2=var("shared_w2"),
+            num_experts=num_experts, hidden=expert_hidden, top_k=top_k,
+            router="given", gated=True, renormalize=True,
+            route_scale=route_scale, experts_held=experts_held,
+            expert_first=expert_first, shared_hidden=shared_hidden,
+            shared_gated=False, name=name + "_moe")
+
+    hc = (lanes, hc_iters, hc_eps, hc_clamp, hc_res_diag, eps)
+    x = _hyper(data, name + "_attn", attention, *hc)
+    return _hyper(x, name + "_ffn", dense_ffn if dense else experts, *hc)
+
+
+def get_xing4_lm(vocab_size, num_layers, embed_dim, num_heads, q_lora_rank,
+                 kv_lora_rank, nope_dim, rope_dim, v_dim, ffn_hidden,
+                 num_experts, expert_hidden, top_k, shared_hidden,
+                 dense_layers=1, route_scale=1.0, experts_held=0,
+                 expert_first=0, lanes=4, hc_iters=20, hc_eps=1e-6,
+                 hc_clamp=30.0, hc_res_diag=0.0, rope_base=10000.0,
+                 yarn=None, mscale_all_dim=0.0, eps=1e-6, impl="flash",
+                 loss_layout="reference"):
+    """Xing4.0-shaped decoder-only LM: a residual stream of ``lanes``
+    lanes (the embedding copied into each, ``StreamLanes``) through
+    ``num_layers`` of ``xing4_block`` (the first ``dense_layers`` with a
+    dense FFN, the others with routed experts), the lanes summed before
+    the final RMSNorm and an untied head; no biases, no positional table
+    (rotary inside the latent attention). Built from registered Symbol
+    ops like every zoo model: it binds, and is served by ``Decoder`` /
+    ``InferenceEngine``, which keep ONE buffer of latent rows per layer
+    (``parallel/decode.py``, the latent-rows kind). ``loss_layout`` as
+    in ``get_transformer_lm``."""
+    net = sym.Embedding(data=sym.Variable("data"),
+                        weight=sym.Variable("embed_weight"),
+                        input_dim=vocab_size, output_dim=embed_dim,
+                        name="embed")
+    net = sym.StreamLanes(data=net, lanes=lanes, mode="copy",
+                          name="stream_in")
+    for i in range(num_layers):
+        net = xing4_block(
+            net, "layer%d" % i, i < dense_layers, embed_dim, num_heads,
+            q_lora_rank, kv_lora_rank, nope_dim, rope_dim, v_dim,
+            ffn_hidden, num_experts, expert_hidden, top_k, shared_hidden,
+            route_scale=route_scale, experts_held=experts_held,
+            expert_first=expert_first, lanes=lanes, hc_iters=hc_iters,
+            hc_eps=hc_eps, hc_clamp=hc_clamp, hc_res_diag=hc_res_diag,
+            rope_base=rope_base, yarn=yarn, mscale_all_dim=mscale_all_dim, eps=eps, impl=impl)
+    net = sym.StreamLanes(data=net, lanes=lanes, mode="sum",
+                          name="stream_out")
+    logits = sym.FullyConnected(
+        data=_rms(net, "final_norm", eps), num_hidden=vocab_size,
+        no_bias=True, flatten=False, name="lm_head")
+    return _lm_loss(logits, vocab_size, loss_layout)
+
+
 def tp_rules():
     """Tensor-parallel sharding rules for transformer params (Megatron
     layout: QKV/FFN1 column-parallel, proj/FFN2 row-parallel) — pass to

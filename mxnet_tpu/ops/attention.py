@@ -149,12 +149,33 @@ class MoEFFN(OpSpec):
     data: [B, T, E]; out[b,t] = sum_x gate[b,t,x] * FFN_x(data[b,t]).
     Two switches choose the block:
 
-    ``router``: ``"linear"`` (default) owns its gate, ``gate_weight``
-    [X, E]: logits = data . gate^T, softmax over the kept logits.
-    ``"given"`` takes the routing from the graph: ``probs`` [B, T, X]
-    (a router built from ordinary symbols) and ``select_bias`` [X]
-    (balancing biases): the experts are chosen by ``probs +
-    select_bias`` and weighted by ``probs`` itself, not renormalized.
+    ``router``, and the three routers' arithmetic side by side (``s``
+    the scores over all ``num_experts``, ``C`` the ``top_k`` chosen):
+
+    ====================  ======================  =====================
+    router                chosen by               weight of a chosen x
+    ====================  ======================  =====================
+    ``"linear"``          ``s = data . gate^T``   ``softmax over C of
+    (default; OPT-MoE,    (``gate_weight``        s``: renormalized by
+    Qwen3-Next)           [X, E], float32 sums)   construction
+    ``"given"``           ``probs + select_bias`` ``probs[x]``, NOT
+    (ZAYA: an MLP         (``probs`` [B, T, X]    renormalized
+    router's softmax)     from the graph,
+                          ``select_bias`` [X])
+    ``"given"``,          ``probs + select_bias`` ``probs[x] / (sum
+    ``renormalize=True``  (the bias decides the   over C of probs +
+    ``route_scale=c``     choice and never the    1e-20) * c``
+    (sigmoid scores       weight: ``noaux_tc``)
+    under a balancing
+    bias)
+    ====================  ======================  =====================
+
+    ``"linear"`` owns its gate; ``"given"`` takes the routing from the
+    graph (a router built from ordinary symbols, in float32). Ties are
+    broken by index (``lax.top_k``). ``renormalize`` and
+    ``route_scale`` belong to ``"given"``; their defaults (False, 1.0)
+    trace no operation, so a graph that does not set them compiles to
+    the program it compiled to before they existed.
 
     ``gated``: False (default) is the biased ReLU pair, ``expert_w1``
     [X, H, E], ``expert_b1`` [X, H], ``expert_w2`` [X, E, H],
@@ -197,8 +218,9 @@ class MoEFFN(OpSpec):
     SiLU-gated expert that every token takes, behind a sigmoid gate:
     ``out += sigmoid(shared_gate . x) * shared_w2 (silu(shared_w1[:H]
     x) * (shared_w1[H:] x))``, ``shared_w1`` [2H, E], ``shared_w2``
-    [E, H], ``shared_gate`` [1, E]. Every holder of a share computes
-    it alike, so it counts once in their sum.
+    [E, H], ``shared_gate`` [1, E]; with ``shared_gated=False`` there
+    is no gate and no ``shared_gate`` argument. Every holder of a share
+    computes it alike, so it counts once in their sum.
     """
 
     name = "MoEFFN"
@@ -208,15 +230,20 @@ class MoEFFN(OpSpec):
               "gated": Param("bool", False),
               "experts_held": Param("int", 0),
               "expert_first": Param("int", 0),
-              "shared_hidden": Param("int", 0)}
+              "shared_hidden": Param("int", 0),
+              "renormalize": Param("bool", False),
+              "route_scale": Param("float", 1.0),
+              "shared_gated": Param("bool", True)}
 
     def arguments(self, p):
         route = ["probs", "select_bias"] if self.given(p) \
             else ["gate_weight"]
         experts = ["expert_w1", "expert_w2"] if p["gated"] else \
             ["expert_w1", "expert_b1", "expert_w2", "expert_b2"]
-        shared = ["shared_w1", "shared_w2", "shared_gate"] \
-            if p.get("shared_hidden") else []
+        shared = []
+        if p.get("shared_hidden"):
+            shared = ["shared_w1", "shared_w2"] \
+                + (["shared_gate"] if p.get("shared_gated", True) else [])
         return ["data"] + route + experts + shared
 
     @staticmethod
@@ -326,8 +353,17 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     b1 = None if gated else next(it)
     w2 = next(it)
     b2 = None if gated else next(it)
-    shared = (next(it), next(it), next(it)) \
-        if p.get("shared_hidden") else None
+    shared = None
+    if p.get("shared_hidden"):
+        shared = (next(it), next(it),
+                  next(it) if p.get("shared_gated", True) else None)
+    renorm = bool(p.get("renormalize", False))
+    route_scale = float(p.get("route_scale", 1.0))
+    if (renorm or route_scale != 1.0) and not given:
+        raise MXNetError(
+            "MoEFFN: renormalize / route_scale belong to router='given' "
+            "(the linear router renormalizes over its kept logits by "
+            "its softmax)")
     k = int(p["top_k"])
     nx = int(p["num_experts"])
     first, held = MoEFFN.held(p)
@@ -362,6 +398,11 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
                            axis=-2) > 0
         if given:
             gates = probs if k == 0 else jnp.where(mask, probs, 0)
+            if renorm:
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                                 + 1e-20)
+            if route_scale != 1.0:
+                gates = gates * route_scale
         else:
             if k > 0:
                 # mask BEFORE the softmax, so kept gates renormalize
@@ -488,13 +529,16 @@ def _routed_sum(x, idx, gates, nx, w1, b1, w2, b2, gated, stats=None):
 
 def _shared_expert(x, w1, w2, gate):
     """The shared expert's part, float32 [B, T, E]: the SiLU-gated
-    pair on every token, times ``sigmoid(gate . x)``."""
+    pair on every token, times ``sigmoid(gate . x)`` (``gate`` None:
+    ungated)."""
     f32 = jnp.float32
     with jax.named_scope("shared"):
         up = jnp.einsum("bte,he->bth", x, w1)
         hid = up.shape[-1] // 2
         h = jax.nn.silu(up[..., :hid]) * up[..., hid:]
         y = jnp.einsum("bth,eh->bte", h, w2, preferred_element_type=f32)
+        if gate is None:
+            return y
         g = jnp.einsum("bte,oe->bto", x, gate, preferred_element_type=f32)
         return y * jax.nn.sigmoid(g)
 
@@ -559,7 +603,32 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
         return y.at[dest].get(mode="fill", fill_value=0).reshape(n, k, -1)
 
 
-def rope_rotate(x, positions, base=10000.0, rotary_dim=None):
+def yarn_frequencies(half, base, factor, original_max, beta_fast=32.0,
+                     beta_slow=1.0):
+    """The ``half`` rotary frequencies under YaRN's scaling (Peng et
+    al., arXiv:2309.00071, as DeepSeek-V2 runs it), float32 numpy: pair
+    ``i`` keeps ``theta_i = base^(-i/half)`` where it turns more than
+    ``beta_fast`` times over ``original_max`` positions, takes
+    ``theta_i / factor`` where it turns fewer than ``beta_slow`` times,
+    and a linear blend between the two pair indices those turn counts
+    give (floor of the one, ceiling of the other, within
+    ``[0, 2 half - 1]``)."""
+    dim = 2 * int(half)
+    theta = float(base) ** (-np.arange(half, dtype=np.float64) / half)
+
+    def pair_of(turns):
+        return dim * np.log(original_max / (turns * 2.0 * np.pi)) \
+            / (2.0 * np.log(float(base)))
+
+    low = max(int(np.floor(pair_of(beta_fast))), 0)
+    high = min(int(np.ceil(pair_of(beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return (theta / float(factor) * ramp
+            + theta * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_rotate(x, positions, base=10000.0, rotary_dim=None, yarn=None):
     """Rotary position embedding (RoFormer / GPT-NeoX half-split form):
     rotate the two halves of each head dim by position-dependent angles,
     so q·k depends only on RELATIVE distance. x: [B, T, H, D] (D even);
@@ -568,14 +637,20 @@ def rope_rotate(x, positions, base=10000.0, rotary_dim=None):
     batched walk — every row gets its own angles). ``rotary_dim``
     (even, default D): only the first ``rotary_dim`` dims of each head
     turn, as two halves of their own; the rest pass through (partial
-    rotary)."""
+    rotary). ``yarn`` (optional ``(factor, original_max, beta_fast,
+    beta_slow)``): the frequencies are ``yarn_frequencies``'; cos and
+    sin stay unscaled."""
     d = x.shape[-1]
     r = d if rotary_dim is None else int(rotary_dim)
     if r < d:
         return jnp.concatenate(
-            [rope_rotate(x[..., :r], positions, base), x[..., r:]], -1)
+            [rope_rotate(x[..., :r], positions, base, yarn=yarn),
+             x[..., r:]], -1)
     half = d // 2
-    freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(yarn_frequencies(half, base, *yarn))
     ang = positions[..., None].astype(jnp.float32) * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if ang.ndim == 2:          # positions [T]: broadcast over batch
@@ -1386,3 +1461,398 @@ def gattn_out(o, gate, wo):
             * jax.nn.sigmoid(gate.astype(jnp.float32))
         return jnp.einsum("btq,eq->bte",
                           o.reshape(b, c, h * d).astype(gate.dtype), wo)
+
+
+# -- multi-head latent attention ----------------------------------------------
+# (DeepSeek-V2, arXiv:2405.04434 section 2.1.) A token's keys and values
+# are re-made from ONE stored row ``[c ; k_r]``: ``c`` the normalized
+# latent (``kv_lora_rank`` numbers), ``k_r`` the rotated positional key
+# that every head shares (``rope_dim``). Per head ``[k_n ; v] = W_ukv c``.
+# Two algebraically equal forms of the read:
+#   expanded  score_h = q_n,h . k_n,h + q_r,h . k_r;  o_h = sum p v_h
+#   absorbed  q~_h = W_uk,h^T q_n,h;  score_h = q~_h . c + q_r,h . k_r;
+#             o_h = W_uv,h (sum p c)
+# The absorbed form reads the stored row as it is, all heads against one
+# row like a many-query, one-kv-head attention with keys of
+# ``kv_lora_rank + rope_dim`` and values of ``kv_lora_rank``: the decode
+# step's. The expanded form costs fewer operations per (query, key) pair:
+# the prefill's and the full forward's.
+
+def _rms_scaled(z, gamma, eps):
+    zf = z.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(zf), axis=-1, keepdims=True)
+    return zf * jax.lax.rsqrt(ms + eps) * gamma.astype(jnp.float32)
+
+
+@register
+class LatentAttention(OpSpec):
+    """Causal multi-head latent attention (MLA), no biases. data
+    [B, T, E] (already normalized); ``H`` heads whose query/key width
+    ``Dn + Dr`` (``nope_dim`` + ``rope_dim``) is not their value width
+    ``Dv``; ``Rq = q_lora_rank``, ``R = kv_lora_rank``:
+
+    - ``q_down_weight`` [Rq, E], ``q_norm`` [Rq]:
+      ``c_q = RMSNorm(W_dq x)``; ``q_up_weight`` [H (Dn + Dr), Rq]: per
+      head ``[q_n ; q_r] = W_uq c_q``;
+    - ``kv_down_weight`` [R + Dr, E], ``kv_norm`` [R]:
+      ``[c ; k_r] = W_dkv x``, ``c <- RMSNorm(c)``;
+    - ``q_r`` and ``k_r`` rotated by position in the half-split order
+      at ``rope_base`` (``yarn_factor`` > 0: YaRN's frequencies over
+      ``yarn_original_max`` positions, cos and sin unscaled); ``k_r``
+      is one vector for all heads;
+    - ``kv_up_weight`` [H (Dn + Dv), R]: per head ``[k_n ; v] = W_ukv c``;
+    - ``score = (q_n . k_n + q_r . k_r) (Dn + Dr)^-0.5 m^2``,
+      ``m = 0.1 mscale_all_dim ln(yarn_factor) + 1`` (1 without YaRN);
+      causal softmax; ``out_weight`` [E, H Dv].
+
+    The full forward runs the EXPANDED form (``impl``: ``flash`` pads
+    the values to the query's width for the flash kernel, which takes
+    one width; ``dense``). The decoder's cached form
+    (``parallel/decode.py``, the latent-rows kind) stores ``[c ; k_r]``
+    after norm and rotation, ``R + Dr`` numbers a token with no head
+    axis, reads it expanded in a prefill piece and ABSORBED in a decode
+    step."""
+
+    name = "LatentAttention"
+    params = {"num_heads": Param("int"), "q_lora_rank": Param("int"),
+              "kv_lora_rank": Param("int"), "nope_dim": Param("int"),
+              "rope_dim": Param("int"), "v_dim": Param("int"),
+              "rope_base": Param("float", 10000.0),
+              "yarn_factor": Param("float", 0.0),
+              "yarn_original_max": Param("int", 4096),
+              "yarn_beta_fast": Param("float", 32.0),
+              "yarn_beta_slow": Param("float", 1.0),
+              "mscale_all_dim": Param("float", 0.0),
+              "eps": Param("float", 1e-6),
+              "impl": Param("str", "flash")}
+
+    def arguments(self, p):
+        return ["data", "q_down_weight", "q_norm", "q_up_weight",
+                "kv_down_weight", "kv_norm", "kv_up_weight", "out_weight"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        if len(d) != 3:
+            raise MXNetError("LatentAttention: data must be [B, T, E]")
+        if p["rope_dim"] % 2:
+            raise MXNetError("LatentAttention: rope_dim=%d must be even"
+                             % p["rope_dim"])
+        e, h = d[2], p["num_heads"]
+        rq, r = p["q_lora_rank"], p["kv_lora_rank"]
+        dn, dr, dv = p["nope_dim"], p["rope_dim"], p["v_dim"]
+        want = [d, (rq, e), (rq,), (h * (dn + dr), rq), (r + dr, e), (r,),
+                (h * (dn + dv), r), (e, h * dv)]
+        return [shape_assign(s, w, "LatentAttention " + n)
+                for s, w, n in zip(in_shapes, want,
+                                   self.arguments(p))], [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x = ins[0]
+        b, t, _ = x.shape
+        q, rows = mla_down(p, x, ins[1:6],
+                           jnp.arange(t, dtype=jnp.int32)[None])
+        with jax.named_scope("expand"):
+            kn, v, kr = mla_expand(p, rows, ins[6])
+        with jax.named_scope("attend"):
+            h, dr = p["num_heads"], p["rope_dim"]
+            k = jnp.concatenate(
+                [kn, jnp.broadcast_to(kr[:, :, None], (b, t, h, dr))], -1)
+            scale = mla_softmax_scale(p)
+            if p["impl"] == "flash":
+                from .pallas_kernels import flash_attention
+                pad = q.shape[-1] - v.shape[-1]
+                vp = jnp.pad(v, [(0, 0)] * 3 + [(0, pad)])
+                o = flash_attention(q, k, vp, causal=True,
+                                    scale=scale)[..., :v.shape[-1]]
+            elif p["impl"] == "dense":
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                               preferred_element_type=jnp.float32) * scale
+                mask = jnp.tril(jnp.ones((t, t), bool))
+                s = jnp.where(mask[None, None], s, -jnp.inf)
+                o = jnp.einsum("bhqk,bkhd->bqhd",
+                               jax.nn.softmax(s, axis=-1).astype(v.dtype),
+                               v)
+            else:
+                raise MXNetError("LatentAttention: unknown impl %r (flash "
+                                 "or dense)" % (p["impl"],))
+        return [mla_out(o, ins[7])], []
+
+
+def mla_softmax_scale(p):
+    """``(Dn + Dr)^-0.5 m^2``: YaRN's ``m = 0.1 mscale_all_dim
+    ln(factor) + 1`` enters the softmax scale squared (q and k each
+    carry one) where ``mscale_all_dim`` is set."""
+    m = 1.0
+    if p.get("yarn_factor", 0.0) > 1.0 and p.get("mscale_all_dim", 0.0):
+        m = 0.1 * float(p["mscale_all_dim"]) \
+            * float(np.log(p["yarn_factor"])) + 1.0
+    return float((p["nope_dim"] + p["rope_dim"]) ** -0.5) * m * m
+
+
+def _mla_yarn(p):
+    if p.get("yarn_factor", 0.0) > 1.0:
+        return (p["yarn_factor"], p["yarn_original_max"],
+                p["yarn_beta_fast"], p["yarn_beta_slow"])
+    return None
+
+
+def mla_down(p, x, weights, positions):
+    """LatentAttention's queries and latent rows of a chunk ``x``
+    [B, C, E] at ``positions`` ([1 or B, C] int32, absolute): both
+    down-projections, their norms, the queries' up-projection and the
+    rotary turn. One function for the full forward and the decoder's
+    cached walk. Returns q [B, C, H, Dn + Dr] (``[q_n ; q_r]``, ``q_r``
+    rotated) and the rows to store, ``[c ; k_r]`` [B, C, R + Dr], in
+    ``x``'s dtype."""
+    wdq, qn, wuq, wdkv, kvn = weights
+    h, r = p["num_heads"], p["kv_lora_rank"]
+    dn, dr = p["nope_dim"], p["rope_dim"]
+    b, c, _ = x.shape
+    dt = x.dtype
+    positions = jnp.asarray(positions, jnp.int32)
+    yarn = _mla_yarn(p)
+    with jax.named_scope("down"):
+        cq = _rms_scaled(jnp.einsum("bte,fe->btf", x, wdq), qn,
+                         p["eps"]).astype(dt)
+        q = jnp.einsum("btf,gf->btg", cq, wuq).reshape(b, c, h, dn + dr)
+        q = jnp.concatenate(
+            [q[..., :dn],
+             rope_rotate(q[..., dn:].astype(jnp.float32), positions,
+                         p["rope_base"], yarn=yarn).astype(dt)], -1)
+        ckv = jnp.einsum("bte,fe->btf", x, wdkv)
+        lat = _rms_scaled(ckv[..., :r], kvn, p["eps"])
+        kr = rope_rotate(ckv[..., None, r:].astype(jnp.float32), positions,
+                         p["rope_base"], yarn=yarn)[:, :, 0]
+        rows = jnp.concatenate([lat, kr], -1).astype(dt)
+    return q, rows
+
+
+def mla_expand(p, rows, wukv):
+    """Stored rows ``[c ; k_r]`` [B, L, R + Dr or wider] (lanes past
+    ``R + Dr`` are a store's padding) up-projected to per-head keys and
+    values: (k_n [B, L, H, Dn], v [B, L, H, Dv], k_r [B, L, Dr]) in the
+    weight's dtype."""
+    h, r = p["num_heads"], p["kv_lora_rank"]
+    dn, dv, dr = p["nope_dim"], p["v_dim"], p["rope_dim"]
+    b, l, _ = rows.shape
+    kv = jnp.einsum("blr,fr->blf", rows[..., :r].astype(wukv.dtype),
+                    wukv).reshape(b, l, h, dn + dv)
+    return kv[..., :dn], kv[..., dn:], \
+        rows[..., r:r + dr].astype(wukv.dtype)
+
+
+def mla_absorb_q(p, q, wukv):
+    """``[q_n ; q_r]`` [B, C, H, Dn + Dr] with ``W_uk`` folded in:
+    ``[W_uk,h^T q_n,h ; q_r,h]`` [B, C, H, R + Dr], which scores
+    against a stored row as it is."""
+    h, r = p["num_heads"], p["kv_lora_rank"]
+    dn, dv = p["nope_dim"], p["v_dim"]
+    wuk = wukv.reshape(h, dn + dv, r)[:, :dn]
+    qa = jnp.einsum("bchd,hdr->bchr", q[..., :dn], wuk)
+    return jnp.concatenate([qa.astype(q.dtype), q[..., dn:]], -1)
+
+
+def mla_absorb_out(p, o, wukv):
+    """The mix of latents ``sum p c`` [B, C, H, R] through each head's
+    ``W_uv``: [B, C, H, Dv]."""
+    h, r = p["num_heads"], p["kv_lora_rank"]
+    dn, dv = p["nope_dim"], p["v_dim"]
+    wuv = wukv.reshape(h, dn + dv, r)[:, dn:]
+    return jnp.einsum("bchr,hdr->bchd", o.astype(wukv.dtype), wuv)
+
+
+def mla_out(o, wo):
+    """``out_weight`` on the heads' outputs [B, C, H, Dv]."""
+    with jax.named_scope("out"):
+        b, c, h, dv = o.shape
+        return jnp.einsum("btq,eq->bte",
+                          o.reshape(b, c, h * dv).astype(wo.dtype), wo)
+
+
+# -- hyper-connections: a residual stream of several lanes --------------------
+# (Zhu et al., arXiv:2409.19606; the manifold-constrained form,
+# arXiv:2512.24880.) The stream holds ``n`` lanes of E numbers a token,
+# flat [B, T, n E] (lane i owns [i E, (i+1) E)). Around a sublayer ``f``:
+#     z = X / rms(X) over all n E numbers (no scale);
+#     [a_pre (n) ; a_post (n) ; a_res (n n)] = Phi z;
+#     H_pre = sigmoid(alpha_0 a_pre + b_pre);
+#     H_post = 2 sigmoid(alpha_1 a_post + b_post);
+#     M = exp(clip(alpha_2 mat(a_res) + B_res, -clamp, clamp)), then
+#     ``iters`` rounds of M <- M / (rowsum + eps); M <- M / (colsum + eps)
+#     (Sinkhorn: H_res = M is doubly stochastic to the rounds' convergence);
+#     u = H_pre X (E numbers: the sublayer's input, before its own norm);
+#     X <- H_res X + outer(H_post, f(...)).
+# ``HyperConnectionPre`` gives ``u`` and the float32 coefficients
+# ``[H_post ; H_res]`` of a token; ``HyperConnectionPost`` takes the
+# sublayer's output and writes the stream. All of it in float32 whatever
+# the stream's type; the stream is stored in its own.
+
+@register
+class StreamLanes(OpSpec):
+    """The ends of a several-lane residual stream. ``mode="copy"``:
+    [B, T, E] -> [B, T, n E], the token's vector in each of ``lanes``
+    lanes; ``mode="sum"``: [B, T, n E] -> [B, T, E], the lanes added up
+    (in float32). Position-wise."""
+
+    name = "StreamLanes"
+    params = {"lanes": Param("int"), "mode": Param("str")}
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if p["mode"] not in ("copy", "sum"):
+            raise MXNetError("StreamLanes: mode must be 'copy' or 'sum', "
+                             "got %r" % (p["mode"],))
+        if d is None:
+            return list(in_shapes), [None], []
+        n = p["lanes"]
+        if p["mode"] == "copy":
+            return [d], [tuple(d[:-1]) + (d[-1] * n,)], []
+        if d[-1] % n:
+            raise MXNetError("StreamLanes: %d lanes do not divide the "
+                             "stream's width %d" % (n, d[-1]))
+        return [d], [tuple(d[:-1]) + (d[-1] // n,)], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x, n = ins[0], p["lanes"]
+        if p["mode"] == "copy":
+            return [jnp.tile(x, (1,) * (x.ndim - 1) + (n,))], []
+        xl = x.reshape(x.shape[:-1] + (n, x.shape[-1] // n))
+        return [jnp.sum(xl.astype(jnp.float32), axis=-2).astype(x.dtype)], []
+
+
+def hc_coefficients(p, x, phi, alpha, bias):
+    """The hyper-connection's coefficients of every token of the stream
+    ``x`` [B, T, n E], float32: (H_pre [B, T, n], H_post [B, T, n],
+    H_res [B, T, n, n]). The Sinkhorn rounds run with the tokens on the
+    minor axis ([n, n, tokens]: sums over rows and columns are adds of
+    whole vectors)."""
+    n = p["lanes"]
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    b, t, _ = x.shape
+    with jax.named_scope("coef"):
+        xf = x.astype(f32)
+        z = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
+                               + p["norm_eps"])
+        a = jnp.einsum("bte,fe->fbt", z, phi.astype(f32), precision=hi)
+        al, bi = alpha.astype(f32), bias.astype(f32)[:, None, None]
+        pre = jax.nn.sigmoid(al[0] * a[:n] + bi[:n])
+        post = 2.0 * jax.nn.sigmoid(al[1] * a[n:2 * n] + bi[n:2 * n])
+    with jax.named_scope("sinkhorn"):
+        bres = bi[2 * n:]
+        if p.get("res_diag"):
+            bres = bres + p["res_diag"] * jnp.eye(n, dtype=f32).reshape(
+                n * n, 1, 1)
+        m = jnp.exp(jnp.clip(al[2] * a[2 * n:] + bres,
+                             -p["clamp"], p["clamp"])).reshape(n, n, b, t)
+        for _ in range(p["iters"]):
+            m = m / (jnp.sum(m, axis=1, keepdims=True) + p["eps"])
+            m = m / (jnp.sum(m, axis=0, keepdims=True) + p["eps"])
+    return (jnp.moveaxis(pre, 0, -1), jnp.moveaxis(post, 0, -1),
+            jnp.moveaxis(m, (0, 1), (-2, -1)))
+
+
+_HC_PARAMS = {"lanes": Param("int"), "iters": Param("int", 20),
+              "eps": Param("float", 1e-6), "clamp": Param("float", 30.0),
+              "res_diag": Param("float", 0.0),
+              "norm_eps": Param("float", 1e-6)}
+
+
+@register
+class HyperConnectionPre(OpSpec):
+    """The reading half of a hyper-connection (the section above): from
+    the stream ``data`` [B, T, n E] the sublayer's input ``u = H_pre X``
+    [B, T, E] (output 0, the stream's type) and the token's
+    coefficients ``[H_post (n) ; H_res (n n, row-major)]``
+    [B, T, n + n n] in float32 (output 1), which
+    ``HyperConnectionPost`` takes. ``phi`` [2n + n n, n E] (rows
+    ``a_pre``, ``a_post``, ``a_res``), ``alpha`` [3], ``bias``
+    [2n + n n] (``b_pre``, ``b_post``, ``B_res`` row-major; ``B_res``
+    is stored as its departure from ``res_diag`` times the identity,
+    ``B_res = res_diag I + stored``: a storage choice, 0 by default).
+    Position-wise."""
+
+    name = "HyperConnectionPre"
+    params = dict(_HC_PARAMS)
+
+    def arguments(self, p):
+        return ["data", "phi", "alpha", "bias"]
+
+    def outputs(self, p):
+        return ["output", "mix"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None, None], []
+        n = p["lanes"]
+        if len(d) != 3 or d[2] % n:
+            raise MXNetError("HyperConnectionPre: data must be "
+                             "[B, T, lanes * E], got %s" % (d,))
+        k = 2 * n + n * n
+        ins = [d, shape_assign(in_shapes[1], (k, d[2]),
+                               "HyperConnectionPre phi"),
+               shape_assign(in_shapes[2], (3,), "HyperConnectionPre alpha"),
+               shape_assign(in_shapes[3], (k,), "HyperConnectionPre bias")]
+        return ins, [(d[0], d[1], d[2] // n), (d[0], d[1], n + n * n)], []
+
+    def infer_type(self, p, in_types):
+        dt = next((t for t in in_types if t is not None), None)
+        return [dt] * len(in_types), [dt, np.dtype(np.float32)], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x, phi, alpha, bias = ins
+        n = p["lanes"]
+        b, t, w = x.shape
+        pre, post, res = hc_coefficients(p, x, phi, alpha, bias)
+        with jax.named_scope("mix"):
+            xl = x.reshape(b, t, n, w // n).astype(jnp.float32)
+            u = sum(pre[..., i, None] * xl[:, :, i] for i in range(n))
+        mix = jnp.concatenate([post, res.reshape(b, t, n * n)], axis=-1)
+        return [u.astype(x.dtype), mix], []
+
+
+@register
+class HyperConnectionPost(OpSpec):
+    """The writing half of a hyper-connection: ``X <- H_res X +
+    outer(H_post, y)`` from the stream ``data`` [B, T, n E], the
+    sublayer's output ``branch`` [B, T, E] and ``mix`` [B, T, n + n n]
+    (``HyperConnectionPre``'s second output), in float32; the stream
+    keeps its type. Position-wise."""
+
+    name = "HyperConnectionPost"
+    params = {"lanes": Param("int")}
+
+    def arguments(self, p):
+        return ["data", "branch", "mix"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        n = p["lanes"]
+        return [d, shape_assign(in_shapes[1], (d[0], d[1], d[2] // n),
+                                "HyperConnectionPost branch"),
+                shape_assign(in_shapes[2], (d[0], d[1], n + n * n),
+                             "HyperConnectionPost mix")], [d], []
+
+    def infer_type(self, p, in_types):
+        dt = in_types[0] if in_types[0] is not None else in_types[1]
+        return [dt, dt, np.dtype(np.float32)], [dt], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x, y, mix = ins
+        n = p["lanes"]
+        b, t, w = x.shape
+        f32 = jnp.float32
+        with jax.named_scope("mix"):
+            xl = x.reshape(b, t, n, w // n).astype(f32)
+            mix = mix.astype(f32)
+            yf = y.astype(f32)
+            lanes = [sum(mix[..., n + i * n + j, None] * xl[:, :, j]
+                         for j in range(n)) + mix[..., i, None] * yf
+                     for i in range(n)]
+            out = jnp.concatenate(lanes, axis=-1)
+        return [out.astype(x.dtype)], []

@@ -1076,6 +1076,23 @@ def paged_rows_fetched(lens, max_len, block_k):
     return jnp.sum((lens + (block_k - 1)) // block_k * block_k)
 
 
+def _paged_visit(lens, max_len, block_k):
+    """What the paged reads scalar-prefetch, from the slots' lengths
+    ``lens`` [S]: the order of the visit (slots with rows first; a
+    stable sort keeps their order), each visit's count of live blocks,
+    and for a visit with none the (slot, block) the last live visit ended
+    on, where every one of its steps parks."""
+    i32 = jnp.int32
+    nkb_slot = (jnp.clip(lens, 0, max_len) + (block_k - 1)) // block_k
+    order = jnp.argsort(nkb_slot == 0, stable=True).astype(i32)
+    nkb = nkb_slot[order]
+    last = order[jnp.maximum(jnp.sum(nkb_slot > 0) - 1, 0)]
+    kslot = jnp.where(nkb > 0, order, last).astype(i32)
+    kblk = jnp.broadcast_to(jnp.maximum(nkb_slot[last] - 1, 0),
+                            lens.shape).astype(i32)
+    return order, nkb, kslot, kblk
+
+
 def _paged_attn_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, pos_ref,
                        q_ref, k_ref, v_ref, hm_ref, *rest, block_k,
                        chunk, group, n_blocks, scale, quant, kv_heads,
@@ -1254,16 +1271,7 @@ def paged_attention(q, k, v, pos, *, kv_heads, lens=None, k_scale=None,
     i32 = jnp.int32
     pos = jnp.asarray(pos, i32)
     lens = pos + c if lens is None else jnp.asarray(lens, i32)
-    # the visit: slots with rows first (a stable sort keeps their
-    # order), every step of the others parked on the block the last of
-    # them ended on
-    nkb_slot = (jnp.clip(lens, 0, l_) + (block_k - 1)) // block_k
-    order = jnp.argsort(nkb_slot == 0, stable=True).astype(i32)
-    nkb = nkb_slot[order]
-    last = order[jnp.maximum(jnp.sum(nkb_slot > 0) - 1, 0)]
-    kslot = jnp.where(nkb > 0, order, last).astype(i32)
-    kblk = jnp.broadcast_to(jnp.maximum(nkb_slot[last] - 1, 0),
-                            (s_,)).astype(i32)
+    order, nkb, kslot, kblk = _paged_visit(lens, l_, block_k)
     # query rows r = (c, g), lanes (kv, d) as the cache stores them:
     # the decoder's GQA fold (Decoder._lane_attn)
     qr = q.reshape(s_, c, kv, g, d).transpose(0, 1, 3, 2, 4) \
@@ -1338,6 +1346,155 @@ def paged_attention(q, k, v, pos, *, kv_heads, lens=None, k_scale=None,
         interpret=interpret)
     return out.reshape(s_, c, g, kv, d).transpose(0, 1, 3, 2, 4) \
         .reshape(s_, c, h, d)
+
+
+# -- the latent read: one block of rows for scores AND values ----------
+#
+# Multi-head latent attention's decode step (ops/attention.py, the absorbed
+# form) reads a cache of LATENT rows ``[c ; k_r]`` [S, L, W] with no head
+# axis: every head's query ``[q~_h ; q_r,h]`` (W numbers) scores against
+# the whole row, and the values are the row's first ``v_width`` numbers
+# (``c``). The walk is ``paged_attention``'s (same scalar prefetch, same
+# visit order, dead blocks never fetched); what differs is inside a
+# block: ONE [block_k, W] fetch serves both products, the H heads (times
+# the chunk's C positions) are the query rows against it, there is no
+# block-diagonal spread, and W need not be whole lane tiles (576 = 512 +
+# 64: the two parts are contracted apart, each over whole or half tiles;
+# the decoder stores the row padded to whole tiles, which is what the
+# chip keeps of a 576-wide row anyway).
+
+def _latent_attn_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, pos_ref,
+                        q_ref, c_ref, o_ref, acc_ref, l_ref, m_ref, *,
+                        block_k, chunk, heads, n_blocks, scale, v_width,
+                        width):
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    n = chunk * heads
+    neg_big = jnp.float32(-1e30)
+    f32 = jnp.float32
+    cdt = q_ref.dtype
+    prec = lax.Precision.HIGHEST if cdt == jnp.float32 else None
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        m_ref[...] = jnp.full(m_ref.shape, neg_big, f32)
+
+    @pl.when(j < nkb_ref[i])
+    def _block():
+        p0 = pos_ref[order_ref[i]]
+        row = lax.broadcasted_iota(jnp.int32, (n, block_k), 0)
+        qpos = jnp.full((n, block_k), p0, jnp.int32)
+        for c in range(1, chunk):
+            qpos = jnp.where(row >= c * heads, p0 + c, qpos)
+        kpos = j * block_k + lax.broadcasted_iota(
+            jnp.int32, (n, block_k), 1)
+        mask = kpos <= qpos
+        q = q_ref[0]                                   # [n, W]
+        lat = c_ref[0, :, :v_width].astype(cdt)        # [bk, R]
+        sc = lax.dot_general(q[:, :v_width], lat, nt, precision=prec,
+                             preferred_element_type=f32)
+        if width > v_width:
+            sc = sc + lax.dot_general(
+                q[:, v_width:], c_ref[0, :, v_width:width].astype(cdt), nt,
+                precision=prec, preferred_element_type=f32)
+        sc = jnp.where(mask, sc * scale, neg_big)
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+        pexp = jnp.where(mask, jnp.exp(sc - new_m), 0.0)
+        corr = jnp.exp(m - new_m)
+        l_ref[...] = l_ref[...] * corr \
+            + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            pexp.astype(cdt), lat, precision=prec,
+            preferred_element_type=f32)                # [n, R]
+        m_ref[...] = new_m
+
+    @pl.when(j == jnp.int32(n_blocks - 1))
+    def _emit():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
+            .astype(o_ref.dtype)
+
+
+def latent_paged_attention(q, rows, pos, *, v_width, scale, lens=None,
+                           block_k=None, interpret=None):
+    """Slot-paged decode attention over a cache of latent rows, each
+    block fetched once for scores and values.
+
+    q: [S, C, H, W], each slot's C-token chunk of absorbed queries;
+    rows: the cache as stored, [S, L, W'] with ``W' >= W`` (no head
+    axis: key and value at once; the decoder stores a row padded to
+    whole lane tiles, and lanes past ``W`` are fetched with their tile
+    and not contracted); the values are ``rows[..., :v_width]``. pos, lens: as
+    ``paged_attention`` takes them (the chunk's rows at [pos, pos + C)
+    already written; ``lens`` 0 for a slot that holds no request, whose
+    output is zeros). Returns [S, C, H, v_width] in q's dtype, float32
+    accumulation. ``scale`` multiplies the scores."""
+    if interpret is None:
+        interpret = _use_interpret()
+    s_, c, h, w = q.shape
+    if rows.ndim != 3 or rows.shape[0] != s_ or rows.shape[2] < w:
+        raise ValueError(
+            "latent_paged_attention: rows must be the stored cache "
+            "[S, L, W' >= W] = [%d, L, >= %d], got %s"
+            % (s_, w, rows.shape))
+    l_, wr = rows.shape[1], rows.shape[2]
+    r = int(v_width)
+    if not 0 < r <= w:
+        raise ValueError("latent_paged_attention: v_width=%d is not in "
+                         "(0, %d]" % (r, w))
+    if block_k is None:
+        block_k = default_paged_block_k(
+            l_, wr * jnp.dtype(rows.dtype).itemsize)
+    if l_ % block_k:
+        raise ValueError(
+            "latent_paged_attention: block_k=%d must divide the cache "
+            "length %d" % (block_k, l_))
+    nb = l_ // block_k
+    n = c * h
+    i32 = jnp.int32
+    pos = jnp.asarray(pos, i32)
+    lens = pos + c if lens is None else jnp.asarray(lens, i32)
+    order, nkb, kslot, kblk = _paged_visit(lens, l_, block_k)
+
+    def qmap(i, j, order, nkb, kslot, kblk, pos):
+        return (order[i], 0, 0)
+
+    def cmap(i, j, order, nkb, kslot, kblk, pos):
+        live = nkb[i] > 0
+        return (kslot[i],
+                jnp.where(live, jnp.minimum(j, nkb[i] - 1), kblk[i]), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(s_, nb),
+        in_specs=[pl.BlockSpec((1, n, w), qmap),
+                  pl.BlockSpec((1, block_k, wr), cmap)],
+        out_specs=pl.BlockSpec((1, n, r), qmap),
+        scratch_shapes=[
+            pltpu.VMEM((n, r), jnp.float32),       # acc
+            pltpu.VMEM((n, 1), jnp.float32),       # l
+            pltpu.VMEM((n, 1), jnp.float32),       # m
+        ],
+    )
+    lanes = _round_up(wr, 128)
+    need = 3 * block_k * lanes * jnp.dtype(rows.dtype).itemsize \
+        + 8 * n * (lanes + block_k) * 4
+    out = _pallas_call(
+        functools.partial(_latent_attn_kernel, block_k=block_k, chunk=c,
+                          heads=h, n_blocks=nb, scale=float(scale),
+                          v_width=r, width=w),
+        order, nkb, kslot, kblk, pos, q.reshape(s_, n, w), rows,
+        out_shape=jax.ShapeDtypeStruct((s_, n, r), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(max(2 * need, 32 << 20),
+                                     100 << 20))),
+        interpret=interpret)
+    return out.reshape(s_, c, h, r)
 
 
 # -- fused quantized matmuls (ISSUE 17) -------------------------------

@@ -14,10 +14,14 @@ them a short ring of the last positions' raw projections: the second
 kind of cache leaf, ``STATE_ROWS`` below), every ``GatedAttention``
 node likewise (K/V rows and nothing else), every ``GatedDeltaNet``
 node for a variant that keeps NO rows at all (a recurrent state per
-sequence: the third kind, "THE STATE KIND" below) and
+sequence: the third kind), every ``LatentAttention`` node for one that
+keeps ONE buffer of latent rows, key and value at once and without a
+head axis (the fourth kind; "THE KINDS OF CACHE ENTRY" below has all
+four side by side) and
 ``PositionalEmbedding`` sliced at the current position. Every other LM op
 (Embedding, LayerNorm, RMSNorm, FullyConnected, activations, elementwise
-arithmetic, ResidualMerge, MoEFFN, BatchNorm-on-rank-2-data) is
+arithmetic, ResidualMerge, MoEFFN, the hyper-connection's two halves and
+the stream's ends, BatchNorm-on-rank-2-data) is
 position-wise and runs
 its ordinary ``OpSpec.forward`` unchanged, so there is no duplicated
 model math to drift. BatchNorm normalizes axis 1 — the TIME axis of
@@ -56,24 +60,48 @@ _POSITIONWISE = {
     "Embedding", "LayerNorm", "FullyConnected", "Activation", "LeakyReLU",
     "MoEFFN", "Dropout", "BlockGrad", "Cast", "ElementWiseSum",
     "BatchNorm", "RMSNorm", "ResidualMerge", "SoftmaxActivation",
+    "StreamLanes", "HyperConnectionPre", "HyperConnectionPost",
     "_Plus", "_Minus", "_Mul", "_Div", "_PlusScalar", "_MinusScalar",
     "_MulScalar", "_DivScalar", "_RMinusScalar", "_RDivScalar",
 }
 # handled specially
 _TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention",
-             "GatedAttention", "GatedDeltaNet"}
+             "GatedAttention", "GatedDeltaNet", "LatentAttention"}
 
-# CCAttention's rolling state, the second kind of cache leaf: per
-# sequence the last STATE_ROWS positions' [u ; v2] (the raw q/k
-# projections and the half of the values the NEXT token takes), the
-# row of position p at p % STATE_ROWS, stored flat [B, STATE_ROWS * W]
-# (rank 2: a leaf without a head axis, replicated under tp like the
-# rings' position buffers). A position reads the two before it and
-# writes its own, so THREE rows make a step idempotent: the engine
-# re-runs a frozen slot's last step every round, and a ring of two
-# would have overwritten u_{t-2} the first time. Rows of positions
-# before 0 are never read (``ops.attention.cca_qkv`` masks by
-# position), so a reused slot needs no clearing.
+# THE KINDS OF CACHE ENTRY. One entry per cached node, a tuple of leaves
+# whose axis 0 is the batch row / serving slot:
+#
+# kind         | node                  | leaves (rank)                  | a re-run step              | a reused slot            | refused by
+# -------------+-----------------------+--------------------------------+----------------------------+--------------------------+-----------------------------
+# K/V ROWS     | MultiHeadAttention,   | K, V [B, rows, Hkv*D] (3);     | rewrites its row with the  | nothing: rows at or past | a windowed ring: the prefix
+#              | GatedAttention        | int8: + scales [B, rows, Hkv]  | same values (idempotent)   | the position are masked  | pool, speculation, the
+#              |                       | (3); a windowed ring: + the    |                            | until overwritten (a     | handoff, the bounded read
+#              |                       | rows' positions [B, win] (2)   |                            | ring: positions reset)   |
+# ROLLING      | CCAttention           | K, V rows as above (3) + the   | idempotent: three rows of  | nothing: rows of         | int8 rows, quantized
+# STATE        |                       | last STATE_ROWS positions'     | state, a position reads    | positions before 0 are   | weights, tp, the prefix
+#              |                       | [u ; v2], flat [B, 3 W'] (2)   | two and writes its own     | masked by position       | pool, the handoff,
+#              |                       |                                |                            |                          | speculation
+# RECURRENT    | GatedDeltaNet         | S [B, Hv, Dk, Dv] float32 (4)  | ADVANCES the state: a row  | a chunk at position 0    | the same as the rolling
+# STATE        |                       | + the last kernel-1 positions' | that holds no request      | starts from zeros,       | state (refuse_rolling_state)
+#              |                       | convolution inputs, flat (2);  | (``lens`` 0) leaves it     | whatever the slot held   |
+#              |                       | NO rows                        | untouched                  |                          |
+# LATENT ROWS  | LatentAttention       | ONE buffer [B, rows, R + Dr]   | rewrites its row with the  | nothing: as K/V rows     | int8 rows, quantized
+#              |                       | (3): ``[c ; k_r]`` after norm  | same values (idempotent)   |                          | weights, tp (no head axis
+#              |                       | and rotation, key and value at |                            |                          | to shard), the prefix pool,
+#              |                       | once, no head axis             |                            |                          | the handoff
+#              |                       |                                |                            |                          | (refuse_latent_rows)
+#
+# CCAttention's rolling state: per sequence the last STATE_ROWS
+# positions' [u ; v2] (the raw q/k projections and the half of the
+# values the NEXT token takes), the row of position p at
+# p % STATE_ROWS, stored flat [B, STATE_ROWS * W] (rank 2: a leaf
+# without a head axis, replicated under tp like the rings' position
+# buffers). A position reads the two before it and writes its own, so
+# THREE rows make a step idempotent: the engine re-runs a frozen slot's
+# last step every round, and a ring of two would have overwritten
+# u_{t-2} the first time. Rows of positions before 0 are never read
+# (``ops.attention.cca_qkv`` masks by position), so a reused slot needs
+# no clearing.
 STATE_ROWS = 3
 
 # THE STATE KIND: a GatedDeltaNet node's cache entry holds no rows. Per
@@ -96,6 +124,24 @@ STATE_ROWS = 3
 # What cannot carry such a leaf refuses by name
 # (``Decoder.refuse_rolling_state``): int8 rows, quantized weights, tp,
 # the prefix pool, the KV handoff, speculation.
+#
+# THE LATENT-ROWS KIND: a LatentAttention node's entry is ONE rank-3
+# leaf [B, rows, R + Dr], a token's ``[c ; k_r]`` after norm and rotation
+# (``ops.attention.mla_down``): every head's keys and values are re-made
+# from it, so it has no head axis and is key and value at once. (The
+# row is stored padded with zeros to whole lane tiles, 576 -> 640:
+# ``Decoder.latent_row_lanes`` says why.) It keeps
+# the contracts of K/V rows: a position writes its own row and nothing
+# else, so a re-run step is idempotent, a right-padded piece's padding
+# rows sit past the true length until overwritten, and a reused slot
+# needs no clearing. A prefill piece writes its rows as one block and
+# reads all the rows so far EXPANDED (per-head keys and values up-
+# projected a block of rows at a time, as many blocks as the position
+# needs); a decode step writes its row in place and reads ABSORBED
+# through the bounded read over live slots
+# (``pallas_kernels.latent_paged_attention``: one fetch of a block serves
+# scores and values). What moves or re-types K/V rows by their (K, V,
+# head) layout refuses by name (``Decoder.refuse_latent_rows``).
 
 _LOSS_HEADS = {"SoftmaxOutput", "SoftmaxCELoss"}
 
@@ -233,6 +279,7 @@ class Decoder:
         self._mha = []      # MultiHeadAttention nodes
         self._cca = []      # CCAttention nodes (K/V rows + rolling state)
         self._gdn = []      # GatedDeltaNet nodes (a state, no rows)
+        self._mla = []      # LatentAttention nodes (latent rows)
         self._cached = []   # all, in graph order: one cache entry each
         for n in self._topo:
             if n.is_var:
@@ -253,6 +300,9 @@ class Decoder:
                 self._cached.append(n)
             elif name == "GatedDeltaNet":
                 self._gdn.append(n)
+                self._cached.append(n)
+            elif name == "LatentAttention":
+                self._mla.append(n)
                 self._cached.append(n)
             elif name == "SoftmaxActivation" \
                     and n.params["mode"] != "instance":
@@ -309,6 +359,8 @@ class Decoder:
 
         if self.has_state and self._cache_int8:
             self.refuse_rolling_state("cache_dtype='int8'")
+        if self._mla and self._cache_int8:
+            self.refuse_latent_rows("cache_dtype='int8'")
 
         # pos_embed bounds the decodable length
         for n in self._topo:
@@ -337,6 +389,9 @@ class Decoder:
             if self.has_state:
                 self.refuse_rolling_state("weight_dtype=%r"
                                           % (weight_dtype,))
+            if self._mla:
+                self.refuse_latent_rows("weight_dtype=%r"
+                                        % (weight_dtype,))
             self.refuse_given_router("weight_dtype=%r" % (weight_dtype,))
         self.weight_dtype = weight_dtype
         self.weight_group = weight_group
@@ -427,6 +482,37 @@ class Decoder:
             "that %s cannot carry yet (ROADMAP R-M5 / D3)"
             % (feature, node.spec.name, node.name, what, feature))
 
+    @property
+    def has_latent(self):
+        """Whether a cached node keeps latent rows (a LatentAttention
+        node: one buffer, key and value at once, no head axis)."""
+        return bool(self._mla)
+
+    @staticmethod
+    def latent_row_lanes(p):
+        """Lanes of one stored latent row: ``R + Dr`` numbers padded
+        with zeros to whole tiles of 128 (576 -> 640). The chip keeps a
+        576-wide row in five tiles whatever the buffer is called; a
+        buffer declared [B, rows, 576] it prefers to lay out rows-minor,
+        and every program then copies the whole cache on its way in and
+        out (tests/test_chip_compile.py, the longctx decode program)."""
+        w = p["kv_lora_rank"] + p["rope_dim"]
+        return -(-w // 128) * 128
+
+    def refuse_latent_rows(self, feature):
+        """Raise for a feature that moves or re-types K/V rows by their
+        (K, V, head) layout and so cannot carry a latent row, naming the
+        first node that keeps one: never a silent dense or wrong read."""
+        node = self._mla[0]
+        p = node.params
+        raise MXNetError(
+            "%s does not compose with LatentAttention (node %r): its "
+            "cache entry is one buffer of latent rows [c ; k_r], %d "
+            "numbers a token with no head axis, key and value at once, "
+            "which %s cannot carry yet (ROADMAP R-M4)"
+            % (feature, node.name, p["kv_lora_rank"] + p["rope_dim"],
+               feature))
+
     def refuse_given_router(self, feature):
         """Raise if a MoEFFN node takes its routing from the graph
         (``router='given'``) or has gated experts: the quantized and
@@ -492,8 +578,16 @@ class Decoder:
 
         if kv_sharding is not None and self._gdn:
             self.refuse_rolling_state("init_cache(kv_sharding=...)")
+        if kv_sharding is not None and self._mla:
+            self.refuse_latent_rows("init_cache(kv_sharding=...)")
         caches = []
         for n in self._cached:
+            if n.spec.name == "LatentAttention":
+                caches.append((jnp.zeros(
+                    (batch_size, self.max_len,
+                     self.latent_row_lanes(n.params)),
+                    self._cache_dtype),))
+                continue
             if n.spec.name == "GatedDeltaNet":
                 p = n.params
                 caches.append((
@@ -551,7 +645,10 @@ class Decoder:
         [Hkv*D], or the [Hkv] scales — over ``axis``, so a shard holds
         whole kv heads; every other leaf replicates (rank 2: the rings'
         position buffers, CCAttention's rolling state; rank 4: a
-        GatedDeltaNet's state, which tp refuses anyway).
+        GatedDeltaNet's state, which tp refuses anyway). A
+        LatentAttention's rows are rank 3 WITHOUT a head axis; tp
+        refuses them before any spec is asked for
+        (``refuse_latent_rows``).
         Shared by ``init_cache(kv_sharding=...)`` and the
         serving engine's shard_map program specs, so the two can never
         drift."""
@@ -564,7 +661,8 @@ class Decoder:
     @staticmethod
     def row_buffers(caches):
         """The K buffer [B, rows, Hkv*D] of every cache entry that
-        holds rows (a GatedDeltaNet's entry holds none: its first leaf
+        holds rows, a LatentAttention's one buffer [B, rows, R + Dr]
+        among them (a GatedDeltaNet's entry holds none: its first leaf
         is the rank-4 state)."""
         return [e[0] for e in caches if jnp.ndim(e[0]) == 3]
 
@@ -610,7 +708,15 @@ class Decoder:
         else:
             new = (fold_heads(k).astype(entry[0].dtype),
                    fold_heads(v).astype(entry[1].dtype))
-        b, c = k.shape[:2]
+        return self._put_rows(entry, new, pos)
+
+    @staticmethod
+    def _put_rows(entry, new, pos):
+        """``new`` (one [B, C, ...] chunk per buffer of ``entry``) at
+        rows ``[pos, pos + C)``: the scatter or the block of
+        ``_write_cache``, by the chunk's length and the kind of
+        ``pos``."""
+        b, c = new[0].shape[:2]
         p = jnp.asarray(pos, jnp.int32)
         if p.ndim == 1 or c <= _SHORT_CHUNK:
             rows = p.reshape(-1, 1) + jnp.arange(c, dtype=jnp.int32)
@@ -805,6 +911,11 @@ class Decoder:
     # CCAttention, one scalar position:
     #     ``_cached_cca`` (``_lane_attn`` for a short chunk,
     #     ``_head_attn`` for a long one).
+    # LatentAttention (``_cached_mla``): a short chunk at a position
+    # vector reads ABSORBED through the bounded latent read
+    # (``pallas_kernels.latent_paged_attention``), a short chunk at one
+    # position absorbed over all rows, a long chunk (prefill) EXPANDED a
+    # block of rows at a time (``_latent_attn_blocks``).
     def _cached_mha(self, node, ins, entry, pos, valid_len=None,
                     tp=None, mm_impl=None, lens=None, stats=None):
         from ..ops.attention import MultiHeadAttention as _MHA
@@ -1021,6 +1132,156 @@ class Decoder:
                 o = self._gqa_attn_blocks(
                     q, *self._read_cache(entry, q.dtype, kv, limit), pos)
         return gattn_out(o, gate, ins[6]), entry
+
+    # a prefill piece reads the latent rows so far this many at a time
+    # (a power of two that divides the cache, else the whole cache): per
+    # block the up-projection to per-head keys and values, scores and
+    # values, merged by an online softmax; the number of blocks follows
+    # the position, so a piece early in a long slot does not pay for the
+    # slot's length
+    _LATENT_BLOCK = 1024
+
+    def _cached_mla(self, node, ins, entry, pos, lens=None, stats=None):
+        """LatentAttention on a chunk at ``pos`` (a scalar, or a [B]
+        vector) against its cache entry, ONE buffer of latent rows (the
+        latent-rows kind, top of this module). Queries and the rows to
+        store are the op's own (``ops.attention.mla_down``); the rows
+        are written like K/V rows (``_put_rows``) and read three ways:
+        a short chunk at a position vector (the slot walk) ABSORBED
+        through the bounded read, which fetches a block of rows once
+        for scores and values (``lens``, ``stats`` as ``_paged_read``;
+        ``stats["latent_rows_live"]`` grows by the slots' true lengths);
+        a short chunk at one position absorbed over all rows, masked;
+        a long chunk (prefill) EXPANDED, a block of rows at a time
+        (``_latent_attn_blocks``)."""
+        from ..ops.attention import (mla_absorb_out, mla_absorb_q,
+                                     mla_down, mla_out, mla_softmax_scale)
+        x, wukv, wo = ins[0], ins[6], ins[7]
+        p = node.params
+        c = x.shape[1]
+        r = p["kv_lora_rank"]
+        i32, f32 = jnp.int32, jnp.float32
+        positions = jnp.asarray(pos, i32).reshape(-1, 1) \
+            + jnp.arange(c, dtype=i32)
+        q, new = mla_down(p, x, ins[1:6], positions)
+        with jax.named_scope("cache"):
+            pad = entry[0].shape[2] - new.shape[2]
+            new = jnp.pad(new.astype(entry[0].dtype),
+                          [(0, 0), (0, 0), (0, pad)])
+            (rows,) = self._put_rows(entry, (new,), pos)
+        scale = mla_softmax_scale(p)
+        if jnp.ndim(pos) == 1:
+            if c > _SHORT_CHUNK:
+                raise MXNetError(
+                    "Decoder: a chunk of %d tokens at per-slot "
+                    "positions has no read (LatentAttention node %r)"
+                    % (c, node.name))
+            from ..ops.pallas_kernels import (default_paged_block_k,
+                                              latent_paged_attention,
+                                              paged_rows_fetched)
+            posv = jnp.asarray(pos, i32)
+            if lens is None:
+                lens = posv + c
+            bk = default_paged_block_k(
+                rows.shape[1], rows.shape[2] * rows.dtype.itemsize)
+            with jax.named_scope("absorb"):
+                qa = mla_absorb_q(p, q, wukv)
+            with jax.named_scope("attend"):
+                o = latent_paged_attention(qa, rows, posv, v_width=r,
+                                           scale=scale, lens=lens,
+                                           block_k=bk)
+            if stats is not None:
+                stats["attn_rows_read"] = paged_rows_fetched(
+                    lens, rows.shape[1], bk) \
+                    + stats.get("attn_rows_read", 0)
+                stats["latent_rows_live"] = jnp.sum(
+                    jnp.clip(jnp.asarray(lens, i32), 0, rows.shape[1])) \
+                    + stats.get("latent_rows_live", 0)
+            with jax.named_scope("absorb"):
+                o = mla_absorb_out(p, o, wukv)
+        else:
+            limit = self.max_len
+            if isinstance(pos, (int, np.integer)):
+                limit = min(self.max_len, int(pos) + c)
+            if c <= _SHORT_CHUNK:
+                (live,) = self._live_rows((rows,), limit)
+                with jax.named_scope("absorb"):
+                    qa = mla_absorb_q(p, q, wukv)
+                with jax.named_scope("attend"):
+                    s = jnp.einsum(
+                        "bchw,blw->bhcl", qa,
+                        live[..., :qa.shape[-1]].astype(qa.dtype),
+                        preferred_element_type=f32) * f32(scale)
+                    kpos = jnp.arange(live.shape[1])[None, None, None]
+                    qpos = pos + jnp.arange(c)[None, None, :, None]
+                    pr = jax.nn.softmax(
+                        jnp.where(kpos <= qpos, s, f32(-1e30)), axis=-1)
+                    o = jnp.einsum("bhcl,blr->bchr", pr.astype(qa.dtype),
+                                   live[..., :r].astype(qa.dtype),
+                                   preferred_element_type=f32)
+                with jax.named_scope("absorb"):
+                    o = mla_absorb_out(p, o.astype(qa.dtype), wukv)
+            else:
+                o = self._latent_attn_blocks(p, q, rows, wukv, pos, scale)
+        return mla_out(o, wo), (rows,)
+
+    def _latent_attn_blocks(self, p, q, rows, wukv, pos, scale):
+        """The EXPANDED read of a LONG query chunk ``q`` [B, C, H,
+        Dn + Dr] at ``pos`` (one position, traced or static) against
+        latent rows [B, L, R + Dr] that already hold the chunk's own:
+        ``_LATENT_BLOCK`` rows at a time, for as many blocks as
+        ``pos + C`` needs (a loop whose length follows the position),
+        each block up-projected to per-head keys and values
+        (``ops.attention.mla_expand``), scored in float32 and merged
+        by an online softmax. Returns [B, C, H, Dv] in ``q``'s dtype."""
+        from ..ops.attention import mla_expand
+        b, c, h, _ = q.shape
+        dn, dv = p["nope_dim"], p["v_dim"]
+        total = rows.shape[1]
+        bk = 1
+        while bk * 2 <= self._LATENT_BLOCK and total % (bk * 2) == 0:
+            bk *= 2
+        if bk < 8:
+            bk = total
+        f32, i32 = jnp.float32, jnp.int32
+        qn, qr = q[..., :dn], q[..., dn:]
+        qpos = pos + jnp.arange(c, dtype=i32)
+        neg = f32(-1e30)
+
+        def body(j, carry):
+            m, l, acc = carry
+            with jax.named_scope("cache"):
+                blk = lax.dynamic_slice_in_dim(rows, j * bk, bk, axis=1)
+            with jax.named_scope("expand"):
+                kn, v, kr = mla_expand(p, blk, wukv)
+            with jax.named_scope("attend"):
+                s = (jnp.einsum("bqhd,bkhd->bhqk", qn, kn,
+                                preferred_element_type=f32)
+                     + jnp.einsum("bqhd,bkd->bhqk", qr, kr,
+                                  preferred_element_type=f32)) * f32(scale)
+                kpos = j * bk + jnp.arange(bk, dtype=i32)
+                ok = (kpos[None, :] <= qpos[:, None])[None, None]
+                s = jnp.where(ok, s, neg)
+                new_m = jnp.maximum(m, jnp.max(s, axis=-1))
+                pe = jnp.where(ok, jnp.exp(s - new_m[..., None]), 0.0)
+                corr = jnp.exp(m - new_m)
+                l = l * corr + jnp.sum(pe, axis=-1)
+                acc = acc * corr[..., None] + jnp.einsum(
+                    "bhqk,bkhd->bhqd", pe.astype(v.dtype), v,
+                    preferred_element_type=f32)
+            return new_m, l, acc
+
+        init = (jnp.full((b, h, c), neg, f32), jnp.zeros((b, h, c), f32),
+                jnp.zeros((b, h, c, dv), f32))
+        if isinstance(pos, (int, np.integer)):
+            nblk = min(-(-(int(pos) + c) // bk), total // bk)
+        else:
+            nblk = jnp.minimum((jnp.asarray(pos, i32) + c + bk - 1) // bk,
+                               total // bk)
+        _, l, acc = lax.fori_loop(0, nblk, body, init)
+        with jax.named_scope("attend"):
+            o = acc / jnp.maximum(l, 1e-30)[..., None]
+            return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
     def _cached_gdn(self, node, ins, entry, pos, valid_len=None,
                     lens=None, stats=None):
@@ -1260,7 +1521,9 @@ class Decoder:
         over the attention nodes): ``serving.attn_rows_read``;
         ``state_advanced`` (batch rows whose recurrent state a
         GatedDeltaNet node advanced, summed over those nodes):
-        ``serving.state_slots_advanced``.
+        ``serving.state_slots_advanced``; ``latent_rows_live`` (the
+        batch rows' true lengths, summed over the LatentAttention
+        nodes): ``serving.latent_rows_live``.
 
         ``lens`` ([B] int32, with a vector ``pos``): the rows of each
         batch row's cache that the bounded read may fetch — the slot
@@ -1306,12 +1569,17 @@ class Decoder:
                     mha_i += 1
                     env[(id(n), 0)] = out
                     continue
-                if name in ("GatedAttention", "GatedDeltaNet"):
+                if name in ("GatedAttention", "GatedDeltaNet",
+                            "LatentAttention"):
                     if tp is not None:
                         raise MXNetError(
                             "Decoder: %s (node %r) has no tensor-"
                             "parallel form" % (name, n.name))
-                    if name == "GatedAttention":
+                    if name == "LatentAttention":
+                        out, new_caches[mha_i] = self._cached_mla(
+                            n, ins, new_caches[mha_i], pos, lens=lens,
+                            stats=stats)
+                    elif name == "GatedAttention":
                         out, new_caches[mha_i] = self._cached_gattn(
                             n, ins, new_caches[mha_i], pos, lens=lens,
                             stats=stats)

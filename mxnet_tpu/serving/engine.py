@@ -194,6 +194,11 @@ _TM_STATE_POOL = tele.counter("serving.state_slots_pool")
 # the cache a decode step reads
 _TM_ATTN_ROWS_READ = tele.counter("serving.attn_rows_read")
 _TM_ATTN_ROWS_POOL = tele.counter("serving.attn_rows_pool")
+# latent rows (LatentAttention nodes): the live slots' TRUE lengths,
+# summed on the device over those layers and decode steps (one more
+# column): the rows a step's latent reads have to fetch, before blocks
+# round them up (the layer-steps are attn_rows_pool's)
+_TM_LATENT_ROWS_LIVE = tele.counter("serving.latent_rows_live")
 _TM_PREFILLS = tele.counter("serving.prefills")
 _TM_ADMITTED = tele.histogram(
     "serving.admitted_per_round", buckets=(0, 1, 2, 4, 8, 16, 32, 64))
@@ -865,6 +870,11 @@ class InferenceEngine:
         rolling = decoder.has_state
         if rolling and tp > 1:
             decoder.refuse_rolling_state("tp=%d" % tp)
+        # latent rows (a LatentAttention's one buffer, no head axis):
+        # the same rule, by their own name
+        latent = decoder.has_latent
+        if latent and tp > 1:
+            decoder.refuse_latent_rows("tp=%d" % tp)
         if ep > 1:
             decoder.refuse_given_router("ep=%d" % ep)
         moe_nodes = [n for n in decoder._topo
@@ -936,6 +946,9 @@ class InferenceEngine:
             if rolling:
                 decoder.refuse_rolling_state("weight_dtype=%r"
                                              % (weight_dtype,))
+            if latent:
+                decoder.refuse_latent_rows("weight_dtype=%r"
+                                           % (weight_dtype,))
             decoder.refuse_given_router("weight_dtype=%r"
                                         % (weight_dtype,))
         self.weight_dtype = weight_dtype
@@ -1022,12 +1035,16 @@ class InferenceEngine:
                 # the default pool; none where a rolling state would
                 # have to be snapshotted with the rows (asked for by
                 # value, it refuses below)
-                prefix_cache_mb = 0 if rolling else 64
+                prefix_cache_mb = 0 if rolling or latent else 64
         self.prefix_cache_mb = float(prefix_cache_mb)
         if rolling and self.prefix_cache_mb > 0:
             decoder.refuse_rolling_state(
                 "prefix_cache_mb=%g (the prefix pool copies rows)"
                 % self.prefix_cache_mb)
+        if latent and self.prefix_cache_mb > 0:
+            decoder.refuse_latent_rows(
+                "prefix_cache_mb=%g (the prefix pool sizes and copies "
+                "K/V rows)" % self.prefix_cache_mb)
         if self.prefix_cache_mb < 0:
             raise MXNetError("InferenceEngine: prefix_cache_mb must "
                              "be >= 0 (0 disables the prefix cache)")
@@ -1065,6 +1082,9 @@ class InferenceEngine:
         if role != "unified" and rolling:
             decoder.refuse_rolling_state(
                 "role=%r (the KV handoff ships rows)" % (role,))
+        if role != "unified" and latent:
+            decoder.refuse_latent_rows(
+                "role=%r (the KV handoff ships K/V rows)" % (role,))
         if role != "unified" and self._windowed:
             raise MXNetError(
                 "InferenceEngine: windowed-ring decoders do not "
@@ -1297,15 +1317,19 @@ class InferenceEngine:
         cs = self._cache_spec(self._caches)
         # routed MoEFFN layers whose touched experts the decode program
         # counts (0: none; counted for the walk of a decoder with a
-        # state leaf, whose experts run in the routed form), and the
+        # state leaf or latent rows, whose experts run in the routed
+        # form), and the
         # token-expert pairs a step routes in them
         counted = [n for n in moe_nodes if n.params["top_k"] > 0] \
-            if rolling and not decoder._mha else []
+            if (rolling or latent) and not decoder._mha else []
         self._moe_counted = len(counted)
         self._moe_pairs_step = self.slots * sum(
             n.params["top_k"] for n in counted)
         # GatedDeltaNet layers, whose advanced slots it counts
         self._state_layers = len(decoder._gdn)
+        # LatentAttention layers, whose live rows it sums
+        self._latent_layers = len(decoder._mla) \
+            if decoder._slots_batched else 0
         # rows of the K buffers of every attention layer (a
         # CCAttention's K rows too; a GatedDeltaNet layer has none),
         # which a decode step's bounded reads are counted against (0:
@@ -1469,6 +1493,7 @@ class InferenceEngine:
 
         counted = self._moe_counted
         state_counted = self._state_layers
+        latent_counted = self._latent_layers
         rows_counted = self._attn_pool_rows
 
         def one_step(caches, state, params, aux):
@@ -1484,7 +1509,7 @@ class InferenceEngine:
             # leaves its state untouched (doc/serving.md "The decode
             # round")
             stats = {} if counted or rows_counted or state_counted \
-                else None
+                or latent_counted else None
             logits, caches = dec._run_slots(
                 params, aux, caches, pos, tok[:, None], tp=tp_ax,
                 mm_impl=mm, ep=ep_ax, stats=stats,
@@ -1522,6 +1547,7 @@ class InferenceEngine:
             cols = (["experts_touched", "pairs_held", "rows_masked"]
                     if counted else []) \
                 + (["state_advanced"] if state_counted else []) \
+                + (["latent_rows_live"] if latent_counted else []) \
                 + (["attn_rows_read"] if rows_counted else [])
             if cols:
                 out = jnp.concatenate(
@@ -2946,6 +2972,9 @@ class InferenceEngine:
                 _TM_STATE_ADVANCED.inc(int(rounds[:, col].sum()))
                 _TM_STATE_POOL.inc(self.slots * self._state_layers
                                    * rounds.shape[0])
+                col += 1
+            if self._latent_layers:
+                _TM_LATENT_ROWS_LIVE.inc(int(rounds[:, col].sum()))
             if self._attn_pool_rows:
                 # the last column: cache rows the step's bounded reads
                 # fetched, against the pool's rows over the same steps

@@ -124,6 +124,20 @@ object GeneratedOps {
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("GatedDeltaNet", name, attrs, inputs)
 
+  /** The writing half of a hyper-connection: ``X <- H_res X +
+    * params: lanes: int
+    * inputs: data, branch, mix */
+  def HyperConnectionPost(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("HyperConnectionPost", name, attrs, inputs)
+
+  /** The reading half of a hyper-connection (the section above): from
+    * params: clamp: float = 30.0; eps: float = 1e-06; iters: int = 20; lanes: int; norm_eps: float = 1e-06; res_diag: float = 0.0
+    * inputs: data, phi, alpha, bias */
+  def HyperConnectionPre(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("HyperConnectionPre", name, attrs, inputs)
+
   /** Identity forward that attaches a KL sparsity penalty gradient
     * params: momentum: float = 0.9; penalty: float = 0.001; sparseness_target: float = 0.1
     * inputs: data */
@@ -137,6 +151,13 @@ object GeneratedOps {
   def LRN(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("LRN", name, attrs, inputs)
+
+  /** Causal multi-head latent attention (MLA), no biases. data
+    * params: eps: float = 1e-06; impl: str = flash; kv_lora_rank: int; mscale_all_dim: float = 0.0; nope_dim: int; num_heads: int; q_lora_rank: int; rope_base: float = 10000.0; rope_dim: int; v_dim: int; yarn_beta_fast: float = 32.0; yarn_beta_slow: float = 1.0; yarn_factor: float = 0.0; yarn_original_max: int = 4096
+    * inputs: data, q_down_weight, q_norm, q_up_weight, kv_down_weight, kv_norm, kv_up_weight, out_weight */
+  def LatentAttention(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("LatentAttention", name, attrs, inputs)
 
   /** Layer normalization over the trailing axis: gamma/beta learnable.
     * params: eps: float = 1e-05
@@ -174,7 +195,7 @@ object GeneratedOps {
     Symbol.createFromNamedArgs("MAERegressionOutput", name, attrs, inputs)
 
   /** Mixture-of-experts position-wise FFN (soft or top-k routing).
-    * params: expert_first: int = 0; experts_held: int = 0; gated: bool = False; hidden: int; num_experts: int; router: str = linear; shared_hidden: int = 0; top_k: int = 0
+    * params: expert_first: int = 0; experts_held: int = 0; gated: bool = False; hidden: int; num_experts: int; renormalize: bool = False; route_scale: float = 1.0; router: str = linear; shared_gated: bool = True; shared_hidden: int = 0; top_k: int = 0
     * inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2 */
   def MoEFFN(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
@@ -256,6 +277,13 @@ object GeneratedOps {
   def SpaceToDepth(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("SpaceToDepth", name, attrs, inputs)
+
+  /** The ends of a several-lane residual stream. ``mode="copy"``:
+    * params: lanes: int; mode: str
+    * inputs: data */
+  def StreamLanes(name: String, inputs: Map[String, Symbol],
+      attrs: Map[String, String] = Map.empty): Symbol =
+    Symbol.createFromNamedArgs("StreamLanes", name, attrs, inputs)
 
   /** Swap two axes (``swapaxis-inl.h``).
     * params: dim1: int = 0; dim2: int = 0

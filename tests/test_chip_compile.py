@@ -506,3 +506,106 @@ def test_longdoc_prefill_program(longdoc_engine, one_chip, monkeypatch):
             for kind, dims in re.findall(
                 r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
     assert fast and max(fast)[0] <= 64 << 20, max(fast)
+
+
+# -- Xing4.0: latent rows, read once a block, in one decode program -------
+
+@pytest.mark.parametrize("chunk", [1, 5])
+def test_latent_paged_attention(compile_for_chip, chunk):
+    """The latent read at the published widths: 32 heads against rows of
+    576 bf16 numbers (not whole lane tiles: 512 + 64), stored as they are
+    and stored padded to 640 as the decoder stores them, 48 slots x 9,216
+    rows, values the first 512."""
+    s, l, h, w, r = 48, 9216, 32, 576, 512
+    for stored in (w, 640):
+        compile_for_chip(
+            lambda q, c, pos, lens: pk.latent_paged_attention(
+                q, c, pos, v_width=r, scale=0.1447, lens=lens,
+                interpret=False),
+            ((s, chunk, h, w), BF16), ((s, l, stored), BF16), ((s,), I32),
+            ((s,), I32))
+
+
+@pytest.fixture(scope="module")
+def longctx_engine():
+    """The benchmark's serve-longctx engine at Xing4.0-29B-A4B's published
+    widths cut to 2 layers, one of each kind (a dense layer, a routed
+    layer with 16 of 64 experts held), a cut vocabulary: 48 slots x 9216
+    rows, 8 steps a round, prompts in pieces of 1024. Weights are zeros:
+    only shapes reach the compiler."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_xing4_lm
+    sym = get_xing4_lm(
+        8192, 2, 3584, num_heads=32, q_lora_rank=768, kv_lora_rank=512,
+        nope_dim=128, rope_dim=64, v_dim=128, ffn_hidden=9216,
+        num_experts=64, expert_hidden=1024, top_k=4, shared_hidden=1024,
+        dense_layers=1, route_scale=2.0, experts_held=16, lanes=4,
+        hc_res_diag=2.0, yarn=(64.0, 4096, 32.0, 1.0), mscale_all_dim=1.0)
+    shapes = {"data": (1, 8), "softmax_label": (1, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    params = {n: jnp.zeros(s, BF16)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    dec = mx.parallel.Decoder(sym, params, max_len=9216,
+                              compute_dtype="bfloat16")
+    eng = mx.serving.InferenceEngine(
+        dec, slots=48, prefill_buckets=(256, 512, 1024),
+        steps_per_round=8, prefix_cache_mb=0, prefill_chunk=1024)
+    yield eng
+    eng.close()
+
+
+def test_longctx_decode_program(longctx_engine, one_chip, monkeypatch):
+    """The decode program of the cell's two kinds of layer, compiled for
+    the chip: each layer's latent read is the one kernel (no second fetch
+    of the rows: one custom call a layer) and the routed layer's experts
+    two grouped products (four kernels in the step's body); the 566 MB
+    buffer of latent rows is written where it lies: no ``copy`` of it
+    (declared 576 wide and not 640, the compiler laid it out rows-minor
+    and copied it whole on the way in and out), and the temporaries stay
+    under a tenth of the caches."""
+    eng = longctx_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    args = _abstract([eng._params, eng._aux, eng._caches, eng._state],
+                     one_chip)
+    compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    rows = eng._caches[0][0]
+    assert rows.shape == (48, 9216, 640) and rows.dtype == BF16
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert "48,9216,640" not in copies, copies
+    cache_bytes = sum(x.nbytes for x in
+                      jax.tree_util.tree_leaves(eng._caches))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.1 * cache_bytes
+
+
+def test_longctx_prefill_program(longctx_engine, one_chip, monkeypatch):
+    """The largest prefill program of the cell (the 1,024-token piece),
+    compiled for the chip: the expanded read a block of 1,024 latent rows
+    at a time in a loop whose length follows the position, the routed
+    experts in one pass, the hyper-connections over 1,024 x 4 lanes; the
+    temporaries stay under 1 GB and no buffer the compiler keeps in fast
+    memory is over 64 MiB of the chip's 128."""
+    from mxnet_tpu.serving.engine import _raw_key
+    eng = longctx_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    bucket = 1024
+    fn = eng._prefill_fn(bucket)
+    i32 = np.int32
+    args = [eng._params, eng._aux, eng._caches, eng._state, i32(0),
+            jnp.zeros((1, bucket), I32), i32(0), i32(bucket),
+            np.bool_(True), np.float32(0), _raw_key(0), i32(-1), i32(8)]
+    compiled = jax.jit(fn, donate_argnums=(2, 3)) \
+        .lower(*_abstract(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    fast = [(int(np.prod([int(d) for d in dims.split(",")])) * width[kind],
+             kind, dims)
+            for kind, dims in re.findall(
+                r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
+    assert fast and max(fast)[0] <= 64 << 20, max(fast)

@@ -1,9 +1,11 @@
 """A batch row that holds no request routes nothing (``moe_ffn_math``'s
 ``live``): the node alone, the decoder's slot walk under ``lens``, and
 the engine's counters, each over a ZAYA-like layer (top-1, every expert
-held, biased ReLU experts, a router from the graph) and a Qwen3-Next-like
-one (top-k of a held share, SiLU-gated experts, a shared expert), float32
-on the CPU."""
+held, biased ReLU experts, a router from the graph), a Qwen3-Next-like
+one (top-k of a held share, SiLU-gated experts, a shared expert) and a
+Xing4.0-like one (sigmoid scores from the graph under a balancing bias,
+renormalized over the chosen and scaled, a held share, an ungated shared
+expert), float32 on the CPU."""
 import json
 import os
 
@@ -19,7 +21,7 @@ from mxnet_tpu.ops import attention as A
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MAX_LEN, BUCKETS = 48, (8, 16)
-KINDS = ("zaya", "qwen3_next")
+KINDS = ("zaya", "qwen3_next", "xing4")
 
 
 # -- the node ---------------------------------------------------------------
@@ -42,6 +44,18 @@ def node(kind, shape=(6, 2), seed=0):
         ins = [x, probs, beta, f(nx, h, e), f(nx, h), f(nx, e, h), f(nx, e)]
         choice = np.asarray(jax.lax.top_k(probs + beta, 1)[1])
         return p, ins, choice, None
+    if kind == "xing4":
+        p = {"num_experts": nx, "hidden": h, "top_k": 3, "router": "given",
+             "gated": True, "renormalize": True, "route_scale": 2.0,
+             "experts_held": 4, "expert_first": 2, "shared_hidden": 8,
+             "shared_gated": False}
+        probs = jax.nn.sigmoid(f(*shape, nx) * 4.0)
+        beta = f(nx) * 0.05
+        shared = (f(16, e), f(e, 8), None)
+        ins = [x, probs, beta, f(4, 2 * h, e), f(4, e, h)] + list(shared[:2])
+        choice = np.asarray(jax.lax.top_k(probs + beta, 3)[1]) - 2
+        choice = np.where((choice >= 0) & (choice < 4), choice, -1)
+        return p, ins, choice, shared
     p = {"num_experts": nx, "hidden": h, "top_k": 3, "router": "linear",
          "gated": True, "experts_held": 4, "expert_first": 2,
          "shared_hidden": 8}
@@ -103,11 +117,12 @@ def test_the_dense_forms_ignore_live(kind):
     """Soft routing and a custom product compute every expert on every
     token whatever the gates say: ``live`` changes nothing there."""
     p, ins, _, _ = node(kind)
-    if kind == "qwen3_next":            # a share has the routed form only
+    if kind != "zaya":                  # a share has the routed form only
         p = dict(p, experts_held=0, expert_first=0, shared_hidden=0)
         rng = np.random.default_rng(1)
-        ins = ins[:2] + [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
-                         for s in ((8, 24, 16), (8, 16, 12))]
+        ins = ins[:3 if kind == "xing4" else 2] \
+            + [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+               for s in ((8, 24, 16), (8, 16, 12))]
     up = lambda x, w: jnp.einsum("bte,xhe->btxh", x, w)
     live = jnp.asarray([True, False] * 3)
     want = A.moe_ffn_math(p, ins, up_mm=up)
@@ -128,8 +143,8 @@ def harness():
 def model(request, harness):
     """(toy configuration, decoder) of a family, float32 weights from a
     seed."""
-    name = {"zaya": "zaya1-8b", "qwen3_next": "qwen3-next-80b-a3b"}[
-        request.param]
+    name = {"zaya": "zaya1-8b", "qwen3_next": "qwen3-next-80b-a3b",
+            "xing4": "xing4.0-29b-a4b"}[request.param]
     cfg = json.load(open(os.path.join(ROOT, "benchmark", "configs",
                                       name + ".json")))
     cfg.update(cfg.pop("toy"))

@@ -461,3 +461,41 @@ def test_read_cache_static_clamp_value_identity(int8_dec):
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(want_logits).argmax(-1),
                                   np.asarray(got_logits).argmax(-1))
+
+
+@pytest.mark.parametrize("stored", [576, 640])
+@pytest.mark.parametrize("chunk", [1, 3])
+def test_latent_read_matches_the_plain_form(chunk, stored):
+    """The latent read in interpret mode at a row width of 576 (512
+    values + 64 rotary, not whole lane tiles), stored as it is and padded
+    to 640 as the decoder stores it: one block of rows serves scores
+    (all 576 columns) and values (the first 512); a dead slot emits zeros
+    and its (poisoned) rows are never read; lanes past 576 are not
+    contracted."""
+    from mxnet_tpu.ops.pallas_kernels import latent_paged_attention
+    s_, h, w, r, l_ = 4, 8, 576, 512, 64
+    rng = np.random.default_rng(chunk)
+    q = jnp.asarray(rng.normal(size=(s_, chunk, h, w)) * 0.1, jnp.float32)
+    rows = rng.normal(size=(s_, l_, stored)).astype(np.float32)
+    rows[1] = np.nan                              # nobody's rows
+    rows[:, :, w:] = 1e3                          # a store's padding
+    rows = jnp.asarray(rows)
+    pos = jnp.asarray([5, 40, 33, l_ - chunk], jnp.int32)
+    lens = jnp.where(jnp.arange(s_) == 1, 0, pos + chunk)
+    with jax.default_matmul_precision("highest"):
+        got = latent_paged_attention(q, rows, pos, v_width=r, scale=0.3,
+                                     lens=lens, block_k=16)
+        sc = jnp.einsum("schw,slw->shcl", q, rows[..., :w]) * 0.3
+        qpos = pos[:, None] + jnp.arange(chunk)[None]
+        mask = jnp.arange(l_)[None, None, None] <= qpos[:, None, :, None]
+        want = jnp.einsum(
+            "shcl,slr->schr",
+            jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1),
+            rows[..., :r])
+    assert got.shape == (s_, chunk, h, r)
+    live = np.asarray([0, 2, 3])
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-6)
+    assert np.array_equal(np.asarray(got)[1], np.zeros((chunk, h, r)))
+    with pytest.raises(ValueError, match="stored cache"):
+        latent_paged_attention(q, rows[..., :500], pos, v_width=r, scale=1.0)
