@@ -1098,9 +1098,12 @@ class MultiHeadAttention(OpSpec):
 # beta_t the write strength:
 #     S' = exp(g_t) S_{t-1};  d_t = beta_t (v_t - S'^T k_t);
 #     S_t = S' + k_t d_t^T;   o_t = S_t^T q_t.
-# ``gdn_step`` is that recurrence for one position (the decode step, and
-# under ``lax.scan`` the definition the chunked form is held to);
-# ``gdn_chunked`` is the same recurrence over a sequence in chunks.
+# ``gdn_step`` is that recurrence for one position (under ``lax.scan``
+# the definition the chunked form is held to, and the decode step of a
+# walk without ``lens``); ``gdn_chunked`` is the same recurrence over a
+# sequence in chunks; a slot walk's decode step takes
+# ``pallas_kernels.gdn_state_step``, ``gdn_step`` for the live slots alone
+# (``gdn_steps_in_place``).
 
 _HI = jax.lax.Precision.HIGHEST     # the state is float32 and stays it
 
@@ -1272,7 +1275,22 @@ class GatedDeltaNet(OpSpec):
         return [y], []
 
 
-def gdn_mix(p, x, weights, state, prev, real=None, live=None):
+def gdn_steps_in_place(p, c, lens):
+    """Whether ``gdn_mix`` advances the state of a chunk of ``c``
+    positions through ``pallas_kernels.gdn_state_step`` (the live rows'
+    states alone, where they lie), by what the code can see: ONE
+    position of a slot walk (``lens`` given), and on the chip heads of
+    whole lane tiles (the interpreter takes any). Everything else (the
+    full forward, a prefill piece, offline ``generate``) runs
+    ``gdn_step`` / ``gdn_chunked``, the definition."""
+    from . import pallas_kernels as pk
+    if c != 1 or lens is None:
+        return False
+    return pk._use_interpret() or (p["head_k_dim"] % 128 == 0
+                                   and p["head_v_dim"] % 128 == 0)
+
+
+def gdn_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
     """The mixer on a chunk ``x`` [B, C, E] that continues a sequence:
     ``state`` [B, Hv, Dk, Dv] float32 and ``prev`` [B, kernel-1, F],
     the ``u`` of the positions before the chunk (zeros at the start of
@@ -1280,9 +1298,13 @@ def gdn_mix(p, x, weights, state, prev, real=None, live=None):
     cached walk. ``real`` ([B, 1] int32, optional): only the chunk's
     first ``real`` positions are tokens, the rest padding, which must
     leave the state and ``prev`` of the last real one (``beta = 0, g =
-    0`` there). ``live`` ([B] bool, optional): a row that is not live
-    keeps its state and ``prev`` untouched. Returns (y [B, C, E], the
-    new state, the new prev)."""
+    0`` there). ``lens`` ([B] int32, optional: a slot walk's): a row
+    with ``lens`` 0 is not live and keeps its state and ``prev``
+    untouched. ``fresh`` ([B] bool, optional): a live row that starts
+    from the ZERO state whatever ``state`` holds for it. Where
+    ``gdn_steps_in_place`` says so, nothing outside the kernel touches
+    the whole ``state``. Returns (y [B, C, E], the new state, the new
+    prev)."""
     wqkvz, wba, wconv, a_log, dt_bias, wnorm, wout = weights
     kw, vw, f = GatedDeltaNet.widths(p)
     hk, hv = p["num_k_heads"], p["num_v_heads"]
@@ -1325,15 +1347,26 @@ def gdn_mix(p, x, weights, state, prev, real=None, live=None):
             pad = (jnp.arange(c, dtype=jnp.int32) >= real)[..., None]
             beta = jnp.where(pad, 0.0, beta)
             g = jnp.where(pad, 0.0, g)
+    live = None if lens is None else jnp.asarray(lens, jnp.int32) > 0
     with jax.named_scope("state"):
-        if c == 1:
-            new, o = gdn_step(state, q[:, 0], k[:, 0], v[:, 0],
-                              beta[:, 0], g[:, 0])
+        if gdn_steps_in_place(p, c, lens):
+            from .pallas_kernels import gdn_state_step
+            new, o = gdn_state_step(
+                state, q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0], lens,
+                jnp.zeros((b,), bool) if fresh is None else fresh)
             o = o[:, None]
         else:
-            new, o = gdn_chunked(state, q, k, v, beta, g)
+            start = state if fresh is None else \
+                jnp.where(fresh[:, None, None, None], 0, state)
+            if c == 1:
+                new, o = gdn_step(start, q[:, 0], k[:, 0], v[:, 0],
+                                  beta[:, 0], g[:, 0])
+                o = o[:, None]
+            else:
+                new, o = gdn_chunked(start, q, k, v, beta, g)
+            if live is not None:
+                new = jnp.where(live[:, None, None, None], new, state)
         if live is not None:
-            new = jnp.where(live[:, None, None, None], new, state)
             nxt = jnp.where(live[:, None, None], nxt,
                             prev.astype(nxt.dtype))
     with jax.named_scope("norm"):

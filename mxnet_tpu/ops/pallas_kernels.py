@@ -1497,6 +1497,159 @@ def latent_paged_attention(q, rows, pos, *, v_width, scale, lens=None,
     return out.reshape(s_, c, h, r)
 
 
+# -- the recurrent state's decode step: live slots only, in place ---------
+#
+# A Gated DeltaNet layer keeps per slot a float32 matrix state [Hv, Dk, Dv]
+# (2 MB at 32 heads of 128 x 128) and a decode step advances it by one
+# position (``ops.attention.gdn_step``, the definition). The walk is the
+# bounded reads' (``_paged_visit``: live slots first, every step of a dead
+# slot parked on the block just visited, its body gated off), with blocks
+# of heads where they have blocks of rows; what differs is that the block
+# is an ALIASED operand, fetched once, advanced in fast memory and written
+# back where it lay. A dead slot's state is neither read nor written.
+
+# a block of heads' state in flight holds at most this many bytes (two in
+# and two out are in flight: 8 MB at the cap)
+_STATE_BLOCK_BYTES = 2 << 20
+
+
+def _gdn_state_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, fresh_ref,
+                      kq_ref, vbg_ref, s_ref, o_ref, so_ref, *, heads):
+    """One (slot visit, block of heads) grid cell. ``kq_ref`` [1, 2, hb,
+    Dk] holds the block's k and q rows, ``vbg_ref`` [1, 3, hb, Dv] its v
+    rows and ``beta`` and ``g`` spread over Dv; ``s_ref`` / ``so_ref``
+    [1, hb, Dk, Dv] are the same block of the state, before and after.
+    Per head, with Dk on the sublanes and Dv on the lanes: k and q are
+    wanted as COLUMNS (one transpose of the block's rows gives them all),
+    everything else as rows. Float32 on the vector unit throughout: the
+    sums over Dk are elementwise products added up, exact products."""
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    f32 = jnp.float32
+    live = j < nkb_ref[i]
+
+    @pl.when(live)
+    def _advance():
+        dk = kq_ref.shape[-1]
+        zero = fresh_ref[order_ref[i]] != 0
+        cols = kq_ref[0].reshape(2 * heads, dk).T          # [Dk, 2 hb]
+        vbg = vbg_ref[0]
+        decay = jnp.exp(vbg[2])                             # [hb, Dv]
+        for h in range(heads):
+            kc = cols[:, h:h + 1]                           # [Dk, 1]
+            qc = cols[:, heads + h:heads + h + 1]
+            sd = jnp.where(zero, 0.0, s_ref[0, h] * decay[h:h + 1])
+            ks = jnp.sum(sd * kc, axis=0, keepdims=True)    # [1, Dv]
+            qs = jnp.sum(sd * qc, axis=0, keepdims=True)
+            d = vbg[1, h:h + 1] * (vbg[0, h:h + 1] - ks)
+            o_ref[0, h:h + 1] = qs + d * jnp.sum(kc * qc, axis=0,
+                                                 keepdims=True)
+            so_ref[0, h] = sd + kc * d
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+        # live slots come first, so no slot is live at all: every step
+        # parks on the first block, which is written back once, at the
+        # end, and must hold what it held
+        @pl.when(i == 0)
+        def _keep():
+            so_ref[...] = s_ref[...]
+
+
+def default_state_block_h(heads, dk, dv):
+    """Heads of a slot's state per block of ``gdn_state_step``: the
+    most that divide ``heads``, are whole sublane tiles (or all of
+    them) and keep a float32 block within 2 MB."""
+    fits = [h for h in range(heads, 0, -1)
+            if heads % h == 0 and (h % 8 == 0 or h == heads)]
+    for h in fits:
+        if h * dk * dv * 4 <= _STATE_BLOCK_BYTES:
+            return h
+    return fits[-1]
+
+
+def gdn_state_step(state, q, k, v, beta, g, lens, fresh, *, block_h=None,
+                   interpret=None):
+    """One decode position of the gated delta rule for the slots that
+    hold a request, the state advanced where it lies.
+
+    state: [S, Hv, Dk, Dv] float32, ALIASED to the first result (donate
+    it, or the caller's copy is XLA's). q, k: [S, Hv, Dk]; v: [S, Hv,
+    Dv]; beta, g: [S, Hv]; all float32. lens: [S] int32, 0 for a slot
+    that holds no request: its state is neither read nor written and its
+    output is zeros. fresh: [S] bool, a live slot that starts from the
+    ZERO state whatever it held (a dead slot's flag is not read). Returns (the state, o [S, Hv, Dv]),
+    ``ops.attention.gdn_step``'s for the live slots up to the order of
+    its sums.
+
+    Grid (slot visit, block of heads) under a ``PrefetchScalarGridSpec``
+    with ``paged_attention``'s visit order: a live slot's blocks are
+    fetched once each and written back to the same place; every step
+    of a dead slot stays on the block visited last (no copy in, none
+    out). With no live slot at all that block is copied through. On
+    the chip Dk and Dv must be whole lane tiles (128); the interpreter
+    takes any shape."""
+    if interpret is None:
+        interpret = _use_interpret()
+    s_, hv, dk, dv = state.shape
+    f32, i32 = jnp.float32, jnp.int32
+    if state.dtype != f32:
+        raise ValueError("gdn_state_step: the state is float32, got %s"
+                         % state.dtype)
+    hb = default_state_block_h(hv, dk, dv) if block_h is None \
+        else int(block_h)
+    if hv % hb:
+        raise ValueError("gdn_state_step: block_h=%d must divide the %d "
+                         "heads" % (hb, hv))
+    nb = hv // hb
+    live = jnp.asarray(lens, i32) > 0
+    order, nkb, kslot, kblk = _paged_visit(live.astype(i32) * nb, nb, 1)
+    kq = jnp.stack([k, q], axis=1).astype(f32)             # [S, 2, Hv, Dk]
+    wide = (s_, hv, dv)
+    vbg = jnp.stack([v.astype(f32),
+                     jnp.broadcast_to(beta.astype(f32)[..., None], wide),
+                     jnp.broadcast_to(g.astype(f32)[..., None], wide)],
+                    axis=1)                                # [S, 3, Hv, Dv]
+
+    def blk(i, j, order, nkb, kslot, kblk, fresh):
+        return jnp.where(nkb[i] > 0, j, kblk[i])
+
+    def smap(i, j, *pre):
+        return (pre[2][i], blk(i, j, *pre), 0, 0)
+
+    def rmap(i, j, *pre):
+        return (pre[2][i], 0, blk(i, j, *pre), 0)
+
+    def omap(i, j, *pre):
+        return (pre[0][i], j, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(s_, nb),
+        in_specs=[pl.BlockSpec((1, 2, hb, dk), rmap),
+                  pl.BlockSpec((1, 3, hb, dv), rmap),
+                  pl.BlockSpec((1, hb, dk, dv), smap)],
+        out_specs=[pl.BlockSpec((1, hb, dv), omap),
+                   pl.BlockSpec((1, hb, dk, dv), smap)],
+    )
+    block_bytes = hb * dk * dv * 4
+    o, new = _pallas_call(
+        functools.partial(_gdn_state_kernel, heads=hb),
+        order, nkb, kslot, kblk, fresh.astype(i32), kq, vbg, state,
+        out_shape=[jax.ShapeDtypeStruct((s_, hv, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        grid_spec=grid_spec,
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(max(8 * block_bytes, 32 << 20),
+                                     100 << 20))),
+        interpret=interpret)
+    return new, o
+
+
 # -- fused quantized matmuls (ISSUE 17) -------------------------------
 
 def _unpack4_halves(u):

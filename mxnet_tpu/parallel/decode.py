@@ -114,10 +114,19 @@ STATE_ROWS = 3
 # - a decode step ADVANCES the state, so re-running one is not
 #   idempotent: a batch row that holds no request (``lens`` 0 in the
 #   slot walk: a finished slot, one parked between prefill pieces)
-#   leaves its state exactly as it was;
+#   leaves its state exactly as it was. A decode step of the slot walk
+#   (one position) does not even read it: the states advance through
+#   ``pallas_kernels.gdn_state_step``, which visits live slots first,
+#   fetches a live slot's state once, writes it back where it lay (the
+#   leaf is the kernel's aliased operand) and parks every step of a
+#   dead slot on the block visited last, body gated off; nothing
+#   outside the kernel takes the whole leaf. With NO slot live every
+#   step parks on the first block, which is copied through unchanged
+#   (a parked block is written back once, at the end);
 # - a chunk that starts at position 0 starts from the ZERO state,
 #   whatever the slot held: a reused slot is cleared by its position,
-#   not by the caller;
+#   not by the caller (in the decode step's kernel a flag per slot,
+#   not a pass over the leaf);
 # - a right-padded chunk leaves the state of its last REAL token
 #   (``valid_len``): padding runs the recurrence with ``beta = 0,
 #   g = 0`` and does not enter the convolution's window.
@@ -1293,15 +1302,19 @@ class Decoder:
         a chunk at position 0 starts from zeros, and a right-padded
         chunk (``valid_len``, absolute) leaves the state of its last
         real token. ``stats["state_advanced"]`` grows by the rows whose
-        state this chunk advanced."""
-        from ..ops.attention import gdn_mix
+        state this chunk advanced, ``stats["state_in_place"]`` by one
+        where it advanced them through the kernel that visits live rows
+        only (``ops.attention.gdn_steps_in_place``)."""
+        from ..ops.attention import gdn_mix, gdn_steps_in_place
         x = ins[0]
         b, c, _ = x.shape
         i32 = jnp.int32
         state, flat = entry
         first = jnp.broadcast_to(
             jnp.asarray(pos, i32).reshape(-1, 1), (b, 1))     # [B, 1]
-        live = None if lens is None else jnp.asarray(lens, i32) > 0
+        if lens is not None:
+            lens = jnp.asarray(lens, i32)
+        live = None if lens is None else lens > 0
         with jax.named_scope("state"):
             fresh = first == 0
             if live is not None:
@@ -1309,14 +1322,15 @@ class Decoder:
             prev = jnp.where(fresh[..., None], 0,
                              flat.reshape(b, node.params["conv_kernel"]
                                           - 1, -1))
-            state = jnp.where(fresh[..., None, None], 0, state)
         real = None if valid_len is None else \
             jnp.clip(jnp.asarray(valid_len, i32) - first, 0, c)
         y, state, prev = gdn_mix(node.params, x, ins[1:], state, prev,
-                                 real=real, live=live)
+                                 real=real, lens=lens, fresh=fresh[:, 0])
         if stats is not None:
             n = jnp.int32(b) if live is None else jnp.sum(live, dtype=i32)
             stats["state_advanced"] = n + stats.get("state_advanced", 0)
+            stats["state_in_place"] = stats.get("state_in_place", 0) \
+                + int(gdn_steps_in_place(node.params, c, lens))
         return y, (state, prev.reshape(flat.shape))
 
     @staticmethod
@@ -1521,7 +1535,9 @@ class Decoder:
         over the attention nodes): ``serving.attn_rows_read``;
         ``state_advanced`` (batch rows whose recurrent state a
         GatedDeltaNet node advanced, summed over those nodes):
-        ``serving.state_slots_advanced``; ``latent_rows_live`` (the
+        ``serving.state_slots_advanced``; ``state_in_place`` (those
+        nodes that advanced it through ``gdn_state_step``):
+        ``serving.state_steps_in_place``; ``latent_rows_live`` (the
         batch rows' true lengths, summed over the LatentAttention
         nodes): ``serving.latent_rows_live``.
 

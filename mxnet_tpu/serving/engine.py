@@ -188,6 +188,7 @@ _TM_MOE_ROWS_MASKED = tele.counter("serving.moe_rows_masked")
 # more column), beside slots x layers x steps, the host's product
 _TM_STATE_ADVANCED = tele.counter("serving.state_slots_advanced")
 _TM_STATE_POOL = tele.counter("serving.state_slots_pool")
+_TM_STATE_IN_PLACE = tele.counter("serving.state_steps_in_place")
 # cache rows the decode rounds' bounded reads fetched (block-rounded,
 # summed over slots, attention layers and steps) and the rows of the
 # pool over the same layers and steps: their quotient is the share of
@@ -1542,11 +1543,13 @@ class InferenceEngine:
             # experts this step touched, the pairs that fell on held
             # experts and the slots that routed nothing, over the
             # routed layers; the slots whose state it advanced, over
-            # the GatedDeltaNet layers; the cache rows its bounded
+            # the GatedDeltaNet layers, and how many of those layers
+            # advanced it in place; the cache rows its bounded
             # reads fetched, over the attention layers
             cols = (["experts_touched", "pairs_held", "rows_masked"]
                     if counted else []) \
-                + (["state_advanced"] if state_counted else []) \
+                + (["state_advanced", "state_in_place"]
+                   if state_counted else []) \
                 + (["latent_rows_live"] if latent_counted else []) \
                 + (["attn_rows_read"] if rows_counted else [])
             if cols:
@@ -2972,7 +2975,8 @@ class InferenceEngine:
                 _TM_STATE_ADVANCED.inc(int(rounds[:, col].sum()))
                 _TM_STATE_POOL.inc(self.slots * self._state_layers
                                    * rounds.shape[0])
-                col += 1
+                _TM_STATE_IN_PLACE.inc(int(rounds[:, col + 1].sum()))
+                col += 2
             if self._latent_layers:
                 _TM_LATENT_ROWS_LIVE.inc(int(rounds[:, col].sum()))
             if self._attn_pool_rows:

@@ -444,14 +444,39 @@ def _abstract(tree, sharding):
                                        sharding=sharding), tree)
 
 
+def test_gdn_state_step(compile_for_chip):
+    """The decode step's recurrence alone at the cell's shapes (a
+    float32 leaf of 64 slots x 32 heads of 128 x 128, a slot's whole
+    2 MB state a block): the in-kernel transpose that turns a block's k
+    and q rows into columns, the sums over the sublanes and four blocks
+    of 2 MB in flight are what interpret mode cannot see. The leaf is
+    the call's aliased operand."""
+    s, h, d = 64, 32, 128
+    assert pk.default_state_block_h(h, d, d) == h
+
+    def step(state, q, k, v, beta, g, lens, fresh):
+        return pk.gdn_state_step(state, q, k, v, beta, g, lens, fresh,
+                                 interpret=False)
+
+    text = compile_for_chip(
+        step, ((s, h, d, d), F32), *[((s, h, d), F32)] * 3,
+        *[((s, h), F32)] * 2, ((s,), I32), ((s,), jnp.bool_))
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "output_to_operand_aliasing" in call, call[:300]
+
+
 def test_longdoc_decode_program(longdoc_engine, one_chip, monkeypatch):
     """The decode program of the cell's two kinds of layer, compiled for
-    the chip: the attention layer's read is the bounded kernel and the
-    routed experts two grouped products (five kernels in the step's
-    body); the 134 MB float32 state leaf is updated where it lies: no
-    ``copy`` of it, not staged through fast memory, and the temporaries
-    stay under a tenth of the caches (the step reads the state in two
-    fusions, the second of which writes it)."""
+    the chip: the attention layer's read is the bounded kernel, the
+    routed experts two grouped products and the DeltaNet layer's state
+    step the kernel that visits live slots only (six kernels in the
+    step's body); the 134 MB float32 state leaf is updated where it
+    lies: no ``copy`` of it, not staged through fast memory, the
+    temporaries under a tenth of the caches, and NO fusion takes the
+    leaf as an operand: nothing outside the kernel reads or writes it
+    (a ``where`` over it, for the zero start or for the slots that are
+    not live, is a pass over every slot's state)."""
     eng = longdoc_engine
     monkeypatch.setattr(pk, "_use_interpret", lambda: False)
     args = _abstract([eng._params, eng._aux, eng._caches, eng._state],
@@ -459,7 +484,7 @@ def test_longdoc_decode_program(longdoc_engine, one_chip, monkeypatch):
     compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
         .lower(*args).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 5
+    assert text.count("tpu_custom_call") == 6
     state = eng._caches[0][0]
     assert state.shape == (64, 32, 128, 128) and state.dtype == F32
     dims = ",".join(str(d) for d in state.shape)
@@ -469,6 +494,11 @@ def test_longdoc_decode_program(longdoc_engine, one_chip, monkeypatch):
               if re.search(r"\b(copy-start|copy-done|slice-start)\(", line)
               and re.search(r"f32\[[\d,]*32,128,128\]\{[^}]*S\(1\)", line)]
     assert not staged, staged
+    leaf = "f32[%s]" % dims
+    fused = [line.strip()[:200] for line in text.splitlines()
+             if leaf in line
+             and re.search(r"fused_computation|\bfusion\(", line)]
+    assert not fused, fused
     cache_bytes = sum(x.nbytes for x in
                       jax.tree_util.tree_leaves(eng._caches))
     assert compiled.memory_analysis().temp_size_in_bytes \

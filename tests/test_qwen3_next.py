@@ -191,6 +191,83 @@ def test_padding_leaves_the_recurrence_state():
     np.testing.assert_allclose(s_pad, s_real, atol=2e-5)
 
 
+# -- the decode step's kernel: live slots only, in place ---------------------
+
+def _step_inputs(slots, seed=0, h=4, dk=8, dv=16):
+    s0, q, k, v, beta, g = _recurrence_inputs(1, seed=seed, b=slots, h=h,
+                                              dk=dk, dv=dv)
+    return s0, q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0]
+
+
+_STEP_CASES = {
+    # lens of the slots, the slots flagged "starts at position 0"
+    "some_live": ([7, 0, 0, 3, 0, 11], []),
+    "none_live": ([0, 0, 0, 0, 0, 0], [2]),
+    "all_live": ([5, 1, 9, 2, 64, 3], []),
+    "position_0": ([1, 0, 1, 8, 0, 0], [0, 2, 4]),
+    "not_live_first": ([0, 0, 4, 0, 6, 2], [4]),
+}
+
+
+@pytest.mark.parametrize("block_h", [None, 2])
+@pytest.mark.parametrize("case", sorted(_STEP_CASES))
+def test_state_step_kernel_is_gdn_step_for_the_live_slots(case, block_h):
+    """``gdn_state_step`` against ``gdn_step`` on the same inputs: a live
+    slot's state and output are the definition's, one flagged as
+    starting at position 0 starts from zeros whatever it held, and a
+    slot that is not live comes back bit for bit with zeros for an
+    output, in the step with no live slot too and whatever the order of
+    the lengths."""
+    lens, zeroed = _STEP_CASES[case]
+    lens = np.asarray(lens, np.int32)
+    fresh = np.zeros(len(lens), bool)
+    fresh[zeroed] = True
+    s0, q, k, v, beta, g = _step_inputs(len(lens), seed=len(case))
+    live = lens > 0
+    start = jnp.where((fresh & live)[:, None, None, None], 0, s0)
+    want_s, want_o = A.gdn_step(start, q, k, v, beta, g)
+    got_s, got_o = jax.jit(
+        lambda *a: pk.gdn_state_step(*a, block_h=block_h))(
+            s0, q, k, v, beta, g, jnp.asarray(lens), jnp.asarray(fresh))
+    got_s, got_o = np.asarray(got_s), np.asarray(got_o)
+    np.testing.assert_allclose(got_s[live], np.asarray(want_s)[live],
+                               atol=2e-6)
+    np.testing.assert_allclose(got_o[live], np.asarray(want_o)[live],
+                               atol=2e-6)
+    assert np.array_equal(got_s[~live], np.asarray(s0)[~live])
+    assert not got_o[~live].any()
+    if fresh[live].any():       # zeros, not the old state times nought
+        dirty = s0.at[np.flatnonzero(fresh & live)[0]].set(jnp.inf)
+        again, _ = pk.gdn_state_step(dirty, q, k, v, beta, g,
+                                     jnp.asarray(lens), jnp.asarray(fresh))
+        assert np.array_equal(np.asarray(again)[live], got_s[live])
+
+
+def test_state_step_kernel_runs_where_a_slot_walk_steps_one_position(
+        toy, decoder):
+    """The dispatch is by what the walk sees: one position under a slot
+    walk's ``lens`` advances both DeltaNet layers' states through the
+    kernel (beside the two attention layers' bounded reads); the same
+    step without ``lens`` (offline ``generate``) and a longer chunk
+    (a prefill piece) run ``gdn_step`` / ``gdn_chunked``."""
+    def calls(chunk, lens):
+        caches = decoder.init_cache(2)
+        pos = jnp.asarray([8, 8], jnp.int32)
+        toks = jnp.zeros((2, chunk), jnp.int32)
+        text = str(jax.make_jaxpr(lambda c: decoder._run_slots(
+            decoder._params, decoder._aux, c, pos, toks, lens=lens))(
+                caches))
+        return text.count("pallas_call")
+
+    lens = jnp.asarray([9, 0], jnp.int32)
+    others = calls(1, None)             # the attention layers' reads
+    assert calls(1, lens) == others + len(decoder._gdn) == 4
+    assert calls(3, lens + 2) == calls(3, None)
+    assert A.gdn_steps_in_place(decoder._gdn[0].params, 1, lens)
+    assert not A.gdn_steps_in_place(decoder._gdn[0].params, 2, lens)
+    assert not A.gdn_steps_in_place(decoder._gdn[0].params, 1, None)
+
+
 # -- the state kind's contracts ---------------------------------------------
 
 def _prefill(decoder, caches, toks, valid_len=None):
@@ -444,6 +521,7 @@ def test_engine_counters_by_hand(toy, engine):
     routed layers, a part of which falls on held experts."""
     tele = mx.telemetry
     names = ("serving.state_slots_advanced", "serving.state_slots_pool",
+             "serving.state_steps_in_place",
              "serving.moe_pairs_held", "serving.moe_pairs_routed",
              "serving.moe_experts_touched", "serving.moe_layer_steps")
     before = {n: tele.counter(n).value for n in names}
@@ -457,6 +535,9 @@ def test_engine_counters_by_hand(toy, engine):
     # the first token comes from the prefill; the other eight each from
     # one step that advanced the slot's state in both DeltaNet layers
     assert got["state_slots_advanced"] == 2 * 8
+    # every step of every round advanced both layers' states through
+    # the kernel, the rounds in which no slot was live included
+    assert got["state_steps_in_place"] == 2 * steps
     assert got["moe_layer_steps"] == 4 * steps
     top_k = toy[1]["num_experts_per_tok"]
     assert got["moe_pairs_routed"] == 3 * top_k * 4 * steps
