@@ -200,6 +200,48 @@ _TM_ATTN_ROWS_POOL = tele.counter("serving.attn_rows_pool")
 # column): the rows a step's latent reads have to fetch, before blocks
 # round them up (the layer-steps are attn_rows_pool's)
 _TM_LATENT_ROWS_LIVE = tele.counter("serving.latent_rows_live")
+
+
+class _RoundCounter(collections.namedtuple(
+        "_RoundCounter", "key counter counts pool per_step",
+        defaults=(None, None))):
+    """One count the decode step makes, declared once
+    (doc/observability.md "Round counters"): ``key``, what
+    ``Decoder._run_slots(stats=)`` fills; ``counter``, where its sum
+    over a round's steps lands; ``counts(engine)``, nonzero where this
+    engine counts it; ``pool`` and ``per_step(engine)``, where it has
+    one, the counter beside it and the host's product per step that
+    goes there. A device value is a column of the round's tokens, in
+    the table's order; a value the walk adds at trace time (a Python
+    int) is the same in every step, so it is counted as a host product
+    per step and travels in no column."""
+
+    __slots__ = ()
+
+
+def _moe_layers(e):
+    return e._moe_counted
+
+
+def _state_layers(e):
+    return e._state_layers
+
+
+_ROUND_COUNTERS = (
+    _RoundCounter("experts_touched", _TM_MOE_TOUCHED, _moe_layers,
+                  _TM_MOE_LAYER_STEPS, _moe_layers),
+    _RoundCounter("pairs_held", _TM_MOE_PAIRS_HELD, _moe_layers,
+                  _TM_MOE_PAIRS_ROUTED, lambda e: e._moe_pairs_step),
+    _RoundCounter("rows_masked", _TM_MOE_ROWS_MASKED, _moe_layers),
+    _RoundCounter("state_advanced", _TM_STATE_ADVANCED, _state_layers,
+                  _TM_STATE_POOL, lambda e: e.slots * e._state_layers),
+    _RoundCounter("state_in_place", _TM_STATE_IN_PLACE, _state_layers),
+    _RoundCounter("latent_rows_live", _TM_LATENT_ROWS_LIVE,
+                  lambda e: e._latent_layers),
+    _RoundCounter("attn_rows_read", _TM_ATTN_ROWS_READ,
+                  lambda e: e._attn_pool_rows, _TM_ATTN_ROWS_POOL,
+                  lambda e: e._attn_pool_rows),
+)
 _TM_PREFILLS = tele.counter("serving.prefills")
 _TM_ADMITTED = tele.histogram(
     "serving.admitted_per_round", buckets=(0, 1, 2, 4, 8, 16, 32, 64))
@@ -1340,6 +1382,9 @@ class InferenceEngine:
             k.shape[0] * k.shape[1]
             for k in Decoder.row_buffers(self._caches)) \
             if decoder._slots_batched else 0
+        # the registry's counters the decode step counts, as its trace
+        # found them: device columns, and {counter: value per step}
+        self._round_cols, self._round_facts = [], {}
         self._step_fn = jax.jit(
             self._wrap_tp(self._make_step(),
                           (ps, "r", cs, "r"), (cs, "r", "r")),
@@ -1492,10 +1537,7 @@ class InferenceEngine:
         tp_ax = self._tp_ax
         ep_ax = self._ep_ax
 
-        counted = self._moe_counted
-        state_counted = self._state_layers
-        latent_counted = self._latent_layers
-        rows_counted = self._attn_pool_rows
+        counted = [rc for rc in _ROUND_COUNTERS if rc.counts(self)]
 
         def one_step(caches, state, params, aux):
             pos, tok, live, temp, keys, eos, last = state
@@ -1509,8 +1551,7 @@ class InferenceEngine:
             # advances it, so a slot that is not live (``lens`` 0)
             # leaves its state untouched (doc/serving.md "The decode
             # round")
-            stats = {} if counted or rows_counted or state_counted \
-                or latent_counted else None
+            stats = {} if counted else None
             logits, caches = dec._run_slots(
                 params, aux, caches, pos, tok[:, None], tp=tp_ax,
                 mm_impl=mm, ep=ep_ax, stats=stats,
@@ -1539,23 +1580,20 @@ class InferenceEngine:
                            lambda _: greedy, None)
             done_now = (nxt == eos) | (nxt_pos >= last)
             out = jnp.where(live, nxt, -1)     # -1: slot had no token
-            # more columns beside the S tokens, in this order: the
-            # experts this step touched, the pairs that fell on held
-            # experts and the slots that routed nothing, over the
-            # routed layers; the slots whose state it advanced, over
-            # the GatedDeltaNet layers, and how many of those layers
-            # advanced it in place; the cache rows its bounded
-            # reads fetched, over the attention layers
-            cols = (["experts_touched", "pairs_held", "rows_masked"]
-                    if counted else []) \
-                + (["state_advanced", "state_in_place"]
-                   if state_counted else []) \
-                + (["latent_rows_live"] if latent_counted else []) \
-                + (["attn_rows_read"] if rows_counted else [])
-            if cols:
+            # the step's counts: a device value is one more column
+            # beside the S tokens, in the registry's order; a value
+            # the walk added at trace time is kept for the drain
+            found = {rc: stats[rc.key] for rc in counted
+                     if rc.key in stats}
+            self._round_cols = [rc for rc, v in found.items()
+                                if not isinstance(v, int)]
+            self._round_facts = {rc: v for rc, v in found.items()
+                                 if isinstance(v, int)}
+            if self._round_cols:
                 out = jnp.concatenate(
-                    [out] + [jnp.asarray(stats[c], out.dtype).reshape(1)
-                             for c in cols])
+                    [out] + [jnp.asarray(stats[rc.key],
+                                         out.dtype).reshape(1)
+                             for rc in self._round_cols])
             live2 = live & ~done_now
             pos2 = jnp.where(live, nxt_pos, pos)
             tok2 = jnp.where(live, nxt, tok)
@@ -2959,37 +2997,33 @@ class InferenceEngine:
                 _TM_SPEC_ACCEPTED.inc(acc)
         else:
             rounds = np.asarray(entry[1])   # [steps_per_round, S + columns]
-            col = self.slots
-            if self._moe_counted:
-                # the device's counts, step by step: experts touched,
-                # pairs on held experts, slots that routed nothing
-                _TM_MOE_TOUCHED.inc(int(rounds[:, col].sum()))
-                _TM_MOE_LAYER_STEPS.inc(
-                    self._moe_counted * rounds.shape[0])
-                _TM_MOE_PAIRS_HELD.inc(int(rounds[:, col + 1].sum()))
-                _TM_MOE_PAIRS_ROUTED.inc(
-                    self._moe_pairs_step * rounds.shape[0])
-                _TM_MOE_ROWS_MASKED.inc(int(rounds[:, col + 2].sum()))
-                col += 3
-            if self._state_layers:
-                _TM_STATE_ADVANCED.inc(int(rounds[:, col].sum()))
-                _TM_STATE_POOL.inc(self.slots * self._state_layers
-                                   * rounds.shape[0])
-                _TM_STATE_IN_PLACE.inc(int(rounds[:, col + 1].sum()))
-                col += 2
-            if self._latent_layers:
-                _TM_LATENT_ROWS_LIVE.inc(int(rounds[:, col].sum()))
-            if self._attn_pool_rows:
-                # the last column: cache rows the step's bounded reads
-                # fetched, against the pool's rows over the same steps
-                _TM_ATTN_ROWS_READ.inc(int(rounds[:, -1].sum()))
-                _TM_ATTN_ROWS_POOL.inc(
-                    self._attn_pool_rows * rounds.shape[0])
-            for row in rounds:
-                for s in range(self.slots):
-                    req = self._mirror[s]
-                    if req is not None:
-                        self._push_token(req, s, int(row[s]), now)
+            # the round's counts on the profiler's clock, beside the
+            # serving.decode_round span of the same ``round``
+            with tele.span("serving.round_counts", cat="serving",
+                           round=entry[2], **self._count_round(rounds)):
+                for row in rounds:
+                    for s in range(self.slots):
+                        req = self._mirror[s]
+                        if req is not None:
+                            self._push_token(req, s, int(row[s]), now)
+
+    def _count_round(self, rounds):
+        """Add a drained round's counts to the registry's counters: a
+        column's sum over the round's steps, a trace-time value and a
+        pool's product times the steps. Returns what was added, under
+        each counter's short name."""
+        steps = rounds.shape[0]
+        got = {}
+        for i, rc in enumerate(self._round_cols, self.slots):
+            got[rc.counter] = int(rounds[:, i].sum())
+        for rc, per_step in self._round_facts.items():
+            got[rc.counter] = per_step * steps
+        for rc in (*self._round_cols, *self._round_facts):
+            if rc.pool is not None:
+                got[rc.pool] = rc.per_step(self) * steps
+        for c, n in got.items():
+            c.inc(n)
+        return {c.name.split(".", 1)[1]: n for c, n in got.items()}
 
     def _spec_round(self, busy):
         """Try to dispatch ONE verify round (doc/serving.md
@@ -3225,7 +3259,9 @@ class InferenceEngine:
                     # write): plain decode serves the round
                     _TM_SPEC_FALLBACK.inc()
                     self.stats["spec_fallback_rounds"] += 1
-                with self._phase("decode_round", slots_busy=busy):
+                n = self.stats["steps"]
+                with self._phase("decode_round", slots_busy=busy,
+                                 round=n):
                     self._caches, self._state, out = self._step_fn(
                         self._params, self._aux,
                         self._caches, self._state)
@@ -3236,7 +3272,7 @@ class InferenceEngine:
                         "serving_decode", self._step_fn,
                         (self._params, self._aux,
                          self._caches, self._state))
-                self._drain.append(("step", out))
+                self._drain.append(("step", out, n))
                 self.stats["steps"] += 1
                 _TM_ROUNDS.inc()
                 _TM_SLOTS_BUSY.observe(busy)

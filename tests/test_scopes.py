@@ -237,13 +237,23 @@ def test_engine_round_spans_are_disjoint_inside_the_round(toy_engine,
     evs = [e for e in json.load(open(path))["traceEvents"]
            if e["name"].startswith("serving.") and e["ph"] == "X"]
     rounds = [e for e in evs if e["name"] == "serving.round"]
-    phases = [e for e in evs if e["name"] != "serving.round"]
+    # a decode round's counts are no phase: they lie inside the drain
+    # of that round's tokens (tests/test_round_counters.py)
+    counts = [e for e in evs if e["name"] == "serving.round_counts"]
+    phases = [e for e in evs
+              if e["name"] not in ("serving.round", "serving.round_counts")]
     assert {"serving.prefill", "serving.decode_round", "serving.drain",
             "serving.h2d"} <= {e["name"] for e in phases}
     dec = [e for e in phases if e["name"] == "serving.decode_round"]
     assert all(1 <= e["args"]["slots_busy"] <= 2 for e in dec)
-    assert all(set(e["args"]) == {"slots_busy"} for e in dec)
+    assert all(set(e["args"]) == {"slots_busy", "round"} for e in dec)
+    assert sorted(e["args"]["round"] for e in counts) \
+        == sorted(e["args"]["round"] for e in dec)
+    drains = [e for e in phases if e["name"] == "serving.drain"]
     eps = 1e-3                               # microseconds
+    for c in counts:
+        assert any(d["ts"] - eps <= c["ts"] and c["ts"] + c["dur"]
+                   <= d["ts"] + d["dur"] + eps for d in drains), c
     for p in phases:
         inside = [r for r in rounds if r["ts"] - eps <= p["ts"]
                   and p["ts"] + p["dur"] <= r["ts"] + r["dur"] + eps]
