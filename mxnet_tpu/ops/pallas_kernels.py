@@ -1497,6 +1497,174 @@ def latent_paged_attention(q, rows, pos, *, v_width, scale, lens=None,
     return out.reshape(s_, c, h, r)
 
 
+# -- the expanded latent read of a prefill piece: one block a call --------
+#
+# A prefill piece of C queries at one position reads the latent rows so
+# far a block at a time (``Decoder._latent_attn_blocks``): each block is
+# up-projected to per-head keys ``k_n`` and values and to the rotary part
+# ``k_r`` the heads share, then scored against the piece. This kernel is
+# one block's scores, causal mask, online-softmax update and values
+# product, the scores in fast memory; the running max, sum and
+# accumulator are float32 operands aliased to its results, carried by the
+# caller from block to block. Queries lie on the lanes: a key sub-tile's
+# scores are [keys, queries], so a query's max and sum are a ROW (a
+# reduction over sublanes), stored lane-dense as [B, H, 1, C], and the
+# accumulator is [B, H, Dv, C] against values that come transposed,
+# [B, H Dv, L]. A head's keys and values are a column block of lanes of
+# the up-projection's output as it is; nothing is relaid per block.
+
+def _latent_prefill_kernel(at_ref, qn_ref, qr_ref, kn_ref, kr_ref, vt_ref,
+                           m_ref, l_ref, acc_ref, mo_ref, lo_ref, ao_ref, *,
+                           block_q, block_k, rows, scale):
+    i = pl.program_id(2)
+    f32 = jnp.float32
+    neg_big = jnp.float32(-1e30)
+    prec = lax.Precision.HIGHEST if qn_ref.dtype == jnp.float32 else None
+    nt = (((1,), (1,)), ((), ()))
+    q0 = at_ref[0] + i * jnp.int32(block_q)    # the tile's first query
+    mo_ref[...] = m_ref[...]
+    lo_ref[...] = l_ref[...]
+    ao_ref[...] = acc_ref[...]
+
+    def update(sl, k0, masked):
+        s = lax.dot_general(kn_ref[0, sl, :], qn_ref[0, 0], nt,
+                            precision=prec, preferred_element_type=f32)
+        s = (s + lax.dot_general(kr_ref[0, sl, :], qr_ref[0, 0], nt,
+                                 precision=prec,
+                                 preferred_element_type=f32)) * scale
+        if masked:
+            ok = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+                <= q0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(ok, s, neg_big)
+        m = mo_ref[0, 0]                                    # [1, tq]
+        new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        pe = jnp.exp(s - new_m)
+        if masked:
+            pe = jnp.where(ok, pe, 0.0)
+        corr = jnp.exp(m - new_m)
+        lo_ref[0, 0] = lo_ref[0, 0] * corr \
+            + jnp.sum(pe, axis=0, keepdims=True)
+        ao_ref[0, 0] = ao_ref[0, 0] * corr + jnp.dot(
+            vt_ref[0, :, sl], pe.astype(vt_ref.dtype), precision=prec,
+            preferred_element_type=f32)                     # [Dv, tq]
+        mo_ref[0, 0] = new_m
+
+    for t in range(rows // block_k):
+        k0 = at_ref[1] + jnp.int32(t * block_k)
+        sl = slice(t * block_k, (t + 1) * block_k)
+        # every key at or before the tile's first query: nothing masked
+        whole = k0 + jnp.int32(block_k - 1) <= q0
+        # a key sub-tile wholly after the tile's last query adds nothing
+        some = k0 < q0 + jnp.int32(block_q)
+        pl.when(whole)(functools.partial(update, sl, k0, False))
+        pl.when(some & jnp.logical_not(whole))(
+            functools.partial(update, sl, k0, True))
+
+
+def latent_prefill_tiles(c, interpret=None):
+    """(padded length, query tile) of a prefill piece of ``c`` queries
+    for ``latent_prefill_attention``: on the chip the queries lie on
+    the lanes, so the piece is padded to whole tiles of 128 and read
+    512 (or 256, 128) at a time; the interpreter takes ``c`` whole."""
+    if interpret is None:
+        interpret = _use_interpret()
+    if interpret:
+        return c, c
+    cp = _round_up(c, 128)
+    return cp, next(t for t in (512, 256, 128) if cp % t == 0)
+
+
+def latent_prefill_attention(qn, qr, kn, kr, vt, m, l, acc, pos, first, *,
+                             scale, block_q=None, block_k=None,
+                             interpret=None):
+    """One block of latent rows, expanded, against a prefill piece: the
+    online-softmax update of the piece's running (m, l, acc).
+
+    qn: [B, H, C, Dn] and qr: [B, H, C, Dr], the piece's queries head-
+    major (C padded as ``latent_prefill_tiles`` says); kn: [B, L, H Dn]
+    and vt: [B, H Dv, L], the block's per-head keys and values (head h
+    owns lanes [h Dn, (h+1) Dn) of ``kn`` and rows [h Dv, (h+1) Dv) of
+    ``vt``); kr: [B, L, Dr], the rotary keys all heads share. m, l:
+    [B, H, 1, C] float32, acc: [B, H, Dv, C] float32, the running max,
+    sum and unnormalized values (aliased to the results). ``pos``: the
+    piece's first position; ``first``: the block's first row; a key
+    counts for a query at or after it. Scores are float32 from the
+    operands' dtype times ``scale``, probabilities are cast to the
+    values' dtype for their product. Returns (m, l, acc).
+
+    Grid (batch row, head, query tile); a key sub-tile of ``block_k``
+    rows wholly after a tile's last query is skipped. On the chip Dn and
+    Dv must be whole lane tiles and C whole query tiles; the
+    interpreter takes any shape."""
+    if interpret is None:
+        interpret = _use_interpret()
+    c, rows = qn.shape[2], kn.shape[1]
+    if block_q is None:
+        block_q = latent_prefill_tiles(c, interpret)[1]
+    if block_k is None:
+        block_k = next((t for t in (512, 256, 128) if rows % t == 0), rows)
+    if c % block_q or rows % block_k:
+        raise ValueError(
+            "latent_prefill_attention: block_q=%d must divide the %d "
+            "queries and block_k=%d the %d rows"
+            % (block_q, c, block_k, rows))
+    at = jnp.stack([jnp.asarray(pos, jnp.int32),
+                    jnp.asarray(first, jnp.int32)])
+    return _latent_prefill_call(at, qn, qr, kn, kr, vt, m, l, acc,
+                                scale=float(scale), block_q=block_q,
+                                block_k=block_k, interpret=interpret)
+
+
+# one trace and one lowering of the kernel for every latent layer of a
+# program: the layers' calls are the same shapes
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k",
+                                             "interpret"))
+def _latent_prefill_call(at, qn, qr, kn, kr, vt, m, l, acc, *, scale,
+                         block_q, block_k, interpret):
+    b, h, c, dn = qn.shape
+    dr = qr.shape[-1]
+    rows = kn.shape[1]
+    dv = vt.shape[1] // h
+
+    def tile(b_, h_, i, at):
+        return (b_, h_, 0, i)
+
+    stat = pl.BlockSpec((1, 1, 1, block_q), tile)
+    accs = pl.BlockSpec((1, 1, dv, block_q), tile)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, h, c // block_q),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, dn),
+                         lambda b_, h_, i, at: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dr),
+                         lambda b_, h_, i, at: (b_, h_, i, 0)),
+            pl.BlockSpec((1, rows, dn), lambda b_, h_, i, at: (b_, 0, h_)),
+            pl.BlockSpec((1, rows, dr), lambda b_, h_, i, at: (b_, 0, 0)),
+            pl.BlockSpec((1, dv, rows), lambda b_, h_, i, at: (b_, h_, 0)),
+            stat, stat, accs],
+        out_specs=[stat, stat, accs],
+    )
+    f32 = jnp.float32
+    lanes = _round_up(block_q, 128)
+    itm = jnp.dtype(kn.dtype).itemsize
+    need = 2 * rows * (_round_up(dn, 128) + 128 + _round_up(dv, 8)) * itm \
+        + 6 * block_k * lanes * 4 + 8 * (dv + 16) * lanes * 4
+    return _pallas_call(
+        functools.partial(_latent_prefill_kernel, block_q=block_q,
+                          block_k=block_k, rows=rows, scale=scale),
+        at, qn, qr, kn, kr, vt, m, l, acc,
+        out_shape=[jax.ShapeDtypeStruct(m.shape, f32),
+                   jax.ShapeDtypeStruct(l.shape, f32),
+                   jax.ShapeDtypeStruct(acc.shape, f32)],
+        grid_spec=grid_spec,
+        input_output_aliases={6: 0, 7: 1, 8: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=int(min(max(2 * need, 32 << 20), 100 << 20))),
+        interpret=interpret)
+
+
 # -- the recurrent state's decode step: live slots only, in place ---------
 #
 # A Gated DeltaNet layer keeps per slot a float32 matrix state [Hv, Dk, Dv]

@@ -508,6 +508,31 @@ class Decoder:
         w = p["kv_lora_rank"] + p["rope_dim"]
         return -(-w // 128) * 128
 
+    @staticmethod
+    def latent_read_in_kernel(p, c):
+        """Whether the expanded read of a chunk of ``c`` positions at one
+        position (a prefill piece) runs through
+        ``pallas_kernels.latent_prefill_attention``, by what the code can
+        see: a chunk longer than a decode step's, and on the chip heads
+        of whole lane tiles (the interpreter takes any). Everything else
+        runs the einsums of ``_latent_attn_blocks``, the definition."""
+        from ..ops import pallas_kernels as pk
+        if c <= _SHORT_CHUNK:
+            return False
+        return pk._use_interpret() or (p["nope_dim"] % 128 == 0
+                                       and p["v_dim"] % 128 == 0)
+
+    def latent_prefill_blocks(self, pos, c):
+        """Blocks of latent rows the kernel visits for a prefill piece of
+        ``c`` positions (the program's chunk, padding included) at
+        ``pos``, summed over the LatentAttention layers: ceil((pos + c)
+        / block) a layer, 0 for a layer whose read takes the einsums
+        (the serving engine's ``serving.latent_prefill_blocks``)."""
+        bk = self._latent_block(self.max_len)
+        return sum(min(-(-(int(pos) + c) // bk), self.max_len // bk)
+                   for n in self._mla
+                   if self.latent_read_in_kernel(n.params, c))
+
     def refuse_latent_rows(self, feature):
         """Raise for a feature that moves or re-types K/V rows by their
         (K, V, head) layout and so cannot carry a latent row, naming the
@@ -1242,17 +1267,24 @@ class Decoder:
         ``pos + C`` needs (a loop whose length follows the position),
         each block up-projected to per-head keys and values
         (``ops.attention.mla_expand``), scored in float32 and merged
-        by an online softmax. Returns [B, C, H, Dv] in ``q``'s dtype."""
+        by an online softmax. Returns [B, C, H, Dv] in ``q``'s dtype.
+        Where ``latent_read_in_kernel`` says so a block's scores, softmax
+        and values are ONE kernel (``_latent_attn_kernel_blocks``); the
+        einsums below are its definition."""
         from ..ops.attention import mla_expand
         b, c, h, _ = q.shape
         dn, dv = p["nope_dim"], p["v_dim"]
         total = rows.shape[1]
-        bk = 1
-        while bk * 2 <= self._LATENT_BLOCK and total % (bk * 2) == 0:
-            bk *= 2
-        if bk < 8:
-            bk = total
+        bk = self._latent_block(total)
         f32, i32 = jnp.float32, jnp.int32
+        if isinstance(pos, (int, np.integer)):
+            nblk = min(-(-(int(pos) + c) // bk), total // bk)
+        else:
+            nblk = jnp.minimum((jnp.asarray(pos, i32) + c + bk - 1) // bk,
+                               total // bk)
+        if self.latent_read_in_kernel(p, c):
+            return self._latent_attn_kernel_blocks(p, q, rows, wukv, pos,
+                                                   scale, bk, nblk)
         qn, qr = q[..., :dn], q[..., dn:]
         qpos = pos + jnp.arange(c, dtype=i32)
         neg = f32(-1e30)
@@ -1282,15 +1314,64 @@ class Decoder:
 
         init = (jnp.full((b, h, c), neg, f32), jnp.zeros((b, h, c), f32),
                 jnp.zeros((b, h, c, dv), f32))
-        if isinstance(pos, (int, np.integer)):
-            nblk = min(-(-(int(pos) + c) // bk), total // bk)
-        else:
-            nblk = jnp.minimum((jnp.asarray(pos, i32) + c + bk - 1) // bk,
-                               total // bk)
         _, l, acc = lax.fori_loop(0, nblk, body, init)
         with jax.named_scope("attend"):
             o = acc / jnp.maximum(l, 1e-30)[..., None]
             return jnp.swapaxes(o, 1, 2).astype(q.dtype)
+
+    def _latent_block(self, total):
+        """Rows of a latent block for a cache of ``total`` rows: the
+        largest power of two up to ``_LATENT_BLOCK`` that divides it, or
+        the whole cache where that is under 8."""
+        bk = 1
+        while bk * 2 <= self._LATENT_BLOCK and total % (bk * 2) == 0:
+            bk *= 2
+        return total if bk < 8 else bk
+
+    @staticmethod
+    def _latent_attn_kernel_blocks(p, q, rows, wukv, pos, scale, bk, nblk):
+        """``_latent_attn_blocks`` with a block's scores, mask, online
+        softmax and values in ONE kernel
+        (``pallas_kernels.latent_prefill_attention``), the running
+        (m, l, acc) carried in float32 from block to block. A block is
+        up-projected as ``mla_expand`` does it, by the weight's key and
+        value halves apart (the same products), into the layouts the
+        kernel reads: keys [B, bk, H Dn], values transposed [B, H Dv,
+        bk]. The queries are laid head-major once a piece."""
+        from ..ops.pallas_kernels import (latent_prefill_attention,
+                                          latent_prefill_tiles)
+        b, c, h, _ = q.shape
+        r, dn, dv, dr = (p["kv_lora_rank"], p["nope_dim"], p["v_dim"],
+                         p["rope_dim"])
+        f32 = jnp.float32
+        cp, _ = latent_prefill_tiles(c)
+        with jax.named_scope("attend"):
+            qt = jnp.pad(jnp.swapaxes(q, 1, 2),
+                         [(0, 0), (0, 0), (0, cp - c), (0, 0)])
+            qn, qr = qt[..., :dn], qt[..., dn:]
+        with jax.named_scope("expand"):
+            w = wukv.reshape(h, dn + dv, r)
+            wuk = w[:, :dn].reshape(h * dn, r)
+            wuv = w[:, dn:].reshape(h * dv, r)
+
+        def body(j, carry):
+            with jax.named_scope("cache"):
+                blk = lax.dynamic_slice_in_dim(rows, j * bk, bk, axis=1)
+            with jax.named_scope("expand"):
+                lat = blk[..., :r].astype(wukv.dtype)
+                kn = jnp.einsum("blr,fr->blf", lat, wuk)
+                vt = jnp.einsum("blr,fr->bfl", lat, wuv)
+                kr = blk[..., r:r + dr].astype(wukv.dtype)
+            with jax.named_scope("attend"):
+                return latent_prefill_attention(
+                    qn, qr, kn, kr, vt, *carry, pos, j * bk, scale=scale)
+
+        init = (jnp.full((b, h, 1, cp), -1e30, f32),
+                jnp.zeros((b, h, 1, cp), f32), jnp.zeros((b, h, dv, cp), f32))
+        _, l, acc = lax.fori_loop(0, nblk, body, init)
+        with jax.named_scope("attend"):
+            o = acc / jnp.maximum(l, 1e-30)
+            return jnp.transpose(o, (0, 3, 1, 2))[:, :c].astype(q.dtype)
 
     def _cached_gdn(self, node, ins, entry, pos, valid_len=None,
                     lens=None, stats=None):

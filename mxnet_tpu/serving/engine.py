@@ -200,6 +200,11 @@ _TM_ATTN_ROWS_POOL = tele.counter("serving.attn_rows_pool")
 # column): the rows a step's latent reads have to fetch, before blocks
 # round them up (the layer-steps are attn_rows_pool's)
 _TM_LATENT_ROWS_LIVE = tele.counter("serving.latent_rows_live")
+# blocks of latent rows the prefill pieces' expanded reads took through
+# the kernel, summed over LatentAttention layers and pieces (the host's
+# count at dispatch, from the piece's position and length; 0 for a piece
+# whose read takes the einsums)
+_TM_LATENT_PREFILL_BLOCKS = tele.counter("serving.latent_prefill_blocks")
 
 
 class _RoundCounter(collections.namedtuple(
@@ -2793,6 +2798,9 @@ class InferenceEngine:
                 _raw_key(req.seed),
                 np.int32(-1 if req.eos_id is None else req.eos_id),
                 np.int32(req.limit - req.resumed))
+        if self._dec.has_latent:
+            _TM_LATENT_PREFILL_BLOCKS.inc(
+                self._dec.latent_prefill_blocks(start, bucket))
         if ("prefill", bucket) not in self._prog_seen:
             self._prog_seen.add(("prefill", bucket))
             # post-dispatch arrays carry the same avals the dispatch

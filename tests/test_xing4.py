@@ -150,7 +150,8 @@ def test_prefill_in_pieces_then_decode_agrees_with_the_reference(
         toy, decoder, pieces, monkeypatch):
     """The prompt enters in pieces, each reading the earlier pieces'
     latent rows in the expanded form (blocks of 16 rows here, so a piece
-    walks several), then every further token through a decode step at one
+    walks several; a piece of more than 8 through the kernel, a shorter
+    one absorbed), then every further token through a decode step at one
     position (the absorbed form over all rows): the logits at every
     position are the reference's one full forward."""
     monkeypatch.setattr(Decoder, "_LATENT_BLOCK", 16)
@@ -170,6 +171,75 @@ def test_prefill_in_pieces_then_decode_agrees_with_the_reference(
             outs.append(np.asarray(lg)[:, None])
     got = np.concatenate(outs, axis=1)
     assert np.abs(got - ref).max() <= TOL * ref.std()
+
+
+@pytest.mark.parametrize("b", [1, 2])
+@pytest.mark.parametrize("pos,c", [(0, 12), (20, 10), (12, 24), (40, 24)],
+                         ids=["at_0", "inside_a_block", "across_blocks",
+                              "to_max_len"])
+def test_prefill_kernel_equals_the_einsums(toy, decoder, b, pos, c,
+                                           monkeypatch):
+    """The expanded read of a piece through the kernel against the
+    einsum body of ``_latent_attn_blocks`` on the same queries and rows
+    (blocks of 16 rows of 64): a piece at position 0, one inside a block,
+    one across two block boundaries, one that ends at ``max_len``; the
+    position traced as the engine's prefill has it. Agree to 1e-5 of the
+    output's spread in float32 at full precision (the same function,
+    other orders of the sums)."""
+    monkeypatch.setattr(Decoder, "_LATENT_BLOCK", 16)
+    _, cfg, _, _, _ = toy
+    p = attn_params(cfg)
+    h, dn, dr = p["num_heads"], p["nope_dim"], p["rope_dim"]
+    r = p["kv_lora_rank"]
+    key = jax.random.split(jax.random.PRNGKey(b * 100 + pos), 2)
+    q = jax.random.normal(key[0], (b, c, h, dn + dr), jnp.float32)
+    rows = jnp.pad(jax.random.normal(key[1], (b, MAX_LEN, r + dr),
+                                     jnp.float32),
+                   [(0, 0), (0, 0), (0, 128 - r - dr)])
+    wukv = attn_weights(toy)["attn_kv_up_weight"]
+    scale = A.mla_softmax_scale(p)
+    assert Decoder.latent_read_in_kernel(p, c)
+
+    def read():
+        # a new function a trace: jit would reuse the first one's
+        return jax.jit(lambda q, rows, pos: decoder._latent_attn_blocks(
+            p, q, rows, wukv, pos, scale))(q, rows, jnp.int32(pos))
+
+    with jax.default_matmul_precision("highest"):
+        got = read()
+        monkeypatch.setattr(Decoder, "latent_read_in_kernel",
+                            staticmethod(lambda p, c: False))
+        want = read()
+    assert got.shape == want.shape == (b, c, h, p["v_dim"])
+    assert np.abs(np.asarray(got - want)).max() \
+        <= 1e-5 * float(want.std())
+
+
+def test_prefill_pieces_take_the_kernel_and_decode_steps_do_not(
+        toy, decoder, monkeypatch):
+    """Which read a chunk at one position takes, by what the code sees:
+    a chunk of more than 8 positions the kernel (in every latent layer),
+    one of 8 or fewer the einsums; on the chip heads of whole lane tiles
+    only (the published 128 and 128, not the toy's 8)."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    calls = []
+    real = pk.latent_prefill_attention
+    monkeypatch.setattr(pk, "latent_prefill_attention",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    caches = decoder.init_cache(1)
+    for c, kernels in ((9, 3), (8, 0), (1, 0)):
+        del calls[:]
+        jax.jit(decoder._run).lower(
+            decoder._params, decoder._aux, caches, jnp.int32(0),
+            jnp.zeros((1, c), jnp.int32))
+        assert len(calls) == kernels, c
+    p = attn_params(toy[1])
+    assert [Decoder.latent_read_in_kernel(p, c) for c in (8, 9)] \
+        == [False, True]
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    assert not Decoder.latent_read_in_kernel(p, 1024)
+    assert Decoder.latent_read_in_kernel(dict(p, nope_dim=128, v_dim=128),
+                                         1024)
 
 
 def test_slot_walk_with_dead_slots_and_a_padded_piece(toy, decoder):
@@ -524,6 +594,35 @@ def test_engine_counters_by_hand(toy, engine):
     assert 0 < got["moe_pairs_held"] <= 4 * 2 * 8
     assert 0 < got["moe_experts_touched"] <= got["moe_pairs_held"]
     assert got["moe_rows_masked"] == 2 * 3 * steps - 2 * 8
+
+
+def test_latent_prefill_blocks_by_hand(toy, decoder, monkeypatch):
+    """Prompts in pieces of up to 16 (blocks of 16 rows): a piece the
+    program runs as a chunk of 16 reads ceil((pos + 16) / 16) blocks
+    through the kernel in each of three layers, one run as a chunk of 8
+    takes the einsums and counts nothing; the served tokens are the
+    reference's best."""
+    monkeypatch.setattr(Decoder, "_LATENT_BLOCK", 16)
+    counter = mx.telemetry.counter("serving.latent_prefill_blocks")
+    before = counter.value
+    eng = mx.serving.InferenceEngine(
+        decoder, slots=2, prefill_buckets=BUCKETS, steps_per_round=4,
+        prefill_chunk=16)
+    prompts = [tokens(toy, (n,), seed=80 + n) for n in (40, 20)]
+    handles = [eng.submit(q, max_tokens=4) for q in prompts]
+    eng.serve_forever()
+    eng.close()
+    # 40: chunks of 16 at 0 and 16, 8 at 32; 20: 16 at 0, 8 at 16
+    pieces = [(0, 16), (16, 16), (0, 16)]
+    assert counter.value - before \
+        == 3 * sum(-(-(pos + c) // 16) for pos, c in pieces) == 12
+    assert decoder.latent_prefill_blocks(32, 8) == 0
+    for q, h in zip(prompts, handles):
+        seq = np.concatenate([q, np.asarray(h.tokens, np.int32)])[None]
+        ref = reference(toy, seq)[0]
+        rows = ref[len(q) - 1:len(q) - 1 + len(h.tokens)]
+        got = rows[np.arange(len(h.tokens)), h.tokens]
+        assert float(np.max((rows.max(-1) - got) / rows.std(-1))) <= 1e-3
 
 
 def test_speculation_carries_latent_rows(toy, decoder):
