@@ -236,6 +236,15 @@
   mx.symbol.internal.create("MAERegressionOutput", name, list(...))
 }
 
+#' A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Granite 4.0-Hs
+#'
+#' Parameters: chunk_size (int, default 256); conv_kernel (int, default 4); eps (float, default 1e-05); head_dim (int); num_heads (int); state_size (int)
+#' Symbol inputs: data, in_weight, conv_weight, conv_bias, a_log, d_skip, dt_bias, norm_weight, out_weight
+#' @export
+`mx.symbol.Mamba2` <- function(..., name = "") {
+  mx.symbol.internal.create("Mamba2", name, list(...))
+}
+
 #' Mixture-of-experts position-wise FFN (soft or top-k routing).
 #'
 #' Parameters: expert_first (int, default 0); experts_held (int, default 0); gated (bool, default False); hidden (int); num_experts (int); renormalize (bool, default False); route_scale (float, default 1.0); router (str, default linear); shared_gated (bool, default True); shared_hidden (int, default 0); top_k (int, default 0)
@@ -247,7 +256,7 @@
 
 #' Multi-head self-attention with fused QKV projection.
 #'
-#' Parameters: axis_name (str, default sp); causal (bool, default True); dropout (float, default 0.0); impl (str, default flash); num_heads (int); num_kv_heads (int, default 0); rope (bool, default False); rope_base (float, default 10000.0); window (int, default 0)
+#' Parameters: axis_name (str, default sp); causal (bool, default True); dropout (float, default 0.0); impl (str, default flash); no_bias (bool, default False); num_heads (int); num_kv_heads (int, default 0); rope (bool, default False); rope_base (float, default 10000.0); scale (float, default 0.0); window (int, default 0)
 #' Symbol inputs: data, qkv_weight, qkv_bias, out_weight, out_bias
 #' @export
 `mx.symbol.MultiHeadAttention` <- function(..., name = "") {

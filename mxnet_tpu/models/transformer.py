@@ -28,7 +28,8 @@ def _ln(data, name):
                          name=name)
 
 __all__ = ["transformer_block", "moe_transformer_block",
-           "get_transformer_lm", "zaya_block", "get_zaya_lm", "tp_rules",
+           "get_transformer_lm", "zaya_block", "get_zaya_lm",
+           "granite_hybrid_block", "get_granite_hybrid_lm", "tp_rules",
            "ep_rules"]
 
 
@@ -398,6 +399,94 @@ def get_qwen3_next_lm(vocab_size, num_layers, embed_dim, num_heads,
     logits = sym.FullyConnected(
         data=_rms(net, "final_norm", eps), num_hidden=vocab_size,
         no_bias=True, flatten=False, name="lm_head")
+    return _lm_loss(logits, vocab_size, loss_layout)
+
+
+def granite_hybrid_block(data, name, attention, embed_dim, num_heads,
+                         num_kv_heads, mlp_hidden, mamba_heads, mamba_head_dim,
+                         mamba_state, conv_kernel=4,
+                         chunk_size=256, residual_multiplier=1.0,
+                         attention_multiplier=0.0, eps=1e-5, impl="flash"):
+    """One Granite 4.0-H layer: ``x + m mixer(RMSNorm(x))`` then
+    ``x + m mlp(RMSNorm(x))`` with ``m = residual_multiplier``, the mixer
+    a bias-free grouped-query ``MultiHeadAttention`` without positions
+    whose scores are scaled by ``attention_multiplier`` (``attention``)
+    or a ``Mamba2`` mixer, the MLP ``W_down (silu(W_gate h) * W_up h)``.
+    The checkpoint's fused ``input_linear`` [2 F, E] is stored as its two
+    halves, gate then up; its q/k/v projections as one [q ; k ; v]
+    matrix: storage choices, the same function."""
+    h = _rms(data, name + "_mixer_norm", eps)
+
+    def var(tag):
+        return sym.Variable("%s_%s" % (name, tag))
+
+    if attention:
+        mix = sym.MultiHeadAttention(
+            data=h, qkv_weight=var("attn_qkv_weight"),
+            out_weight=var("attn_out_weight"), num_heads=num_heads,
+            num_kv_heads=num_kv_heads, causal=True, impl=impl,
+            scale=attention_multiplier, no_bias=True, name=name + "_attn")
+    else:
+        mix = sym.Mamba2(
+            data=h, in_weight=var("mamba_in_weight"),
+            conv_weight=var("mamba_conv_weight"),
+            conv_bias=var("mamba_conv_bias"), a_log=var("mamba_a_log"),
+            d_skip=var("mamba_d_skip"), dt_bias=var("mamba_dt_bias"),
+            norm_weight=var("mamba_norm_weight"),
+            out_weight=var("mamba_out_weight"), num_heads=mamba_heads,
+            head_dim=mamba_head_dim, state_size=mamba_state,
+            conv_kernel=conv_kernel,
+            chunk_size=chunk_size, eps=eps, name=name + "_mamba")
+    x = data + mix * residual_multiplier
+    h = _rms(x, name + "_mlp_norm", eps)
+
+    def fc(z, width, tag):
+        return sym.FullyConnected(data=z, num_hidden=width, no_bias=True,
+                                  flatten=False, name="%s_mlp_%s"
+                                  % (name, tag))
+
+    act = sym.Activation(data=fc(h, mlp_hidden, "gate"), act_type="silu",
+                         name=name + "_mlp_act") * fc(h, mlp_hidden, "up")
+    return x + fc(act, embed_dim, "down") * residual_multiplier
+
+
+def get_granite_hybrid_lm(vocab_size, layer_types, embed_dim, num_heads,
+                          num_kv_heads, mlp_hidden, mamba_heads,
+                          mamba_head_dim, mamba_state, conv_kernel=4,
+                          chunk_size=256,
+                          embedding_multiplier=1.0, residual_multiplier=1.0,
+                          attention_multiplier=0.0, logits_scaling=1.0,
+                          eps=1e-5, impl="flash", loss_layout="reference"):
+    """Granite-4.0-H-shaped decoder-only LM (IBM's ``granitemoehybrid``
+    without experts): one ``granite_hybrid_block`` per entry of
+    ``layer_types`` (``"attention"`` or ``"mamba"``), between an
+    embedding times ``embedding_multiplier`` and the SAME matrix as the
+    output head (tied) behind a final RMSNorm, its logits divided by
+    ``logits_scaling``; no biases, no positions (the Mamba-2 layers
+    order the sequence), one group of B and C in every Mamba-2 layer.
+    Built from registered Symbol ops like every zoo model: it binds, and
+    is served by ``Decoder`` / ``InferenceEngine``, which keep K/V rows
+    for the attention layers and a recurrent state for the others.
+    ``loss_layout`` as in ``get_transformer_lm``."""
+    kinds = set(layer_types) - {"attention", "mamba"}
+    if kinds:
+        raise ValueError("layer_types: 'attention' or 'mamba', got %s"
+                         % sorted(kinds))
+    embed = sym.Variable("embed_weight")
+    net = sym.Embedding(data=sym.Variable("data"), weight=embed,
+                        input_dim=vocab_size, output_dim=embed_dim,
+                        name="embed") * embedding_multiplier
+    for i, kind in enumerate(layer_types):
+        net = granite_hybrid_block(
+            net, "layer%d" % i, kind == "attention", embed_dim, num_heads,
+            num_kv_heads, mlp_hidden, mamba_heads, mamba_head_dim,
+            mamba_state, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, residual_multiplier=residual_multiplier,
+            attention_multiplier=attention_multiplier, eps=eps, impl=impl)
+    logits = sym.FullyConnected(
+        data=_rms(net, "final_norm", eps), weight=embed,
+        num_hidden=vocab_size, no_bias=True, flatten=False,
+        name="lm_head") / logits_scaling
     return _lm_loss(logits, vocab_size, loss_layout)
 
 

@@ -878,6 +878,12 @@ class MultiHeadAttention(OpSpec):
     and per-token cache reads are O(window) no matter how long the
     generation runs (with rope there is no positional table to outgrow
     either).
+
+    ``scale`` (default 0 = ``1/sqrt(D)``) multiplies the scores before
+    the softmax, in every impl and in the decoder's reads (Granite 4.0-H
+    scales by ``attention_multiplier`` = 1/D). ``no_bias=True`` drops
+    both biases: the op then takes ``data, qkv_weight, out_weight`` and
+    stores no zero bias leaves.
     """
 
     name = "MultiHeadAttention"
@@ -889,7 +895,9 @@ class MultiHeadAttention(OpSpec):
               "rope": Param("bool", False),
               "rope_base": Param("float", 10000.0),
               "window": Param("int", 0),
-              "axis_name": Param("str", "sp")}
+              "axis_name": Param("str", "sp"),
+              "scale": Param("float", 0.0),
+              "no_bias": Param("bool", False)}
 
     @staticmethod
     def kv_heads(p):
@@ -917,7 +925,26 @@ class MultiHeadAttention(OpSpec):
                 "must stay whole on their kv head's shard) — use a "
                 "tp that divides num_kv_heads" % (where, kv, tp))
 
+    @staticmethod
+    def softmax_scale(p, d):
+        """What the scores of heads of ``d`` are multiplied by: the
+        ``scale`` parameter, or ``1/sqrt(d)`` where it is 0. A plain
+        float: ``np.sqrt`` returns a STRONG f64 scalar under x64, which
+        would silently promote the whole graph."""
+        return float(p.get("scale", 0.0) or 1.0 / np.sqrt(d))
+
+    @staticmethod
+    def split_inputs(p, ins):
+        """(data, qkv_weight, qkv_bias, out_weight, out_bias) of the op's
+        inputs, the biases None under ``no_bias``."""
+        if p.get("no_bias"):
+            x, wqkv, wo = ins
+            return x, wqkv, None, wo, None
+        return tuple(ins)
+
     def arguments(self, p):
+        if p.get("no_bias"):
+            return ["data", "qkv_weight", "out_weight"]
         return ["data", "qkv_weight", "qkv_bias", "out_weight", "out_bias"]
 
     def infer_shape(self, p, in_shapes):
@@ -943,6 +970,10 @@ class MultiHeadAttention(OpSpec):
                 raise MXNetError("MultiHeadAttention: window>0 is "
                                  "defined for causal attention only")
         f = e + 2 * kv * (e // p["num_heads"])  # q rows + kv k/v rows
+        if p.get("no_bias"):
+            return [d, shape_assign(in_shapes[1], (f, e), "qkv_weight"),
+                    shape_assign(in_shapes[2], (e, e), "out_weight")], \
+                [d], []
         ins = [d,
                shape_assign(in_shapes[1], (f, e), "qkv_weight"),
                shape_assign(in_shapes[2], (f,), "qkv_bias"),
@@ -951,12 +982,15 @@ class MultiHeadAttention(OpSpec):
         return ins, [d], []
 
     def forward(self, p, ins, aux, is_train, rng):
-        x, wqkv, bqkv, wo, bo = ins
+        x, wqkv, bqkv, wo, bo = self.split_inputs(p, ins)
         b, t, e = x.shape
         h = p["num_heads"]
         d = e // h
         kv = self.kv_heads(p)
-        qkv = jnp.einsum("bte,fe->btf", x, wqkv) + bqkv
+        scale = self.softmax_scale(p, d) if p.get("scale") else None
+        qkv = jnp.einsum("bte,fe->btf", x, wqkv)
+        if bqkv is not None:
+            qkv = qkv + bqkv
         q = qkv[..., :e].reshape(b, t, h, d)
         k = qkv[..., e:e + kv * d].reshape(b, t, kv, d)
         v = qkv[..., e + kv * d:].reshape(b, t, kv, d)
@@ -1001,16 +1035,17 @@ class MultiHeadAttention(OpSpec):
         if impl == "flash":
             from .pallas_kernels import flash_attention
             o = flash_attention(q, k, v, causal=p["causal"],
-                                window=window)
+                                window=window, scale=scale)
         elif impl == "blockwise":
             from ..parallel.ring import blockwise_attention
             o = blockwise_attention(q, k, v, causal=p["causal"],
-                                    window=window)
+                                    window=window, scale=scale)
         elif impl == "dense":
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
             # float(): np.sqrt returns a STRONG f64 scalar under x64,
             # which would silently promote the whole graph (and f64 is
             # emulated, ~10x slower, on TPU)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / float(np.sqrt(d))
+            s = s / float(np.sqrt(d)) if scale is None else s * scale
             if p["causal"]:
                 qpos_m = jnp.arange(t)[:, None]
                 kpos_m = jnp.arange(t)[None, :]
@@ -1028,7 +1063,7 @@ class MultiHeadAttention(OpSpec):
             try:
                 o = _ring_attention_local(q, k, v,
                                           axis_name=p["axis_name"],
-                                          causal=p["causal"], scale=None)
+                                          causal=p["causal"], scale=scale)
             except NameError as e:
                 raise MXNetError(
                     "MultiHeadAttention impl='ring' needs mesh axis %r "
@@ -1079,12 +1114,14 @@ class MultiHeadAttention(OpSpec):
                         .reshape(B_, C_, H_, D_)
 
             o = undeal(_striped_ring_local(deal(q), deal(k), deal(v),
-                                           axis_name=axis, scale=None,
+                                           axis_name=axis, scale=scale,
                                            block_q=128, block_k=128))
         else:
             raise MXNetError("MultiHeadAttention: unknown impl %r" % impl)
         o = o.reshape(b, t, e)
-        out = jnp.einsum("bte,fe->btf", o, wo) + bo
+        out = jnp.einsum("bte,fe->btf", o, wo)
+        if bo is not None:
+            out = out + bo
         if is_train and p["dropout"] > 0.0:
             keep = 1.0 - p["dropout"]
             mask = jax.random.bernoulli(rng, keep, out.shape)
@@ -1377,6 +1414,254 @@ def gdn_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
         y = jnp.einsum("btv,ev->bte", o.reshape(b, c, vw).astype(x.dtype),
                        wout)
     return y, new, nxt.astype(prev.dtype)
+
+
+# -- Mamba-2: a state-space mixer in its SSD form -----------------------------
+# (Dao & Gu, arXiv:2405.21060, as Granite 4.0-H runs it.) Per head a float32
+# state S [P, N]; with ``dt_t > 0`` the head's step and ``a = -exp(A_log)``:
+#     S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T;   y_t = S_t C_t
+# (the ``D`` skip is added outside). B and C are one group's, shared by every
+# head. ``ssd_step`` is that recurrence for one position (under ``lax.scan``
+# the definition the chunked form is held to, and the decode step of a walk
+# without ``lens``); ``ssd_chunked`` is the state-space duality's chunked
+# form over a sequence; a slot walk's decode step takes
+# ``pallas_kernels.ssd_state_step``, ``ssd_step`` for the live slots alone
+# (``ssd_steps_in_place``).
+
+
+def ssd_step(state, x, b, c, dt, a):
+    """One position of the recurrence for every (batch row, head):
+    ``state`` [B, H, P, N] float32, ``x`` [B, H, P], ``b``/``c`` [B, N],
+    ``dt`` [B, H], ``a`` [H] (negative), all float32. Returns (the new
+    state, y [B, H, P]): one pass over the state, which is decayed, takes
+    the rank-one write and is read against ``c``."""
+    s = state * jnp.exp(dt * a)[..., None, None] \
+        + (dt[..., None] * x)[..., None] * b[:, None, None, :]
+    return s, jnp.sum(s * c[:, None, None, :], axis=-1)
+
+
+def ssd_sequential(state, x, b, c, dt, a):
+    """The recurrence position by position (``lax.scan`` of
+    ``ssd_step``): ``x`` [B, T, H, P], ``b``/``c`` [B, T, N], ``dt``
+    [B, T, H]. The definition; a prefill takes ``ssd_chunked``. Returns
+    (state, y [B, T, H, P])."""
+    state, y = jax.lax.scan(
+        lambda s, xs: ssd_step(s, *xs, a), state,
+        tuple(jnp.moveaxis(z, 1, 0) for z in (x, b, c, dt)))
+    return state, jnp.moveaxis(y, 0, 1)
+
+
+def ssd_chunked(state, x, b, c, dt, a, chunk=256):
+    """The same recurrence in chunks of ``chunk`` positions (the SSD
+    form). Inside a chunk, with ``l_i = sum_{j<=i} dt_j a`` per head:
+    ``y = tril(C B^T exp(l_i - l_j)) (dt x) + exp(l_i) C S`` for the
+    state ``S`` the chunk starts from, and ``S <- exp(l_C) S + sum_j
+    exp(l_C - l_j) dt_j x_j B_j^T``. Everything that does not need
+    ``S`` is computed for all chunks at once; the chunks then follow
+    each other in a ``lax.scan`` of two small products. A sequence that
+    is not whole chunks is padded with positions of ``dt = 0``, which
+    leave the state as it is. Shapes as ``ssd_sequential``; float32 at
+    full product precision."""
+    bsz, t, h, pdim = x.shape
+    n = b.shape[-1]
+    cs = min(int(chunk), t)
+    nc = -(-t // cs)
+    pad = nc * cs - t
+
+    def chunks(z):                      # [B, T, ...] -> [B, Nc, C, ...]
+        if pad:
+            z = jnp.pad(z, [(0, 0), (0, pad)] + [(0, 0)] * (z.ndim - 2))
+        return z.reshape((bsz, nc, cs) + z.shape[2:])
+
+    x, b, c, dt = (chunks(z) for z in (x, b, c, dt))
+    cum = jnp.cumsum(dt * a, axis=2)                        # [B,Nc,C,H]
+    cum = jnp.moveaxis(cum, 3, 2)                           # [B,Nc,H,C]
+    low = jnp.tril(jnp.ones((cs, cs), bool))
+    # exp only where j <= i: above the diagonal the difference is
+    # positive and may overflow
+    decay = jnp.exp(jnp.where(low, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))                    # [B,Nc,H,C,C]
+    xdt = x * dt[..., None]                                 # [B,Nc,C,H,P]
+    cb = jnp.einsum("bzis,bzjs->bzij", c, b, precision=_HI)
+    y = jnp.einsum("bzhij,bzjhp->bzihp", cb[:, :, None] * decay, xdt,
+                   precision=_HI)
+    last = cum[..., -1:]                                    # [B,Nc,H,1]
+    own = jnp.einsum("bzjs,bzhj,bzjhp->bzhps", b, jnp.exp(last - cum),
+                     xdt, precision=_HI)                    # [B,Nc,H,P,N]
+
+    def body(s, xs):
+        c_, cum_, last_, own_ = xs
+        off = jnp.einsum("bis,bhps->bihp", c_, s, precision=_HI) \
+            * jnp.moveaxis(jnp.exp(cum_), 1, 2)[..., None]
+        s = s * jnp.exp(last_)[..., None] + own_
+        return s, off
+
+    state, off = jax.lax.scan(
+        body, state, tuple(jnp.moveaxis(z, 1, 0)
+                           for z in (c, cum, last, own)))
+    y = y + jnp.moveaxis(off, 0, 1)                         # [B,Nc,C,H,P]
+    return state, y.reshape(bsz, nc * cs, h, pdim)[:, :t]
+
+
+@register
+class Mamba2(OpSpec):
+    """A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Granite 4.0-H's
+    ``mamba`` layers run it. data [B, T, E] (already normalized). With
+    ``H`` heads of ``P`` (``head_dim``), a state of ``N``
+    (``state_size``) and ONE group of B and C (shared by every head),
+    ``D_in = H P`` and ``F = D_in + 2N``:
+
+    - ``in_weight`` [D_in + F + H, E]: ``[z ; xBC ; dt] = W x``, no bias;
+    - ``conv_weight`` [F, kernel], ``conv_bias`` [F]: depthwise and
+      causal over ``xBC``, zeros before position 0,
+      ``xBC_t = silu(sum_j w[:, j] u_{t-(kernel-1)+j} + bias)``, then
+      split into ``x`` [H, P], ``B`` [N], ``C`` [N];
+    - ``dt = softplus(dt + dt_bias)``, ``a = -exp(a_log)`` per head
+      (``a_log``, ``dt_bias``, ``d_skip`` [H]), float32;
+    - the recurrence at the top of this section, state float32, then
+      ``y = S C + d_skip x`` per head;
+    - ``out = out_weight [rmsnorm(y silu(z)) norm_weight]``: the gate
+      comes first and the norm runs over the whole ``D_in`` (one
+      group), ``norm_weight`` [D_in], ``out_weight`` [E, D_in].
+
+    The full-sequence forward starts from the zero state and runs the
+    chunked form in chunks of ``chunk_size``; the decoder's cached form
+    (``parallel/decode.py``) keeps NO rows: per sequence the state
+    [H, P, N] and the last ``kernel - 1`` positions' ``xBC``."""
+
+    name = "Mamba2"
+    params = {"num_heads": Param("int"), "head_dim": Param("int"),
+              "state_size": Param("int"), "conv_kernel": Param("int", 4),
+              "chunk_size": Param("int", 256),
+              "eps": Param("float", 1e-5)}
+
+    def arguments(self, p):
+        return ["data", "in_weight", "conv_weight", "conv_bias", "a_log",
+                "d_skip", "dt_bias", "norm_weight", "out_weight"]
+
+    @staticmethod
+    def widths(p):
+        """(D_in, F): the inner and the convolved widths (one group of
+        B and C)."""
+        d_in = p["num_heads"] * p["head_dim"]
+        return d_in, d_in + 2 * p["state_size"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return list(in_shapes), [None], []
+        if len(d) != 3:
+            raise MXNetError("Mamba2: data must be [B, T, E]")
+        d_in, f = self.widths(p)
+        e, h = d[2], p["num_heads"]
+        want = [d, (d_in + f + h, e), (f, p["conv_kernel"]), (f,), (h,),
+                (h,), (h,), (d_in,), (e, d_in)]
+        return [shape_assign(s, w, "Mamba2 " + n)
+                for s, w, n in zip(in_shapes, want,
+                                   self.arguments(p))], [d], []
+
+    def forward(self, p, ins, aux, is_train, rng):
+        x = ins[0]
+        b = x.shape[0]
+        _, f = self.widths(p)
+        state = jnp.zeros((b, p["num_heads"], p["head_dim"],
+                           p["state_size"]), jnp.float32)
+        prev = jnp.zeros((b, p["conv_kernel"] - 1, f), x.dtype)
+        y, _, _ = mamba_mix(p, x, ins[1:], state, prev)
+        return [y], []
+
+
+def ssd_steps_in_place(p, c, lens):
+    """Whether ``mamba_mix`` advances the state of a chunk of ``c``
+    positions through ``pallas_kernels.ssd_state_step`` (the live rows'
+    states alone, where they lie), by what the code can see: ONE
+    position of a slot walk (``lens`` given), and on the chip a state of
+    whole lane tiles and heads of whole sublane tiles (the interpreter
+    takes any). Everything else (the full forward, a prefill piece,
+    offline ``generate``) runs ``ssd_step`` / ``ssd_chunked``, the
+    definition."""
+    from . import pallas_kernels as pk
+    if c != 1 or lens is None:
+        return False
+    return pk._use_interpret() or (p["state_size"] % 128 == 0
+                                   and p["head_dim"] % 8 == 0)
+
+
+def mamba_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
+    """The mixer on a chunk ``x`` [B, C, E] that continues a sequence:
+    ``state`` [B, H, P, N] float32 and ``prev`` [B, kernel-1, F], the
+    ``xBC`` of the positions before the chunk (zeros at the start of a
+    sequence). One function for the full forward and the decoder's
+    cached walk, with ``gdn_mix``'s contracts: ``real`` ([B, 1] int32,
+    optional): only the chunk's first ``real`` positions are tokens, the
+    rest padding, which leaves the state and ``prev`` of the last real
+    one (``dt = 0`` there); ``lens`` ([B] int32, optional: a slot
+    walk's): a row with ``lens`` 0 is not live and keeps its state and
+    ``prev`` untouched; ``fresh`` ([B] bool, optional): a live row that
+    starts from the ZERO state whatever ``state`` holds for it. Where
+    ``ssd_steps_in_place`` says so, nothing outside the kernel touches
+    the whole ``state``. Returns (out [B, C, E], the new state, the new
+    prev)."""
+    win_, wconv, bconv, a_log, d_skip, dt_bias, wnorm, wout = weights
+    d_in, f = Mamba2.widths(p)
+    h, pdim, n = p["num_heads"], p["head_dim"], p["state_size"]
+    b, c, _ = x.shape
+    f32 = jnp.float32
+    with jax.named_scope("proj"):
+        zxd = jnp.einsum("bte,fe->btf", x, win_)
+        z, u, dt = zxd[..., :d_in], zxd[..., d_in:d_in + f], \
+            zxd[..., d_in + f:]
+    with jax.named_scope("conv"):
+        win = jnp.concatenate([prev.astype(u.dtype), u], axis=1)
+        wf = wconv.astype(f32)
+        taps = wconv.shape[1]
+        cv = jax.nn.silu(sum(wf[:, j] * win[:, j:j + c].astype(f32)
+                             for j in range(taps)) + bconv.astype(f32))
+        # the window the NEXT chunk starts from: the last kernel-1
+        # real positions (of ``prev`` too, where the chunk holds fewer)
+        if real is None:
+            nxt = win[:, c:]
+        else:
+            at = real + jnp.arange(taps - 1, dtype=jnp.int32)
+            nxt = jnp.take_along_axis(win, at[..., None], axis=1)
+        xs = cv[..., :d_in].reshape(b, c, h, pdim)
+        bb, cc = cv[..., d_in:d_in + n], cv[..., d_in + n:]
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        if real is not None:
+            pad = (jnp.arange(c, dtype=jnp.int32) >= real)[..., None]
+            dt = jnp.where(pad, 0.0, dt)
+        a = -jnp.exp(a_log.astype(f32))
+    live = None if lens is None else jnp.asarray(lens, jnp.int32) > 0
+    with jax.named_scope("state"):
+        if ssd_steps_in_place(p, c, lens):
+            from .pallas_kernels import ssd_state_step
+            new, y = ssd_state_step(
+                state, xs[:, 0], bb[:, 0], cc[:, 0], dt[:, 0], a, lens,
+                jnp.zeros((b,), bool) if fresh is None else fresh)
+            y = y[:, None]
+        else:
+            start = state if fresh is None else \
+                jnp.where(fresh[:, None, None, None], 0, state)
+            if c == 1:
+                new, y = ssd_step(start, xs[:, 0], bb[:, 0], cc[:, 0],
+                                  dt[:, 0], a)
+                y = y[:, None]
+            else:
+                new, y = ssd_chunked(start, xs, bb, cc, dt, a,
+                                     p.get("chunk_size", 256))
+            if live is not None:
+                new = jnp.where(live[:, None, None, None], new, state)
+        if live is not None:
+            nxt = jnp.where(live[:, None, None], nxt,
+                            prev.astype(nxt.dtype))
+    with jax.named_scope("norm"):
+        y = (y + d_skip.astype(f32)[:, None] * xs).reshape(b, c, d_in) \
+            * jax.nn.silu(z.astype(f32))
+        ms = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+        y = y * jax.lax.rsqrt(ms + p["eps"]) * wnorm.astype(f32)
+    with jax.named_scope("proj"):
+        out = jnp.einsum("btv,ev->bte", y.astype(x.dtype), wout)
+    return out, new, nxt.astype(prev.dtype)
 
 
 # -- gated softmax attention -------------------------------------------------

@@ -1674,7 +1674,9 @@ def _latent_prefill_call(at, qn, qr, kn, kr, vt, m, l, acc, *, scale,
 # slot parked on the block just visited, its body gated off), with blocks
 # of heads where they have blocks of rows; what differs is that the block
 # is an ALIASED operand, fetched once, advanced in fast memory and written
-# back where it lay. A dead slot's state is neither read nor written.
+# back where it lay. A dead slot's state is neither read nor written. A
+# Mamba-2 layer's state [H, P, N] (2 MB at 64 heads of 64 x 128) takes the
+# same walk with the SSD update in the body (``ssd_state_step``).
 
 # a block of heads' state in flight holds at most this many bytes (two in
 # and two out are in flight: 8 MB at the cap)
@@ -1816,6 +1818,135 @@ def gdn_state_step(state, q, k, v, beta, g, lens, fresh, *, block_h=None,
                                      100 << 20))),
         interpret=interpret)
     return new, o
+
+
+def _ssd_state_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, fresh_ref,
+                      x_ref, dec_ref, bc_ref, s_ref, o_ref, so_ref, *,
+                      heads):
+    """One (slot visit, block of heads) grid cell of the SSD update.
+    ``x_ref`` [1, hb, P] holds the block's ``dt x`` rows, ``dec_ref``
+    [1, hb, N] each head's decay ``exp(dt a)`` spread over N, ``bc_ref``
+    [1, 2, N] the slot's B and C rows (one group, every head's);
+    ``s_ref`` / ``so_ref`` [1, hb, P, N] are the same block of the
+    state, before and after. Per head, with P on the sublanes and N on
+    the lanes: ``x`` is wanted as a COLUMN (one transpose of the block's
+    rows gives them all), B and C as rows, and the head's ``S C`` comes
+    out as a column, gathered into the block's columns and turned once.
+    Float32 on the vector unit throughout: exact products, the sum over
+    N added up."""
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    f32 = jnp.float32
+    live = j < nkb_ref[i]
+
+    @pl.when(live)
+    def _advance():
+        zero = fresh_ref[order_ref[i]] != 0
+        cols = x_ref[0].T                                   # [P, hb]
+        dec = dec_ref[0]                                    # [hb, N]
+        brow = bc_ref[0, 0:1]                               # [1, N]
+        crow = bc_ref[0, 1:2]
+        lane = lax.broadcasted_iota(jnp.int32, cols.shape, 1)
+        y = jnp.zeros(cols.shape, f32)
+        for h in range(heads):
+            sd = jnp.where(zero, 0.0, s_ref[0, h] * dec[h:h + 1])
+            sd = sd + cols[:, h:h + 1] * brow
+            so_ref[0, h] = sd
+            y = jnp.where(lane == h,
+                          jnp.sum(sd * crow, axis=1, keepdims=True), y)
+        o_ref[0] = y.T
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+        # as in ``_gdn_state_kernel``: with no slot live every step parks
+        # on the first block, which must be written back as it was
+        @pl.when(i == 0)
+        def _keep():
+            so_ref[...] = s_ref[...]
+
+
+def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
+                   interpret=None):
+    """One decode position of the Mamba-2 (SSD) recurrence for the slots
+    that hold a request, the state advanced where it lies.
+
+    state: [S, H, P, N] float32, ALIASED to the first result (donate it,
+    or the caller's copy is XLA's). x: [S, H, P]; b, c: [S, N] (one
+    group, every head's); dt: [S, H]; a: [H] (negative); all float32.
+    lens: [S] int32, 0 for a slot that holds no request: its state is
+    neither read nor written and its output is zeros. fresh: [S] bool, a
+    live slot that starts from the ZERO state whatever it held. Returns
+    (the state, y [S, H, P]), ``ops.attention.ssd_step``'s for the live
+    slots up to the order of its sums (the ``D`` skip is the caller's).
+
+    ``gdn_state_step``'s walk: grid (slot visit, block of heads) under a
+    ``PrefetchScalarGridSpec`` with ``paged_attention``'s visit order
+    (``_paged_visit``), a live slot's blocks fetched once each and
+    written back to the same place, every step of a dead slot parked on
+    the block visited last. On the chip N must be whole lane tiles
+    (128) and P whole sublane tiles; the interpreter takes any shape."""
+    if interpret is None:
+        interpret = _use_interpret()
+    s_, h, pdim, n = state.shape
+    f32, i32 = jnp.float32, jnp.int32
+    if state.dtype != f32:
+        raise ValueError("ssd_state_step: the state is float32, got %s"
+                         % state.dtype)
+    hb = default_state_block_h(h, pdim, n) if block_h is None \
+        else int(block_h)
+    if h % hb:
+        raise ValueError("ssd_state_step: block_h=%d must divide the %d "
+                         "heads" % (hb, h))
+    nb = h // hb
+    live = jnp.asarray(lens, i32) > 0
+    order, nkb, kslot, kblk = _paged_visit(live.astype(i32) * nb, nb, 1)
+    dt = dt.astype(f32)
+    xdt = dt[..., None] * x.astype(f32)                    # [S, H, P]
+    dec = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None],
+                           (s_, h, n))                     # [S, H, N]
+    bc = jnp.stack([b, c], axis=1).astype(f32)             # [S, 2, N]
+
+    def blk(i, j, order, nkb, kslot, kblk, fresh):
+        return jnp.where(nkb[i] > 0, j, kblk[i])
+
+    def smap(i, j, *pre):
+        return (pre[2][i], blk(i, j, *pre), 0, 0)
+
+    def rmap(i, j, *pre):
+        return (pre[2][i], blk(i, j, *pre), 0)
+
+    def bmap(i, j, *pre):
+        return (pre[2][i], 0, 0)
+
+    def omap(i, j, *pre):
+        return (pre[0][i], j, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(s_, nb),
+        in_specs=[pl.BlockSpec((1, hb, pdim), rmap),
+                  pl.BlockSpec((1, hb, n), rmap),
+                  pl.BlockSpec((1, 2, n), bmap),
+                  pl.BlockSpec((1, hb, pdim, n), smap)],
+        out_specs=[pl.BlockSpec((1, hb, pdim), omap),
+                   pl.BlockSpec((1, hb, pdim, n), smap)],
+    )
+    block_bytes = hb * pdim * n * 4
+    y, new = _pallas_call(
+        functools.partial(_ssd_state_kernel, heads=hb),
+        order, nkb, kslot, kblk, fresh.astype(i32), xdt, dec, bc, state,
+        out_shape=[jax.ShapeDtypeStruct((s_, h, pdim), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        grid_spec=grid_spec,
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(max(8 * block_bytes, 32 << 20),
+                                     100 << 20))),
+        interpret=interpret)
+    return new, y
 
 
 # -- fused quantized matmuls (ISSUE 17) -------------------------------

@@ -13,11 +13,11 @@ every ``CCAttention`` node likewise (its K/V rows the same, and beside
 them a short ring of the last positions' raw projections: the second
 kind of cache leaf, ``STATE_ROWS`` below), every ``GatedAttention``
 node likewise (K/V rows and nothing else), every ``GatedDeltaNet``
-node for a variant that keeps NO rows at all (a recurrent state per
-sequence: the third kind), every ``LatentAttention`` node for one that
-keeps ONE buffer of latent rows, key and value at once and without a
-head axis (the fourth kind; "THE KINDS OF CACHE ENTRY" below has all
-four side by side) and
+and every ``Mamba2`` node for a variant that keeps NO rows at all (a
+recurrent state per sequence: the third kind), every ``LatentAttention``
+node for one that keeps ONE buffer of latent rows, key and value at
+once and without a head axis (the fourth kind; "THE KINDS OF CACHE
+ENTRY" below has all four side by side) and
 ``PositionalEmbedding`` sliced at the current position. Every other LM op
 (Embedding, LayerNorm, RMSNorm, FullyConnected, activations, elementwise
 arithmetic, ResidualMerge, MoEFFN, the hyper-connection's two halves and
@@ -66,7 +66,9 @@ _POSITIONWISE = {
 }
 # handled specially
 _TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention",
-             "GatedAttention", "GatedDeltaNet", "LatentAttention"}
+             "GatedAttention", "GatedDeltaNet", "Mamba2", "LatentAttention"}
+# the nodes whose entry is the state kind: no rows, a recurrent state
+_STATE_NODES = ("GatedDeltaNet", "Mamba2")
 
 # THE KINDS OF CACHE ENTRY. One entry per cached node, a tuple of leaves
 # whose axis 0 is the batch row / serving slot:
@@ -85,6 +87,9 @@ _TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention",
 # STATE        |                       | + the last kernel-1 positions' | that holds no request      | starts from zeros,       | state (refuse_rolling_state)
 #              |                       | convolution inputs, flat (2);  | (``lens`` 0) leaves it     | whatever the slot held   |
 #              |                       | NO rows                        | untouched                  |                          |
+#              | Mamba2                | S [B, H, P, N] float32 (4) +   | as GatedDeltaNet's         | as GatedDeltaNet's       | as GatedDeltaNet's
+#              |                       | the last kernel-1 positions'   |                            |                          |
+#              |                       | xBC, flat (2); NO rows         |                            |                          |
 # LATENT ROWS  | LatentAttention       | ONE buffer [B, rows, R + Dr]   | rewrites its row with the  | nothing: as K/V rows     | int8 rows, quantized
 #              |                       | (3): ``[c ; k_r]`` after norm  | same values (idempotent)   |                          | weights, tp (no head axis
 #              |                       | and rotation, key and value at |                            |                          | to shard), the prefix pool,
@@ -104,22 +109,23 @@ _TEMPORAL = {"MultiHeadAttention", "PositionalEmbedding", "CCAttention",
 # no clearing.
 STATE_ROWS = 3
 
-# THE STATE KIND: a GatedDeltaNet node's cache entry holds no rows. Per
-# sequence it is (S [Hv, Dk, Dv] float32, the recurrence's matrix state
-# whatever the compute dtype; the last ``kernel - 1`` positions'
-# convolution inputs, flat [(kernel-1) * F] in the cache dtype, oldest
-# first). Nothing re-derives it from a few positions, so the three
-# contracts that rows (and CCAttention's ring) lean on do not hold and
-# it has its own:
+# THE STATE KIND: a GatedDeltaNet or a Mamba2 node's cache entry holds no
+# rows. Per sequence it is (S, the recurrence's matrix state in float32
+# whatever the compute dtype: [Hv, Dk, Dv] for the delta rule, [H, P, N]
+# for the SSD; the last ``kernel - 1`` positions' convolution inputs,
+# flat [(kernel-1) * F] in the cache dtype, oldest first). Nothing
+# re-derives it from a few positions, so the three contracts that rows
+# (and CCAttention's ring) lean on do not hold and it has its own:
 # - a decode step ADVANCES the state, so re-running one is not
 #   idempotent: a batch row that holds no request (``lens`` 0 in the
 #   slot walk: a finished slot, one parked between prefill pieces)
 #   leaves its state exactly as it was. A decode step of the slot walk
 #   (one position) does not even read it: the states advance through
-#   ``pallas_kernels.gdn_state_step``, which visits live slots first,
-#   fetches a live slot's state once, writes it back where it lay (the
-#   leaf is the kernel's aliased operand) and parks every step of a
-#   dead slot on the block visited last, body gated off; nothing
+#   ``pallas_kernels.gdn_state_step`` (``ssd_state_step`` for the SSD),
+#   which visits live slots first, fetches a live slot's state once,
+#   writes it back where it lay (the leaf is the kernel's aliased
+#   operand) and parks every step of a dead slot on the block visited
+#   last, body gated off; nothing
 #   outside the kernel takes the whole leaf. With NO slot live every
 #   step parks on the first block, which is copied through unchanged
 #   (a parked block is written back once, at the end);
@@ -129,7 +135,8 @@ STATE_ROWS = 3
 #   not a pass over the leaf);
 # - a right-padded chunk leaves the state of its last REAL token
 #   (``valid_len``): padding runs the recurrence with ``beta = 0,
-#   g = 0`` and does not enter the convolution's window.
+#   g = 0`` (the SSD: ``dt = 0``) and does not enter the convolution's
+#   window.
 # What cannot carry such a leaf refuses by name
 # (``Decoder.refuse_rolling_state``): int8 rows, quantized weights, tp,
 # the prefix pool, the KV handoff, speculation.
@@ -288,6 +295,7 @@ class Decoder:
         self._mha = []      # MultiHeadAttention nodes
         self._cca = []      # CCAttention nodes (K/V rows + rolling state)
         self._gdn = []      # GatedDeltaNet nodes (a state, no rows)
+        self._ssm = []      # Mamba2 nodes (a state, no rows)
         self._mla = []      # LatentAttention nodes (latent rows)
         self._cached = []   # all, in graph order: one cache entry each
         for n in self._topo:
@@ -309,6 +317,9 @@ class Decoder:
                 self._cached.append(n)
             elif name == "GatedDeltaNet":
                 self._gdn.append(n)
+                self._cached.append(n)
+            elif name == "Mamba2":
+                self._ssm.append(n)
                 self._cached.append(n)
             elif name == "LatentAttention":
                 self._mla.append(n)
@@ -465,9 +476,9 @@ class Decoder:
     @property
     def has_state(self):
         """Whether a cached node keeps a per-sequence state leaf (a
-        CCAttention's ring, a GatedDeltaNet's recurrent state) beside
-        or instead of rows."""
-        return bool(self._cca or self._gdn)
+        CCAttention's ring, a GatedDeltaNet's or a Mamba2's recurrent
+        state) beside or instead of rows."""
+        return bool(self._cca or self._gdn or self._ssm)
 
     def refuse_rolling_state(self, feature):
         """Raise for a feature that cannot carry a per-sequence state
@@ -475,12 +486,18 @@ class Decoder:
         saying what its state is: never a silent wrong answer (the
         decoder's own options, and the serving engine's)."""
         node = next(n for n in self._cached
-                    if n.spec.name in ("CCAttention", "GatedDeltaNet"))
+                    if n.spec.name in ("CCAttention",) + _STATE_NODES)
+        p = node.params
         if node.spec.name == "CCAttention":
             what = ("its rolling state, the last %d positions' [u ; v2] "
                     "per sequence beside its K/V rows," % STATE_ROWS)
+        elif node.spec.name == "Mamba2":
+            what = ("its recurrent state, a float32 SSD state [%d, %d, %d] "
+                    "and the last %d positions' convolution inputs per "
+                    "sequence, which no rows re-derive,"
+                    % (p["num_heads"], p["head_dim"], p["state_size"],
+                       p["conv_kernel"] - 1))
         else:
-            p = node.params
             what = ("its recurrent state, a float32 matrix [%d, %d, %d] "
                     "and the last %d positions' convolution inputs per "
                     "sequence, which no rows re-derive,"
@@ -594,7 +611,9 @@ class Decoder:
         head axis; see ``STATE_ROWS``). A GatedAttention node gets K
         and V rows. A GatedDeltaNet node gets NO rows: (S
         [B, Hv, Dk, Dv] float32, [B, (kernel-1) * F] convolution
-        inputs): "THE STATE KIND" at the top of this module.
+        inputs), a Mamba2 node likewise (S [B, H, P, N] float32, its
+        [B, (kernel-1) * F]): "THE STATE KIND" at the top of this
+        module.
 
         ``kv_sharding`` (optional ``jax.sharding.NamedSharding`` whose
         spec names dimension 2, e.g.
@@ -608,9 +627,10 @@ class Decoder:
         ``_run_slots``'s ``tp=`` axis."""
         from ..ops.attention import CCAttention as _CCA
         from ..ops.attention import GatedDeltaNet as _GDN
+        from ..ops.attention import Mamba2 as _SSM
         from ..ops.attention import MultiHeadAttention as _MHA
 
-        if kv_sharding is not None and self._gdn:
+        if kv_sharding is not None and (self._gdn or self._ssm):
             self.refuse_rolling_state("init_cache(kv_sharding=...)")
         if kv_sharding is not None and self._mla:
             self.refuse_latent_rows("init_cache(kv_sharding=...)")
@@ -630,6 +650,14 @@ class Decoder:
                               jnp.float32),
                     jnp.zeros((batch_size, (p["conv_kernel"] - 1)
                                * _GDN.widths(p)[2]), self._cache_dtype)))
+                continue
+            if n.spec.name == "Mamba2":
+                p = n.params
+                caches.append((
+                    jnp.zeros((batch_size, p["num_heads"], p["head_dim"],
+                               p["state_size"]), jnp.float32),
+                    jnp.zeros((batch_size, (p["conv_kernel"] - 1)
+                               * _SSM.widths(p)[1]), self._cache_dtype)))
                 continue
             if n.spec.name == "CCAttention":
                 # K and V rows in the stored layout, and the rolling
@@ -798,7 +826,7 @@ class Decoder:
                      for buf in entry)
 
     @jax.named_scope("attend")
-    def _lane_attn(self, q, entry, pos, kv):
+    def _lane_attn(self, q, entry, pos, kv, scale=None):
         """Attention of a SHORT query chunk (decode ``c=1``, the
         speculative verify and draft chunks) read straight off the
         stored rows: no reshape of the cache, so nothing cache-sized is
@@ -824,7 +852,8 @@ class Decoder:
         kv head into the row axis (row = (c, g)), the group fold of
         the per-head path. int8 caches apply the row scales to the
         [L, Hkv] scores and to ``p`` — exact, and cheaper than
-        dequantizing the rows."""
+        dequantizing the rows. ``scale`` multiplies the scores (None:
+        ``1/sqrt(D)``)."""
         b, c, h, d = q.shape
         g = h // kv
         if self._cache_int8:
@@ -845,7 +874,7 @@ class Decoder:
                        preferred_element_type=f32)
         if ks is not None:
             s = s * ks[:, None]
-        s = s * f32(1.0 / float(np.sqrt(d)))
+        s = s * f32(1.0 / float(np.sqrt(d)) if scale is None else scale)
         kpos = jnp.arange(rows)[None, None, :, None]
         if jnp.ndim(pos) == 1:
             pos = jnp.asarray(pos, jnp.int32)[:, None, None, None]
@@ -860,7 +889,8 @@ class Decoder:
         return o.reshape(b, c, g, kv, d).transpose(0, 1, 3, 2, 4) \
             .reshape(b, c, h, d).astype(q.dtype)
 
-    def _paged_read(self, q, entry, pos, kv, lens=None, stats=None):
+    def _paged_read(self, q, entry, pos, kv, lens=None, stats=None,
+                    scale=None):
         """Attention of a SHORT query chunk at a VECTOR of positions
         (the slot walk: decode, verify, draft) through the bounded read
         (ops/pallas_kernels.py ``paged_attention``): only the blocks of
@@ -869,7 +899,8 @@ class Decoder:
         whose output is zeros), the int8 side scales applied IN the
         kernel. ``entry`` is a linear cache entry, K/V [B, L, Hkv*D]
         as ``_lane_attn`` takes it, the chunk's rows already written.
-        ``stats["attn_rows_read"]`` grows by the rows fetched."""
+        ``stats["attn_rows_read"]`` grows by the rows fetched.
+        ``scale`` multiplies the scores (None: ``1/sqrt(D)``)."""
         from ..ops.pallas_kernels import (default_paged_block_k,
                                           paged_attention,
                                           paged_rows_fetched)
@@ -883,7 +914,8 @@ class Decoder:
             if self._cache_int8 else {}
         with jax.named_scope("attend"):
             o = paged_attention(q, ck, cv, posv, kv_heads=kv,
-                                lens=lens, block_k=bk, **scales)
+                                lens=lens, block_k=bk, scale=scale,
+                                **scales)
         if stats is not None:
             stats["attn_rows_read"] = paged_rows_fetched(
                 lens, rows, bk) + stats.get("attn_rows_read", 0)
@@ -955,19 +987,23 @@ class Decoder:
         from ..ops.attention import MultiHeadAttention as _MHA
         from ..serving.quant import QuantizedTensor
 
-        x, wqkv, bqkv, wo, bo = ins
+        x, wqkv, bqkv, wo, bo = _MHA.split_inputs(node.params, ins)
         b, c, e = x.shape
         h = node.params["num_heads"]
         d = e // h
         kv = _MHA.kv_heads(node.params)
+        scale = _MHA.softmax_scale(node.params, d) \
+            if node.params.get("scale") else None
         if isinstance(wqkv, QuantizedTensor):
             # weight-only int8/int4: dequantized on the fly inside
             # the product (serving/quant.py; matmul_impl picks the
             # fori loop or the Pallas kernel) — the projection reads
             # the stored quantized stream, no float weight copy
-            qkv = self._qmm(x, wqkv, mm_impl) + bqkv
+            qkv = self._qmm(x, wqkv, mm_impl)
         else:
-            qkv = jnp.einsum("bte,fe->btf", x, wqkv) + bqkv
+            qkv = jnp.einsum("bte,fe->btf", x, wqkv)
+        if bqkv is not None:
+            qkv = qkv + bqkv
         q = qkv[..., :e].reshape(b, c, h, d)
         k = qkv[..., e:e + kv * d].reshape(b, c, kv, d)
         v = qkv[..., e + kv * d:].reshape(b, c, kv, d)
@@ -989,8 +1025,10 @@ class Decoder:
         def out_proj(o):
             o = o.reshape(b, c, e)
             if isinstance(wo, QuantizedTensor):
-                return self._qmm(o, wo, mm_impl) + bo
-            return jnp.einsum("bte,fe->btf", o, wo) + bo
+                o = self._qmm(o, wo, mm_impl)
+            else:
+                o = jnp.einsum("bte,fe->btf", o, wo)
+            return o if bo is None else o + bo
 
         if tp is not None:
             # tensor-parallel serving (inside the engine's shard_map —
@@ -1016,13 +1054,13 @@ class Decoder:
                     "position; a position vector has no ring read"
                     % node.name)
             o, entry = self._window_attn(q, k, v, entry, pos, win,
-                                         valid_len)
+                                         valid_len, scale)
             if tp is not None:
                 o = lax.all_gather(o, tp[0], axis=2, tiled=True)
             return out_proj(o), entry
         entry = self._write_cache(entry, k, v, pos)
         if jnp.ndim(pos) == 1:
-            o = self._paged_read(q, entry, pos, kv, lens, stats)
+            o = self._paged_read(q, entry, pos, kv, lens, stats, scale)
         else:
             # dense read. A STATIC dispatch position (offline
             # generate/beam prefill call _run with a python-int pos)
@@ -1037,12 +1075,12 @@ class Decoder:
                 # a matrix-vector read wants the lanes: straight off
                 # the stored rows
                 o = self._lane_attn(q, self._live_rows(entry, limit),
-                                    pos, kv)
+                                    pos, kv, scale)
             else:
                 # a matrix-matrix read (prefill) wants the heads
                 o = self._head_attn(
                     q, *self._read_cache(entry, q.dtype, kv, limit),
-                    pos)
+                    pos, scale)
         if tp is not None:
             # ONE collective per attention node: gather the per-shard
             # head outputs (axis 2 is kv-major in every o layout —
@@ -1373,20 +1411,25 @@ class Decoder:
             o = acc / jnp.maximum(l, 1e-30)
             return jnp.transpose(o, (0, 3, 1, 2))[:, :c].astype(q.dtype)
 
-    def _cached_gdn(self, node, ins, entry, pos, valid_len=None,
-                    lens=None, stats=None):
-        """GatedDeltaNet on a chunk at ``pos`` against its cache entry
-        ``(state, convolution inputs)``: the op's own mixer
-        (``ops.attention.gdn_mix``) continued from what the entry
-        holds, under the state kind's three contracts (the top of this
-        module): a batch row with ``lens`` 0 keeps its entry as it was,
-        a chunk at position 0 starts from zeros, and a right-padded
-        chunk (``valid_len``, absolute) leaves the state of its last
-        real token. ``stats["state_advanced"]`` grows by the rows whose
-        state this chunk advanced, ``stats["state_in_place"]`` by one
-        where it advanced them through the kernel that visits live rows
-        only (``ops.attention.gdn_steps_in_place``)."""
-        from ..ops.attention import gdn_mix, gdn_steps_in_place
+    def _cached_state(self, node, ins, entry, pos, valid_len=None,
+                      lens=None, stats=None):
+        """A GatedDeltaNet or a Mamba2 node on a chunk at ``pos`` against
+        its cache entry ``(state, convolution inputs)``: the op's own
+        mixer (``ops.attention.gdn_mix`` / ``mamba_mix``) continued from
+        what the entry holds, under the state kind's three contracts
+        (the top of this module): a batch row with ``lens`` 0 keeps its
+        entry as it was, a chunk at position 0 starts from zeros, and a
+        right-padded chunk (``valid_len``, absolute) leaves the state of
+        its last real token. ``stats["state_advanced"]`` grows by the
+        rows whose state this chunk advanced, ``stats["state_in_place"]``
+        by one where it advanced them through the kernel that visits
+        live rows only (``ops.attention.gdn_steps_in_place`` /
+        ``ssd_steps_in_place``)."""
+        from ..ops.attention import (gdn_mix, gdn_steps_in_place,
+                                     mamba_mix, ssd_steps_in_place)
+        mix, in_place = (mamba_mix, ssd_steps_in_place) \
+            if node.spec.name == "Mamba2" \
+            else (gdn_mix, gdn_steps_in_place)
         x = ins[0]
         b, c, _ = x.shape
         i32 = jnp.int32
@@ -1405,13 +1448,13 @@ class Decoder:
                                           - 1, -1))
         real = None if valid_len is None else \
             jnp.clip(jnp.asarray(valid_len, i32) - first, 0, c)
-        y, state, prev = gdn_mix(node.params, x, ins[1:], state, prev,
-                                 real=real, lens=lens, fresh=fresh[:, 0])
+        y, state, prev = mix(node.params, x, ins[1:], state, prev,
+                             real=real, lens=lens, fresh=fresh[:, 0])
         if stats is not None:
             n = jnp.int32(b) if live is None else jnp.sum(live, dtype=i32)
             stats["state_advanced"] = n + stats.get("state_advanced", 0)
             stats["state_in_place"] = stats.get("state_in_place", 0) \
-                + int(gdn_steps_in_place(node.params, c, lens))
+                + int(in_place(node.params, c, lens))
         return y, (state, prev.reshape(flat.shape))
 
     @staticmethod
@@ -1448,14 +1491,19 @@ class Decoder:
 
     @staticmethod
     @jax.named_scope("attend")
-    def _head_attn(q, ck, cv, pos):
+    def _head_attn(q, ck, cv, pos, scale=None):
         """Per-head matrix products of a LONG query chunk (the prefill
         buckets, offline ``generate``'s prompt) against K/V unfolded
-        to [B, rows, Hkv, D] (``_read_cache``), masked by position."""
+        to [B, rows, Hkv, D] (``_read_cache``), masked by position.
+        ``scale`` multiplies the scores (None: ``1/sqrt(D)``)."""
         b, c, h, d = q.shape
         rows, kv = ck.shape[1], ck.shape[2]
+
+        def scaled(s):
+            return s / float(np.sqrt(d)) if scale is None else s * scale
+
         if kv == h:
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, ck) / float(np.sqrt(d))
+            s = scaled(jnp.einsum("bqhd,bkhd->bhqk", q, ck))
             kpos = jnp.arange(rows)[None, None, None, :]
             qpos = pos + jnp.arange(c)[None, None, :, None]
             s = jnp.where(kpos <= qpos, s,
@@ -1466,14 +1514,15 @@ class Decoder:
         # heads fold to [B, C, Hkv, G, D] and contract against their
         # shared K/V head, no repeated copy of the rows
         qg = q.reshape(b, c, kv, h // kv, d)
-        s = jnp.einsum("bqKgd,bkKd->bKgqk", qg, ck) / float(np.sqrt(d))
+        s = scaled(jnp.einsum("bqKgd,bkKd->bKgqk", qg, ck))
         kpos = jnp.arange(rows)[None, None, None, None, :]
         qpos = pos + jnp.arange(c)[None, None, None, :, None]
         s = jnp.where(kpos <= qpos, s, jnp.float32(-1e30).astype(s.dtype))
         return jnp.einsum("bKgqk,bkKd->bqKgd",
                           jax.nn.softmax(s, axis=-1), cv)
 
-    def _window_attn(self, q, k, v, entry, pos, win, valid_len=None):
+    def _window_attn(self, q, k, v, entry, pos, win, valid_len=None,
+                     scale=None):
         """Sliding-window attention against a ring-buffer cache.
 
         EXACT for any chunk size: queries score the PRE-CHUNK ring
@@ -1517,7 +1566,8 @@ class Decoder:
         vf = to_h(v.astype(jnp.float32))
         with jax.named_scope("attend"):
             qpos = pos + jnp.arange(c)
-            scale = 1.0 / float(np.sqrt(d))
+            if scale is None:
+                scale = 1.0 / float(np.sqrt(d))
 
             s_ring = jnp.einsum("bqhd,bkhd->bhqk", qf, ckf) * scale
             cp = cpos[:, None, None, :]
@@ -1615,9 +1665,10 @@ class Decoder:
         (cache rows the bounded reads fetched, block-rounded, summed
         over the attention nodes): ``serving.attn_rows_read``;
         ``state_advanced`` (batch rows whose recurrent state a
-        GatedDeltaNet node advanced, summed over those nodes):
-        ``serving.state_slots_advanced``; ``state_in_place`` (those
-        nodes that advanced it through ``gdn_state_step``):
+        GatedDeltaNet or a Mamba2 node advanced, summed over those
+        nodes): ``serving.state_slots_advanced``; ``state_in_place``
+        (those nodes that advanced it through ``gdn_state_step`` /
+        ``ssd_state_step``):
         ``serving.state_steps_in_place``; ``latent_rows_live`` (the
         batch rows' true lengths, summed over the LatentAttention
         nodes): ``serving.latent_rows_live``.
@@ -1626,8 +1677,8 @@ class Decoder:
         batch row's cache that the bounded read may fetch — the slot
         walk's ``pos + C`` for a slot that holds a request, 0 for one
         that does not (its output is then discarded by the caller, a
-        GatedDeltaNet node leaves its state untouched: the state
-        kind's "not live", and a routed MoEFFN node gives its tokens
+        GatedDeltaNet or a Mamba2 node leaves its state untouched: the
+        state kind's "not live", and a routed MoEFFN node gives its tokens
         no expert: ``moe_ffn_math``'s ``live``)."""
         from ..ops.attention import moe_ffn_math
         from ..serving.quant import (QuantizedTensor, embedding_rows,
@@ -1666,8 +1717,8 @@ class Decoder:
                     mha_i += 1
                     env[(id(n), 0)] = out
                     continue
-                if name in ("GatedAttention", "GatedDeltaNet",
-                            "LatentAttention"):
+                if name in ("GatedAttention", "LatentAttention")\
+                        + _STATE_NODES:
                     if tp is not None:
                         raise MXNetError(
                             "Decoder: %s (node %r) has no tensor-"
@@ -1681,7 +1732,7 @@ class Decoder:
                             n, ins, new_caches[mha_i], pos, lens=lens,
                             stats=stats)
                     else:
-                        out, new_caches[mha_i] = self._cached_gdn(
+                        out, new_caches[mha_i] = self._cached_state(
                             n, ins, new_caches[mha_i], pos, valid_len,
                             lens=lens, stats=stats)
                     mha_i += 1

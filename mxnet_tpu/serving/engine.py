@@ -183,9 +183,9 @@ _TM_MOE_LAYER_STEPS = tele.counter("serving.moe_layer_steps")
 _TM_MOE_PAIRS_HELD = tele.counter("serving.moe_pairs_held")
 _TM_MOE_PAIRS_ROUTED = tele.counter("serving.moe_pairs_routed")
 _TM_MOE_ROWS_MASKED = tele.counter("serving.moe_rows_masked")
-# recurrent state (GatedDeltaNet nodes): slots whose state a decode
-# step advanced, summed on the device over those layers and steps (one
-# more column), beside slots x layers x steps, the host's product
+# recurrent state (GatedDeltaNet and Mamba2 nodes): slots whose state a
+# decode step advanced, summed on the device over those layers and steps
+# (one more column), beside slots x layers x steps, the host's product
 _TM_STATE_ADVANCED = tele.counter("serving.state_slots_advanced")
 _TM_STATE_POOL = tele.counter("serving.state_slots_pool")
 _TM_STATE_IN_PLACE = tele.counter("serving.state_steps_in_place")
@@ -912,9 +912,10 @@ class InferenceEngine:
                              "MXNET_SERVING_EP sets the default), "
                              "got %d" % ep)
         # the decoder has a state leaf (a CCAttention's rolling state
-        # beside its rows, a GatedDeltaNet's recurrent state instead of
-        # rows); every feature that cannot carry it refuses by the
-        # op's name, here and below (ROADMAP D4: no silent fallback)
+        # beside its rows, a GatedDeltaNet's or a Mamba2's recurrent
+        # state instead of rows); every feature that cannot carry it
+        # refuses by the op's name, here and below (ROADMAP D4: no silent
+        # fallback)
         rolling = decoder.has_state
         if rolling and tp > 1:
             decoder.refuse_rolling_state("tp=%d" % tp)
@@ -1373,8 +1374,9 @@ class InferenceEngine:
         self._moe_counted = len(counted)
         self._moe_pairs_step = self.slots * sum(
             n.params["top_k"] for n in counted)
-        # GatedDeltaNet layers, whose advanced slots it counts
-        self._state_layers = len(decoder._gdn)
+        # GatedDeltaNet and Mamba2 layers, whose advanced slots it
+        # counts
+        self._state_layers = len(decoder._gdn) + len(decoder._ssm)
         # LatentAttention layers, whose live rows it sums
         self._latent_layers = len(decoder._mla) \
             if decoder._slots_batched else 0

@@ -247,6 +247,9 @@ def quantized_weight_names(topo):
         if n.is_var:
             continue
         idxs = _QUANT_ARGS.get(n.spec.name, ())
+        if n.spec.name == "MultiHeadAttention" \
+                and n.params.get("no_bias"):
+            idxs = (1, 2)                  # data, qkv_weight, out_weight
         for j, (inp, _) in enumerate(n.inputs):
             if not inp.is_var:
                 continue

@@ -670,3 +670,118 @@ def test_longctx_prefill_program(longctx_engine, one_chip, monkeypatch):
             for kind, dims in re.findall(
                 r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
     assert fast and max(fast)[0] <= 64 << 20, max(fast)
+
+
+# -- Granite 4.0-H: a Mamba-2 state and K/V rows in one decode program ----
+
+@pytest.fixture(scope="module")
+def shortchat_engine():
+    """The benchmark's serve-shortchat engine at Granite-4.0-H-Micro's
+    published widths cut to 2 layers, one of each kind (a Mamba-2 layer,
+    a bias-free GQA 32/8 D=64 attention layer scaled by 1/64), a cut
+    vocabulary: 64 slots x 2048 rows, 8 steps a round, prompts in one
+    bucket of up to 1,024. Weights are zeros: only shapes reach the
+    compiler."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_granite_hybrid_lm
+    sym = get_granite_hybrid_lm(
+        8192, ["mamba", "attention"], 2048, num_heads=32, num_kv_heads=8,
+        mlp_hidden=8192, mamba_heads=64, mamba_head_dim=64, mamba_state=128,
+        chunk_size=256, embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=1.0 / 64, logits_scaling=8.0)
+    shapes = {"data": (1, 8), "softmax_label": (1, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    params = {n: jnp.zeros(s, BF16)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    dec = mx.parallel.Decoder(sym, params, max_len=2048,
+                              compute_dtype="bfloat16")
+    eng = mx.serving.InferenceEngine(
+        dec, slots=64, prefill_buckets=(128, 512, 1024),
+        steps_per_round=8, prefix_cache_mb=0, prefill_chunk=0)
+    yield eng
+    eng.close()
+
+
+def test_ssd_state_step(compile_for_chip):
+    """The decode step's SSD update alone at the cell's shapes (a float32
+    leaf of 64 slots x 64 heads of 64 x 128, a slot's whole 2 MB state a
+    block): the in-kernel transposes of a block's x rows and of its
+    output columns and the sums over the lanes are what interpret mode
+    cannot see. The leaf is the call's aliased operand."""
+    s, h, p, n = 64, 64, 64, 128
+    assert pk.default_state_block_h(h, p, n) == h
+
+    def step(state, x, b, c, dt, a, lens, fresh):
+        return pk.ssd_state_step(state, x, b, c, dt, a, lens, fresh,
+                                 interpret=False)
+
+    text = compile_for_chip(
+        step, ((s, h, p, n), F32), ((s, h, p), F32), *[((s, n), F32)] * 2,
+        ((s, h), F32), ((h,), F32), ((s,), I32), ((s,), jnp.bool_))
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "output_to_operand_aliasing" in call, call[:300]
+
+
+def test_shortchat_decode_program(shortchat_engine, one_chip, monkeypatch):
+    """The decode program of the cell's two kinds of layer, compiled for
+    the chip: the attention layer's read is the bounded kernel and the
+    Mamba-2 layer's state step the kernel that visits live slots only
+    (two kernels in the step's body); the 134 MB float32 state leaf is
+    updated where it lies: no ``copy`` of it, not staged through fast
+    memory, the temporaries under a tenth of the caches, and NO fusion
+    takes the leaf as an operand."""
+    eng = shortchat_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    args = _abstract([eng._params, eng._aux, eng._caches, eng._state],
+                     one_chip)
+    compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    state = eng._caches[0][0]
+    assert state.shape == (64, 64, 64, 128) and state.dtype == F32
+    dims = ",".join(str(d) for d in state.shape)
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert dims not in copies, copies
+    staged = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"\b(copy-start|copy-done|slice-start)\(", line)
+              and re.search(r"f32\[[\d,]*64,64,128\]\{[^}]*S\(1\)", line)]
+    assert not staged, staged
+    leaf = "f32[%s]" % dims
+    fused = [line.strip()[:200] for line in text.splitlines()
+             if leaf in line
+             and re.search(r"fused_computation|\bfusion\(", line)]
+    assert not fused, fused
+    cache_bytes = sum(x.nbytes for x in
+                      jax.tree_util.tree_leaves(eng._caches))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.1 * cache_bytes
+
+
+def test_shortchat_prefill_program(shortchat_engine, one_chip, monkeypatch):
+    """The largest prefill program of the cell (the 1,024-token bucket),
+    compiled for the chip: the chunked SSD in four chunks of 256 and the
+    flash read against 2,048 rows compile, the temporaries stay under
+    0.5 GB and no buffer the compiler keeps in fast memory is over 64
+    MiB of the chip's 128."""
+    from mxnet_tpu.serving.engine import _raw_key
+    eng = shortchat_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    bucket = 1024
+    fn = eng._prefill_fn(bucket)
+    i32 = np.int32
+    args = [eng._params, eng._aux, eng._caches, eng._state, i32(0),
+            jnp.zeros((1, bucket), I32), i32(0), i32(bucket),
+            np.bool_(True), np.float32(0), _raw_key(0), i32(-1), i32(8)]
+    compiled = jax.jit(fn, donate_argnums=(2, 3)) \
+        .lower(*_abstract(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    fast = [(int(np.prod([int(d) for d in dims.split(",")])) * width[kind],
+             kind, dims)
+            for kind, dims in re.findall(
+                r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
+    assert not fast or max(fast)[0] <= 64 << 20, max(fast)
