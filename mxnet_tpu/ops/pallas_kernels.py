@@ -1822,43 +1822,55 @@ def gdn_state_step(state, q, k, v, beta, g, lens, fresh, *, block_h=None,
 
 def _ssd_state_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, fresh_ref,
                       x_ref, dec_ref, bc_ref, s_ref, o_ref, so_ref, *,
-                      heads):
+                      heads, pack):
     """One (slot visit, block of heads) grid cell of the SSD update.
     ``x_ref`` [1, hb, P] holds the block's ``dt x`` rows, ``dec_ref``
     [1, hb, N] each head's decay ``exp(dt a)`` spread over N, ``bc_ref``
     [1, 2, N] the slot's B and C rows (one group, every head's);
     ``s_ref`` / ``so_ref`` [1, hb, P, N] are the same block of the
-    state, before and after. Per head, with P on the sublanes and N on
-    the lanes: ``x`` is wanted as a COLUMN (one transpose of the block's
-    rows gives them all), B and C as rows, and the head's ``S C`` comes
-    out as a column, gathered into the block's columns and turned once.
-    Float32 on the vector unit throughout: exact products, the sum over
-    N added up."""
+    state, before and after, P on the sublanes and N on the lanes;
+    ``o_ref`` [1, hb / pack, pack P] is the block's ``y``, ``pack``
+    heads to a row. Per head ``x`` is wanted as a COLUMN (one transpose
+    of the block's rows gives them all) and B as a row. The read
+    against C is made for ``pack`` heads at once: their products with C
+    [pack P, N] are turned, so that the sum over N runs down the
+    sublanes (vector adds) and lands lane-dense in one row of ``y``; a
+    sum over the lanes would end every head in cross-lane reductions. A
+    slot that starts from zeros takes a branch of its own, which reads
+    none of its stale state. Float32 on the vector unit throughout:
+    exact products, the sum over N added up."""
     i = pl.program_id(0)
     j = pl.program_id(1)
-    f32 = jnp.float32
     live = j < nkb_ref[i]
+    fresh = fresh_ref[order_ref[i]] != 0
 
-    @pl.when(live)
-    def _advance():
-        zero = fresh_ref[order_ref[i]] != 0
+    def advance(zero):
         cols = x_ref[0].T                                   # [P, hb]
         dec = dec_ref[0]                                    # [hb, N]
         brow = bc_ref[0, 0:1]                               # [1, N]
         crow = bc_ref[0, 1:2]
-        lane = lax.broadcasted_iota(jnp.int32, cols.shape, 1)
-        y = jnp.zeros(cols.shape, f32)
-        for h in range(heads):
-            sd = jnp.where(zero, 0.0, s_ref[0, h] * dec[h:h + 1])
-            sd = sd + cols[:, h:h + 1] * brow
-            so_ref[0, h] = sd
-            y = jnp.where(lane == h,
-                          jnp.sum(sd * crow, axis=1, keepdims=True), y)
-        o_ref[0] = y.T
+        for r in range(heads // pack):
+            read = []
+            for h in range(r * pack, (r + 1) * pack):
+                sd = cols[:, h:h + 1] * brow
+                if not zero:
+                    sd = s_ref[0, h] * dec[h:h + 1] + sd
+                so_ref[0, h] = sd
+                read.append(sd * crow)
+            o_ref[0, r:r + 1] = jnp.sum(
+                jnp.concatenate(read, axis=0).T, axis=0, keepdims=True)
+
+    @pl.when(live & fresh)
+    def _start():
+        advance(True)
+
+    @pl.when(live & jnp.logical_not(fresh))
+    def _advance():
+        advance(False)
 
     @pl.when(jnp.logical_not(live))
     def _dead():
-        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
         # as in ``_gdn_state_kernel``: with no slot live every step parks
         # on the first block, which must be written back as it was
@@ -1885,8 +1897,11 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
     ``PrefetchScalarGridSpec`` with ``paged_attention``'s visit order
     (``_paged_visit``), a live slot's blocks fetched once each and
     written back to the same place, every step of a dead slot parked on
-    the block visited last. On the chip N must be whole lane tiles
-    (128) and P whole sublane tiles; the interpreter takes any shape."""
+    the block visited last. The kernel writes ``y`` ``pack`` heads to a
+    row of ``pack P`` lanes (the most heads of the block whose rows fit
+    128 lanes: two at P = 64), turned back to [S, H, P] here. On the
+    chip N must be whole lane tiles (128) and P whole sublane tiles; the
+    interpreter takes any shape."""
     if interpret is None:
         interpret = _use_interpret()
     s_, h, pdim, n = state.shape
@@ -1900,6 +1915,8 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
         raise ValueError("ssd_state_step: block_h=%d must divide the %d "
                          "heads" % (hb, h))
     nb = h // hb
+    pack = max(k for k in range(1, hb + 1)
+               if hb % k == 0 and (k == 1 or k * pdim <= 128))
     live = jnp.asarray(lens, i32) > 0
     order, nkb, kslot, kblk = _paged_visit(live.astype(i32) * nb, nb, 1)
     dt = dt.astype(f32)
@@ -1907,6 +1924,20 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
     dec = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None],
                            (s_, h, n))                     # [S, H, N]
     bc = jnp.stack([b, c], axis=1).astype(f32)             # [S, 2, N]
+    new, y = _ssd_state_call(order, nkb, kslot, kblk, fresh.astype(i32),
+                             xdt, dec, bc, state, hb=hb, pack=pack,
+                             interpret=interpret)
+    return new, y.reshape(s_, h, pdim)
+
+
+# one trace and one lowering of the kernel for every Mamba-2 layer of a
+# program: the layers' calls are the same shapes
+@functools.partial(jax.jit, static_argnames=("hb", "pack", "interpret"))
+def _ssd_state_call(order, nkb, kslot, kblk, fresh, xdt, dec, bc, state, *,
+                    hb, pack, interpret):
+    s_, h, pdim, n = state.shape
+    nb = h // hb
+    f32 = jnp.float32
 
     def blk(i, j, order, nkb, kslot, kblk, fresh):
         return jnp.where(nkb[i] > 0, j, kblk[i])
@@ -1930,14 +1961,14 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
                   pl.BlockSpec((1, hb, n), rmap),
                   pl.BlockSpec((1, 2, n), bmap),
                   pl.BlockSpec((1, hb, pdim, n), smap)],
-        out_specs=[pl.BlockSpec((1, hb, pdim), omap),
+        out_specs=[pl.BlockSpec((1, hb // pack, pack * pdim), omap),
                    pl.BlockSpec((1, hb, pdim, n), smap)],
     )
     block_bytes = hb * pdim * n * 4
     y, new = _pallas_call(
-        functools.partial(_ssd_state_kernel, heads=hb),
-        order, nkb, kslot, kblk, fresh.astype(i32), xdt, dec, bc, state,
-        out_shape=[jax.ShapeDtypeStruct((s_, h, pdim), f32),
+        functools.partial(_ssd_state_kernel, heads=hb, pack=pack),
+        order, nkb, kslot, kblk, fresh, xdt, dec, bc, state,
+        out_shape=[jax.ShapeDtypeStruct((s_, h // pack, pack * pdim), f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
         grid_spec=grid_spec,
         input_output_aliases={8: 1},

@@ -706,9 +706,11 @@ def shortchat_engine():
 def test_ssd_state_step(compile_for_chip):
     """The decode step's SSD update alone at the cell's shapes (a float32
     leaf of 64 slots x 64 heads of 64 x 128, a slot's whole 2 MB state a
-    block): the in-kernel transposes of a block's x rows and of its
-    output columns and the sums over the lanes are what interpret mode
-    cannot see. The leaf is the call's aliased operand."""
+    block): the in-kernel transpose of a block's x rows, two heads'
+    [64, 128] products with C joined and turned to [128, 128], and the
+    sum down the sublanes into one 128-lane row of y are what interpret
+    mode cannot see. y leaves the kernel lane-dense ([64, 32, 128]), and
+    the leaf is the call's aliased operand."""
     s, h, p, n = 64, 64, 64, 128
     assert pk.default_state_block_h(h, p, n) == h
 
@@ -722,6 +724,7 @@ def test_ssd_state_step(compile_for_chip):
     call = next(line for line in text.splitlines()
                 if "tpu_custom_call" in line and "custom-call(" in line)
     assert "output_to_operand_aliasing" in call, call[:300]
+    assert "f32[64,32,128]" in call.split("custom-call(")[0], call[:300]
 
 
 def test_shortchat_decode_program(shortchat_engine, one_chip, monkeypatch):

@@ -199,12 +199,18 @@ def test_padding_leaves_the_ssd_state(real):
 # -- the decode step's kernel: live slots only, in place ---------------------
 
 _STEP_CASES = {
-    # lens of the slots, the slots flagged "starts at position 0"
+    # lens of the slots, the slots flagged "starts at position 0", and
+    # the widths (heads, P, N) where they are not (4, 8, 16)
     "some_live": ([7, 0, 0, 3, 0, 11], []),
     "none_live": ([0, 0, 0, 0, 0, 0], [2]),
     "all_live": ([5, 1, 9, 2, 64, 3], []),
     "position_0": ([1, 0, 1, 8, 0, 0], [0, 2, 4]),
     "not_live_first": ([0, 0, 4, 0, 6, 2], [4]),
+    # three live slots of seven; six heads of 16, so y's rows hold six
+    # heads (96 lanes) or, in blocks of two, two
+    "odd_live": ([0, 5, 0, 2, 9, 0, 0], [3], (6, 16, 32)),
+    # the cell's P and N: two heads of 64 to each 128-lane row of y
+    "two_heads_a_row": ([4, 0, 7, 1, 0, 2], [3], (4, 64, 128)),
 }
 
 
@@ -214,16 +220,17 @@ def test_ssd_state_step_kernel_is_the_recurrence_for_the_live_slots(
         case, block_h):
     """``ssd_state_step`` against ``ssd_step`` on the same inputs: a live
     slot's state and output are the definition's, one flagged as
-    starting at position 0 starts from zeros whatever it held, and a
-    slot that is not live comes back bit for bit with zeros for an
-    output, in the step with no live slot too and whatever the order of
-    the lengths."""
-    lens, zeroed = _STEP_CASES[case]
+    starting at position 0 starts from zeros whatever it held (``inf``
+    included), and a slot that is not live comes back bit for bit with
+    zeros for an output, in the step with no live slot too and whatever
+    the order of the lengths, the widths or the heads to a row of y."""
+    lens, zeroed, *widths = _STEP_CASES[case]
+    h, p, n = widths[0] if widths else (4, 8, 16)
     lens = np.asarray(lens, np.int32)
     fresh = np.zeros(len(lens), bool)
     fresh[zeroed] = True
-    s0, x, b, c, dt, a = _ssd_inputs(1, seed=len(case), b=len(lens), h=4,
-                                     p=8, n=16)
+    s0, x, b, c, dt, a = _ssd_inputs(1, seed=len(case), b=len(lens), h=h,
+                                     p=p, n=n)
     x, b, c, dt = x[:, 0], b[:, 0], c[:, 0], dt[:, 0]
     live = lens > 0
     start = jnp.where((fresh & live)[:, None, None, None], 0, s0)
@@ -239,10 +246,31 @@ def test_ssd_state_step_kernel_is_the_recurrence_for_the_live_slots(
     assert np.array_equal(got_s[~live], np.asarray(s0)[~live])
     assert not got_y[~live].any()
     if fresh[live].any():       # zeros, not the old state times its decay
-        dirty = s0.at[np.flatnonzero(fresh & live)[0]].set(jnp.inf)
-        again, _ = pk.ssd_state_step(dirty, x, b, c, dt, a,
-                                     jnp.asarray(lens), jnp.asarray(fresh))
+        dirty = s0.at[np.flatnonzero(fresh & live)].set(jnp.inf)
+        again, y_again = pk.ssd_state_step(
+            dirty, x, b, c, dt, a, jnp.asarray(lens), jnp.asarray(fresh),
+            block_h=block_h)
         assert np.array_equal(np.asarray(again)[live], got_s[live])
+        assert np.array_equal(np.asarray(y_again)[live], got_y[live])
+
+
+def _kernel_calls(jaxpr):
+    """The ``pallas_call``s a program makes, a jaxpr that it calls (a
+    kernel behind a ``jit`` of its own, traced once for every layer)
+    counted at each call."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += 1
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, ClosedJaxpr):
+                    n += _kernel_calls(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    n += _kernel_calls(sub)
+    return n
 
 
 def test_state_step_kernel_runs_where_a_slot_walk_steps_one_position(
@@ -255,10 +283,9 @@ def test_state_step_kernel_runs_where_a_slot_walk_steps_one_position(
         caches = decoder.init_cache(2)
         pos = jnp.asarray([8, 8], jnp.int32)
         toks = jnp.zeros((2, chunk), jnp.int32)
-        text = str(jax.make_jaxpr(lambda c: decoder._run_slots(
+        return _kernel_calls(jax.make_jaxpr(lambda c: decoder._run_slots(
             decoder._params, decoder._aux, c, pos, toks, lens=lens))(
-                caches))
-        return text.count("pallas_call")
+                caches).jaxpr)
 
     lens = jnp.asarray([9, 0], jnp.int32)
     others = calls(1, None)             # the attention layer's read
