@@ -238,7 +238,7 @@
 
 #' A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Granite 4.0-Hs
 #'
-#' Parameters: chunk_size (int, default 256); conv_kernel (int, default 4); eps (float, default 1e-05); head_dim (int); num_heads (int); state_size (int)
+#' Parameters: chunk_size (int, default 256); conv_kernel (int, default 4); eps (float, default 1e-05); head_dim (int); n_groups (int, default 1); num_heads (int); state_size (int)
 #' Symbol inputs: data, in_weight, conv_weight, conv_bias, a_log, d_skip, dt_bias, norm_weight, out_weight
 #' @export
 `mx.symbol.Mamba2` <- function(..., name = "") {
@@ -247,7 +247,7 @@
 
 #' Mixture-of-experts position-wise FFN (soft or top-k routing).
 #'
-#' Parameters: expert_first (int, default 0); experts_held (int, default 0); gated (bool, default False); hidden (int); num_experts (int); renormalize (bool, default False); route_scale (float, default 1.0); router (str, default linear); shared_gated (bool, default True); shared_hidden (int, default 0); top_k (int, default 0)
+#' Parameters: expert_first (int, default 0); experts_held (int, default 0); gated (bool, default False); hidden (int); latent (int, default 0); num_experts (int); relu2 (bool, default False); renormalize (bool, default False); route_scale (float, default 1.0); router (str, default linear); shared_gated (bool, default True); shared_hidden (int, default 0); top_k (int, default 0)
 #' Symbol inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2
 #' @export
 `mx.symbol.MoEFFN` <- function(..., name = "") {

@@ -15,7 +15,7 @@ Parity map (reference ``example/``):
 * no reference counterpart (decoder-only LMs):
   ``get_transformer_lm``, ``get_zaya_lm``,
   ``get_qwen3_next_lm``, ``get_xing4_lm``,
-  ``get_granite_hybrid_lm``                             -> :mod:`.transformer`
+  ``get_granite_hybrid_lm``, ``get_nemotron_h_lm``      -> :mod:`.transformer`
 
 Every constructor returns a :class:`mxnet_tpu.symbol.Symbol` whose single
 head is a ``SoftmaxOutput`` (classification) so it drops straight into
@@ -38,7 +38,8 @@ from .transformer import (get_transformer_lm, transformer_block,
                           moe_transformer_block, get_zaya_lm, zaya_block,
                           get_qwen3_next_lm, qwen3_next_block,
                           get_xing4_lm, xing4_block,
-                          get_granite_hybrid_lm, granite_hybrid_block)
+                          get_granite_hybrid_lm, granite_hybrid_block,
+                          get_nemotron_h_lm, nemotron_h_block)
 
 _REGISTRY = {
     "mlp": get_mlp,

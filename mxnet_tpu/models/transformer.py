@@ -490,6 +490,106 @@ def get_granite_hybrid_lm(vocab_size, layer_types, embed_dim, num_heads,
     return _lm_loss(logits, vocab_size, loss_layout)
 
 
+def nemotron_h_block(data, name, kind, embed_dim, num_heads, num_kv_heads,
+                     mamba_heads, mamba_head_dim, mamba_state, mamba_groups,
+                     num_experts, expert_hidden, top_k, shared_hidden, latent,
+                     route_scale=1.0, experts_held=0, expert_first=0,
+                     conv_kernel=4, chunk_size=256, eps=1e-5, impl="flash"):
+    """One Nemotron-H layer, ONE sublayer behind its own RMSNorm:
+    ``x + f(RMSNorm(x))``, ``f`` by ``kind``, a letter of the published
+    ``hybrid_override_pattern``: ``"M"`` a ``Mamba2`` mixer with
+    ``mamba_groups`` groups of B and C; ``"*"`` bias-free grouped-query
+    ``MultiHeadAttention`` without positions, scores at 1/sqrt(D); ``"E"``
+    a LatentMoE: sigmoid scores of a float32 router over all
+    ``num_experts``, the ``top_k`` largest of the scores plus a
+    correction bias chosen, renormalized over the chosen and times
+    ``route_scale``, the held experts (``experts_held`` /
+    ``expert_first``: the share that lives here, 0 = all) relu² in a
+    ``latent``-wide space, plus an ungated relu² shared expert of width
+    ``shared_hidden`` on the full stream (``MoEFFN``)."""
+    h = _rms(data, name + "_norm", eps)
+
+    def var(tag):
+        return sym.Variable("%s_%s" % (name, tag))
+
+    if kind == "M":
+        f = sym.Mamba2(
+            data=h, in_weight=var("mamba_in_weight"),
+            conv_weight=var("mamba_conv_weight"),
+            conv_bias=var("mamba_conv_bias"), a_log=var("mamba_a_log"),
+            d_skip=var("mamba_d_skip"), dt_bias=var("mamba_dt_bias"),
+            norm_weight=var("mamba_norm_weight"),
+            out_weight=var("mamba_out_weight"), num_heads=mamba_heads,
+            head_dim=mamba_head_dim, state_size=mamba_state,
+            n_groups=mamba_groups, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, eps=eps, name=name + "_mamba")
+    elif kind == "*":
+        f = sym.MultiHeadAttention(
+            data=h, qkv_weight=var("attn_qkv_weight"),
+            out_weight=var("attn_out_weight"), num_heads=num_heads,
+            num_kv_heads=num_kv_heads, causal=True, impl=impl,
+            no_bias=True, name=name + "_attn")
+    else:
+        # the router in float32 at full product precision whatever the
+        # model computes in: what it decides is discrete
+        scores = sym.Activation(
+            data=sym.FullyConnected(
+                data=sym.Cast(data=h, dtype="float32",
+                              name=name + "_router_in"),
+                num_hidden=num_experts, no_bias=True, flatten=False,
+                precision="highest", name=name + "_router"),
+            act_type="sigmoid", name=name + "_router_scores")
+        f = sym.MoEFFN(
+            data=h, probs=scores, select_bias=var("router_bias"),
+            latent_down=var("latent_down_weight"),
+            latent_up=var("latent_up_weight"),
+            expert_w1=var("expert_w1"), expert_w2=var("expert_w2"),
+            shared_w1=var("shared_w1"), shared_w2=var("shared_w2"),
+            num_experts=num_experts, hidden=expert_hidden, top_k=top_k,
+            router="given", relu2=True, latent=latent, renormalize=True,
+            route_scale=route_scale, experts_held=experts_held,
+            expert_first=expert_first, shared_hidden=shared_hidden,
+            shared_gated=False, name=name + "_moe")
+    return data + f
+
+
+def get_nemotron_h_lm(vocab_size, pattern, embed_dim, num_heads,
+                      num_kv_heads, mamba_heads, mamba_head_dim, mamba_state,
+                      mamba_groups, num_experts, expert_hidden, top_k,
+                      shared_hidden, latent, route_scale=1.0, experts_held=0,
+                      expert_first=0, conv_kernel=4, chunk_size=256,
+                      eps=1e-5, impl="flash", loss_layout="reference"):
+    """Nemotron-H-shaped decoder-only LM (NVIDIA's ``nemotron_h``): one
+    ``nemotron_h_block`` per letter of ``pattern`` (the published
+    ``hybrid_override_pattern``: ``M`` Mamba-2, ``*`` attention, ``E``
+    LatentMoE), between an untied, unscaled embedding and an untied head
+    behind a final RMSNorm; no biases, no positions (the Mamba-2 layers
+    order the sequence). Built from registered Symbol ops like every zoo
+    model: it binds, and is served by ``Decoder`` / ``InferenceEngine``,
+    which keep K/V rows for the attention layers and a recurrent state
+    for the Mamba-2 ones. ``loss_layout`` as in ``get_transformer_lm``."""
+    kinds = set(pattern) - set("M*E")
+    if kinds:
+        raise ValueError("pattern: 'M', '*' or 'E' a layer, got %s"
+                         % sorted(kinds))
+    net = sym.Embedding(data=sym.Variable("data"),
+                        weight=sym.Variable("embed_weight"),
+                        input_dim=vocab_size, output_dim=embed_dim,
+                        name="embed")
+    for i, kind in enumerate(pattern):
+        net = nemotron_h_block(
+            net, "layer%d" % i, kind, embed_dim, num_heads, num_kv_heads,
+            mamba_heads, mamba_head_dim, mamba_state, mamba_groups,
+            num_experts, expert_hidden, top_k, shared_hidden, latent,
+            route_scale=route_scale, experts_held=experts_held,
+            expert_first=expert_first, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, eps=eps, impl=impl)
+    logits = sym.FullyConnected(
+        data=_rms(net, "final_norm", eps), num_hidden=vocab_size,
+        no_bias=True, flatten=False, name="lm_head")
+    return _lm_loss(logits, vocab_size, loss_layout)
+
+
 def _hyper(data, name, branch_fn, lanes, iters, hc_eps, clamp, res_diag,
            eps):
     """One sublayer inside its hyper-connection: ``u = H_pre X``,

@@ -182,7 +182,18 @@ class MoEFFN(OpSpec):
     ``expert_b2`` [X, E]. True is the SiLU-gated pair without biases:
     ``expert_w1`` [X, 2H, E] (the gate's rows, then the up
     projection's), ``expert_w2`` [X, E, H]:
-    ``w2 (silu(w1[:H] x) * (w1[H:] x))``.
+    ``w2 (silu(w1[:H] x) * (w1[H:] x))``. ``relu2=True`` (with
+    ``gated`` False) is the ungated relu² pair without biases,
+    ``expert_w1`` [X, H, E], ``expert_w2`` [X, E, H]:
+    ``w2 relu(w1 x)^2``; the shared expert, if any, is then relu² too.
+
+    A LATENT space (``latent`` = its width L > 0, Nemotron-H's
+    LatentMoE): the routed experts read ``u = latent_down x``
+    (``latent_down`` [L, E]) and their weighted sum ``r`` [L] is
+    projected back, ``out = latent_up r`` (``latent_up`` [E, L]), once a
+    token and not once a pair; ``expert_w1`` [X, ., L], ``expert_w2``
+    [X, L, H]. The router and the shared expert read ``x`` itself. The
+    two projections trace under the scope ``latent``. Routed form only.
 
     Routing: ``top_k=0`` (default) is soft routing: every expert
     weighs in and computes every token, fully differentiable.
@@ -233,18 +244,22 @@ class MoEFFN(OpSpec):
               "shared_hidden": Param("int", 0),
               "renormalize": Param("bool", False),
               "route_scale": Param("float", 1.0),
-              "shared_gated": Param("bool", True)}
+              "shared_gated": Param("bool", True),
+              "latent": Param("int", 0),
+              "relu2": Param("bool", False)}
 
     def arguments(self, p):
         route = ["probs", "select_bias"] if self.given(p) \
             else ["gate_weight"]
-        experts = ["expert_w1", "expert_w2"] if p["gated"] else \
+        latent = ["latent_down", "latent_up"] if p.get("latent") else []
+        experts = ["expert_w1", "expert_w2"] \
+            if p["gated"] or p.get("relu2") else \
             ["expert_w1", "expert_b1", "expert_w2", "expert_b2"]
         shared = []
         if p.get("shared_hidden"):
             shared = ["shared_w1", "shared_w2"] \
                 + (["shared_gate"] if p.get("shared_gated", True) else [])
-        return ["data"] + route + experts + shared
+        return ["data"] + route + latent + experts + shared
 
     @staticmethod
     def held(p):
@@ -278,11 +293,15 @@ class MoEFFN(OpSpec):
             names = self.arguments(p)
             _, n = self.held(p)
             sh = p.get("shared_hidden") or 0
+            lw = p.get("latent") or 0
+            ew = lw or e                # what the routed experts read
             want = {"gate_weight": (x, e), "probs": (d[0], d[1], x),
-                    "select_bias": (x,),
-                    "expert_w1": (n, 2 * h if p["gated"] else h, e),
-                    "expert_b1": (n, h), "expert_w2": (n, e, h),
-                    "expert_b2": (n, e), "shared_w1": (2 * sh, e),
+                    "select_bias": (x,), "latent_down": (lw, e),
+                    "latent_up": (e, lw),
+                    "expert_w1": (n, 2 * h if p["gated"] else h, ew),
+                    "expert_b1": (n, h), "expert_w2": (n, ew, h),
+                    "expert_b2": (n, e),
+                    "shared_w1": (sh if p.get("relu2") else 2 * sh, e),
                     "shared_w2": (e, sh), "shared_gate": (1, e)}
             for i, n in enumerate(names[1:], 1):
                 ins[i] = shape_assign(ins[i], want[n], "MoEFFN " + n)
@@ -339,6 +358,12 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
     gates say, so masking saves nothing. With ``live=None`` the traced
     program is the one without the argument."""
     given, gated = MoEFFN.given(p), bool(p.get("gated", False))
+    relu2, lw = bool(p.get("relu2", False)), int(p.get("latent") or 0)
+    if relu2 and gated:
+        raise MXNetError("MoEFFN: relu2 experts are ungated; gated=True "
+                         "is the SiLU-gated pair")
+    # the experts' activation: the biased ReLU pair, SiLU-gated, relu²
+    act = "silu" if gated else "relu2" if relu2 else "relu"
     it = iter(ins)
     x = next(it)
     if given:
@@ -349,10 +374,12 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
                 "or with quantized weights yet (ROADMAP R-M2)")
     else:
         gate_w = next(it)
+    if lw:
+        latent_down, latent_up = next(it), next(it)
     w1 = next(it)
-    b1 = None if gated else next(it)
+    b1 = None if act != "relu" else next(it)
     w2 = next(it)
-    b2 = None if gated else next(it)
+    b2 = None if act != "relu" else next(it)
     shared = None
     if p.get("shared_hidden"):
         shared = (next(it), next(it),
@@ -419,7 +446,7 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
                     gates, i * nloc, nloc, axis=-1)
     plain = gate_mm is None and up_mm is None and down_mm is None
     if k > 0 and plain and not sharded:
-        b, t, e = x.shape
+        b, t, _ = x.shape
         picked = jnp.take_along_axis(gates, idx, axis=-1)     # [b,t,k]
         # the held experts count from 0; a pair on an absent expert
         # gets the index ``held``, which no group has
@@ -429,17 +456,27 @@ def moe_ffn_math(p, ins, gate_mm=None, up_mm=None, down_mm=None,
             local = jnp.where(live[:, None, None], local, held)
             if stats is not None:
                 stats["rows_masked"] = jnp.sum(~live).astype(jnp.int32)
-        out = _routed_sum(x.reshape(b * t, e), local.reshape(b * t, k),
+        u = x
+        if lw:
+            with jax.named_scope("latent"):
+                u = jnp.einsum("bte,le->btl", x, latent_down)
+        out = _routed_sum(u.reshape(b * t, -1), local.reshape(b * t, k),
                           picked.reshape(b * t, k), held, w1, b1, w2, b2,
-                          gated, stats).reshape(b, t, e)
+                          act, stats).reshape(b, t, -1)
+        if lw:
+            with jax.named_scope("latent"):
+                out = jnp.einsum("btl,el->bte", out.astype(x.dtype),
+                                 latent_up,
+                                 preferred_element_type=jnp.float32)
         if shared is not None:
-            out = out + _shared_expert(x, *shared)
+            out = out + _shared_expert(x, *shared, relu2=relu2)
         return out.astype(x.dtype)
-    if held != nx or shared is not None:
+    if held != nx or shared is not None or lw or relu2:
         raise MXNetError(
-            "MoEFFN: a share of the experts (experts_held=%d of %d) and "
-            "a shared expert have the routed form only: top_k > 0, "
-            "plain products, no ep (ROADMAP R-M2)" % (held, nx))
+            "MoEFFN: a share of the experts (experts_held=%d of %d), a "
+            "shared expert, a latent space and relu2 experts have the "
+            "routed form only: top_k > 0, plain products, no ep "
+            "(ROADMAP R-M2)" % (held, nx))
     with jax.named_scope("experts"):
         up = up_mm(x, w1) if up_mm is not None \
             else jnp.einsum("bte,xhe->btxh", x, w1)
@@ -483,28 +520,46 @@ def routed_block_rows(pairs, num_experts):
 # layer alone runs, 5.9 ms); at 10,240 pairs the program ran (PERF.md
 # section 6, PR 34)
 _ROUTED_PASS_PAIRS = 8192
+# ... and its first product's float32 rows [rows, N] (N the up width) take
+# at most this many bytes: with 22 choices of experts 2,688 wide a pass of
+# 256 tokens is 7,680 rows, 79 MiB of fast memory (PERF.md section 6);
+# Xing4.0's pieces of 1,024 tokens lay out 48 MiB
+_ROUTED_PASS_BYTES = 56 << 20
 
 
-def _routed_sum(x, idx, gates, nx, w1, b1, w2, b2, gated, stats=None):
+def _routed_layout(pairs, num_experts):
+    """(rows of a block, blocks) of a pass of ``pairs`` token-expert
+    pairs: ``pairs // rows`` full blocks at most, and one partly filled
+    one per expert."""
+    rows = routed_block_rows(pairs, num_experts)
+    return rows, pairs // rows + min(num_experts, pairs)
+
+
+def _routed_sum(x, idx, gates, nx, w1, b1, w2, b2, act, stats=None):
     """``x`` [N, E] through each token's experts ``idx`` [N, k] (``nx``
     marks a pair whose expert is not here), weighted by ``gates`` [N, k]
     and summed over the k: float32 [N, E]. A long chunk goes in passes
     of a power-of-two number of tokens, at most ``_ROUTED_PASS_PAIRS``
-    pairs each, one after the other (``lax.map``); every pass streams
-    the experts it touches again, so a decode step and a short chunk are
-    one pass. A chunk that is not whole passes is filled up with tokens
+    pairs and ``_ROUTED_PASS_BYTES`` of first products each, one after
+    the other (``lax.map``); every pass streams the experts it touches
+    again, so a decode step and a short chunk are one pass. A chunk that is not whole passes is filled up with tokens
     whose pairs are all absent: no pass is ever larger than the limit."""
     n, k = idx.shape
     f32 = jnp.float32
 
     def one(x, idx, gates):
         seen = {}
-        y = _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, seen)
+        y = _routed_experts(x, idx, nx, w1, b1, w2, b2, act, seen)
         out = jnp.sum(y.astype(f32) * gates[..., None].astype(f32), axis=1)
         return out, seen["experts_touched"], seen["pairs_held"]
 
+    def up_bytes(pairs):
+        rows, nb = _routed_layout(pairs, nx)
+        return nb * rows * w1.shape[1] * 4
+
     per = 1
-    while per * 2 * k <= _ROUTED_PASS_PAIRS:
+    while per * 2 * k <= _ROUTED_PASS_PAIRS \
+            and up_bytes(per * 2 * k) <= _ROUTED_PASS_BYTES:
         per *= 2
     if n <= per:
         out, touched, pairs = one(x, idx, gates)
@@ -527,15 +582,18 @@ def _routed_sum(x, idx, gates, nx, w1, b1, w2, b2, gated, stats=None):
     return out
 
 
-def _shared_expert(x, w1, w2, gate):
+def _shared_expert(x, w1, w2, gate, relu2=False):
     """The shared expert's part, float32 [B, T, E]: the SiLU-gated
-    pair on every token, times ``sigmoid(gate . x)`` (``gate`` None:
-    ungated)."""
+    pair (``relu2``: ``relu(w1 x)^2``, ungated) on every token, times
+    ``sigmoid(gate . x)`` (``gate`` None: ungated)."""
     f32 = jnp.float32
     with jax.named_scope("shared"):
         up = jnp.einsum("bte,he->bth", x, w1)
-        hid = up.shape[-1] // 2
-        h = jax.nn.silu(up[..., :hid]) * up[..., hid:]
+        if relu2:
+            h = jnp.square(jax.nn.relu(up))
+        else:
+            hid = up.shape[-1] // 2
+            h = jax.nn.silu(up[..., :hid]) * up[..., hid:]
         y = jnp.einsum("bth,eh->bte", h, w2, preferred_element_type=f32)
         if gate is None:
             return y
@@ -543,12 +601,14 @@ def _shared_expert(x, w1, w2, gate):
         return y * jax.nn.sigmoid(g)
 
 
-def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
+def _routed_experts(x, idx, nx, w1, b1, w2, b2, act, stats=None):
     """``x`` [N, E] through each token's own experts ``idx`` [N, k]:
-    returns [N, k, E]. The N*k token-expert pairs are sorted by expert
-    and laid out in blocks of ``routed_block_rows`` rows, each block
-    one expert's (a group is padded to whole blocks); the two products
-    then run block by block against that block's expert
+    returns [N, k, E]; ``act`` is ``moe_ffn_math``'s (``"relu"``, the
+    biased pair; ``"silu"``, gated; ``"relu2"``, ungated without biases).
+    The N*k token-expert pairs are sorted by expert and laid out in
+    blocks of ``routed_block_rows`` rows, each block one expert's (a
+    group is padded to whole blocks); the two products then run block by
+    block against that block's expert
     (``pallas_kernels.grouped_matmul``), and the rows go back to their
     pairs. The number of blocks is static: ``pairs // rows`` full ones
     at most, and one partly filled one per expert. An index of ``nx``
@@ -558,8 +618,7 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
     from .pallas_kernels import grouped_matmul
     n, k = idx.shape
     pairs = n * k
-    rows = routed_block_rows(pairs, nx)
-    nb = pairs // rows + min(nx, pairs)
+    rows, nb = _routed_layout(pairs, nx)
     i32 = jnp.int32
     with jax.named_scope("route"):
         expert = idx.reshape(pairs).astype(i32)
@@ -591,13 +650,15 @@ def _routed_experts(x, idx, nx, w1, b1, w2, b2, gated, stats=None):
         def per_row(v):             # an expert's vector for its blocks
             return jnp.repeat(v[block_e], rows, axis=0)
         up = grouped_matmul(xp, w1, block_e, used, rows)
-        if gated:
+        if act == "silu":
             hid = up.shape[-1] // 2
             h = jax.nn.silu(up[:, :hid]) * up[:, hid:]
+        elif act == "relu2":
+            h = jnp.square(jax.nn.relu(up))
         else:
             h = jax.nn.relu(up + per_row(b1))
         y = grouped_matmul(h.astype(x.dtype), w2, block_e, used, rows)
-        if not gated:
+        if act == "relu":
             y = y + per_row(b2)
     with jax.named_scope("route"):
         return y.at[dest].get(mode="fill", fill_value=0).reshape(n, k, -1)
@@ -1420,8 +1481,10 @@ def gdn_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
 # (Dao & Gu, arXiv:2405.21060, as Granite 4.0-H runs it.) Per head a float32
 # state S [P, N]; with ``dt_t > 0`` the head's step and ``a = -exp(A_log)``:
 #     S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T;   y_t = S_t C_t
-# (the ``D`` skip is added outside). B and C are one group's, shared by every
-# head. ``ssd_step`` is that recurrence for one position (under ``lax.scan``
+# (the ``D`` skip is added outside). B and C come in ``G`` groups: group ``g``
+# serves heads ``[g H/G, (g+1) H/G)``; with one group (Granite's) they are
+# [.., N] and every head's, with G they are [.., G, N] (Nemotron-H's eight).
+# ``ssd_step`` is that recurrence for one position (under ``lax.scan``
 # the definition the chunked form is held to, and the decode step of a walk
 # without ``lens``); ``ssd_chunked`` is the state-space duality's chunked
 # form over a sequence; a slot walk's decode step takes
@@ -1429,22 +1492,32 @@ def gdn_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
 # (``ssd_steps_in_place``).
 
 
+def _per_head(v, heads):
+    """B or C of one position, [B, N] (one group) or [B, G, N], laid
+    against a state [B, H, P, N]."""
+    if v.ndim == 2:
+        return v[:, None, None, :]
+    return jnp.repeat(v, heads // v.shape[1], axis=1)[:, :, None, :]
+
+
 def ssd_step(state, x, b, c, dt, a):
     """One position of the recurrence for every (batch row, head):
-    ``state`` [B, H, P, N] float32, ``x`` [B, H, P], ``b``/``c`` [B, N],
-    ``dt`` [B, H], ``a`` [H] (negative), all float32. Returns (the new
-    state, y [B, H, P]): one pass over the state, which is decayed, takes
-    the rank-one write and is read against ``c``."""
+    ``state`` [B, H, P, N] float32, ``x`` [B, H, P], ``b``/``c`` [B, N]
+    (one group) or [B, G, N], ``dt`` [B, H], ``a`` [H] (negative), all
+    float32. Returns (the new state, y [B, H, P]): one pass over the
+    state, which is decayed, takes the rank-one write and is read
+    against ``c``."""
+    h = state.shape[1]
     s = state * jnp.exp(dt * a)[..., None, None] \
-        + (dt[..., None] * x)[..., None] * b[:, None, None, :]
-    return s, jnp.sum(s * c[:, None, None, :], axis=-1)
+        + (dt[..., None] * x)[..., None] * _per_head(b, h)
+    return s, jnp.sum(s * _per_head(c, h), axis=-1)
 
 
 def ssd_sequential(state, x, b, c, dt, a):
     """The recurrence position by position (``lax.scan`` of
-    ``ssd_step``): ``x`` [B, T, H, P], ``b``/``c`` [B, T, N], ``dt``
-    [B, T, H]. The definition; a prefill takes ``ssd_chunked``. Returns
-    (state, y [B, T, H, P])."""
+    ``ssd_step``): ``x`` [B, T, H, P], ``b``/``c`` [B, T, N] or
+    [B, T, G, N], ``dt`` [B, T, H]. The definition; a prefill takes
+    ``ssd_chunked``. Returns (state, y [B, T, H, P])."""
     state, y = jax.lax.scan(
         lambda s, xs: ssd_step(s, *xs, a), state,
         tuple(jnp.moveaxis(z, 1, 0) for z in (x, b, c, dt)))
@@ -1460,13 +1533,23 @@ def ssd_chunked(state, x, b, c, dt, a, chunk=256):
     ``S`` is computed for all chunks at once; the chunks then follow
     each other in a ``lax.scan`` of two small products. A sequence that
     is not whole chunks is padded with positions of ``dt = 0``, which
-    leave the state as it is. Shapes as ``ssd_sequential``; float32 at
-    full product precision."""
+    leave the state as it is. Shapes as ``ssd_sequential``; with G
+    groups the heads are taken as [G, H/G] and ``C B^T`` is made once a
+    group. Float32 at full product precision."""
     bsz, t, h, pdim = x.shape
     n = b.shape[-1]
     cs = min(int(chunk), t)
     nc = -(-t // cs)
     pad = nc * cs - t
+    grouped = b.ndim == 4
+    # einsum labels: a head is ``h`` of one group, or ``gh``
+    g, hd = ("g", "gh") if grouped else ("", "h")
+    if grouped:
+        ng = b.shape[2]
+        x = x.reshape(bsz, t, ng, h // ng, pdim)
+        dt = dt.reshape(bsz, t, ng, h // ng)
+        a = a.reshape(ng, h // ng)
+        state = state.reshape(bsz, ng, h // ng, pdim, n)
 
     def chunks(z):                      # [B, T, ...] -> [B, Nc, C, ...]
         if pad:
@@ -1475,24 +1558,28 @@ def ssd_chunked(state, x, b, c, dt, a, chunk=256):
 
     x, b, c, dt = (chunks(z) for z in (x, b, c, dt))
     cum = jnp.cumsum(dt * a, axis=2)                        # [B,Nc,C,H]
-    cum = jnp.moveaxis(cum, 3, 2)                           # [B,Nc,H,C]
+    cum = jnp.moveaxis(cum, 2, -1)                          # [B,Nc,H,C]
     low = jnp.tril(jnp.ones((cs, cs), bool))
     # exp only where j <= i: above the diagonal the difference is
     # positive and may overflow
     decay = jnp.exp(jnp.where(low, cum[..., :, None] - cum[..., None, :],
                               -jnp.inf))                    # [B,Nc,H,C,C]
     xdt = x * dt[..., None]                                 # [B,Nc,C,H,P]
-    cb = jnp.einsum("bzis,bzjs->bzij", c, b, precision=_HI)
-    y = jnp.einsum("bzhij,bzjhp->bzihp", cb[:, :, None] * decay, xdt,
+    cb = jnp.einsum("bzi%ss,bzj%ss->bz%sij" % (g, g, g), c, b,
+                    precision=_HI)                          # [B,Nc,C,C]
+    cb = cb[:, :, :, None] if grouped else cb[:, :, None]
+    y = jnp.einsum("bz%sij,bzj%sp->bzi%sp" % (hd, hd, hd), cb * decay, xdt,
                    precision=_HI)
     last = cum[..., -1:]                                    # [B,Nc,H,1]
-    own = jnp.einsum("bzjs,bzhj,bzjhp->bzhps", b, jnp.exp(last - cum),
-                     xdt, precision=_HI)                    # [B,Nc,H,P,N]
+    own = jnp.einsum("bzj%ss,bz%sj,bzj%sp->bz%sps" % (g, hd, hd, hd), b,
+                     jnp.exp(last - cum), xdt,
+                     precision=_HI)                         # [B,Nc,H,P,N]
 
     def body(s, xs):
         c_, cum_, last_, own_ = xs
-        off = jnp.einsum("bis,bhps->bihp", c_, s, precision=_HI) \
-            * jnp.moveaxis(jnp.exp(cum_), 1, 2)[..., None]
+        off = jnp.einsum("bi%ss,b%sps->bi%sp" % (g, hd, hd), c_, s,
+                         precision=_HI) \
+            * jnp.moveaxis(jnp.exp(cum_), -1, 1)[..., None]
         s = s * jnp.exp(last_)[..., None] + own_
         return s, off
 
@@ -1500,29 +1587,33 @@ def ssd_chunked(state, x, b, c, dt, a, chunk=256):
         body, state, tuple(jnp.moveaxis(z, 1, 0)
                            for z in (c, cum, last, own)))
     y = y + jnp.moveaxis(off, 0, 1)                         # [B,Nc,C,H,P]
+    if grouped:
+        state = state.reshape(bsz, h, pdim, n)
     return state, y.reshape(bsz, nc * cs, h, pdim)[:, :t]
 
 
 @register
 class Mamba2(OpSpec):
     """A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Granite 4.0-H's
-    ``mamba`` layers run it. data [B, T, E] (already normalized). With
-    ``H`` heads of ``P`` (``head_dim``), a state of ``N``
-    (``state_size``) and ONE group of B and C (shared by every head),
-    ``D_in = H P`` and ``F = D_in + 2N``:
+    ``mamba`` layers and Nemotron-H's ``M`` layers run it. data [B, T, E]
+    (already normalized). With ``H`` heads of ``P`` (``head_dim``), a
+    state of ``N`` (``state_size``) and ``G`` groups of B and C
+    (``n_groups``; group ``g`` serves heads ``[g H/G, (g+1) H/G)``),
+    ``D_in = H P`` and ``F = D_in + 2 G N``:
 
     - ``in_weight`` [D_in + F + H, E]: ``[z ; xBC ; dt] = W x``, no bias;
     - ``conv_weight`` [F, kernel], ``conv_bias`` [F]: depthwise and
       causal over ``xBC``, zeros before position 0,
       ``xBC_t = silu(sum_j w[:, j] u_{t-(kernel-1)+j} + bias)``, then
-      split into ``x`` [H, P], ``B`` [N], ``C`` [N];
+      split into ``x`` [H, P], ``B`` [G, N], ``C`` [G, N];
     - ``dt = softplus(dt + dt_bias)``, ``a = -exp(a_log)`` per head
       (``a_log``, ``dt_bias``, ``d_skip`` [H]), float32;
     - the recurrence at the top of this section, state float32, then
       ``y = S C + d_skip x`` per head;
     - ``out = out_weight [rmsnorm(y silu(z)) norm_weight]``: the gate
-      comes first and the norm runs over the whole ``D_in`` (one
-      group), ``norm_weight`` [D_in], ``out_weight`` [E, D_in].
+      comes first and the norm runs over each group's ``D_in / G``
+      channels (the whole ``D_in`` with one group), ``norm_weight``
+      [D_in], ``out_weight`` [E, D_in].
 
     The full-sequence forward starts from the zero state and runs the
     chunked form in chunks of ``chunk_size``; the decoder's cached form
@@ -1533,7 +1624,8 @@ class Mamba2(OpSpec):
     params = {"num_heads": Param("int"), "head_dim": Param("int"),
               "state_size": Param("int"), "conv_kernel": Param("int", 4),
               "chunk_size": Param("int", 256),
-              "eps": Param("float", 1e-5)}
+              "eps": Param("float", 1e-5),
+              "n_groups": Param("int", 1)}
 
     def arguments(self, p):
         return ["data", "in_weight", "conv_weight", "conv_bias", "a_log",
@@ -1541,10 +1633,14 @@ class Mamba2(OpSpec):
 
     @staticmethod
     def widths(p):
-        """(D_in, F): the inner and the convolved widths (one group of
-        B and C)."""
+        """(D_in, F): the inner and the convolved widths (``G`` groups
+        of B and C)."""
         d_in = p["num_heads"] * p["head_dim"]
-        return d_in, d_in + 2 * p["state_size"]
+        ng = p.get("n_groups", 1)
+        if ng < 1 or p["num_heads"] % ng:
+            raise MXNetError("Mamba2: n_groups=%d must divide the %d heads"
+                             % (ng, p["num_heads"]))
+        return d_in, d_in + 2 * ng * p["state_size"]
 
     def infer_shape(self, p, in_shapes):
         d = in_shapes[0]
@@ -1605,6 +1701,7 @@ def mamba_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
     win_, wconv, bconv, a_log, d_skip, dt_bias, wnorm, wout = weights
     d_in, f = Mamba2.widths(p)
     h, pdim, n = p["num_heads"], p["head_dim"], p["state_size"]
+    ng = p.get("n_groups", 1)
     b, c, _ = x.shape
     f32 = jnp.float32
     with jax.named_scope("proj"):
@@ -1625,7 +1722,9 @@ def mamba_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
             at = real + jnp.arange(taps - 1, dtype=jnp.int32)
             nxt = jnp.take_along_axis(win, at[..., None], axis=1)
         xs = cv[..., :d_in].reshape(b, c, h, pdim)
-        bb, cc = cv[..., d_in:d_in + n], cv[..., d_in + n:]
+        bb, cc = cv[..., d_in:d_in + ng * n], cv[..., d_in + ng * n:]
+        if ng > 1:                                  # [B, C, G, N]
+            bb, cc = bb.reshape(b, c, ng, n), cc.reshape(b, c, ng, n)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
         if real is not None:
             pad = (jnp.arange(c, dtype=jnp.int32) >= real)[..., None]
@@ -1657,8 +1756,13 @@ def mamba_mix(p, x, weights, state, prev, real=None, lens=None, fresh=None):
     with jax.named_scope("norm"):
         y = (y + d_skip.astype(f32)[:, None] * xs).reshape(b, c, d_in) \
             * jax.nn.silu(z.astype(f32))
+        if ng > 1:                      # the norm runs over each group
+            y = y.reshape(b, c, ng, d_in // ng)
         ms = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-        y = y * jax.lax.rsqrt(ms + p["eps"]) * wnorm.astype(f32)
+        y = y * jax.lax.rsqrt(ms + p["eps"])
+        if ng > 1:
+            y = y.reshape(b, c, d_in)
+        y = y * wnorm.astype(f32)
     with jax.named_scope("proj"):
         out = jnp.einsum("btv,ev->bte", y.astype(x.dtype), wout)
     return out, new, nxt.astype(prev.dtype)

@@ -1826,7 +1826,8 @@ def _ssd_state_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, fresh_ref,
     """One (slot visit, block of heads) grid cell of the SSD update.
     ``x_ref`` [1, hb, P] holds the block's ``dt x`` rows, ``dec_ref``
     [1, hb, N] each head's decay ``exp(dt a)`` spread over N, ``bc_ref``
-    [1, 2, N] the slot's B and C rows (one group, every head's);
+    [1, 2, N] the slot's B and C rows (one group, every head's; with
+    groups [1, 1, 2, N], the rows of the group the block lies in);
     ``s_ref`` / ``so_ref`` [1, hb, P, N] are the same block of the
     state, before and after, P on the sublanes and N on the lanes;
     ``o_ref`` [1, hb / pack, pack P] is the block's ``y``, ``pack``
@@ -1847,8 +1848,12 @@ def _ssd_state_kernel(order_ref, nkb_ref, kslot_ref, kblk_ref, fresh_ref,
     def advance(zero):
         cols = x_ref[0].T                                   # [P, hb]
         dec = dec_ref[0]                                    # [hb, N]
-        brow = bc_ref[0, 0:1]                               # [1, N]
-        crow = bc_ref[0, 1:2]
+        if len(bc_ref.shape) == 3:
+            brow = bc_ref[0, 0:1]                           # [1, N]
+            crow = bc_ref[0, 1:2]
+        else:
+            brow = bc_ref[0, 0, 0:1]
+            crow = bc_ref[0, 0, 1:2]
         for r in range(heads // pack):
             read = []
             for h in range(r * pack, (r + 1) * pack):
@@ -1886,7 +1891,9 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
 
     state: [S, H, P, N] float32, ALIASED to the first result (donate it,
     or the caller's copy is XLA's). x: [S, H, P]; b, c: [S, N] (one
-    group, every head's); dt: [S, H]; a: [H] (negative); all float32.
+    group, every head's) or [S, G, N] (group g's serve heads
+    [g H/G, (g+1) H/G), and a block of heads lies within one group); dt:
+    [S, H]; a: [H] (negative); all float32.
     lens: [S] int32, 0 for a slot that holds no request: its state is
     neither read nor written and its output is zeros. fresh: [S] bool, a
     live slot that starts from the ZERO state whatever it held. Returns
@@ -1909,11 +1916,13 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
     if state.dtype != f32:
         raise ValueError("ssd_state_step: the state is float32, got %s"
                          % state.dtype)
-    hb = default_state_block_h(h, pdim, n) if block_h is None \
+    groups = 1 if b.ndim == 2 else b.shape[1]
+    per = h // groups                          # heads of a group
+    hb = default_state_block_h(per, pdim, n) if block_h is None \
         else int(block_h)
-    if h % hb:
+    if h % groups or per % hb:
         raise ValueError("ssd_state_step: block_h=%d must divide the %d "
-                         "heads" % (hb, h))
+                         "heads of a group" % (hb, per))
     nb = h // hb
     pack = max(k for k in range(1, hb + 1)
                if hb % k == 0 and (k == 1 or k * pdim <= 128))
@@ -1923,7 +1932,7 @@ def ssd_state_step(state, x, b, c, dt, a, lens, fresh, *, block_h=None,
     xdt = dt[..., None] * x.astype(f32)                    # [S, H, P]
     dec = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None],
                            (s_, h, n))                     # [S, H, N]
-    bc = jnp.stack([b, c], axis=1).astype(f32)             # [S, 2, N]
+    bc = jnp.stack([b, c], axis=b.ndim - 1).astype(f32)    # [S, (G,) 2, N]
     new, y = _ssd_state_call(order, nkb, kslot, kblk, fresh.astype(i32),
                              xdt, dec, bc, state, hb=hb, pack=pack,
                              interpret=interpret)
@@ -1951,6 +1960,9 @@ def _ssd_state_call(order, nkb, kslot, kblk, fresh, xdt, dec, bc, state, *,
     def bmap(i, j, *pre):
         return (pre[2][i], 0, 0)
 
+    def gmap(i, j, *pre):                # the group the block lies in
+        return (pre[2][i], blk(i, j, *pre) // (nb // bc.shape[1]), 0, 0)
+
     def omap(i, j, *pre):
         return (pre[0][i], j, 0)
 
@@ -1959,7 +1971,8 @@ def _ssd_state_call(order, nkb, kslot, kblk, fresh, xdt, dec, bc, state, *,
         grid=(s_, nb),
         in_specs=[pl.BlockSpec((1, hb, pdim), rmap),
                   pl.BlockSpec((1, hb, n), rmap),
-                  pl.BlockSpec((1, 2, n), bmap),
+                  pl.BlockSpec((1, 2, n), bmap) if bc.ndim == 3
+                  else pl.BlockSpec((1, 1, 2, n), gmap),
                   pl.BlockSpec((1, hb, pdim, n), smap)],
         out_specs=[pl.BlockSpec((1, hb // pack, pack * pdim), omap),
                    pl.BlockSpec((1, hb, pdim, n), smap)],

@@ -1367,10 +1367,10 @@ class InferenceEngine:
         # routed MoEFFN layers whose touched experts the decode program
         # counts (0: none; counted for the walk of a decoder with a
         # state leaf or latent rows, whose experts run in the routed
-        # form), and the
+        # form on one shard, beside attention rows or not), and the
         # token-expert pairs a step routes in them
         counted = [n for n in moe_nodes if n.params["top_k"] > 0] \
-            if (rolling or latent) and not decoder._mha else []
+            if (rolling or latent) and ep == 1 else []
         self._moe_counted = len(counted)
         self._moe_pairs_step = self.slots * sum(
             n.params["top_k"] for n in counted)

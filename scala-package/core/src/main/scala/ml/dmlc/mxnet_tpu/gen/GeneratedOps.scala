@@ -195,14 +195,14 @@ object GeneratedOps {
     Symbol.createFromNamedArgs("MAERegressionOutput", name, attrs, inputs)
 
   /** A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Granite 4.0-H's
-    * params: chunk_size: int = 256; conv_kernel: int = 4; eps: float = 1e-05; head_dim: int; num_heads: int; state_size: int
+    * params: chunk_size: int = 256; conv_kernel: int = 4; eps: float = 1e-05; head_dim: int; n_groups: int = 1; num_heads: int; state_size: int
     * inputs: data, in_weight, conv_weight, conv_bias, a_log, d_skip, dt_bias, norm_weight, out_weight */
   def Mamba2(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =
     Symbol.createFromNamedArgs("Mamba2", name, attrs, inputs)
 
   /** Mixture-of-experts position-wise FFN (soft or top-k routing).
-    * params: expert_first: int = 0; experts_held: int = 0; gated: bool = False; hidden: int; num_experts: int; renormalize: bool = False; route_scale: float = 1.0; router: str = linear; shared_gated: bool = True; shared_hidden: int = 0; top_k: int = 0
+    * params: expert_first: int = 0; experts_held: int = 0; gated: bool = False; hidden: int; latent: int = 0; num_experts: int; relu2: bool = False; renormalize: bool = False; route_scale: float = 1.0; router: str = linear; shared_gated: bool = True; shared_hidden: int = 0; top_k: int = 0
     * inputs: data, gate_weight, expert_w1, expert_b1, expert_w2, expert_b2 */
   def MoEFFN(name: String, inputs: Map[String, Symbol],
       attrs: Map[String, String] = Map.empty): Symbol =

@@ -788,3 +788,122 @@ def test_shortchat_prefill_program(shortchat_engine, one_chip, monkeypatch):
             for kind, dims in re.findall(
                 r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
     assert not fast or max(fast)[0] <= 64 << 20, max(fast)
+
+
+# -- Nemotron-H: groups of B and C, latent relu² experts, K/V rows --------
+
+@pytest.fixture(scope="module")
+def think_engine():
+    """The benchmark's serve-think engine at NVIDIA-Nemotron-3-Super's
+    published widths cut to 3 layers, one of each kind (a LatentMoE of
+    128 held of 512 relu² experts in a 1,024-wide latent space, a Mamba-2
+    layer of 128 heads with 8 groups of B and C, a bias-free GQA 32/2
+    D=128 layer), a cut vocabulary: 64 slots x 2048 rows, 8 steps a
+    round, prompts in one bucket of up to 512. Weights are zeros: only
+    shapes reach the compiler."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_nemotron_h_lm
+    sym = get_nemotron_h_lm(
+        8192, "EM*", 4096, num_heads=32, num_kv_heads=2, mamba_heads=128,
+        mamba_head_dim=64, mamba_state=128, mamba_groups=8,
+        num_experts=512, expert_hidden=2688, top_k=22, shared_hidden=5376,
+        latent=1024, route_scale=5.0, experts_held=128, chunk_size=128)
+    shapes = {"data": (1, 8), "softmax_label": (1, 8)}
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    params = {n: jnp.zeros(s, BF16)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in shapes}
+    dec = mx.parallel.Decoder(sym, params, max_len=2048,
+                              compute_dtype="bfloat16")
+    eng = mx.serving.InferenceEngine(
+        dec, slots=64, prefill_buckets=(128, 512), steps_per_round=8,
+        prefix_cache_mb=0, prefill_chunk=0)
+    yield eng
+    eng.close()
+
+
+def test_grouped_ssd_state_step(compile_for_chip):
+    """The decode step's SSD update with 8 groups of B and C at the
+    cell's shapes (a float32 leaf of 64 slots x 128 heads of 64 x 128):
+    a block of 16 heads, one group's, 0.5 MB, reads its group's B and C
+    rows [2, 128]; the leaf is the call's aliased operand."""
+    s, h, p, n, g = 64, 128, 64, 128, 8
+    assert pk.default_state_block_h(h // g, p, n) == h // g
+
+    def step(state, x, b, c, dt, a, lens, fresh):
+        return pk.ssd_state_step(state, x, b, c, dt, a, lens, fresh,
+                                 interpret=False)
+
+    text = compile_for_chip(
+        step, ((s, h, p, n), F32), ((s, h, p), F32),
+        *[((s, g, n), F32)] * 2, ((s, h), F32), ((h,), F32), ((s,), I32),
+        ((s,), jnp.bool_))
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "output_to_operand_aliasing" in call, call[:300]
+
+
+def test_think_decode_program(think_engine, one_chip, monkeypatch):
+    """The decode program of the cell's three kinds of layer, compiled
+    for the chip: the attention layer's bounded read, the Mamba-2 layer's
+    grouped state step and the routed experts' two grouped products in
+    the latent space (four kernels in the step's body); the 268 MB
+    float32 state leaf is updated where it lies: no ``copy`` of it, not
+    staged through fast memory, and NO fusion takes it as an operand."""
+    eng = think_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    args = _abstract([eng._params, eng._aux, eng._caches, eng._state],
+                     one_chip)
+    compiled = jax.jit(eng._make_step(), donate_argnums=(2, 3)) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    state = eng._caches[0][0]
+    assert state.shape == (64, 128, 64, 128) and state.dtype == F32
+    dims = ",".join(str(d) for d in state.shape)
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert dims not in copies, copies
+    staged = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"\b(copy-start|copy-done|slice-start)\(", line)
+              and re.search(r"f32\[[\d,]*128,64,128\]\{[^}]*S\(1\)", line)]
+    assert not staged, staged
+    leaf = "f32[%s]" % dims
+    fused = [line.strip()[:200] for line in text.splitlines()
+             if leaf in line
+             and re.search(r"fused_computation|\bfusion\(", line)]
+    assert not fused, fused
+    cache_bytes = sum(x.nbytes for x in
+                      jax.tree_util.tree_leaves(eng._caches))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.1 * cache_bytes
+
+
+def test_think_prefill_program(think_engine, one_chip, monkeypatch):
+    """The largest prefill program of the cell (the 512-token bucket),
+    compiled for the chip: the grouped chunked SSD in four chunks of 128,
+    the flash read and the routed experts in four passes of 128 tokens
+    (2,816 token-expert pairs a pass) compile, the temporaries stay under
+    0.5 GB and no buffer the compiler keeps in fast memory is over 64 MiB
+    of the chip's 128: a pass of 256 tokens kept its first products,
+    float32 [7680, 2688], there (79 MiB), and the routed layout of 20,480
+    pairs at once was 112 MiB there and that prefill program never came
+    back (PERF.md section 6)."""
+    from mxnet_tpu.serving.engine import _raw_key
+    eng = think_engine
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    bucket = 512
+    fn = eng._prefill_fn(bucket)
+    i32 = np.int32
+    args = [eng._params, eng._aux, eng._caches, eng._state, i32(0),
+            jnp.zeros((1, bucket), I32), i32(0), i32(bucket),
+            np.bool_(True), np.float32(0), _raw_key(0), i32(-1), i32(8)]
+    compiled = jax.jit(fn, donate_argnums=(2, 3)) \
+        .lower(*_abstract(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    fast = [(int(np.prod([int(d) for d in dims.split(",")])) * width[kind],
+             kind, dims)
+            for kind, dims in re.findall(
+                r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]\{[^}]*S\(1\)", text)]
+    assert not fast or max(fast)[0] <= 64 << 20, max(fast)
